@@ -1,11 +1,14 @@
-"""Build-on-first-import for the native components.
+"""Build-on-first-use for the native components.
 
-Compiles <name>.cc into build/lib<name>.so with g++ (cached by source
-mtime; atomic rename so concurrently-importing worker processes never see
-a half-written library).
+Compiles <name>.cc into build/lib<name>-<hash of the source>.so with g++.
+The name carries the source's content hash, so a library is stale exactly
+when its source changed (file times mean nothing after a copy or a
+checkout), and the atomic rename means concurrently-importing worker
+processes never see a half-written library. build/ is not tracked by git.
 """
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -15,11 +18,13 @@ _BUILD_DIR = os.path.join(_HERE, "build")
 
 
 def build_library(name: str) -> str:
-    """Return the path to lib<name>.so, compiling if stale or missing."""
+    """Return the path to the library built from <name>.cc, compiling it
+    if no build of this exact source exists."""
     src = os.path.join(_HERE, f"{name}.cc")
-    out = os.path.join(_BUILD_DIR, f"lib{name}.so")
-    if (os.path.exists(out)
-            and os.path.getmtime(out) >= os.path.getmtime(src)):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_BUILD_DIR, f"lib{name}-{digest}.so")
+    if os.path.exists(out):
         return out
     os.makedirs(_BUILD_DIR, exist_ok=True)
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)

@@ -207,9 +207,9 @@ class LocalNodeProvider(NodeProvider):
         if tpus:
             env["RAY_TPU_CHIPS"] = str(tpus)
         else:
-            # CPU-only node types stay off the TPU plugin; TPU node
-            # types keep the real backend (their tpu_capable workers
-            # must see the chips).
+            # CPU-only node types are pinned to the CPU; TPU node types
+            # keep the ambient platform (the agent itself never opens a
+            # backend; it pins each worker it spawns).
             from ..util.jaxenv import subprocess_env_cpu  # noqa: PLC0415
             subprocess_env_cpu(env)
         cmd = [sys.executable, "-m", "ray_tpu.core.node",

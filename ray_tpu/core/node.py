@@ -611,9 +611,8 @@ class NodeAgent:
             # workers join the agent-local dispatch plane (two-level
             # scheduling) before they register with the driver
             env["RAY_TPU_AGENT_ADDR"] = self.agent_addr
-        if not tpu_capable:
-            from ..util.jaxenv import subprocess_env_cpu  # noqa: PLC0415
-            subprocess_env_cpu(env)
+        from ..util.jaxenv import subprocess_env_for_worker  # noqa: PLC0415
+        subprocess_env_for_worker(env, tpu_capable)   # one owner per chip
         self.workers[wid] = subprocess.Popen(
             [sys.executable, "-m", "ray_tpu.core.worker",
              self.driver_address, wid],

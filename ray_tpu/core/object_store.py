@@ -18,7 +18,9 @@ the reference's in-band "plasma promotion" threshold
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
+import subprocess
 import threading
 from typing import Any, Dict, Optional
 
@@ -434,9 +436,16 @@ class ChannelSegmentReader:
 
 
 def make_store(capacity_bytes: int, is_owner: bool):
-    """Return the best available store backend (native C++ if built)."""
+    """Return the native C++ store (built from source on first use), or
+    the Python ShmStore with a WARNING that says why: the compiler is
+    missing or failed, the library does not load, or the arena cannot be
+    mapped (a worker also lands here when its driver did)."""
     try:
         from .._native.store_binding import NativeStore  # noqa: PLC0415
         return NativeStore(capacity_bytes=capacity_bytes, is_owner=is_owner)
-    except Exception:
+    except (OSError, subprocess.SubprocessError, RuntimeError) as e:
+        logging.getLogger("ray_tpu.core.object_store").warning(
+            "native object store unavailable (%s: %s %s); using the "
+            "Python ShmStore", type(e).__name__, e,
+            (getattr(e, "stderr", "") or "")[-2000:])
         return ShmStore(capacity_bytes=capacity_bytes, is_owner=is_owner)

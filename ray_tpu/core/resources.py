@@ -74,17 +74,32 @@ def detect_tpu_topology(num_chips: Optional[int] = None) -> Dict[str, str]:
 
 
 def _detect_tpu_chips() -> int:
-    # Avoid importing jax here (heavy, and workers may be CPU-only); trust
-    # the environment first, mirroring reference TPU detection via env/
-    # metadata (python/ray/_private/accelerators/tpu.py).
+    """Count this host's chips WITHOUT initialising a JAX backend: a chip
+    belongs to one process, and the driver or node agent that counts must
+    never be that process (one owner per chip, util/jaxenv.py).
+
+    The count is the device files the TPU runtime itself opens —
+    `/dev/accel<N>` (v2-v4) and the VFIO groups `/dev/vfio/<N>` (v5e and
+    later). The TPU_* environment and sysfs describe the whole host even
+    where this machine was handed one chip of it, so they are not used.
+    A tree pinned off the TPU by JAX_PLATFORMS has no chips to schedule.
+    An unreadable /dev raises: a miscount must not look like "0 chips".
+    """
     env = knobs.get_int("RAY_TPU_CHIPS")
     if env is not None:   # 0 is a real override: force chipless
         return env
-    try:
-        import jax  # noqa: PLC0415
-        return sum(1 for d in jax.devices() if d.platform == "tpu")
-    except Exception:
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
         return 0
+    n = 0
+    for directory, prefix in (("/dev", "accel"), ("/dev/vfio", "")):
+        try:
+            names = os.listdir(directory)
+        except FileNotFoundError:
+            continue   # this host has no such driver
+        n += sum(1 for name in names if name.startswith(prefix)
+                 and name[len(prefix):].isdigit())
+    return n
 
 
 def _detect_memory_bytes() -> int:

@@ -1201,6 +1201,23 @@ class WorkerRuntime:
         return fn
 
 
+def _check_chip_owner() -> None:
+    """One owner per chip (util/jaxenv.py): an actor that was granted
+    chips and brought up a JAX backend in its constructor must be on the
+    TPU — it fails here instead of computing on another platform. An
+    actor that never touched JAX is left alone (no import, no backend)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    from jax._src import xla_bridge  # noqa: PLC0415
+    if (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "tpu"):
+        raise RuntimeError(
+            f"actor was granted TPU resources but its JAX backend is "
+            f"{jax.default_backend()!r} "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+
+
 def _check_spec_payload(spec) -> None:
     """Fail fast on a spec whose user payload could not be unpickled on
     THIS worker (protocol.py stamps `wire_error` instead of dropping
@@ -1851,7 +1868,11 @@ class WorkerLoop:
             _check_spec_payload(acspec)
             cls = serialization.loads_call(acspec.class_bytes)
             args, kwargs = _resolve_args(self.rt, acspec.args, acspec.kwargs)
+            self.rt.current_tpu_ids = list(
+                getattr(acspec, "tpu_ids", []) or [])
             self._actor_instance = cls(*args, **kwargs)
+            if self.rt.current_tpu_ids:
+                _check_chip_owner()
             if ckpt is not None and hasattr(self._actor_instance,
                                             "__ray_restore__"):
                 # restart of a checkpointing actor: the constructor ran
@@ -1866,8 +1887,6 @@ class WorkerLoop:
                     actor_id=acspec.actor_id, worker_id=self.worker_id)
             self._actor_spec = acspec
             self.rt.current_actor_id = acspec.actor_id
-            self.rt.current_tpu_ids = list(
-                getattr(acspec, "tpu_ids", []) or [])
             groups = getattr(acspec, "concurrency_groups", None) or {}
             if acspec.max_concurrency > 1 or groups:
                 self._actor_pool = ThreadPoolExecutor(

@@ -123,7 +123,7 @@ def error_cause_is(exc: BaseException, *names: str) -> bool:
 
 def classify_request_failure(exc: BaseException) -> str:
     """Symbolic failure class of a serve request, shared by every
-    ingress so the retriable/shed/timeout taxonomy can't drift between
+    ingress so the retriable/shed/timeout classification can't drift between
     proxies: "backpressure" (client should back off), "no_capacity"
     (all replicas saturated; retriable), "shed" (deadline expired
     before execution; retriable), "timeout" (executed but blew the

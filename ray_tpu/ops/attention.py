@@ -10,7 +10,7 @@ dependency in its model code (e.g. rllib models and train examples).
 """
 from __future__ import annotations
 
-import logging
+import functools
 from typing import Optional
 
 import jax
@@ -18,58 +18,9 @@ import jax.numpy as jnp
 
 from ..util import knobs
 
-logger = logging.getLogger("ray_tpu.ops.attention")
-
 
 def causal_attention_mask(seq_len: int, dtype=jnp.bool_) -> jax.Array:
     return jnp.tril(jnp.ones((seq_len, seq_len), dtype=dtype))
-
-
-# signature -> bool: does the Pallas flash kernel lower on this backend?
-_PALLAS_LOWER_CACHE: dict = {}
-
-
-def pallas_flash_lowers(q, k, v, causal: bool,
-                        scale: Optional[float]) -> bool:
-    """Compile-check the Pallas flash kernel (forward AND backward) for
-    this shape signature, once, off to the side of any surrounding trace.
-
-    A Mosaic lowering failure must degrade to the XLA path with a warning
-    — never kill the surrounding train/serve step (a single kernel bug
-    zeroed the round-2 headline bench). Both directions are probed because
-    whether the caller will take grads is unknowable at trace time and a
-    fwd-ok/bwd-broken split would die mid-train; the extra compile is
-    once per shape signature.
-    """
-    key = (q.shape, k.shape, str(q.dtype), str(k.dtype), bool(causal))
-    hit = _PALLAS_LOWER_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if jax.default_backend() != "tpu":
-        # interpret mode: no Mosaic lowering to fail
-        _PALLAS_LOWER_CACHE[key] = True
-        return True
-    from .pallas.flash_attention import flash_attention  # noqa: PLC0415
-
-    def probe(q, k, v):
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=causal, scale=scale)
-            return out.astype(jnp.float32).sum()
-        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    try:
-        abstract = [jax.ShapeDtypeStruct(x.shape, x.dtype)
-                    for x in (q, k, v)]
-        jax.jit(probe).lower(*abstract).compile()
-        ok = True
-    except Exception as exc:  # Mosaic/XLA lowering errors are varied
-        logger.warning(
-            "Pallas flash attention failed to lower for q=%s k=%s "
-            "(%s: %s); falling back to the XLA path for this signature.",
-            q.shape, k.shape, type(exc).__name__, exc)
-        ok = False
-    _PALLAS_LOWER_CACHE[key] = ok
-    return ok
 
 
 def _resolve_impl(impl: str, q: jax.Array, k: jax.Array, causal: bool,
@@ -247,19 +198,15 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # contiguous gather temp, and work scales with real sequence
     # lengths. RAY_TPU_PAGED_ATTN_IMPL: auto|gather|pallas.
     impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
-    if s == 1 and impl != "gather":
-        on_tpu = jax.default_backend() == "tpu"
-        if impl == "pallas" or on_tpu:
-            from .pallas.paged_attention import (  # noqa: PLC0415
-                paged_decode_attention, paged_decode_lowers)
-            if impl == "pallas" or paged_decode_lowers(
-                    q[:, 0], k_flat, page_table, page_size):
-                out = paged_decode_attention(
-                    q[:, 0], k_flat, v_flat, page_table, new_lengths,
-                    page_size, qpos=positions[:, 0], scale=scale,
-                    interpret=not on_tpu)
-                return out[:, None], PagedKV(
-                    k_flat, v_flat, page_table, new_lengths, page_size)
+    if s == 1 and impl != "gather" and (
+            impl == "pallas" or jax.default_backend() == "tpu"):
+        from .pallas.paged_attention import (  # noqa: PLC0415
+            paged_decode_attention)
+        out = paged_decode_attention(
+            q[:, 0], k_flat, v_flat, page_table, new_lengths,
+            page_size, qpos=positions[:, 0], scale=scale)
+        return out[:, None], PagedKV(
+            k_flat, v_flat, page_table, new_lengths, page_size)
 
     # gather each sequence's contiguous KV view from its pages
     gather_idx = (page_table[:, :, None] * page_size
@@ -281,15 +228,22 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     Returns (B, Sq, Hq, D).
     """
-    explicit_pallas = impl == "pallas"
     impl = _resolve_impl(impl, q, k, causal, segment_ids)
-    # Explicitly-requested pallas runs unconditionally (a lowering bug
-    # must surface to the caller, not hide behind a silent fallback);
-    # only the "auto" route degrades to XLA when the probe fails.
-    if impl == "pallas" and (explicit_pallas
-                             or pallas_flash_lowers(q, k, v, causal, scale)):
+    if impl == "pallas":
+        # no fallback: a kernel Mosaic refuses fails the caller's compile
+        from ..parallel.sharding import attention_shard_spec  # noqa: PLC0415
         from .pallas.flash_attention import flash_attention  # noqa: PLC0415
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        flash = functools.partial(flash_attention, causal=causal,
+                                  scale=scale)
+        sharded = attention_shard_spec(k.shape)
+        if sharded is None:
+            return flash(q, k, v)
+        # inside a sharded train step the kernel runs per device on its
+        # share of batch and heads (GQA groups stay whole: q and kv
+        # heads split over tp at the same boundaries)
+        mesh, spec = sharded
+        return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     if impl == "dpa":
         # jax.nn.dot_product_attention: XLA's own fused attention,
         # which on TPU can lower to the compiler's flash kernel —

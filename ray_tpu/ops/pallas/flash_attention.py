@@ -27,8 +27,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...util import knobs
-from ...util.jax_compat import pallas_tpu_compiler_params \
-    as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -139,7 +137,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -281,7 +279,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -304,7 +302,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -358,7 +356,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.default_backend() == "cpu"
 
     def flat(x):
         return x.transpose(0, 2, 1, 3).reshape(b * hq, x.shape[1], d)

@@ -2,7 +2,7 @@
 
 The serve engine's paged cache (ops/attention.py:PagedKV) stores KV as
 flat token rows in a shared pool with per-sequence page tables. The
-XLA fallback path gathers each sequence's pages into a contiguous
+XLA gather path gathers each sequence's pages into a contiguous
 (S, L, Hkv, D) view per layer per decode step — correct, but it
 materializes L*page_size rows of temp HBM traffic per layer even when
 sequences are short. This kernel reads the pages DIRECTLY:
@@ -34,14 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...util.jax_compat import pallas_tpu_compiler_params \
-    as _CompilerParams
-
 NEG_INF = -1e30
-
-# signature -> bool compile-probe cache (mirrors flash_attention's
-# pallas_flash_lowers: Mosaic failures degrade to the gather path)
-_LOWER_CACHE: dict = {}
 
 
 def _decode_kernel(pt_ref, len_ref, qpos_ref, q_ref, k_ref, v_ref,
@@ -114,13 +107,14 @@ def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
                            page_size: int,
                            qpos=None,
                            scale: "float | None" = None,
-                           interpret: bool = False):
+                           interpret: "bool | None" = None):
     """q: (S, Hq, D) one decode token per sequence (cache already holds
     its KV); k_flat/v_flat: (N_flat, Hkv, D) page pools; page_table:
     (S, P) int32; lengths: (S,) int32 — keys valid at positions
     < lengths. qpos: (S,) int32 query positions (causal bound: keys at
     positions <= qpos attend; default lengths-1, the decode-at-end
-    case). Returns (S, Hq, D)."""
+    case). interpret defaults to True only on the CPU backend.
+    Returns (S, Hq, D)."""
     s_n, hq, d = q.shape
     n_flat, hkv, _ = k_flat.shape
     assert n_flat % page_size == 0, (n_flat, page_size)
@@ -133,6 +127,8 @@ def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
     P = page_table.shape[1]
     if qpos is None:
         qpos = lengths - 1
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, page_size=page_size,
@@ -160,43 +156,8 @@ def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, hq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, jnp.asarray(qpos, jnp.int32), q, kp, vp)
 
-
-def paged_decode_lowers(q, k_flat, page_table, page_size: int) -> bool:
-    """Compile-probe the kernel once per shape signature; a Mosaic
-    failure degrades the engine to the XLA gather path with a warning
-    instead of killing the decode step (same contract as
-    flash_attention.pallas_flash_lowers)."""
-    key = (q.shape, str(q.dtype), k_flat.shape, str(k_flat.dtype),
-           page_table.shape, page_size)
-    hit = _LOWER_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if jax.default_backend() != "tpu":
-        _LOWER_CACHE[key] = True
-        return True
-    import logging
-    try:
-        abstract = [
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(k_flat.shape, k_flat.dtype),
-            jax.ShapeDtypeStruct(k_flat.shape, k_flat.dtype),
-            jax.ShapeDtypeStruct(page_table.shape, jnp.int32),
-            jax.ShapeDtypeStruct((q.shape[0],), jnp.int32),
-        ]
-        jax.jit(functools.partial(
-            paged_decode_attention, page_size=page_size)).lower(
-            *abstract).compile()
-        ok = True
-    except Exception as exc:  # Mosaic/XLA lowering errors are varied
-        logging.getLogger("ray_tpu.ops.pallas.paged").warning(
-            "paged decode kernel failed to lower for q=%s pool=%s "
-            "(%s: %s); using the XLA gather path.",
-            q.shape, k_flat.shape, type(exc).__name__, exc)
-        ok = False
-    _LOWER_CACHE[key] = ok
-    return ok

@@ -77,7 +77,7 @@ def fused_rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
     into the kernel grid.
     """
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = jax.default_backend() == "cpu"
     d = x.shape[-1]
     lead = x.shape[:-1]
     x2d = x.reshape(-1, d)

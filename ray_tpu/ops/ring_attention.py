@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..util.jax_compat import shard_map as _shard_map
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)  # finite: avoids inf-inf
 
@@ -113,7 +112,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(f"seq len {s} not divisible by {axis_name}={n}")
 
     spec = P(None, axis_name, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name,
                           n_chunks=n, causal=causal, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..util.jax_compat import shard_map as _shard_map
-
 
 def stack_stage_params(params_list):
     """Stack per-stage param pytrees along a new leading stage axis."""
@@ -102,7 +100,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x: jax.Array, *,
     batch_spec = P(None, tuple(data_axes) if data_axes else None)
     param_specs = jax.tree_util.tree_map(
         lambda a: P(axis_name), stacked_params)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn,
                           axis_name=axis_name, n_stages=n,
                           n_micro=n_microbatches),

@@ -195,6 +195,22 @@ def constrain_activations(x, *, seq_axis: Optional[str] = "sp"):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def attention_shard_spec(kv_shape: Tuple[int, ...]):
+    """(mesh, spec) for (B, S, H, D) attention operands under the
+    activation mesh — batch over (dp, fsdp), heads over tp, each only as
+    far as it divides the k/v shape — or None outside an activation_mesh
+    context and on a one-device mesh. For an op the SPMD partitioner
+    cannot see into (a Pallas call is refused outright: "Mosaic kernels
+    cannot be automatically partitioned"): the caller wraps it in
+    jax.shard_map with this spec, and each device runs the kernel on its
+    own rows and heads."""
+    mesh = _ACTIVATION_MESH[0]
+    if mesh is None or mesh.size == 1:
+        return None
+    return mesh, _clip_to_mesh(P(("dp", "fsdp"), None, "tp", None),
+                               kv_shape, mesh)
+
+
 def batch_sharding(mesh: Mesh, *, seq_axis: Optional[str] = "sp") -> NamedSharding:
     """Input batch (B, S, ...) sharded over data axes, seq over sp."""
     data = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)

@@ -898,6 +898,9 @@ class ServeController:
             resources.setdefault(
                 "CPU",
                 st.config.ray_actor_options.get("num_cpus", 1) or 1)
+            if st.config.ray_actor_options.get("num_tpus"):
+                resources.setdefault(
+                    "TPU", st.config.ray_actor_options["num_tpus"])
             pg_strategy = st.config.placement_group_strategy
             dep_name, app_name = st.name, st.app_name
 

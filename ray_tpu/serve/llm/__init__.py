@@ -192,14 +192,21 @@ def build_llm_deployment(model_factory, *, engine_config=None,
                          max_ongoing_requests: int = 32,
                          cached_prefixes=None,
                          server_cls=None, server_kwargs=None,
+                         ray_actor_options: Optional[dict] = None,
                          route_prefix: str = "/") -> Application:
     """Build a ready-to-run LLM serving app:
-    `serve.run(build_llm_deployment(factory))`. `server_cls` swaps the
+    `serve.run(build_llm_deployment(factory,
+    ray_actor_options={"num_tpus": 1}))`. `server_cls` swaps the
     deployment class (e.g. openai_api.OpenAIServer); `cached_prefixes`
-    registers shared prompt prefixes for engine prefix caching."""
+    registers shared prompt prefixes for engine prefix caching.
+
+    `ray_actor_options` is how a replica gets its chip: a replica whose
+    options request no TPU runs in a worker pinned to the CPU (one owner
+    per chip, util/jaxenv.py)."""
     dep = deployment_decorator(
         server_cls or LLMServer, name=name, num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=ray_actor_options,
         route_prefix=route_prefix)
     return dep.bind(model_factory, engine_config=engine_config,
                     tokenizer=tokenizer,

@@ -444,12 +444,16 @@ def build_openai_deployment(model_factory, *, engine_config=None,
                             num_replicas: int = 1,
                             route_prefix: str = "/v1",
                             cached_prefixes=None,
-                            max_ongoing_requests: int = 64) -> Application:
+                            max_ongoing_requests: int = 64,
+                            ray_actor_options: Optional[dict] = None
+                            ) -> Application:
     """An Application serving /v1/completions + /v1/chat/completions.
 
     cached_prefixes: shared prompt prefixes (e.g. the system prompt's
     token ids or text) prefilled once at startup; any request starting
-    with one adopts its KV instead of re-prefilling (prefix caching)."""
+    with one adopts its KV instead of re-prefilling (prefix caching).
+    ray_actor_options: the replica's actor options — pass
+    {"num_tpus": 1} to put the engine on a chip (build_llm_deployment)."""
     engine_config = dict(engine_config or {})
     # the completions `logprobs` field needs the engine to fetch them
     engine_config.setdefault("logprobs", True)
@@ -460,6 +464,7 @@ def build_openai_deployment(model_factory, *, engine_config=None,
         cached_prefixes=cached_prefixes,
         server_cls=OpenAIServer,
         server_kwargs={"model_name": model_name},
+        ray_actor_options=ray_actor_options,
         route_prefix=route_prefix)
 
 

@@ -64,11 +64,7 @@ class _SpmdHost:
             # (the env var alone is not read by this jax version).
             impl = os.environ.get(
                 "JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", impl)
-            except Exception:  # noqa: BLE001 — older/newer jax: best effort
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", impl)
         jax.distributed.initialize(coordinator, num_processes=self.world,
                                    process_id=self.rank)
         return {"rank": self.rank, "world": self.world,

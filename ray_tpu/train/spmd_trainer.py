@@ -55,6 +55,7 @@ class SpmdTrainer:
         self.data_iter_fn = data_iter_fn
         self.run_config = run_config or RunConfig(name="spmd_trainer")
         self.report_fn = report_fn
+        self.state = self.step = None     # set by fit()
 
     def fit(self, resume_from: Optional[str] = None) -> Result:
         import jax
@@ -65,12 +66,15 @@ class SpmdTrainer:
         if isinstance(model, str):
             from ..models import get_model
             model = get_model(model)
+        from ..util.jaxenv import enable_compile_cache
+        enable_compile_cache()
         devices = jax.devices()
         spec = cfg.mesh
-        if spec.size != len(devices):
-            # single-host convenience: use however many devices exist
-            spec = MeshSpec(dp=len(devices)) if len(devices) > 1 else MeshSpec()
-        mesh = build_mesh(spec, devices=devices[:spec.size])
+        if spec == MeshSpec():
+            # only the untouched default adapts to the devices present;
+            # a mesh the user wrote must fit them (build_mesh raises)
+            spec = MeshSpec(dp=len(devices))
+        mesh = build_mesh(spec, devices=devices)
 
         schedule = warmup_cosine(cfg.learning_rate, cfg.warmup_steps,
                                  cfg.total_steps)
@@ -127,6 +131,9 @@ class SpmdTrainer:
         final_ckpt = None
         if cfg.checkpoint_every:
             final_ckpt = manager.save(jax.device_get(state), cfg.total_steps)
+        # the sharded TrainState and the SpmdStep that produced it, for
+        # callers that go on (eval, export, inspecting the layout)
+        self.state, self.step = state, step_fn
         return Result(metrics=history[-1] if history else {},
                       checkpoint=final_ckpt or manager.latest(),
                       metrics_history=history,

@@ -90,16 +90,15 @@ def test_flash_gradients_bf16_finite():
         assert np.isfinite(np.asarray(a, np.float32)).all()
 
 
-def test_pallas_lowering_failure_falls_back_to_xla(monkeypatch):
-    """A Mosaic lowering failure must degrade to the XLA path, never kill
-    the step (round-2 regression: one kernel bug zeroed the bench)."""
+def test_pallas_lowering_failure_surfaces(monkeypatch):
+    """When the auto route picks the Pallas kernel and Mosaic refuses it,
+    the error reaches the caller: no quiet XLA stand-in."""
+    import importlib
+
     import ray_tpu.ops.attention as attn_mod
+    fa_mod = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
 
     monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(attn_mod, "_PALLAS_LOWER_CACHE", {})
-
-    import importlib
-    fa_mod = importlib.import_module("ray_tpu.ops.pallas.flash_attention")
 
     def boom(*a, **kw):
         raise RuntimeError("Mosaic lowering failed (simulated)")
@@ -107,15 +106,40 @@ def test_pallas_lowering_failure_falls_back_to_xla(monkeypatch):
     monkeypatch.setattr(fa_mod, "flash_attention", boom)
 
     rng = np.random.RandomState(5)
-    # seq >= 2048: the only regime where "auto" still prefers pallas
+    # seq >= 2048: the only regime where "auto" prefers pallas
     q, k, v = _rand_qkv(rng, 1, 2048, 2048, 1, 1, 16)
-    out = attn_mod.multi_head_attention(q, k, v, causal=True, impl="auto")
-    ref = attn_mod.multi_head_attention(q, k, v, causal=True, impl="xla")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
-    # and the verdict is cached as "broken" for this signature
-    key = next(iter(attn_mod._PALLAS_LOWER_CACHE))
-    assert attn_mod._PALLAS_LOWER_CACHE[key] is False
+    with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
+        attn_mod.multi_head_attention(q, k, v, causal=True, impl="auto")
+
+
+def test_pallas_attention_inside_sharded_step_matches_xla():
+    """Under an activation mesh the Pallas kernel runs inside shard_map
+    (XLA refuses to partition a Mosaic call): batch over fsdp, heads over
+    tp with GQA groups whole — values and gradients equal the XLA path."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import activation_mesh
+
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=jax.devices()[:4])
+    rng = np.random.RandomState(6)
+    q, k, v = jax.device_put(
+        _rand_qkv(rng, 4, 64, 64, 4, 2, 16),
+        NamedSharding(mesh, P("fsdp", None, "tp", None)))
+
+    def grads(impl):
+        def loss(q, k, v):
+            with activation_mesh(mesh):
+                out = multi_head_attention(q, k, v, causal=True, impl=impl)
+            return (out ** 2).sum(), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))(q, k, v)
+
+    (_, out), g = grads("pallas")
+    (_, ref), g_ref = grads("xla")
+    assert out.sharding.spec == P("fsdp", None, "tp", None)
+    for a, b in zip((out, *g), (ref, *g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.slow

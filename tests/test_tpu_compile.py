@@ -1,0 +1,90 @@
+"""The main path's Pallas kernels compile for the real chip — with no chip.
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+chip that is described, not attached (on-chip-measurement guide §2.3).
+Interpret-mode tests cannot see what Mosaic refuses (tiling, VMEM
+budget); these can, at Llama-3.2-1B and Llama-3-8B attention widths, at
+a few seconds in all. Nothing runs, so nothing here is a result or a time.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops.pallas.flash_attention import flash_attention
+from ray_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+WIDTHS = {"llama1b": (32, 8, 64), "llama8b": (32, 8, 128)}  # Hq, Hkv, D
+SEQ = 2048
+SLOTS = 8
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip; the persistent
+    compile cache is off around the module (an entry written without a
+    chip cannot be read back and only warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _flash_bwd(q, k, v):
+    def loss(q, k, v):
+        return _flash_fwd(q, k, v).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_mosaic(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+@pytest.mark.parametrize("fn", [_flash_fwd, _flash_bwd],
+                         ids=["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(chip, fn, width):
+    hq, hkv, d = WIDTHS[width]
+    q = jax.ShapeDtypeStruct((1, SEQ, hq, d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, SEQ, hkv, d), jnp.bfloat16,
+                              sharding=chip)
+    _assert_mosaic(jax.jit(fn).lower(q, kv, kv).compile())
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+@pytest.mark.parametrize("page_size", [16, 64])
+def test_paged_decode_compiles_for_v5e(chip, page_size, width):
+    hq, hkv, d = WIDTHS[width]
+    pages = SEQ // page_size
+    n_flat = (SLOTS * pages + 1) * page_size
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(q, k_flat, v_flat, page_table, lengths):
+        return paged_decode_attention(q, k_flat, v_flat, page_table,
+                                      lengths, page_size, interpret=False)
+
+    _assert_mosaic(jax.jit(decode).lower(
+        sds((SLOTS, hq, d), jnp.bfloat16),
+        sds((n_flat, hkv, d), jnp.bfloat16),
+        sds((n_flat, hkv, d), jnp.bfloat16),
+        sds((SLOTS, pages), jnp.int32),
+        sds((SLOTS,), jnp.int32)).compile())

@@ -1,12 +1,11 @@
 """On-hardware kernel tests: run on the REAL TPU backend, interpret=False.
 
 Unlike tests/, this suite does NOT force CPU — it exists precisely to
-exercise Mosaic lowering, the blind spot that let the round-2 flash
-kernel ship with a tiling bug no interpret-mode test could catch.
+exercise Mosaic lowering, which no interpret-mode test can see.
 Everything here skips unless jax.default_backend() == "tpu".
 
 Run: python -m pytest tests_tpu/ -x -q   (on a TPU host)
-bench.py also runs the same checks as its kernel-smoke phase.
+chip_smoke.py and bench.py's kernels phase run the same kind of check.
 """
 import os
 import sys
@@ -16,16 +15,7 @@ sys.path.insert(0, _REPO)
 
 
 def pytest_configure(config):
-    # Persistent compilation cache (TPU-only, same dir bench.py uses):
-    # the first full tests_tpu run burned its entire 2400 s sweep budget
-    # on cold Mosaic/XLA compiles (2026-07-31); cached, a rerun is
-    # minutes. CPU is excluded — XLA:CPU AOT entries embed host CPU
-    # features and can SIGILL on a different machine.
-    import jax
-
-    if jax.default_backend() == "tpu":
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                           os.path.join(_REPO, ".jax_cache")))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # Cold Mosaic/XLA compiles dominate a first run of this suite; the
+    # cache placement rule is the program's own (CPU left alone).
+    from ray_tpu.util.jaxenv import enable_compile_cache
+    enable_compile_cache()
