@@ -1,0 +1,8 @@
+"""1 - (union of the device's operation intervals) / traced window."""
+
+
+def read(run, **_):
+    tr = run.get("trace")
+    if not tr or not tr.get("window_s"):
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
