@@ -145,7 +145,7 @@ class HTTPProxy:
                     return result, "text/plain"
                 return json.dumps(result), "application/json"
 
-            def _handle_asgi(self, handle, prefix):
+            def _handle_asgi(self, handle, prefix, recv_ts):
                 """serve.ingress(app) route: ship the RAW request to the
                 replica (the ASGI wrapper drives the app there) and
                 relay its streamed response — start item first, then
@@ -169,7 +169,7 @@ class HTTPProxy:
                 try:
                     gen = handle.options(stream=True).remote(
                         request, __serve_deadline_ts=self._deadline(),
-                        **self._affinity_kw())
+                        __serve_recv_ts=recv_ts, **self._affinity_kw())
                     for item in gen:
                         if isinstance(item, dict) and item.get(START_KEY):
                             status = item["status"]
@@ -218,13 +218,16 @@ class HTTPProxy:
                         gen.close()
 
             def _handle(self):
+                # receipt: rides to the replica beside the deadline
+                # (`request.ingress` in the LLM engine's spans)
+                recv_ts = time.time()
                 handle, prefix, is_asgi = self._match()
                 if handle is None:
                     self._respond(404, json.dumps(
                         {"error": f"no route for {self.path}"}))
                     return
                 if is_asgi:
-                    self._handle_asgi(handle, prefix)
+                    self._handle_asgi(handle, prefix, recv_ts)
                     return
                 try:
                     body = self._body()
@@ -245,6 +248,7 @@ class HTTPProxy:
                     if wants_stream:
                         gen = handle.options(stream=True).remote(
                             body, __serve_deadline_ts=deadline_ts,
+                            __serve_recv_ts=recv_ts,
                             **self._affinity_kw())
                         self.send_response(200)
                         self.send_header("Content-Type",
@@ -270,6 +274,7 @@ class HTTPProxy:
                     else:
                         result = handle.remote(
                             body, __serve_deadline_ts=deadline_ts,
+                            __serve_recv_ts=recv_ts,
                             **self._affinity_kw()
                         ).result(timeout_s=(
                             None if deadline_ts is None

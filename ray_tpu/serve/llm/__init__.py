@@ -127,7 +127,7 @@ class LLMServer:
         """Unary or streaming generate. body: {"prompt": [ids] | str,
         "max_tokens": int, "temperature": float, "top_p": float,
         "stop_token_ids": [ids], "stream": bool}."""
-        from ..context import get_request_deadline
+        from ..context import get_request_deadline, get_request_recv_ts
         prompt, prefix_id = self._match_prefix(
             self._encode(body["prompt"]))
         max_tokens = body.get("max_tokens")
@@ -137,7 +137,8 @@ class LLMServer:
             top_p=float(body.get("top_p", 1.0)),
             stop_token_ids=body.get("stop_token_ids"),
             prefix_id=prefix_id,
-            deadline_ts=get_request_deadline())
+            deadline_ts=get_request_deadline(),
+            recv_ts=get_request_recv_ts())
         if body.get("stream"):
             def gen():
                 for tok in self.engine.stream(rid):

@@ -25,6 +25,12 @@ paged KV, streaming) — re-designed TPU-first:
   sampled on-device inside the prefill and drains through the same
   pipeline. Termination decisions lag by `pipeline_depth` steps — at
   most that many wasted (discarded) tokens per finished request.
+* Spans and counters (observability/profiler.py:SpanTable, always on):
+  the loop's phases are `engine.*` spans on the engine thread — self
+  times in get_stats()["spans"], annotations in a profiler capture —
+  and each request's stamps (`request.*`, `slot.refill`,
+  `stream.deliver`) are table rows with no annotation, so that a
+  per-stream event never takes a device idle gap from the loop's.
 """
 from __future__ import annotations
 
@@ -38,7 +44,19 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ...observability.profiler import SpanTable
 from ...util import knobs
+
+# every phase of _engine_loop; seeded in the table so that a reader of
+# get_stats()["spans"] finds each key whether or not the phase ran
+_LOOP_SPANS = ("engine.loop", "engine.control", "engine.admit",
+               "engine.prefill_dispatch", "engine.chunk_dispatch",
+               "engine.decode_prep", "engine.decode_dispatch",
+               "engine.drain_wait", "engine.emit", "engine.bookkeep",
+               "engine.idle_sleep")
+_REQUEST_SPANS = ("request.ingress", "request.inflight_prefill",
+                  "request.inflight_decode", "slot.refill",
+                  "stream.deliver")
 
 
 @dataclass
@@ -53,8 +71,10 @@ class LLMEngineConfig:
     # steady-state step period is roughly fetch_latency/(depth+1) (each
     # iteration drains the entry dispatched `depth` steps ago), so depth
     # trades termination lag (≤ depth*decode_block discarded tokens per
-    # finished request) against hiding device->host latency (not
-    # measured on the chip yet: ROADMAP A3).
+    # finished request) against hiding device->host latency. Measured
+    # on a v5e (PERF.md, PR 24): at 10 a first token waits ~195 ms for
+    # its turn, 3-7 % of decode rows are discarded, and the fetch never
+    # blocks (the host loop is the slower side): ROADMAP A3.
     pipeline_depth: int = 10
     # Decode steps fused into ONE dispatch via lax.scan: each dispatch
     # emits decode_block tokens per slot, dividing per-token host work
@@ -165,6 +185,9 @@ class _Request:
     # whose deadline expires while still QUEUED is shed at admission
     # (DeadlineExceededError) instead of executed
     deadline_ts: Optional[float] = None
+    # when the serve proxy received the HTTP request (its clock);
+    # None for a direct submit
+    recv_ts: Optional[float] = None
 
 
 _END = ("__end__", None)
@@ -342,7 +365,21 @@ class LLMEngine:
         # prompt+budget at admission, so mid-stream KV eviction (vLLM's
         # preemption trigger) cannot occur by construction
         self.stats = {"prefills": 0, "decode_steps": 0,
-                      "tokens_generated": 0, "prefix_tokens_saved": 0}
+                      "tokens_generated": 0, "prefix_tokens_saved": 0,
+                      # decode rows: steps x max_slots of them ran; a
+                      # row's token is emitted, or discarded (its slot
+                      # was released, reused or over budget: the lag of
+                      # pipeline_depth), or the slot was empty
+                      "decode_slot_steps": 0, "decode_tokens_emitted": 0,
+                      "decode_tokens_discarded": 0,
+                      # prefill programs: rows and tokens asked for
+                      # against what the padded (bucket x group) call ran
+                      "prefill_calls": 0, "prefill_rows_real": 0,
+                      "prefill_rows_padded": 0, "prefill_tokens_real": 0,
+                      "prefill_tokens_padded": 0, "prefill_shapes": {}}
+        self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
+        self._slot_freed_ns: Dict[int, int] = {}    # slot -> _release
+        self._decode_dispatches = 0     # the `step` of a decode's span
         # TTFT breakdown (VERDICT r4 ask): queue wait vs prefill
         # dispatch (compile on a bucket's first use) vs emit lag.
         self._ttft_samples: collections.deque = collections.deque(
@@ -1070,7 +1107,8 @@ class LLMEngine:
                presence_penalty: float = 0.0,
                frequency_penalty: float = 0.0,
                logit_bias: Optional[dict] = None,
-               deadline_ts: Optional[float] = None) -> str:
+               deadline_ts: Optional[float] = None,
+               recv_ts: Optional[float] = None) -> str:
         """guided_fsm: a serve.llm.guided.TokenFSM constraining this
         request's output (per-step vocab masks; EOS only at accepting
         states). Guided traffic decodes synchronously (pipeline drains
@@ -1080,7 +1118,12 @@ class LLMEngine:
         the serve plane). A deadline that already cannot be met is
         rejected HERE — before any queueing — and one that expires
         while queued is shed at admission, both with
-        DeadlineExceededError."""
+        DeadlineExceededError.
+
+        recv_ts: when the serve proxy received the request (epoch
+        seconds on the proxy's clock); recorded as `request.ingress`
+        and `ttft_breakdown_p50_ms.ingress_ms`. Across hosts it is as
+        good as the two clocks agree."""
         from ...exceptions import DeadlineExceededError  # noqa: PLC0415
         if self.wedged:
             from ...exceptions import EngineWedgedError  # noqa: PLC0415
@@ -1160,7 +1203,7 @@ class LLMEngine:
                        frequency_penalty=float(frequency_penalty),
                        logit_bias=dict(logit_bias) if logit_bias
                        else None,
-                       deadline_ts=deadline_ts,
+                       deadline_ts=deadline_ts, recv_ts=recv_ts,
                        hist=(list(map(int, prompt))
                              if (self.cfg.ngram_speculation > 0
                                  and temperature == 0.0
@@ -1168,6 +1211,9 @@ class LLMEngine:
                                  and not (presence_penalty
                                           or frequency_penalty
                                           or logit_bias)) else None))
+        if recv_ts is not None:
+            self._spans.add("request.ingress",
+                            max(0, int((req.submit_ts - recv_ts) * 1e9)))
         with self._lock:
             self._requests[req.request_id] = req
         self._waiting.put(req)
@@ -1192,7 +1238,10 @@ class LLMEngine:
             # would kill legitimate multi-minute first-jit prefills
             kind, payload = req.out_queue.get()
             if kind == "token":
-                yield payload
+                tok, logp, put_ts = payload
+                self._spans.add("stream.deliver", max(0, int(
+                    (time.time() - put_ts) * 1e9)))
+                yield tok, logp
             elif kind == "error":
                 raise payload
             else:  # end
@@ -1316,7 +1365,9 @@ class LLMEngine:
 
     def get_stats(self) -> Dict[str, Any]:
         with self._lock:
-            out = {**self.stats, "device": dict(self.device),
+            out = {**self.stats,
+                   "prefill_shapes": dict(self.stats["prefill_shapes"]),
+                   "device": dict(self.device),
                    "active": len(self._active),
                    "waiting": self._waiting.qsize(),
                    "prefilling": len(self._prefilling),
@@ -1338,12 +1389,19 @@ class LLMEngine:
                 tpots[len(tpots) // 2] * 1000, 2)
         if samples:
             def p50(key):
-                vals = sorted(s[key] for s in samples)
-                return round(vals[len(vals) // 2], 1)
+                # ingress_ms is there only for requests a proxy stamped
+                vals = sorted(s[key] for s in samples if key in s)
+                return round(vals[len(vals) // 2], 1) if vals else None
+            medians = {k: p50(k) for k in (
+                "queue_ms", "prefill_dispatch_ms", "emit_ms", "total_ms",
+                "ingress_ms")}
             out["ttft_breakdown_p50_ms"] = {
-                k: p50(k) for k in ("queue_ms", "prefill_dispatch_ms",
-                                    "emit_ms", "total_ms")}
+                k: v for k, v in medians.items() if v is not None}
         out["prefill_compile_ms"] = dict(self._prefill_compile_ms)
+        out["spans"] = self._spans.snapshot()
+        compiles = self._spans.compiles()
+        out["compiles"] = {k: v[0] for k, v in compiles.items()}
+        out["compile_ns"] = {k: v[1] for k, v in compiles.items()}
         mem = self._jax.devices()[0].memory_stats()  # None on the CPU
         if mem:
             out["peak_device_bytes"] = mem.get("peak_bytes_in_use")
@@ -1468,6 +1526,17 @@ class LLMEngine:
             return False
         return n > self.cfg.prefill_chunk or n > self._largest_bucket()
 
+    def _take_slot(self, req: _Request) -> int:
+        """Admission into a free slot; `slot.refill` is how long the
+        slot stood empty since its last _release."""
+        slot = self._free_slots.pop()
+        req.slot = slot
+        req.admit_ts = time.time()
+        freed = self._slot_freed_ns.pop(slot, None)
+        if freed is not None:
+            self._spans.add("slot.refill", time.perf_counter_ns() - freed)
+        return slot
+
     def _admit_paged(self, req: _Request) -> str:
         """Paged admission: reserve pages + a slot. Returns "ok",
         "nopages" (hold the request), or "failed" (stream errored).
@@ -1498,9 +1567,7 @@ class LLMEngine:
             excl = self._alloc_pages(need_total - n_shared)
             if excl is None:
                 return "nopages"
-            slot = self._free_slots.pop()
-            req.slot = slot
-            req.admit_ts = time.time()
+            slot = self._take_slot(req)
             if plen % ps:
                 try:
                     self._pools = self._copy_page_jit(
@@ -1525,9 +1592,7 @@ class LLMEngine:
         pages = self._alloc_pages(need_total)
         if pages is None:
             return "nopages"
-        slot = self._free_slots.pop()
-        req.slot = slot
-        req.admit_ts = time.time()
+        slot = self._take_slot(req)
         self._slot_pages[slot] = (0, pages)
         self._set_page_row(slot, pages)
         # reset the slot's device length NOW: a reused slot's stale
@@ -1591,9 +1656,7 @@ class LLMEngine:
                     taken.append((self._bucket(req.prompt.size), req,
                                   req.slot))
                 continue
-            slot = self._free_slots.pop()
-            req.slot = slot
-            req.admit_ts = time.time()
+            slot = self._take_slot(req)
             self._event("llm_engine.request_admit", req=req, slot=slot,
                         prompt_len=int(req.prompt.size))
             if req.prefix_id >= 0:
@@ -1633,8 +1696,18 @@ class LLMEngine:
         cap = max(1, self.cfg.max_prefill_batch)
         for pad_len, members in groups.items():
             for i in range(0, len(members), cap):
-                self._dispatch_prefill(inflight, pad_len,
-                                       members[i:i + cap])
+                group = members[i:i + cap]
+                with self._spans.span(
+                        "engine.prefill_dispatch", bucket=pad_len,
+                        group=len(group),
+                        group_padded=self._group_rows(len(group))):
+                    self._dispatch_prefill(inflight, pad_len, group)
+
+    def _group_rows(self, n: int) -> int:
+        """Rows of the prefill program that serves a group of n."""
+        if not self._paged and self.cfg.max_prefill_batch <= 1:
+            return 1
+        return _next_pow2(n)
 
     def _dispatch_prefill(self, inflight, pad_len: int, members) -> None:
         """One prefill call for `members` = [(req, slot), ...] of a
@@ -1642,6 +1715,7 @@ class LLMEngine:
         rows) so compile count stays O(buckets * log2(cap))."""
         jnp = self._jnp
         g_real = len(members)
+        g = self._group_rows(g_real)
         t_dispatch = time.time()
         try:
             self._rng_key, sub = self._jax.random.split(self._rng_key)
@@ -1649,7 +1723,6 @@ class LLMEngine:
                 # unified single/batched paged prefill: pad group size
                 # to a power of two; padding rows hit the scratch slot
                 # whose page row is all-trash
-                g = _next_pow2(g_real)
                 tokens = np.zeros((g, pad_len), np.int32)
                 slots = np.full((g,), self._scratch_slot, np.int32)
                 lens = np.ones((g,), np.int32)
@@ -1693,7 +1766,6 @@ class LLMEngine:
                     jnp.float32(req.top_p), sub, pad_len=pad_len, **kw)
                 toks_dev, lps_dev = tok_dev[None], lp_dev[None]
             else:
-                g = _next_pow2(g_real)
                 tokens = np.zeros((g, pad_len), np.int32)
                 slots = np.full((g,), self._scratch_slot, np.int32)
                 lens = np.ones((g,), np.int32)
@@ -1735,6 +1807,8 @@ class LLMEngine:
         # first dispatch of a bucket blocks on its jit compile: record it
         self._prefill_compile_ms.setdefault(pad_len, round(dispatch_ms, 1))
         self.stats["prefills"] += g_real
+        self._count_prefill(pad_len, g_real, g, sum(
+            int(req.prompt.size) for req, _ in members))
         for req, slot in members:
             req.prefill_dispatch_ms = dispatch_ms
             if self._paged:
@@ -1746,7 +1820,21 @@ class LLMEngine:
         if self.cfg.logprobs:
             self._start_fetch(lps_dev)
         inflight.append(("prefill_batch", [r for r, _ in members],
-                         toks_dev, lps_dev if self.cfg.logprobs else None))
+                         toks_dev, lps_dev if self.cfg.logprobs else None,
+                         time.perf_counter_ns()))
+
+    def _count_prefill(self, width: int, rows: int, rows_padded: int,
+                       tokens: int) -> None:
+        """One prefill program dispatched: `rows` prompts holding
+        `tokens` prompt tokens ran as `rows_padded` x `width`."""
+        st = self.stats
+        st["prefill_calls"] += 1
+        st["prefill_rows_real"] += rows
+        st["prefill_rows_padded"] += rows_padded
+        st["prefill_tokens_real"] += tokens
+        st["prefill_tokens_padded"] += rows_padded * width
+        shape = f"{width}x{rows_padded}"
+        st["prefill_shapes"][shape] = st["prefill_shapes"].get(shape, 0) + 1
 
     def _dispatch_chunk(self, inflight) -> None:
         """Advance the oldest chunk-prefilling request by ONE chunk. The
@@ -1803,6 +1891,7 @@ class LLMEngine:
         if self._paged:
             self._disp_len[req.slot] = req.prefill_pos
         req.prefill_dispatch_ms += (time.time() - t_dispatch) * 1000
+        self._count_prefill(C, 1, 1, true)
         self._progress_ts = time.time()   # watchdog: chunk advanced
         if is_last:
             self._prefilling.popleft()
@@ -1816,7 +1905,8 @@ class LLMEngine:
             if self.cfg.logprobs:
                 self._start_fetch(lps_dev)
             inflight.append(("prefill_batch", [req], toks_dev,
-                             lps_dev if self.cfg.logprobs else None))
+                             lps_dev if self.cfg.logprobs else None,
+                             time.perf_counter_ns()))
 
     @staticmethod
     def _start_fetch(arr):
@@ -1829,20 +1919,24 @@ class LLMEngine:
               logp: Optional[float] = None):
         req.generated += 1
         self.stats["tokens_generated"] += 1
-        self._progress_ts = time.time()   # watchdog: forward progress
-        m = self._m
-        m["tokens"].inc(1.0, tags=self._mtags)
+        # one clock read: the watchdog's forward progress, the first
+        # token's stamp, and the put time that rides with the token
+        # (stream_detailed takes `stream.deliver` from it)
+        now = self._progress_ts = time.time()
         if req.first_token_ts is None:
-            now = time.time()
             req.first_token_ts = now
             admit = req.admit_ts or req.submit_ts
-            self._ttft_samples.append({
+            sample = {
                 "queue_ms": (admit - req.submit_ts) * 1000,
                 "prefill_dispatch_ms": req.prefill_dispatch_ms,
                 "emit_ms": max(0.0, (now - admit) * 1000
                                - req.prefill_dispatch_ms),
-                "total_ms": (now - req.submit_ts) * 1000})
-            m["ttft"].observe(now - req.submit_ts, tags=self._mtags)
+                "total_ms": (now - req.submit_ts) * 1000}
+            if req.recv_ts is not None:
+                sample["ingress_ms"] = max(
+                    0.0, (req.submit_ts - req.recv_ts) * 1000)
+            self._ttft_samples.append(sample)
+            self._m["ttft"].observe(now - req.submit_ts, tags=self._mtags)
         if req.hist is not None:
             req.hist.append(tok)
         # Bounded-wait put: a FULL out_queue means the CONSUMER is slow
@@ -1856,7 +1950,8 @@ class LLMEngine:
         parked_since = None
         while True:
             try:
-                req.out_queue.put(("token", (tok, logp)), timeout=1.0)
+                req.out_queue.put(("token", (tok, logp, now)),
+                                  timeout=1.0)
                 break
             except queue_mod.Full:
                 if req.aborted:
@@ -1971,6 +2066,7 @@ class LLMEngine:
             if req.slot >= 0:
                 self._free_slot_pages(req.slot)
                 self._free_slots.append(req.slot)
+                self._slot_freed_ns[req.slot] = time.perf_counter_ns()
                 self._active.pop(req.slot, None)
                 self._mask_dirty = True
                 self._pen_coef_dirty = True
@@ -2201,9 +2297,10 @@ class LLMEngine:
         moot."""
         ne_dev, lp_dev = ne_lp
         try:
-            out = np.asarray(out_dev)
-            n_emit = np.asarray(ne_dev)
-            lps = np.asarray(lp_dev) if lp_dev is not None else None
+            with self._spans.span("engine.drain_wait"):
+                out = np.asarray(out_dev)
+                n_emit = np.asarray(ne_dev)
+                lps = np.asarray(lp_dev) if lp_dev is not None else None
         except BaseException as e:  # noqa: BLE001
             for slot, req in snapshot:
                 if req.slot == slot:
@@ -2211,13 +2308,16 @@ class LLMEngine:
                     self._release(req)
             return
         self.stats["decode_steps"] += 1
+        self.stats["decode_slot_steps"] += self.cfg.max_slots
         for slot, req in snapshot:
+            n = int(n_emit[slot])
             if req.slot != slot:
+                self.stats["decode_tokens_discarded"] += n
                 continue  # released/reused slot
             if req.generated >= req.max_new_tokens:
+                self.stats["decode_tokens_discarded"] += n
                 self._release(req)
                 continue
-            n = int(n_emit[slot])
             emitted = 0
             for j in range(n):
                 if req.generated >= req.max_new_tokens:
@@ -2226,6 +2326,8 @@ class LLMEngine:
                            float(lps[slot, j]) if lps is not None
                            else None)
                 emitted += 1
+            self.stats["decode_tokens_emitted"] += emitted
+            self.stats["decode_tokens_discarded"] += n - emitted
             self.stats["spec_accepted"] = (
                 self.stats.get("spec_accepted", 0) + max(0, emitted - 1))
             if self._paged and req.slot == slot \
@@ -2242,14 +2344,19 @@ class LLMEngine:
         """Fetch the oldest in-flight result and emit its tokens.
         Termination/EOS checks happen here, `pipeline_depth` steps behind
         dispatch; lagged tokens for finished/reused slots are discarded
-        by the (req.slot == slot, generated < budget) guards."""
-        kind, payload, arr, lp_arr = inflight.popleft()
+        by the (req.slot == slot, generated < budget) guards; each is
+        counted in stats["decode_tokens_discarded"]. Runs inside the
+        loop's `engine.emit` span: only the fetch that blocks on the
+        device is `engine.drain_wait`."""
+        kind, payload, arr, lp_arr, dispatched_ns = inflight.popleft()
         if kind == "verify":
             self._drain_verify(payload, arr, lp_arr)
             return
+        spans, st = self._spans, self.stats
         try:
-            host = np.asarray(arr)
-            lps = np.asarray(lp_arr) if lp_arr is not None else None
+            with spans.span("engine.drain_wait"):
+                host = np.asarray(arr)
+                lps = np.asarray(lp_arr) if lp_arr is not None else None
         except BaseException as e:  # noqa: BLE001  device-side failure
             targets = (list(payload) if kind == "prefill_batch"
                        else [r for _, r in payload])
@@ -2273,6 +2380,8 @@ class LLMEngine:
                 self._emit(req, int(firsts[i]),
                            float(flat_lps[i]) if flat_lps is not None
                            else None)
+                spans.add("request.inflight_prefill",
+                          time.perf_counter_ns() - dispatched_ns)
                 if (req.generated >= req.max_new_tokens
                         or req.prompt.size + req.generated
                         >= self.cfg.max_seq_len):
@@ -2282,20 +2391,27 @@ class LLMEngine:
         lp_rows = None
         if lps is not None:
             lp_rows = lps if lps.ndim == 2 else lps[None, :]
-        self.stats["decode_steps"] += rows.shape[0]
+        spans.add("request.inflight_decode",
+                  time.perf_counter_ns() - dispatched_ns)
+        st["decode_steps"] += rows.shape[0]
+        st["decode_slot_steps"] += rows.shape[0] * self.cfg.max_slots
         for ri, row in enumerate(rows):
             for slot, req in payload:
                 if req.slot != slot:
-                    continue  # released/reused slot: lagged, discard
+                    # released/reused slot: lagged, discard
+                    st["decode_tokens_discarded"] += 1
+                    continue
                 if req.generated >= req.max_new_tokens:
                     # budget shrank out-of-band (abort()): no further
                     # token will cross the threshold inside _emit, so
                     # release here or the slot decodes forever
+                    st["decode_tokens_discarded"] += 1
                     self._release(req)
                     continue
                 self._emit(req, int(row[slot]),
                            float(lp_rows[ri][slot])
                            if lp_rows is not None else None)
+                st["decode_tokens_emitted"] += 1
                 full = (req.prompt.size + req.generated
                         >= self.cfg.max_seq_len)
                 if req.generated >= req.max_new_tokens or full:
@@ -2305,168 +2421,8 @@ class LLMEngine:
         inflight = collections.deque()
         while not self._shutdown.is_set():
             try:
-                while True:
-                    # control commands (paged prefix registration) run
-                    # HERE so pool mutations never race a donated buffer
-                    try:
-                        fn, done = self._control_q.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    # commands are engine work too: a first-use prefix
-                    # prefill can jit-compile for >watchdog_s, so they
-                    # get the same compile grace as dispatches (a truly
-                    # stuck command still wedges after grace x budget —
-                    # the chaos stall exercises exactly that)
-                    self._in_dispatch = True
-                    try:
-                        fn()
-                        done.set_result(None)
-                    except BaseException as e:  # noqa: BLE001
-                        done.set_exception(e)
-                    finally:
-                        self._in_dispatch = False
-                self._in_dispatch = True   # watchdog: compile grace on
-                self._admit_all(inflight)
-                if self._prefilling:
-                    self._dispatch_chunk(inflight)
-                allow = (self._guided_decode_allow()
-                         if self._active else None)
-                pen = self._pen_args() if self._active else None
-                # penalties pipeline fine but the verify kernels don't
-                # thread them: speculation (and its sync stepping)
-                # disables entirely while any penalized request is active
-                spec_sync = (self._active and pen is None
-                             and self._spec_sync_active())
-                need_sync = allow is not None or spec_sync
-                if self._active and (not need_sync or not inflight):
-                    # guided traffic with results in flight waits for
-                    # the drain below: the next mask depends on tokens
-                    # the host hasn't seen yet
-                    mask, temps, top_ps = self._device_mask_temps()
-                    self._rng_key, sub = self._jax.random.split(
-                        self._rng_key)
-                    snapshot = list(self._active.items())
-                    props = (self._spec_plan()
-                             if spec_sync and allow is None else None)
-                    if props is not None:
-                        K = self.cfg.ngram_speculation
-                        if self._paged:
-                            for slot in self._active:
-                                self._disp_len[slot] += K + 1
-                            window = self._decode_window_pages()
-                            out, n_emit, logps, self._pools, \
-                                self._lengths, last = \
-                                self._verify_paged_jit(
-                                    self.params, self._pools,
-                                    self._page_table, self._lengths,
-                                    self._last_tokens, props, mask,
-                                    temps, top_ps, sub,
-                                    window_pages=window)
-                        else:
-                            out, n_emit, logps, self._cache, last = \
-                                self._verify_jit(
-                                    self.params, self._cache,
-                                    self._last_tokens, props, mask,
-                                    temps, top_ps, sub)
-                        self._last_tokens = last
-                        self._start_fetch(out)
-                        self._start_fetch(n_emit)
-                        if self.cfg.logprobs:
-                            self._start_fetch(logps)
-                        self.stats["spec_steps"] = \
-                            self.stats.get("spec_steps", 0) + 1
-                        inflight.append(
-                            ("verify", snapshot, out,
-                             (n_emit, logps if self.cfg.logprobs
-                              else None)))
-                    elif self._paged:
-                        window = self._decode_window_pages()
-                        akw = {} if allow is None else {"allow": allow}
-                        if pen is not None:
-                            akw["pen"] = pen
-                        if self._decode_block_paged_jit is not None \
-                                and allow is None and pen is None:
-                            toks, logps, self._pools, self._lengths, \
-                                last = self._decode_block_paged_jit(
-                                    self.params, self._pools,
-                                    self._page_table, self._lengths,
-                                    self._last_tokens, mask, temps,
-                                    top_ps, sub, window_pages=window)
-                            block = max(1, self.cfg.decode_block)
-                        else:
-                            res = self._decode_paged_jit(
-                                self.params, self._pools,
-                                self._page_table, self._lengths,
-                                self._last_tokens, mask, temps,
-                                top_ps, sub, window_pages=window,
-                                **akw)
-                            if pen is not None:
-                                (toks, logps, self._pools,
-                                 self._lengths, self._pen_counts) = res
-                            else:
-                                (toks, logps, self._pools,
-                                 self._lengths) = res
-                            last = toks
-                            block = 1
-                        for slot in self._active:
-                            # KeyError here = an admission path forgot
-                            # to seed _disp_len; fail loudly — a silent
-                            # 0 default would shrink the window and
-                            # corrupt KV untraceably
-                            self._disp_len[slot] += block
-                    elif self._decode_block_jit is not None \
-                            and allow is None and pen is None:
-                        toks, logps, self._cache, last = \
-                            self._decode_block_jit(
-                                self.params, self._cache,
-                                self._last_tokens, mask, temps, top_ps,
-                                sub)
-                    else:
-                        dkw = {} if allow is None else {"allow": allow}
-                        if pen is not None:
-                            dkw["pen"] = pen
-                        res = self._decode_jit(
-                            self.params, self._cache, self._last_tokens,
-                            mask, temps, top_ps, sub, **dkw)
-                        if pen is not None:
-                            toks, logps, self._cache, \
-                                self._pen_counts = res
-                        else:
-                            toks, logps, self._cache = res
-                        last = toks
-                    if props is None:
-                        self._last_tokens = last
-                        self._start_fetch(toks)
-                        if self.cfg.logprobs:
-                            self._start_fetch(logps)
-                        inflight.append(("decode", snapshot, toks,
-                                         logps if self.cfg.logprobs
-                                         else None))
-                m = self._m = _engine_metrics()
-                m["active"].set(float(len(self._active)),
-                                tags=self._mtags)
-                m["waiting"].set(float(self._waiting.qsize()),
-                                 tags=self._mtags)
-                m["occupancy"].set(
-                    len(self._active) / max(1, self.cfg.max_slots),
-                    tags=self._mtags)
-                if self._paged:
-                    m["kv_util"].set(
-                        (self._n_pages - len(self._free_pages))
-                        / max(1, self._n_pages), tags=self._mtags)
-                if not inflight:
-                    self._in_dispatch = False
-                    time.sleep(0.002)
-                    continue
-                # stay `pipeline_depth` steps ahead while decoding;
-                # drain fully once nothing is active
-                target = self.cfg.pipeline_depth if self._active else 0
-                if allow is not None or spec_sync:
-                    target = 0  # guided masks / n-gram proposals need
-                    #             the previous step's tokens on host
-                while len(inflight) > target:
-                    self._drain_one(inflight)
-                self._in_dispatch = False
+                with self._spans.span("engine.loop"):
+                    self._loop_once(inflight)
             except BaseException as e:  # noqa: BLE001  loop must survive
                 import traceback
                 traceback.print_exc()
@@ -2475,3 +2431,199 @@ class LLMEngine:
                     req.out_queue.put(("error", e))
                     self._release(req)
                 inflight.clear()
+
+    def _loop_once(self, inflight) -> None:
+        """One iteration of the engine loop, each phase in its span (the
+        caller holds `engine.loop`; self times, so the phases sum to it)."""
+        span = self._spans.span
+        with span("engine.control"):
+            while True:
+                # control commands (paged prefix registration) run
+                # HERE so pool mutations never race a donated buffer
+                try:
+                    fn, done = self._control_q.get_nowait()
+                except queue_mod.Empty:
+                    break
+                # commands are engine work too: a first-use prefix
+                # prefill can jit-compile for >watchdog_s, so they
+                # get the same compile grace as dispatches (a truly
+                # stuck command still wedges after grace x budget —
+                # the chaos stall exercises exactly that)
+                self._in_dispatch = True
+                try:
+                    fn()
+                    done.set_result(None)
+                except BaseException as e:  # noqa: BLE001
+                    done.set_exception(e)
+                finally:
+                    self._in_dispatch = False
+        self._in_dispatch = True   # watchdog: compile grace on
+        with span("engine.admit"):
+            self._admit_all(inflight)
+        if self._prefilling:
+            with span("engine.chunk_dispatch"):
+                self._dispatch_chunk(inflight)
+        need_sync = ready = False
+        if self._active:
+            with span("engine.decode_prep"):
+                allow = self._guided_decode_allow()
+                pen = self._pen_args()
+                # penalties pipeline fine but the verify kernels don't
+                # thread them: speculation (and its sync stepping)
+                # disables entirely while any penalized request is active
+                spec_sync = pen is None and self._spec_sync_active()
+                need_sync = allow is not None or spec_sync
+                if not need_sync or not inflight:
+                    # guided traffic with results in flight waits for
+                    # the drain below: the next mask depends on tokens
+                    # the host hasn't seen yet
+                    mask, temps, top_ps = self._device_mask_temps()
+                    self._rng_key, sub = self._jax.random.split(
+                        self._rng_key)
+                    props = (self._spec_plan()
+                             if spec_sync and allow is None else None)
+                    window = 0
+                    if self._paged:
+                        if props is not None:
+                            for slot in self._active:
+                                self._disp_len[slot] += \
+                                    self.cfg.ngram_speculation + 1
+                        window = self._decode_window_pages()
+                    snapshot = list(self._active.items())
+                    ready = True
+        if ready:
+            self._decode_dispatches += 1
+            with span("engine.decode_dispatch",
+                      step=self._decode_dispatches, active=len(snapshot),
+                      window_pages=window):
+                self._dispatch_decode(inflight, snapshot, props, allow,
+                                      pen, mask, temps, top_ps, sub, window)
+        with span("engine.bookkeep"):
+            m = self._m = _engine_metrics()
+            m["active"].set(float(len(self._active)),
+                            tags=self._mtags)
+            m["waiting"].set(float(self._waiting.qsize()),
+                             tags=self._mtags)
+            m["occupancy"].set(
+                len(self._active) / max(1, self.cfg.max_slots),
+                tags=self._mtags)
+            if self._paged:
+                m["kv_util"].set(
+                    (self._n_pages - len(self._free_pages))
+                    / max(1, self._n_pages), tags=self._mtags)
+        if not inflight:
+            self._in_dispatch = False
+            with span("engine.idle_sleep"):
+                time.sleep(0.002)
+            return
+        # stay `pipeline_depth` steps ahead while decoding;
+        # drain fully once nothing is active
+        target = self.cfg.pipeline_depth if self._active else 0
+        if need_sync:
+            target = 0  # guided masks / n-gram proposals need
+            #             the previous step's tokens on host
+        emitted = self.stats["tokens_generated"]
+        with span("engine.emit"):
+            try:
+                while len(inflight) > target:
+                    self._drain_one(inflight)
+            finally:
+                # one registry update per drain, not one per token
+                emitted = self.stats["tokens_generated"] - emitted
+                if emitted:
+                    m["tokens"].inc(float(emitted), tags=self._mtags)
+        self._in_dispatch = False
+
+    def _dispatch_decode(self, inflight, snapshot, props, allow, pen,
+                         mask, temps, top_ps, sub, window: int) -> None:
+        """Enqueue one decode (or speculative verify) program over all
+        slots, start its fetch and append it to `inflight`."""
+        if props is not None:
+            if self._paged:
+                out, n_emit, logps, self._pools, \
+                    self._lengths, last = \
+                    self._verify_paged_jit(
+                        self.params, self._pools,
+                        self._page_table, self._lengths,
+                        self._last_tokens, props, mask,
+                        temps, top_ps, sub,
+                        window_pages=window)
+            else:
+                out, n_emit, logps, self._cache, last = \
+                    self._verify_jit(
+                        self.params, self._cache,
+                        self._last_tokens, props, mask,
+                        temps, top_ps, sub)
+            self._last_tokens = last
+            self._start_fetch(out)
+            self._start_fetch(n_emit)
+            if self.cfg.logprobs:
+                self._start_fetch(logps)
+            self.stats["spec_steps"] = \
+                self.stats.get("spec_steps", 0) + 1
+            inflight.append(
+                ("verify", snapshot, out,
+                 (n_emit, logps if self.cfg.logprobs else None),
+                 time.perf_counter_ns()))
+            return
+        if self._paged:
+            akw = {} if allow is None else {"allow": allow}
+            if pen is not None:
+                akw["pen"] = pen
+            if self._decode_block_paged_jit is not None \
+                    and allow is None and pen is None:
+                toks, logps, self._pools, self._lengths, \
+                    last = self._decode_block_paged_jit(
+                        self.params, self._pools,
+                        self._page_table, self._lengths,
+                        self._last_tokens, mask, temps,
+                        top_ps, sub, window_pages=window)
+                block = max(1, self.cfg.decode_block)
+            else:
+                res = self._decode_paged_jit(
+                    self.params, self._pools,
+                    self._page_table, self._lengths,
+                    self._last_tokens, mask, temps,
+                    top_ps, sub, window_pages=window,
+                    **akw)
+                if pen is not None:
+                    (toks, logps, self._pools,
+                     self._lengths, self._pen_counts) = res
+                else:
+                    (toks, logps, self._pools,
+                     self._lengths) = res
+                last = toks
+                block = 1
+            for slot in self._active:
+                # KeyError here = an admission path forgot
+                # to seed _disp_len; fail loudly — a silent
+                # 0 default would shrink the window and
+                # corrupt KV untraceably
+                self._disp_len[slot] += block
+        elif self._decode_block_jit is not None \
+                and allow is None and pen is None:
+            toks, logps, self._cache, last = \
+                self._decode_block_jit(
+                    self.params, self._cache,
+                    self._last_tokens, mask, temps, top_ps,
+                    sub)
+        else:
+            dkw = {} if allow is None else {"allow": allow}
+            if pen is not None:
+                dkw["pen"] = pen
+            res = self._decode_jit(
+                self.params, self._cache, self._last_tokens,
+                mask, temps, top_ps, sub, **dkw)
+            if pen is not None:
+                toks, logps, self._cache, \
+                    self._pen_counts = res
+            else:
+                toks, logps, self._cache = res
+            last = toks
+        self._last_tokens = last
+        self._start_fetch(toks)
+        if self.cfg.logprobs:
+            self._start_fetch(logps)
+        inflight.append(("decode", snapshot, toks,
+                         logps if self.cfg.logprobs
+                         else None, time.perf_counter_ns()))

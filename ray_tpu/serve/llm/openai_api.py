@@ -254,13 +254,14 @@ class OpenAIServer(LLMServer):
         request ids before re-raising — mirroring the _collect cleanup,
         so failed multi-choice calls never strand siblings on the
         engine."""
-        from ..context import get_request_deadline
+        from ..context import get_request_deadline, get_request_recv_ts
         rids: List[str] = []
         try:
             for _ in range(n):
                 rids.append(self.engine.submit(
                     suffix, prefix_id=prefix_id,
-                    deadline_ts=get_request_deadline(), **sp))
+                    deadline_ts=get_request_deadline(),
+                    recv_ts=get_request_recv_ts(), **sp))
         except BaseException:
             for r in rids:
                 try:

@@ -138,12 +138,14 @@ class Replica:
             raise ValueError(f"unknown chaos mode {mode!r}")
         return True
 
-    def _admit(self, kwargs) -> Optional[float]:
+    def _admit(self, kwargs) -> tuple:
         """Shared admission gate for unary + stream paths: reject while
         draining (retriable — the handle fails over), shed requests
         whose propagated deadline already expired, and apply the chaos
-        delay. Returns the request's absolute deadline (or None)."""
+        delay. Returns the request's stamps for serve/context.py: its
+        absolute deadline and the proxy's receipt time (each or None)."""
         deadline_ts = kwargs.pop("__serve_deadline_ts", None)
+        recv_ts = kwargs.pop("__serve_recv_ts", None)
         if self._draining:
             raise ReplicaDrainingError(
                 f"replica {self._replica_id} is draining")
@@ -154,7 +156,7 @@ class Replica:
                 f"before admission on {self._replica_id}")
         if self._chaos_delay_s > 0:
             time.sleep(self._chaos_delay_s)
-        return deadline_ts
+        return deadline_ts, recv_ts
 
     def _shed(self, reason: str) -> None:
         from ..util import events as events_mod
@@ -216,18 +218,18 @@ class Replica:
         """Unary request. Runs user coroutines on the worker loop; sync
         handlers run in the default executor so they don't block the loop
         (and so max_ongoing_requests > 1 gives real concurrency)."""
-        deadline_ts = self._admit(kwargs)
+        stamps = self._admit(kwargs)
         with self._lock:
             self._ongoing += 1
         try:
             mux_id = kwargs.pop("__serve_multiplexed_model_id", "")
-            from .context import _set_request_deadline
+            from .context import _set_request_stamps
             from .multiplex import _set_multiplexed_model_id
             method = self._resolve_method(method_name)
             if inspect.iscoroutinefunction(method):
                 if mux_id:
                     _set_multiplexed_model_id(mux_id)
-                _set_request_deadline(deadline_ts)
+                _set_request_stamps(*stamps)
                 result = await method(*args, **kwargs)
             else:
                 def _call_sync():
@@ -235,7 +237,7 @@ class Replica:
                     # run_in_executor does not propagate context.
                     if mux_id:
                         _set_multiplexed_model_id(mux_id)
-                    _set_request_deadline(deadline_ts)
+                    _set_request_stamps(*stamps)
                     return method(*args, **kwargs)
                 loop = asyncio.get_running_loop()
                 result = await loop.run_in_executor(None, _call_sync)
@@ -256,18 +258,18 @@ class Replica:
         """Start a streaming call; returns a stream id to poll with
         stream_next(). The generator is drained on a background task and
         chunks buffered, so slow consumers don't stall the handler."""
-        deadline_ts = self._admit(kwargs)
+        stamps = self._admit(kwargs)
         stream_id = f"{self._replica_id}-s{next(self._stream_counter)}"
         q: queue_mod.Queue = queue_mod.Queue(maxsize=1024)
         self._streams[stream_id] = q
         with self._lock:
             self._ongoing += 1
         mux_id = kwargs.pop("__serve_multiplexed_model_id", "")
-        from .context import _set_request_deadline
+        from .context import _set_request_stamps
         from .multiplex import _set_multiplexed_model_id
         if mux_id:
             _set_multiplexed_model_id(mux_id)
-        _set_request_deadline(deadline_ts)
+        _set_request_stamps(*stamps)
         method = self._resolve_method(method_name)
 
         async def _put(item):
@@ -288,7 +290,7 @@ class Replica:
             # var set in the thread actually running its frames.
             if mux_id:
                 _set_multiplexed_model_id(mux_id)
-            _set_request_deadline(deadline_ts)
+            _set_request_stamps(*stamps)
             return next(it, _STREAM_END)
 
         async def _drain():

@@ -23,6 +23,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability.profiler import SpanTable
 from ..parallel.mesh import MeshSpec, build_mesh
 from ..util import knobs
 from .checkpoint import CheckpointManager, restore_pytree
@@ -56,6 +57,7 @@ class SpmdTrainer:
         self.run_config = run_config or RunConfig(name="spmd_trainer")
         self.report_fn = report_fn
         self.state = self.step = None     # set by fit()
+        self.spans: Optional[SpanTable] = None   # fit()'s phase times
 
     def fit(self, resume_from: Optional[str] = None) -> Result:
         import jax
@@ -100,33 +102,39 @@ class SpmdTrainer:
                 start_step, self.data_iter_fn)
             batch = {k: jnp.asarray(v) for k, v in bnp.items()}
 
+        # the loop's phases on the profiler's clock (a capture's idle gaps
+        # take these names) and as self times in `self.spans`
+        spans = self.spans = SpanTable()
         history = []
         tokens_acc, t_last = 0, time.time()
         for i in range(start_step, cfg.total_steps):
-            state, metrics = step_fn(state, batch)
+            with spans.step("train.step", i):
+                state, metrics = step_fn(state, batch)
             tokens_acc += int(np.prod(batch[next(iter(batch))].shape[:2]))
             if (i + 1) % cfg.log_every == 0 or i + 1 == cfg.total_steps:
-                now = time.time()
-                m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=i + 1,
-                         tokens_per_s=tokens_acc / max(now - t_last, 1e-9))
-                tokens_acc, t_last = 0, now
-                history.append(m)
-                if self.report_fn:
-                    self.report_fn(m)
+                with spans.span("train.report"):
+                    now = time.time()
+                    m = {k: float(v) for k, v in metrics.items()}
+                    m.update(step=i + 1, tokens_per_s=tokens_acc
+                             / max(now - t_last, 1e-9))
+                    tokens_acc, t_last = 0, now
+                    history.append(m)
+                    if self.report_fn:
+                        self.report_fn(m)
             if cfg.checkpoint_every and (i + 1) % cfg.checkpoint_every == 0:
                 manager.save(jax.device_get(state), i + 1)
             # only draw ahead if another step will run: finite streams
             # (e.g. a data-service iterator on its last epoch) end
             # exactly at total_steps and must not be over-drawn
             if i + 1 < cfg.total_steps:
-                try:
-                    nxt = next(data)
-                    batch = {k: jnp.asarray(v) for k, v in nxt.items()}
-                except StopIteration:
-                    data = self.data_iter_fn()
-                    batch = {k: jnp.asarray(v)
-                             for k, v in next(data).items()}
+                with spans.span("train.next_batch"):
+                    try:
+                        nxt = next(data)
+                        batch = {k: jnp.asarray(v) for k, v in nxt.items()}
+                    except StopIteration:
+                        data = self.data_iter_fn()
+                        batch = {k: jnp.asarray(v)
+                                 for k, v in next(data).items()}
 
         final_ckpt = None
         if cfg.checkpoint_every:

@@ -334,15 +334,22 @@ def test_profiler_trace_and_timing(tmp_path):
         s = state + batch.sum()
         return s, {"loss": s}
 
+    table = profiler.SpanTable()
     with profiler.trace(str(tmp_path / "prof")):
-        with profiler.annotate("demo-step"):
+        with table.span("demo-step"):
             out, _ = step(jnp.float32(0), jnp.ones((8, 8)))
             out.block_until_ready()
     produced = list((tmp_path / "prof").rglob("*"))
     assert produced, "no trace files written"
-    r = profiler.timed_steps(step, jnp.float32(0), jnp.ones((4, 4)),
-                             warmup=1, iters=3)
-    assert r["steps_per_s"] > 0
+    # timing a jitted step: one span per fenced call
+    state, batch = jnp.float32(0), jnp.ones((4, 4))
+    for i in range(3):
+        with table.step("timed-step", i):
+            state, m = step(state, batch)
+            m["loss"].block_until_ready()
+    n, total_ns, max_ns = table.snapshot()["timed-step"]
+    assert n == 3 and 0 < max_ns <= total_ns
+    assert table.snapshot()["demo-step"][0] == 1
 
 
 def test_cli_serve_run(tmp_path):

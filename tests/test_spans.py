@@ -1,0 +1,394 @@
+"""The program's own spans and counters (observability/profiler.py:
+SpanTable) and what the serving and training hot paths record with it:
+the engine loop's phases, the counters at the same boundaries, each
+request's stamps, and the proxy-to-engine receipt time. The counts are
+exact on the CPU; no time read here is a device metric.
+"""
+import glob
+import json
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from ray_tpu.observability.profiler import SpanTable
+
+PROMPT_LENS = (10, 12, 9, 11)       # one bucket of 16, one group of 4
+NEW_TOKENS = 8
+DEPTH = 3
+
+
+# ---- the primitive ------------------------------------------------------
+def test_span_counts_total_and_max():
+    t = SpanTable(["seeded"])
+    for ms in (1, 3):
+        with t.span("work", bucket=16):
+            time.sleep(ms / 1000)
+    rows = t.snapshot()
+    assert rows["seeded"] == [0, 0, 0]
+    n, total, longest = rows["work"]
+    assert n == 2 and total >= 4_000_000
+    assert 3_000_000 <= longest < total
+
+
+def test_nested_spans_store_self_time():
+    t = SpanTable()
+    t0 = time.perf_counter_ns()
+    with t.span("outer"):
+        time.sleep(0.01)
+        with t.span("inner"):
+            time.sleep(0.03)
+            with t.span("innermost"):
+                time.sleep(0.02)
+    wall = time.perf_counter_ns() - t0
+    rows = t.snapshot()
+    assert rows["innermost"][1] >= 20_000_000
+    assert rows["outer"][1] >= 10_000_000
+    # a parent's row leaves out what its children covered, however long
+    # the sleeps really took
+    assert 30_000_000 <= rows["inner"][1] <= (
+        wall - rows["innermost"][1] - 10_000_000)
+    total = sum(r[1] for r in rows.values())
+    assert wall * 0.95 <= total <= wall                  # they sum to it
+
+
+def test_exception_inside_a_span_is_recorded_and_raised():
+    t = SpanTable()
+    with pytest.raises(KeyError):
+        with t.span("outer"):
+            with t.span("fails"):
+                raise KeyError("boom")
+    assert t.snapshot()["fails"][0] == 1
+    assert t.snapshot()["outer"][0] == 1
+    with t.span("after"):        # the thread's stack was unwound
+        pass
+    with t.span("outer"):
+        pass
+    rows = t.snapshot()
+    assert rows["outer"][0] == 2 and rows["after"][0] == 1
+
+
+def test_a_span_on_another_thread_is_no_child_of_this_one():
+    t = SpanTable()
+
+    def other():
+        with t.span("other"):
+            time.sleep(0.02)
+    with t.span("mine"):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    rows = t.snapshot()
+    assert rows["other"][1] >= 20_000_000
+    assert rows["mine"][1] >= rows["other"][1]     # nothing was taken off
+
+
+def test_adds_from_more_threads_than_cores_lose_nothing():
+    t = SpanTable()
+    per_thread, n_threads = 2000, 32
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def hammer():
+            for i in range(per_thread):
+                t.add("stamp", 1 + i % 7)
+        threads = [threading.Thread(target=hammer)
+                   for _ in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    n, total, longest = t.snapshot()["stamp"]
+    assert n == per_thread * n_threads
+    assert total == n_threads * sum(1 + i % 7 for i in range(per_thread))
+    assert longest == 7
+
+
+def test_a_compile_is_charged_to_the_span_open_on_its_thread():
+    import jax
+    import jax.numpy as jnp
+    t = SpanTable()
+    with t.span("compiling"):
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    with t.span("cached"):
+        pass
+    compiles = t.compiles()
+    assert compiles["compiling"][0] >= 1 and compiles["compiling"][1] > 0
+    assert "cached" not in compiles
+
+
+# ---- a tiny paged engine with known lengths ------------------------------
+@pytest.fixture(scope="module")
+def tiny_llm():
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import Llama, LlamaConfig
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=64, max_seq_len=256, remat=False,
+                      dtype=jnp.float32)
+    model = Llama(cfg)
+    return model, model.init_params(jax.random.PRNGKey(0))
+
+
+def _engine(tiny_llm, **overrides):
+    from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
+    model, params = tiny_llm
+    base = dict(max_slots=4, max_seq_len=128, prefill_buckets=(16, 32),
+                max_prefill_batch=4, kv_page_size=16, pipeline_depth=DEPTH)
+    base.update(overrides)
+    return LLMEngine(model, params, LLMEngineConfig(**base))
+
+
+def _host_events(trace_dir, prefix):
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    return [(ev.name, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+@pytest.fixture(scope="module")
+def drained(tiny_llm, tmp_path_factory):
+    """Four prompts admitted in one pass into all four slots, eight
+    tokens each, under a CPU capture; read after the engine drained."""
+    import jax
+    trace_dir = str(tmp_path_factory.mktemp("engine-trace"))
+    eng = _engine(tiny_llm)
+    started = time.perf_counter_ns()    # the loop thread has just started
+    rids = []
+
+    def submit_all():       # on the loop thread: one admission pass
+        for n in PROMPT_LENS:
+            rids.append(eng.submit(np.arange(1, n + 1),
+                                   max_new_tokens=NEW_TOKENS))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        eng._run_on_loop(submit_all)
+        outs = [list(eng.stream_detailed(rid)) for rid in rids]
+        while eng.get_stats()["spans"]["request.inflight_decode"][0] \
+                < eng._decode_dispatches:
+            time.sleep(0.01)        # the lagged steps drain
+    finally:
+        jax.profiler.stop_trace()
+    stats = eng.get_stats()
+    eng.shutdown()
+    eng._loop_thread.join(timeout=30)
+    assert not eng._loop_thread.is_alive()
+    lived_ns = time.perf_counter_ns() - started
+    return {"stats": stats, "spans": eng._spans.snapshot(), "outs": outs,
+            "lived_ns": lived_ns, "trace_dir": trace_dir,
+            "dispatches": eng._decode_dispatches,
+            "metric": eng._m["tokens"].get(tags=eng._mtags)}
+
+
+def test_prefill_counters_are_bucket_times_padded_group(drained):
+    st = drained["stats"]
+    assert st["prefills"] == st["prefill_rows_real"] == len(PROMPT_LENS)
+    assert st["prefill_calls"] == 1 and st["prefill_rows_padded"] == 4
+    assert st["prefill_tokens_real"] == sum(PROMPT_LENS)
+    assert st["prefill_tokens_padded"] == 16 * 4
+    assert st["prefill_shapes"] == {"16x4": 1}
+
+
+def test_a_group_of_three_pads_to_four_rows(tiny_llm):
+    eng = _engine(tiny_llm)
+    rids = []
+
+    def submit_all():
+        for n in (20, 17, 30):
+            rids.append(eng.submit(np.arange(1, n + 1), max_new_tokens=2))
+    eng._run_on_loop(submit_all)
+    for rid in rids:
+        assert len(list(eng.stream(rid))) == 2
+    st = eng.get_stats()
+    eng.shutdown()
+    assert st["prefill_rows_real"] == 3 and st["prefill_rows_padded"] == 4
+    assert st["prefill_tokens_real"] == 67
+    assert st["prefill_tokens_padded"] == 32 * 4
+    assert st["prefill_shapes"] == {"32x4": 1}
+
+
+def test_discarded_tokens_are_the_slot_steps_not_emitted(drained):
+    st = drained["stats"]
+    n = len(PROMPT_LENS)
+    assert all(len(o) == NEW_TOKENS for o in drained["outs"])
+    assert st["tokens_generated"] == n * NEW_TOKENS
+    # the prefill emits each request's first token, decode rows the rest
+    assert st["decode_tokens_emitted"] == n * (NEW_TOKENS - 1)
+    assert st["decode_slot_steps"] == st["decode_steps"] * 4
+    assert st["decode_steps"] == drained["dispatches"]
+    # every slot was in every dispatched row: emitted or discarded
+    assert st["decode_tokens_discarded"] == (
+        st["decode_slot_steps"] - st["decode_tokens_emitted"])
+    assert 0 < st["decode_tokens_discarded"] <= DEPTH * n
+
+
+def test_request_stamps_count_requests_steps_and_tokens(drained):
+    spans, st = drained["spans"], drained["stats"]
+    assert spans["request.inflight_prefill"][0] == len(PROMPT_LENS)
+    assert spans["request.inflight_decode"][0] == st["decode_steps"]
+    assert spans["stream.deliver"][0] == sum(
+        len(o) for o in drained["outs"])
+    assert spans["slot.refill"][0] == 0         # no slot was used twice
+    assert spans["request.ingress"][0] == 0     # direct submits
+    assert "ingress_ms" not in st["ttft_breakdown_p50_ms"]
+    assert set(st["ttft_breakdown_p50_ms"]) == {
+        "queue_ms", "prefill_dispatch_ms", "emit_ms", "total_ms"}
+
+
+def test_engine_phases_sum_to_the_loops_wall_time(drained):
+    spans = drained["spans"]
+    from ray_tpu.serve.llm.engine import _LOOP_SPANS
+    assert set(_LOOP_SPANS) <= set(spans)
+    phases = sum(spans[name][1] for name in _LOOP_SPANS)
+    # the loop thread lives from the end of the engine's construction to
+    # shutdown and spends all of it inside `engine.loop`
+    assert abs(phases - drained["lived_ns"]) <= 0.02 * drained["lived_ns"]
+    assert spans["engine.decode_dispatch"][0] == drained["dispatches"]
+    assert spans["engine.prefill_dispatch"][0] == 1
+    assert spans["engine.chunk_dispatch"][0] == 0
+    assert spans["engine.idle_sleep"][0] > 0
+
+
+def test_compiles_are_named_by_the_phase_that_compiled(drained):
+    st = drained["stats"]
+    assert st["compiles"]["engine.prefill_dispatch"] >= 1
+    assert st["compiles"]["engine.decode_dispatch"] >= 1
+    assert set(st["compile_ns"]) == set(st["compiles"])
+    assert not any(k.startswith(("request.", "stream."))
+                   for k in st["compiles"])
+
+
+def test_token_metric_total_is_unchanged_by_batched_updates(drained):
+    assert drained["metric"] == drained["stats"]["tokens_generated"]
+
+
+def test_capture_holds_decode_dispatch_events_with_their_step(drained):
+    events = _host_events(drained["trace_dir"], "engine.")
+    names = {name for name, _ in events}
+    assert {"engine.loop", "engine.admit", "engine.prefill_dispatch",
+            "engine.decode_dispatch", "engine.drain_wait",
+            "engine.emit"} <= names
+    steps = sorted(stats["step"] for name, stats in events
+                   if name == "engine.decode_dispatch")
+    assert steps == list(range(1, drained["dispatches"] + 1))
+    prefill = [s for name, s in events if name == "engine.prefill_dispatch"]
+    assert prefill == [{"bucket": 16, "group": 4, "group_padded": 4}]
+    # only the engine thread annotates: no per-request event can take
+    # a device idle gap from the loop's phases
+    assert not _host_events(drained["trace_dir"], "request.")
+    assert not _host_events(drained["trace_dir"], "stream.")
+
+
+def test_slot_refill_counts_each_reuse_of_a_slot(tiny_llm):
+    eng = _engine(tiny_llm, max_slots=2)
+    rids = [eng.submit(np.arange(1, 9), max_new_tokens=3) for _ in range(5)]
+    for rid in rids:
+        assert len(list(eng.stream(rid))) == 3
+    spans = eng.get_stats()["spans"]
+    eng.shutdown()
+    assert spans["slot.refill"][0] == 3          # five requests, two slots
+    assert spans["slot.refill"][1] > 0
+
+
+def test_ingress_is_recorded_only_for_a_stamped_submit(tiny_llm):
+    eng = _engine(tiny_llm)
+    list(eng.stream(eng.submit(np.arange(1, 9), max_new_tokens=2)))
+    assert eng.get_stats()["spans"]["request.ingress"][0] == 0
+    list(eng.stream(eng.submit(np.arange(1, 9), max_new_tokens=2,
+                               recv_ts=time.time() - 0.05)))
+    st = eng.get_stats()
+    eng.shutdown()
+    n, total, _ = st["spans"]["request.ingress"]
+    assert n == 1 and 50_000_000 <= total < 5_000_000_000
+    assert st["ttft_breakdown_p50_ms"]["ingress_ms"] >= 50.0
+
+
+# ---- through serve.run and the HTTP proxy --------------------------------
+def _factory():
+    import jax
+    from ray_tpu.models import Llama, LlamaConfig
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=64, max_seq_len=128, remat=False)
+    model = Llama(cfg)
+    return model, model.init_params(jax.random.PRNGKey(0))
+
+
+def test_ingress_is_stamped_by_the_proxy_and_read_by_the_engine(rt):
+    from ray_tpu import serve
+    from ray_tpu.serve.http_proxy import start_proxy
+    from ray_tpu.serve.llm import build_openai_deployment
+    handle = serve.run(build_openai_deployment(
+        _factory, engine_config={"max_slots": 2, "max_seq_len": 64,
+                                 "prefill_buckets": (16,),
+                                 "kv_page_size": 16},
+        model_name="tiny"), name="spans-app", route_prefix="/v1")
+    try:
+        _proxy, port = start_proxy(port=0)
+        for stream in (False, True):
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{port}/v1/completions",
+                data=json.dumps({"prompt": [1, 2, 3, 4], "max_tokens": 3,
+                                 "stream": stream}).encode(),
+                headers={"Content-Type": "application/json"})
+            deadline = time.time() + 60
+            while True:
+                try:
+                    with urllib.request.urlopen(req, timeout=120) as r:
+                        assert r.status == 200 and r.read()
+                    break
+                except urllib.error.HTTPError as e:
+                    # a new proxy answers 404 until it has the routes; a
+                    # request it turns away never reaches the engine
+                    if e.code != 404 or time.time() > deadline:
+                        raise
+                    time.sleep(0.25)
+        st = handle.stats.remote(None).result(timeout_s=60)
+        n, total, longest = st["spans"]["request.ingress"]
+        assert n == 2 and 0 < longest <= total < 60_000_000_000
+        assert st["ttft_breakdown_p50_ms"]["ingress_ms"] > 0
+        assert st["spans"]["stream.deliver"][0] == 6
+        # a call on the handle did not come through a proxy: no stamp
+        out = handle.remote({"prompt": [1, 2, 3], "max_tokens": 2}).result(
+            timeout_s=60)
+        assert out["usage"]["completion_tokens"] == 2
+        st = handle.stats.remote(None).result(timeout_s=60)
+        assert st["spans"]["request.ingress"][0] == 2
+        assert st["spans"]["request.inflight_prefill"][0] == 3
+    finally:
+        serve.shutdown()
+
+
+# ---- the trainer's loop ----------------------------------------------------
+def test_trainer_phases_are_spans_of_fit(tmp_path):
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import RunConfig, SpmdTrainer, SpmdTrainerConfig
+
+    rng = np.random.RandomState(0)
+
+    def data():
+        while True:
+            yield {"tokens": rng.randint(0, 255, (8, 16))}
+
+    tr = SpmdTrainer(
+        SpmdTrainerConfig(model="llama-debug", mesh=MeshSpec(dp=8),
+                          total_steps=5, log_every=2, warmup_steps=1),
+        data, run_config=RunConfig(name="spans", storage_path=str(tmp_path)))
+    assert tr.spans is None
+    res = tr.fit()
+    assert res.metrics["step"] == 5
+    rows = tr.spans.snapshot()
+    assert rows["train.step"][0] == 5
+    assert rows["train.report"][0] == 3         # steps 2, 4 and the last
+    assert rows["train.next_batch"][0] == 4     # none drawn past the end
+    assert tr.spans.compiles()["train.step"][0] >= 1
