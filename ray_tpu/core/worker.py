@@ -525,10 +525,11 @@ class WorkerRuntime:
         with self._direct_cv:
             self._direct_cv.notify_all()
         if fut.publish and not fut.failover:
-            for oid, f in list(self._direct_results.items()):
-                if f is fut:
-                    self._publish_direct(oid, fut)
-                    break
+            with self._direct_lock:     # see _register_direct_future
+                oid = next((o for o, f in self._direct_results.items()
+                            if f is fut), None)
+            if oid is not None:
+                self._publish_direct(oid, fut)
 
     def _publish_direct(self, oid: str, fut: _DirectFuture) -> None:
         """Seal a direct-call result into the driver's object table: its
@@ -571,21 +572,27 @@ class WorkerRuntime:
                 self._publish_agent(oid, af)
 
     def _register_direct_future(self, oid: str, fut: _DirectFuture) -> None:
-        self._direct_results[oid] = fut
-        while len(self._direct_results) > self._DIRECT_RESULT_RETAIN:
-            old_oid, old = next(iter(self._direct_results.items()))
-            if not old.ev.is_set():
-                break   # oldest still in flight: don't evict live calls
-            del self._direct_results[old_oid]
-            if old._published or old.failover:
-                # the value lives driver-side (escaped-ref publication /
-                # failover resubmit): later local gets resolve it over
-                # the normal driver path — only a never-published local
-                # result is actually lost
-                continue
-            self._direct_evicted.add(old_oid)
-            while len(self._direct_evicted) > 4 * self._DIRECT_RESULT_RETAIN:
-                self._direct_evicted.pop()
+        # under _direct_lock: callers register from many threads at once
+        # (a serve proxy's stream pulls), and an insert between another
+        # thread's iter() and next() below raised "OrderedDict mutated
+        # during iteration" into the caller's request
+        with self._direct_lock:
+            self._direct_results[oid] = fut
+            while len(self._direct_results) > self._DIRECT_RESULT_RETAIN:
+                old_oid, old = next(iter(self._direct_results.items()))
+                if not old.ev.is_set():
+                    break   # oldest still in flight: don't evict live calls
+                del self._direct_results[old_oid]
+                if old._published or old.failover:
+                    # the value lives driver-side (escaped-ref publication
+                    # / failover resubmit): later local gets resolve it
+                    # over the normal driver path — only a never-published
+                    # local result is actually lost
+                    continue
+                self._direct_evicted.add(old_oid)
+                while len(self._direct_evicted) > \
+                        4 * self._DIRECT_RESULT_RETAIN:
+                    self._direct_evicted.pop()
 
     # ---- agent-local dispatch (two-level scheduling) ----------------------
     def _register_agent_future(self, oid: str, fut: _AgentFuture) -> None:
@@ -801,7 +808,8 @@ class WorkerRuntime:
         fut.actor_id = spec.actor_id
         self._register_direct_future(oid, fut)
         if not ch.call(spec, fut):
-            self._direct_results.pop(oid, None)
+            with self._direct_lock:     # see _register_direct_future
+                self._direct_results.pop(oid, None)
             self.direct_fallbacks += 1
             try:
                 mcat.get("ray_tpu_direct_call_fallbacks_total").inc(

@@ -6,6 +6,7 @@ LLMEngine; requests stream tokens via the serve streaming path.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -140,10 +141,16 @@ class LLMServer:
             deadline_ts=get_request_deadline(),
             recv_ts=get_request_recv_ts())
         if body.get("stream"):
-            def gen():
-                for tok in self.engine.stream(rid):
-                    yield self._decode_tok(tok)
-            return gen()
+            # an async generator, for the loop this call runs on (the
+            # replica's stream_start) to consume: tokens arrive by the
+            # engine's hand-over, no thread parks for the stream
+            tokens = self.engine.astream_detailed(rid)
+
+            async def agen():
+                async with contextlib.aclosing(tokens):
+                    async for tok, _lp in tokens:
+                        yield self._decode_tok(tok)
+            return agen()
         toks = list(self.engine.stream(rid))
         if self.tokenizer is not None:
             return {"text": self.tokenizer.decode(toks), "tokens": toks}

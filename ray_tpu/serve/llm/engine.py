@@ -25,6 +25,12 @@ paged KV, streaming) — re-designed TPU-first:
   sampled on-device inside the prefill and drains through the same
   pipeline. Termination decisions lag by `pipeline_depth` steps — at
   most that many wasted (discarded) tokens per finished request.
+* Hand-off to the consumers: a request's tokens, errors and end marker
+  go to its sink. A consumer thread parks on a bounded queue
+  (stream_detailed); a consumer on an event loop (astream_detailed, the
+  serve replica) is woken by ONE call_soon_threadsafe per loop iteration
+  that carries every request's items — a thread woken per token would
+  take the interpreter lock from this loop at its next JAX call.
 * Spans and counters (observability/profiler.py:SpanTable, always on):
   the loop's phases are `engine.*` spans on the engine thread — self
   times in get_stats()["spans"], annotations in a profiler capture —
@@ -34,6 +40,7 @@ paged KV, streaming) — re-designed TPU-first:
 """
 from __future__ import annotations
 
+import asyncio
 import collections
 import itertools
 import queue as queue_mod
@@ -52,8 +59,8 @@ from ...util import knobs
 _LOOP_SPANS = ("engine.loop", "engine.control", "engine.admit",
                "engine.prefill_dispatch", "engine.chunk_dispatch",
                "engine.decode_prep", "engine.decode_dispatch",
-               "engine.drain_wait", "engine.emit", "engine.bookkeep",
-               "engine.idle_sleep")
+               "engine.drain_wait", "engine.emit", "engine.deliver",
+               "engine.bookkeep", "engine.idle_sleep")
 _REQUEST_SPANS = ("request.ingress", "request.inflight_prefill",
                   "request.inflight_decode", "slot.refill",
                   "stream.deliver")
@@ -155,8 +162,16 @@ class _Request:
     temperature: float
     top_p: float = 1.0
     stop_ids: frozenset = frozenset()
-    out_queue: queue_mod.Queue = field(
-        default_factory=lambda: queue_mod.Queue(maxsize=4096))
+    # where this request's tokens, errors and end marker go: a blocking
+    # _QueueSink until an awaitable consumer (astream_detailed) swaps in
+    # its _LoopSink; sink_lock makes that swap atomic against a put
+    sink: "_QueueSink | _LoopSink" = field(
+        default_factory=lambda: _QueueSink())
+    sink_lock: Any = field(default_factory=threading.Lock)
+    # when the sink refused a token (consumer at its bound) and the
+    # consumer had taken `stalled_taken` items: the stall clock
+    stalled_since: Optional[float] = None
+    stalled_taken: int = 0
     slot: int = -1
     generated: int = 0
     aborted: bool = False
@@ -226,26 +241,112 @@ def _engine_metrics():
 
 
 
-def _put_dropping_one(q: "queue_mod.Queue", item) -> None:
-    """Publish a control item (_END / wedged error) to a possibly-full
-    out_queue without ever blocking the engine loop: on Full, drop one
-    buffered token to make room. Single producer (the loop), so the
-    retry cannot race another put; a second Full means the consumer
-    raced a get between our get and put — then the queue has room on
-    the next consumer cycle anyway and the item is dropped."""
-    try:
-        q.put_nowait(item)
-        return
-    except queue_mod.Full:
-        pass
-    try:
-        q.get_nowait()
-    except queue_mod.Empty:
-        pass
-    try:
-        q.put_nowait(item)
-    except queue_mod.Full:
-        pass
+# Items a sink may hold undelivered before it refuses tokens (the stall
+# clock of LLMEngine._put_token starts there).
+_SINK_BOUND = 4096
+
+
+class _QueueSink:
+    """Blocking sink: a bounded queue on which one consumer thread
+    parks (stream_detailed, and through it stream, generate_sync,
+    _collect). Every put wakes that thread."""
+
+    loop = None     # no event loop: the engine may wait for this consumer
+    taken = 0       # its progress shows as an offer that succeeds
+
+    def __init__(self):
+        self.q: queue_mod.Queue = queue_mod.Queue(maxsize=_SINK_BOUND)
+
+    def drain(self) -> list:
+        """Everything still in the queue, in order."""
+        items = []
+        while True:
+            try:
+                items.append(self.q.get_nowait())
+            except queue_mod.Empty:
+                return items
+
+    def offer(self, item) -> bool:
+        """Put a token, waiting up to a second for room; False = still
+        full."""
+        try:
+            self.q.put(item, timeout=1.0)
+            return True
+        except queue_mod.Full:
+            return False
+
+    def force(self, item) -> None:
+        """Publish a control item (error, end marker) without ever
+        blocking the engine loop: on Full, drop one buffered token to
+        make room. A second Full means the consumer raced a get between
+        our get and put — then the queue has room on the next consumer
+        cycle anyway and the item is dropped."""
+        try:
+            self.q.put_nowait(item)
+            return
+        except queue_mod.Full:
+            pass
+        try:
+            self.q.get_nowait()
+        except queue_mod.Empty:
+            pass
+        try:
+            self.q.put_nowait(item)
+        except queue_mod.Full:
+            pass
+
+
+class _LoopSink:
+    """Awaitable sink: the consumer is an async generator on `loop`
+    (astream_detailed). A put only appends to the engine's outbox;
+    LLMEngine._hand_over carries everything one loop iteration put, for
+    every request, to the loop in ONE call_soon_threadsafe, where
+    `deliver` files the items and wakes the consumers that wait. No
+    thread is parked per stream and none is woken per token."""
+
+    def __init__(self, loop, request_id: str, outbox: collections.deque):
+        self.loop = loop
+        self.request_id = request_id
+        self.closed = False     # the consumer is gone: drop what comes
+        self._outbox = outbox
+        self._items: collections.deque = collections.deque()  # loop side
+        self._waiter: Optional[asyncio.Future] = None         # loop side
+        self.offered = 0        # written by the producer
+        self.taken = 0          # written by the consumer
+
+    def offer(self, item) -> bool:
+        """Never waits (the engine cannot park on an event loop); False
+        = _SINK_BOUND items are handed over and not yet taken."""
+        if self.offered - self.taken >= _SINK_BOUND:
+            return False
+        self.force(item)
+        return True
+
+    def force(self, item) -> None:
+        if not self.closed:
+            self.offered += 1
+            self._outbox.append((self, item))
+
+    @staticmethod
+    def deliver(batch) -> None:
+        """On the loop: file one hand-over's (sink, item) pairs."""
+        for sink, item in batch:
+            if sink.closed:
+                continue
+            sink._items.append(item)
+            waiter = sink._waiter
+            if waiter is not None and not waiter.done():
+                waiter.set_result(None)
+
+    async def take(self):
+        while not self._items:
+            self._waiter = self.loop.create_future()
+            try:
+                await self._waiter
+            finally:
+                self._waiter = None
+        self.taken += 1
+        return self._items.popleft()
 
 
 def _next_pow2(n: int) -> int:
@@ -376,7 +477,16 @@ class LLMEngine:
                       # against what the padded (bucket x group) call ran
                       "prefill_calls": 0, "prefill_rows_real": 0,
                       "prefill_rows_padded": 0, "prefill_tokens_real": 0,
-                      "prefill_tokens_padded": 0, "prefill_shapes": {}}
+                      "prefill_tokens_padded": 0, "prefill_shapes": {},
+                      # the hand-off: _hand_over calls that carried
+                      # something and the items they carried (awaitable
+                      # consumers), tokens put on a blocking queue
+                      "deliver_batches": 0, "deliver_items": 0,
+                      "deliver_blocking_tokens": 0}
+        # (sink, item) pairs for awaitable consumers since the last
+        # _hand_over; appends and poplefts are atomic, so abort() and
+        # the watchdog may put from their own threads
+        self._outbox: collections.deque = collections.deque()
         self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
         self._slot_freed_ns: Dict[int, int] = {}    # slot -> _release
         self._decode_dispatches = 0     # the `step` of a decode's span
@@ -1224,30 +1334,83 @@ class LLMEngine:
         for tok, _lp in self.stream_detailed(request_id):
             yield tok
 
+    def _taken(self, payload):
+        """A consumer took a token: close its `stream.deliver` span, from
+        the put time that rode with it."""
+        tok, logp, put_ts = payload
+        self._spans.add("stream.deliver", max(0, int(
+            (time.time() - put_ts) * 1e9)))
+        return tok, logp
+
     def stream_detailed(self, request_id: str):
         """Like stream() but yields (token_id, logprob) — logprob is
-        None unless the engine was built with logprobs=True."""
+        None unless the engine was built with logprobs=True. Parks the
+        calling thread between tokens; a consumer on a running event
+        loop uses astream_detailed."""
         req = self._requests.get(request_id)
         if req is None:
             raise KeyError(request_id)
+        sink = req.sink
+        if sink.loop is not None:
+            raise RuntimeError(f"{request_id} already streams to an "
+                               "awaitable consumer")
         while True:
             # raylint: disable=RT003 the engine loop cannot exit with this
             # request registered: its catch-all errors every active
             # request's queue, failed admits error theirs, and the wedge
             # watchdog aborts stalled requests — while a timeout here
             # would kill legitimate multi-minute first-jit prefills
-            kind, payload = req.out_queue.get()
+            kind, payload = sink.q.get()
             if kind == "token":
-                tok, logp, put_ts = payload
-                self._spans.add("stream.deliver", max(0, int(
-                    (time.time() - put_ts) * 1e9)))
-                yield tok, logp
-            elif kind == "error":
-                raise payload
-            else:  # end
-                break
-        with self._lock:
-            self._requests.pop(request_id, None)
+                yield self._taken(payload)
+            else:  # an error or the end marker: the request is over
+                with self._lock:
+                    self._requests.pop(request_id, None)
+                if kind == "error":
+                    raise payload
+                return
+
+    def astream_detailed(self, request_id: str):
+        """stream_detailed for a consumer on the RUNNING event loop: an
+        async generator of (token_id, logprob), bound to the loop it was
+        created on. From here on the request's items reach that loop in
+        the engine's one hand-over per iteration (_hand_over) — no
+        thread parks for this stream. Closing the generator before its
+        end (the client went away) aborts the engine request."""
+        req = self._requests.get(request_id)
+        if req is None:
+            raise KeyError(request_id)
+        sink = _LoopSink(asyncio.get_running_loop(), request_id,
+                         self._outbox)
+        with req.sink_lock:
+            old = req.sink
+            if old.loop is not None:
+                raise RuntimeError(f"{request_id} already streams to an "
+                                   "awaitable consumer")
+            # what the engine put before this consumer attached
+            sink._items.extend(old.drain())
+            sink.offered = len(sink._items)
+            req.sink = sink
+
+        async def consume():
+            ended = False
+            try:
+                while True:
+                    kind, payload = await sink.take()
+                    if kind == "token":
+                        yield self._taken(payload)
+                        continue
+                    ended = True    # an error or the end marker
+                    if kind == "error":
+                        raise payload
+                    break
+            finally:
+                sink.closed = True
+                if not ended:
+                    self.abort(request_id)
+                with self._lock:
+                    self._requests.pop(request_id, None)
+        return consume()
 
     def abort(self, request_id: str) -> None:
         """Best-effort early termination. Decoding requests collapse
@@ -1268,7 +1431,7 @@ class LLMEngine:
             # unblock the consumer immediately (a duplicate end marker
             # from a concurrent admission is harmless — the consumer
             # stops at the first one)
-            req.out_queue.put(_END)
+            self._put(req, _END)
         elif req.generated > 0:
             req.max_new_tokens = min(req.max_new_tokens, req.generated)
         # else: slot assigned but no token yet (chunk-prefilling / prefill
@@ -1433,10 +1596,10 @@ class LLMEngine:
     # budget so compiles pass and true device hangs are still caught.
     _DISPATCH_GRACE = 10.0
 
-    # A consumer whose out_queue stays full this long without draining
+    # A consumer whose sink stays at its bound this long without taking
     # a single token is treated as gone and its request aborted (see
-    # _emit's bounded put) — the bound that keeps per-request
-    # backpressure from parking the shared loop indefinitely.
+    # _put_token) — the bound that keeps per-request backpressure from
+    # parking the shared loop, or piling up tokens, indefinitely.
     _CONSUMER_STALL_TTL_S = 60.0
 
     def _watchdog_loop(self) -> None:
@@ -1479,7 +1642,9 @@ class LLMEngine:
             # error (not _END) so consumers raise and the serve handle
             # fails the stream over to a healthy replica; bounded put —
             # a full queue (slow consumer) must not swallow the error
-            _put_dropping_one(req.out_queue, ("error", err))
+            self._put(req, ("error", err))
+        # the loop thread is stuck, so its hand-over will not come
+        self._hand_over()
 
     def _chaos_stall(self, seconds: float) -> None:
         """Deterministic wedge injection (serve/chaos.py, tests): park
@@ -1554,11 +1719,11 @@ class LLMEngine:
         n_shared_adopt = (int(self._prefixes[req.prefix_id].size) // ps
                           if req.prefix_id >= 0 else 0)
         if need_total - n_shared_adopt > self._n_pages - pinned:
-            req.out_queue.put(("error", ValueError(
+            self._put(req, ("error", ValueError(
                 f"request needs {need_total - n_shared_adopt} exclusive "
                 f"KV pages but only {self._n_pages - pinned} can ever "
                 f"be free ({pinned} pinned by prefixes)")))
-            req.out_queue.put(_END)
+            self._put(req, _END)
             return "failed"
         if req.prefix_id >= 0:
             prefix_pages = self._prefix_pages[req.prefix_id]
@@ -1577,8 +1742,8 @@ class LLMEngine:
                     self._free_pages.extend(excl)
                     self._free_slots.append(slot)
                     req.slot = -1
-                    req.out_queue.put(("error", e))
-                    req.out_queue.put(_END)
+                    self._put(req, ("error", e))
+                    self._put(req, _END)
                     return "failed"
             all_pages = prefix_pages[:n_shared] + excl
             self._slot_pages[slot] = (n_shared, all_pages)
@@ -1674,8 +1839,8 @@ class LLMEngine:
                     # dispatch paths: free the slot, error the stream
                     self._free_slots.append(slot)
                     req.slot = -1
-                    req.out_queue.put(("error", e))
-                    req.out_queue.put(_END)
+                    self._put(req, ("error", e))
+                    self._put(req, _END)
                     continue
                 req.prefill_pos = plen
                 self.stats["prefix_tokens_saved"] = (
@@ -1800,8 +1965,8 @@ class LLMEngine:
                 self._free_slot_pages(slot)
                 self._free_slots.append(slot)
                 req.slot = -1
-                req.out_queue.put(("error", e))
-                req.out_queue.put(_END)
+                self._put(req, ("error", e))
+                self._put(req, _END)
             return
         dispatch_ms = (time.time() - t_dispatch) * 1000
         # first dispatch of a bucket blocks on its jit compile: record it
@@ -1884,8 +2049,8 @@ class LLMEngine:
             self._free_slot_pages(req.slot)
             self._free_slots.append(req.slot)
             req.slot = -1
-            req.out_queue.put(("error", e))
-            req.out_queue.put(_END)
+            self._put(req, ("error", e))
+            self._put(req, _END)
             return
         req.prefill_pos = start + true
         if self._paged:
@@ -1939,52 +2104,7 @@ class LLMEngine:
             self._m["ttft"].observe(now - req.submit_ts, tags=self._mtags)
         if req.hist is not None:
             req.hist.append(tok)
-        # Bounded-wait put: a FULL out_queue means the CONSUMER is slow
-        # or gone, not that the engine is wedged — refresh the watchdog
-        # clock while parked so per-request backpressure can't get the
-        # whole replica declared wedged and replaced. The park itself
-        # is bounded: a consumer silent past _CONSUMER_STALL_TTL_S
-        # (abandoned generator, crashed client that never cancelled)
-        # gets its request aborted so one dead reader can't stall the
-        # shared loop forever while keeping the watchdog green.
-        parked_since = None
-        while True:
-            try:
-                req.out_queue.put(("token", (tok, logp, now)),
-                                  timeout=1.0)
-                break
-            except queue_mod.Full:
-                if req.aborted:
-                    break
-                now = time.time()
-                if parked_since is None:
-                    parked_since = now
-                    # flag the stall while it is still LIVE so hangs
-                    # the TTL will later mitigate show up in `stuck`
-                    # output and post-mortems as they happen
-                    self._event("sched.hang.suspected",
-                                "request output queue full; consumer "
-                                "stalled (TTL abort after "
-                                f"{self._CONSUMER_STALL_TTL_S:.0f}s)",
-                                req=req, kind="consumer_stalled")
-                elif now - parked_since > self._CONSUMER_STALL_TTL_S:
-                    req.aborted = True
-                    req.max_new_tokens = min(req.max_new_tokens,
-                                             req.generated)
-                    self._event("llm_engine.request_abort", req=req,
-                                generated=req.generated,
-                                reason="consumer_stalled")
-                    # hang-mitigation telemetry: the TTL abort IS a
-                    # resolved hang — make it visible to the wait
-                    # plane's post-mortems, not just the engine log
-                    self._event("sched.hang.resolved",
-                                f"consumer stalled "
-                                f"{now - parked_since:.0f}s; request "
-                                "aborted by the consumer-stall TTL",
-                                req=req, kind="consumer_stalled",
-                                stalled_s=round(now - parked_since, 1))
-                    break
-                self._progress_ts = now
+        self._put_token(req, ("token", (tok, logp, now)))
         if ((self.cfg.eos_token_id is not None
              and tok == self.cfg.eos_token_id)
                 or tok in req.stop_ids):
@@ -1998,6 +2118,123 @@ class LLMEngine:
                     or req.fsm.is_complete(req.fsm_state)):
                 req.max_new_tokens = min(req.max_new_tokens,
                                          req.generated)
+
+    # ---- hand-off to the consumers ----------------------------------------
+    def _put(self, req: _Request, item) -> None:
+        """Publish a control item (an error, the end marker) to the
+        request's consumer: never blocks, never refused."""
+        with req.sink_lock:
+            req.sink.force(item)
+
+    def _put_token(self, req: _Request, item) -> None:
+        """Publish a token. A sink that refuses it is at its bound: the
+        CONSUMER is slow or gone, not the engine wedged — refresh the
+        watchdog clock meanwhile so per-request backpressure can't get
+        the whole replica declared wedged and replaced. A blocking
+        consumer is waited for, a second at a time; an awaitable one
+        cannot be, so its token rides past the bound while the clock
+        runs. The clock restarts whenever the consumer has taken
+        something since it was last read (slow is not gone). Either way
+        the stall is bounded: a consumer that takes nothing for
+        _CONSUMER_STALL_TTL_S (abandoned generator, crashed client that
+        never cancelled) gets its request aborted so one dead reader can
+        neither stall the shared loop nor pile up tokens forever while
+        keeping the watchdog green. What an awaitable sink may hold is
+        therefore _SINK_BOUND plus one TTL of decoding for a consumer
+        that stopped, and the request's own budget for one that is
+        slower than the engine."""
+        while True:
+            with req.sink_lock:
+                sink = req.sink
+                # an awaitable sink answers at once: under the lock
+                offered = sink.loop is not None and sink.offer(item)
+            if sink.loop is None:
+                # this may wait a second for room, so not under the lock
+                # (abort() and astream_detailed take it on the actor's
+                # event loop)
+                offered = sink.offer(item)
+                if offered:
+                    self.stats["deliver_blocking_tokens"] += 1
+                    with req.sink_lock:
+                        if req.sink is not sink:
+                            # an awaitable consumer attached meanwhile,
+                            # and may have emptied the queue before this
+                            # token landed in it: carry the rest over
+                            for left in sink.drain():
+                                req.sink.force(left)
+            if offered:
+                req.stalled_since = None
+                return
+            if req.aborted:
+                return
+            now = time.time()
+            if req.stalled_since is None:
+                req.stalled_since, req.stalled_taken = now, sink.taken
+                # flag the stall while it is still LIVE so hangs the
+                # TTL will later mitigate show up in `stuck` output
+                # and post-mortems as they happen
+                self._event("sched.hang.suspected",
+                            "request output sink full; consumer "
+                            "stalled (TTL abort after "
+                            f"{self._CONSUMER_STALL_TTL_S:.0f}s)",
+                            req=req, kind="consumer_stalled")
+            elif sink.taken != req.stalled_taken:
+                # behind its bound but still taking: the clock restarts,
+                # as a blocking consumer's does with every token it
+                # makes room for
+                req.stalled_since, req.stalled_taken = now, sink.taken
+            elif now - req.stalled_since > self._CONSUMER_STALL_TTL_S:
+                req.aborted = True
+                req.max_new_tokens = min(req.max_new_tokens,
+                                         req.generated)
+                self._event("llm_engine.request_abort", req=req,
+                            generated=req.generated,
+                            reason="consumer_stalled")
+                # hang-mitigation telemetry: the TTL abort IS a
+                # resolved hang — make it visible to the wait plane's
+                # post-mortems, not just the engine log
+                self._event("sched.hang.resolved",
+                            f"consumer stalled "
+                            f"{now - req.stalled_since:.0f}s; request "
+                            "aborted by the consumer-stall TTL",
+                            req=req, kind="consumer_stalled",
+                            stalled_s=round(now - req.stalled_since, 1))
+                return
+            self._progress_ts = now
+            if sink.loop is not None:
+                self._put(req, item)
+                return
+
+    def _hand_over(self) -> None:
+        """Carry what the outbox holds — every (request, item) put since
+        the last hand-over: tokens, errors and end markers alike — to
+        the consumers' event loops, one call_soon_threadsafe per loop
+        (there is one: the replica's). The engine loop calls it once an
+        iteration, after its drain; the watchdog once, for a wedged
+        loop."""
+        box = self._outbox
+        if not box:
+            return
+        with self._spans.span("engine.deliver"):
+            by_loop: Dict[Any, list] = {}
+            while True:
+                try:
+                    pair = box.popleft()
+                except IndexError:
+                    break
+                by_loop.setdefault(pair[0].loop, []).append(pair)
+            for loop, batch in by_loop.items():
+                self.stats["deliver_batches"] += 1
+                self.stats["deliver_items"] += len(batch)
+                try:
+                    loop.call_soon_threadsafe(_LoopSink.deliver, batch)
+                except RuntimeError:
+                    # the loop was closed under its consumers: nobody
+                    # will take these, so stop decoding for them
+                    for sink in {s for s, _ in batch}:
+                        sink.closed = True
+                        self.abort(sink.request_id)
+                        self._requests.pop(sink.request_id, None)
 
     # ---- page allocator (host side) ---------------------------------------
     def _pages_needed(self, req: _Request) -> int:
@@ -2049,7 +2286,7 @@ class LLMEngine:
         events_mod.emit_safe(
             counter="ray_tpu_serve_requests_shed_total",
             counter_tags={"reason": "deadline_expired"})
-        req.out_queue.put(("error", DeadlineExceededError(
+        self._put(req, ("error", DeadlineExceededError(
             f"deadline expired {time.time() - req.deadline_ts:.3f}s "
             f"before engine admission of {req.request_id}")))
 
@@ -2085,7 +2322,7 @@ class LLMEngine:
             # bounded end-marker publish: a full queue (stalled/gone
             # consumer, e.g. the _CONSUMER_STALL_TTL_S abort path)
             # must not park the loop on a blocking put
-            _put_dropping_one(req.out_queue, _END)
+            self._put(req, _END)
 
     def _decode_window_pages(self) -> int:
         """Power-of-2 page window covering every slot that holds KV
@@ -2304,7 +2541,7 @@ class LLMEngine:
         except BaseException as e:  # noqa: BLE001
             for slot, req in snapshot:
                 if req.slot == slot:
-                    req.out_queue.put(("error", e))
+                    self._put(req, ("error", e))
                     self._release(req)
             return
         self.stats["decode_steps"] += 1
@@ -2362,7 +2599,7 @@ class LLMEngine:
                        else [r for _, r in payload])
             for req in targets:
                 if req.slot >= 0:
-                    req.out_queue.put(("error", e))
+                    self._put(req, ("error", e))
                     self._release(req)
             return
         if kind == "prefill_batch":
@@ -2428,9 +2665,10 @@ class LLMEngine:
                 traceback.print_exc()
                 self._in_dispatch = False
                 for req in list(self._active.values()):
-                    req.out_queue.put(("error", e))
+                    self._put(req, ("error", e))
                     self._release(req)
                 inflight.clear()
+                self._hand_over()
 
     def _loop_once(self, inflight) -> None:
         """One iteration of the engine loop, each phase in its span (the
@@ -2513,6 +2751,7 @@ class LLMEngine:
                     / max(1, self._n_pages), tags=self._mtags)
         if not inflight:
             self._in_dispatch = False
+            self._hand_over()   # what admission errored, shed or ended
             with span("engine.idle_sleep"):
                 time.sleep(0.002)
             return
@@ -2532,6 +2771,9 @@ class LLMEngine:
                 emitted = self.stats["tokens_generated"] - emitted
                 if emitted:
                     m["tokens"].inc(float(emitted), tags=self._mtags)
+        # one wake of the consumers' loop for everything this iteration
+        # put, whichever phase put it
+        self._hand_over()
         self._in_dispatch = False
 
     def _dispatch_decode(self, inflight, snapshot, props, allow, pen,

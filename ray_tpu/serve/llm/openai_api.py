@@ -18,6 +18,7 @@ multi-token sequences work too).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -239,9 +240,9 @@ class OpenAIServer(LLMServer):
             err = {"error": {"message": str(e),
                              "type": "invalid_request_error"}}
             if isinstance(body, dict) and body.get("stream"):
-                # a real generator: the replica's streaming path detects
-                # generators, not arbitrary iterators
-                def err_stream():
+                # a real async generator: the replica's streaming path
+                # detects generators, not arbitrary iterators
+                async def err_stream():
                     yield err
                     yield "[DONE]"
                 return err_stream()
@@ -399,44 +400,59 @@ class OpenAIServer(LLMServer):
                         break
             return h
 
-        def gen():
-            if lead_chunk is not None:
-                yield wrap(lead_chunk)
-            emitted = ""     # decoded text already sent to the client
-            toks: List[int] = []
-            last_tok = None
-            by_string = False
-            full = ""
-            for tok, _lp in self.engine.stream_detailed(rid):
-                if by_string:
-                    continue  # draining to the end marker post-abort
-                toks.append(tok)
-                last_tok = tok
-                full, by_string = self._apply_stops(
-                    self._decode_text(toks), stops)
-                # withhold any tail that could still grow into a stop
-                # match (a suffix of the truncated text never reaches
-                # back into already-emitted text: that prefix was itself
-                # a stop prefix and was withheld on the earlier step)
-                safe = full if by_string else full[:len(full)
-                                                   - holdback(full)]
-                delta = safe[len(emitted):]
-                if delta:
-                    emitted = safe
-                    yield wrap(content_chunk(delta))
-                if by_string:
-                    # stop sequence landed: cut the engine request short
-                    # but keep consuming so its stream closes cleanly
-                    self.engine.abort(rid)
+        emitted = ""     # decoded text already sent to the client
+        toks: List[int] = []
+        by_string = False
+        full = ""
+
+        def on_token(tok: int) -> Optional[Dict[str, Any]]:
+            """The chunk this token completes, if it completes one."""
+            nonlocal emitted, by_string, full
+            if by_string:
+                return None  # draining to the end marker post-abort
+            toks.append(tok)
+            full, by_string = self._apply_stops(
+                self._decode_text(toks), stops)
+            if by_string:
+                # stop sequence landed: cut the engine request short
+                # but keep consuming so its stream closes cleanly
+                self.engine.abort(rid)
+            # withhold any tail that could still grow into a stop
+            # match (a suffix of the truncated text never reaches
+            # back into already-emitted text: that prefix was itself
+            # a stop prefix and was withheld on the earlier step)
+            safe = full if by_string else full[:len(full)
+                                               - holdback(full)]
+            delta = safe[len(emitted):]
+            if not delta:
+                return None
+            emitted = safe
+            return wrap(content_chunk(delta))
+
+        def tail():
             if not by_string and len(full) > len(emitted):
                 # stream ended (budget/EOS) with a withheld partial stop
                 # match that can no longer complete: flush it
                 yield wrap(content_chunk(full[len(emitted):]))
             yield wrap(final_extra(), finish=self._finish_reason(
-                len(toks), effective, last_tok, stop_ids, by_string))
+                len(toks), effective, toks[-1] if toks else None,
+                stop_ids, by_string))
             yield "[DONE]"
 
-        return gen()
+        async def agen(tokens):
+            if lead_chunk is not None:
+                yield wrap(lead_chunk)
+            async with contextlib.aclosing(tokens):
+                async for tok, _lp in tokens:
+                    chunk = on_token(tok)
+                    if chunk is not None:
+                        yield chunk
+            for chunk in tail():
+                yield chunk
+
+        # for the replica's stream_start to consume on its loop: no
+        # thread parks for the stream (engine.astream_detailed)
+        return agen(self.engine.astream_detailed(rid))
 
 
 def build_openai_deployment(model_factory, *, engine_config=None,

@@ -5,13 +5,16 @@ ongoing-request accounting, health checks, reconfigure, streaming) —
 re-shaped for the ray_tpu runtime: one actor per replica, async
 `handle_request` running on the worker's persistent asyncio loop, and a
 poll-based streaming protocol (`stream_next`) instead of gRPC streams.
+The streaming protocol lives on that loop too: a stream's drain task
+fills a bounded buffer and `stream_next` awaits it, so an open stream
+costs a task and a future, not a parked thread.
 """
 from __future__ import annotations
 
 import asyncio
+import collections
 import inspect
 import itertools
-import queue as queue_mod
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -21,9 +24,55 @@ from ..exceptions import DeadlineExceededError, ReplicaDrainingError
 _STREAM_END = "__ray_tpu_stream_end__"
 
 
-class _StreamCancelled(BaseException):
-    """Internal: consumer abandoned the stream; stop the drain task.
-    BaseException so a handler's own `except Exception` can't eat it."""
+class _StreamBuffer:
+    """Bounded buffer between one stream's drain task (`put`) and its
+    consumer's `stream_next` calls (`wait`, then `items`), both on the
+    actor's event loop — so no lock, and one waiter a side."""
+
+    def __init__(self, maxsize: int = 1024):
+        self.items: collections.deque = collections.deque()
+        self._maxsize = maxsize
+        self._room: Optional[asyncio.Future] = None    # a parked put
+        self._ready: Optional[asyncio.Future] = None   # a parked wait
+
+    @staticmethod
+    def _wake(fut: Optional[asyncio.Future]) -> None:
+        if fut is not None and not fut.done():
+            fut.set_result(None)
+
+    async def put(self, item) -> None:
+        """Never blocks the event loop: a slow consumer parks the drain
+        task here (and a cancelled stream's task is cancelled here)."""
+        while len(self.items) >= self._maxsize:
+            self._room = asyncio.get_running_loop().create_future()
+            try:
+                await self._room
+            finally:
+                self._room = None
+        self.items.append(item)
+        self._wake(self._ready)
+
+    async def wait(self, timeout_s: float) -> bool:
+        """Until there is an item; False after timeout_s without one. A
+        second poll of one stream (a caller that gave up on its first)
+        takes over: the first then answers "nothing yet" at once."""
+        if self.items:
+            return True
+        self._wake(self._ready)
+        fut = self._ready = asyncio.get_running_loop().create_future()
+        try:
+            await asyncio.wait_for(fut, timeout_s)
+        except asyncio.TimeoutError:
+            pass
+        finally:
+            if self._ready is fut:
+                self._ready = None
+        return bool(self.items)
+
+    def pop(self):
+        item = self.items.popleft()
+        self._wake(self._room)
+        return item
 
 
 class Replica:
@@ -52,17 +101,13 @@ class Replica:
         # modes for the fault-tolerance tests; all default off
         self._chaos_delay_s = 0.0
         self._chaos_health_mode = ""   # "" | "fail" | "hang" | "wedged"
-        self._streams: Dict[str, queue_mod.Queue] = {}
+        self._streams: Dict[str, _StreamBuffer] = {}
         self._stream_counter = itertools.count()
-        # stream ids whose consumer hung up: _drain stops pumping (and
-        # the parked _put unblocks) instead of leaking the queue and a
-        # permanently-elevated _ongoing count
-        self._cancelled_streams: set = set()
-        # stream ids whose drain task is still pumping: stream_cancel
-        # only flags these — flagging a FINISHED drain would leave the
-        # id in _cancelled_streams forever (its finally-discard already
-        # ran), an unbounded leak under abandon-after-completion traffic
-        self._live_drains: set = set()
+        # drain tasks still pumping, by stream id: stream_cancel cancels
+        # the task of a consumer that hung up, wherever it is parked (on
+        # the handler's next chunk or on a full buffer), instead of
+        # leaking the buffer and a permanently-elevated _ongoing count
+        self._drains: Dict[str, asyncio.Task] = {}
 
         target = serialization.loads_call(callable_bytes)
         if inspect.isclass(target):
@@ -257,11 +302,12 @@ class Replica:
     async def stream_start(self, method_name: str, args, kwargs) -> str:
         """Start a streaming call; returns a stream id to poll with
         stream_next(). The generator is drained on a background task and
-        chunks buffered, so slow consumers don't stall the handler."""
+        chunks buffered, so slow consumers don't stall the handler. An
+        async generator (an LLM stream: serve/llm) is iterated on this
+        loop; a plain generator is stepped on the default executor."""
         stamps = self._admit(kwargs)
         stream_id = f"{self._replica_id}-s{next(self._stream_counter)}"
-        q: queue_mod.Queue = queue_mod.Queue(maxsize=1024)
-        self._streams[stream_id] = q
+        buf = self._streams[stream_id] = _StreamBuffer()
         with self._lock:
             self._ongoing += 1
         mux_id = kwargs.pop("__serve_multiplexed_model_id", "")
@@ -271,18 +317,6 @@ class Replica:
             _set_multiplexed_model_id(mux_id)
         _set_request_stamps(*stamps)
         method = self._resolve_method(method_name)
-
-        async def _put(item):
-            # never block the event loop: the queue is bounded, so park
-            # in short async sleeps when a slow consumer falls behind.
-            while True:
-                if stream_id in self._cancelled_streams:
-                    raise _StreamCancelled()
-                try:
-                    q.put_nowait(item)
-                    return
-                except queue_mod.Full:
-                    await asyncio.sleep(0.01)
 
         def _next_with_ctx(it):
             # executor threads don't inherit the loop's contextvars; a
@@ -299,8 +333,13 @@ class Replica:
                 if inspect.iscoroutine(result):
                     result = await result
                 if inspect.isasyncgen(result):
-                    async for chunk in result:
-                        await _put(("chunk", chunk))
+                    try:
+                        async for chunk in result:
+                            await buf.put(("chunk", chunk))
+                    finally:
+                        # a cancelled stream closes its generator NOW:
+                        # an LLM stream aborts its engine request there
+                        await result.aclose()
                 elif inspect.isgenerator(result):
                     loop = asyncio.get_running_loop()
                     it = iter(result)
@@ -309,82 +348,58 @@ class Replica:
                             None, _next_with_ctx, it)
                         if chunk == _STREAM_END:
                             break
-                        await _put(("chunk", chunk))
+                        await buf.put(("chunk", chunk))
                 else:  # unary result streamed as a single chunk
-                    await _put(("chunk", result))
-                await _put(("end", None))
-            except _StreamCancelled:
-                pass               # consumer gone: just stop pumping
+                    await buf.put(("chunk", result))
+                await buf.put(("end", None))
+            except asyncio.CancelledError:
+                raise              # consumer gone: just stop pumping
             except BaseException as e:  # noqa: BLE001
-                try:
-                    await _put(("error", e))
-                except _StreamCancelled:
-                    pass
+                await buf.put(("error", e))
             finally:
+                self._drains.pop(stream_id, None)
                 with self._lock:
-                    # same lock as stream_cancel's check-then-add: the
-                    # cancel path runs on a threadpool thread while this
-                    # finally runs on the asyncio loop thread — unlocked
-                    # interleaving could add the id AFTER this discard,
-                    # leaking it forever
-                    self._live_drains.discard(stream_id)
-                    self._cancelled_streams.discard(stream_id)
                     self._ongoing -= 1
                     self._total_served += 1
 
-        self._live_drains.add(stream_id)
-        asyncio.ensure_future(_drain())
+        self._drains[stream_id] = asyncio.ensure_future(_drain())
         return stream_id
 
-    def stream_cancel(self, stream_id: str) -> bool:
+    async def stream_cancel(self, stream_id: str) -> bool:
         """Consumer abandoned the stream (client hung up): stop the
         drain task and drop the buffer. Idempotent; unknown/finished
         ids are a no-op."""
-        if stream_id in self._streams:
-            with self._lock:
-                if stream_id in self._live_drains:
-                    # only a still-running drain needs the flag (its
-                    # finally-discard cleans it up); a finished drain
-                    # would never remove it — leak
-                    self._cancelled_streams.add(stream_id)
-            self._streams.pop(stream_id, None)
-            return True
-        return False
+        if self._streams.pop(stream_id, None) is None:
+            return False
+        task = self._drains.get(stream_id)
+        if task is not None:
+            task.cancel()
+        return True
 
-    def stream_next(self, stream_id: str, batch: int = 64,
-                    timeout_s: float = 30.0):
-        """Pull up to `batch` buffered chunks. Returns (chunks, done).
-        Raises the handler's exception if the stream errored."""
-        q = self._streams.get(stream_id)
-        if q is None:
+    async def stream_next(self, stream_id: str, batch: int = 64,
+                          timeout_s: float = 30.0):
+        """Pull up to `batch` buffered chunks, waiting up to timeout_s
+        for the first. Returns (chunks, done). Raises the handler's
+        exception if the stream errored."""
+        buf = self._streams.get(stream_id)
+        if buf is None:
             return [], True
-        chunks = []
-        done = False
         from ..util import waits as waits_mod  # noqa: PLC0415
         wtok = waits_mod.park("serve-stream", stream_id,
-                              pending=q.qsize())
+                              pending=len(buf.items))
         try:
-            try:
-                kind, payload = q.get(timeout=timeout_s)
-            finally:
-                waits_mod.unpark(wtok)
-            while True:
-                if kind == "chunk":
-                    chunks.append(payload)
-                elif kind == "end":
-                    done = True
-                    break
-                elif kind == "error":
-                    self._streams.pop(stream_id, None)
-                    raise payload
-                if len(chunks) >= batch:
-                    break
-                try:
-                    kind, payload = q.get_nowait()
-                except queue_mod.Empty:
-                    break
-        except queue_mod.Empty:
-            pass
-        if done:
+            if not await buf.wait(timeout_s):
+                return [], False
+        finally:
+            waits_mod.unpark(wtok)
+        chunks = []
+        while buf.items and len(chunks) < batch:
+            kind, payload = buf.pop()
+            if kind == "chunk":
+                chunks.append(payload)
+                continue
             self._streams.pop(stream_id, None)
-        return chunks, done
+            if kind == "error":
+                raise payload
+            return chunks, True     # end
+        return chunks, False
