@@ -19,6 +19,7 @@ _REGISTRY = {
     "gpt2-large": lambda **kw: GPT2(GPT2Config.large(**kw)),
     "gpt2-debug": lambda **kw: GPT2(GPT2Config.debug(**kw)),
     "mixtral-8x7b": lambda **kw: Mixtral(MixtralConfig.mixtral_8x7b(**kw)),
+    "olmoe-1b-7b": lambda **kw: Mixtral(MixtralConfig.olmoe_1b_7b(**kw)),
     "mixtral-debug": lambda **kw: Mixtral(MixtralConfig.debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),

@@ -11,11 +11,12 @@ from .attention import (multi_head_attention, causal_attention_mask,
                         cached_attention)
 from .activations import swiglu, geglu
 from .ring_attention import ring_attention
-from .moe import (moe_dispatch_combine, top_k_routing, expert_capacity,
-                  MoEAux)
+from .moe import (moe_dispatch_combine, moe_dropless, route, router_aux,
+                  expert_capacity, MoEAux)
 
 __all__ = ["rms_norm", "layer_norm", "apply_rotary", "rope_frequencies",
            "multi_head_attention", "causal_attention_mask",
            "cached_attention", "swiglu",
            "geglu", "ring_attention", "moe_dispatch_combine",
-           "top_k_routing", "expert_capacity", "MoEAux"]
+           "moe_dropless", "route", "router_aux", "expert_capacity",
+           "MoEAux"]

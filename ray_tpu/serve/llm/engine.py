@@ -487,6 +487,13 @@ class LLMEngine:
         # _hand_over; appends and poplefts are atomic, so abort() and
         # the watchdog may put from their own threads
         self._outbox: collections.deque = collections.deque()
+        # counters a model leaves per call (Mixtral: ops/moe.py MOE_STATS);
+        # the paged step programs put them behind the tokens they return,
+        # so they reach the host in the fetch the tokens need anyway
+        self._step_stats = tuple(getattr(model, "step_stats", ()))
+        self._counted = self._paged and bool(self._step_stats)
+        for name in self._step_stats:
+            self.stats[name] = 0
         self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
         self._slot_freed_ns: Dict[int, int] = {}    # slot -> _release
         self._decode_dispatches = 0     # the `step` of a decode's span
@@ -848,15 +855,34 @@ class LLMEngine:
         return [PagedKV(k, v, page_table, lengths, self.cfg.kv_page_size)
                 for (k, v) in pools]
 
+    def _apply_counted(self, params, tokens, entries, positions, row_mask):
+        """model.apply for a step program. A model that declares
+        `step_stats` is told which rows are real and returns, summed
+        over its layers, the int32 vector of those counters; any other
+        model is called as ever and returns None for them."""
+        if not self._counted:
+            logits, new_entries = self.model.apply(
+                {"params": params}, tokens, cache=entries,
+                positions=positions)
+            return logits, new_entries, None
+        (logits, new_entries), sown = self.model.apply(
+            {"params": params}, tokens, cache=entries,
+            positions=positions, row_mask=row_mask,
+            mutable=["step_stats"])
+        leaves = self._jax.tree_util.tree_leaves(sown["step_stats"])
+        return logits, new_entries, sum(leaves)
+
     def _prefill_paged_impl(self, params, pools, page_table, lengths,
                             tokens, slots, true_lens, temps, top_ps,
                             rng_key, pad_len: int, allow=None,
-                            bias=None):
+                            bias=None, n_real=None):
         """Prefill G prompts (single and batched unified): KV streams
         straight into each slot's pages — no small-cache copy-back.
         tokens: (G, pad_len); slots/true_lens/temps/top_ps: (G,).
         Padding rows target the scratch slot, whose page-table row is
-        all-trash, so their writes vanish by construction."""
+        all-trash, so their writes vanish by construction. `n_real`
+        (given for a model that counts its rows): the first n_real rows
+        are prompts, the rest group padding."""
         jnp = self._jnp
         ps = self.cfg.kv_page_size
         g = tokens.shape[0]
@@ -871,14 +897,19 @@ class LLMEngine:
                    for (k, v) in pools]
         positions = jnp.broadcast_to(jnp.arange(pad_len)[None, :],
                                      (g, pad_len))
-        logits, new_entries = self.model.apply(
-            {"params": params}, tokens, cache=entries,
-            positions=positions)
+        real = positions < true_lens[:, None]      # not bucket padding
+        if n_real is not None:
+            real &= (jnp.arange(g) < n_real)[:, None]
+        logits, new_entries, counted = self._apply_counted(
+            params, tokens, entries, positions, real)
         new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
         lengths = lengths.at[slots].set(true_lens)
         last = logits[jnp.arange(g), true_lens - 1]
         toks, logps = self._sample_tokens(last, temps, top_ps, rng_key,
                                           allow=allow, bias=bias)
+        if counted is not None:
+            return (toks, logps, new_pools, lengths,
+                    jnp.concatenate([toks, counted]))
         return toks, logps, new_pools, lengths
 
     def _chunk_paged_impl(self, params, pools, page_table, lengths,
@@ -929,9 +960,9 @@ class LLMEngine:
             page_table = page_table[:, :window_pages]
         entries = self._paged_entries(pools, page_table, lengths)
         positions = lengths[:, None]
-        logits, new_entries = self.model.apply(
-            {"params": params}, last_tokens[:, None], cache=entries,
-            positions=positions)
+        logits, new_entries, counted = self._apply_counted(
+            params, last_tokens[:, None], entries, positions,
+            active_mask[:, None])
         logits = logits[:, 0, :]
         new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
         new_lengths = jnp.where(active_mask, new_entries[0].lengths,
@@ -940,9 +971,14 @@ class LLMEngine:
         nxt, logps = self._sample_tokens(logits, temps, top_ps, rng_key,
                                          allow=allow, bias=bias)
         nxt = jnp.where(active_mask, nxt, last_tokens)
+        out = (nxt, logps, new_pools, new_lengths)
         if pen is not None:
-            return nxt, logps, new_pools, new_lengths, new_counts
-        return nxt, logps, new_pools, new_lengths
+            out += (new_counts,)
+        if counted is not None:
+            # last: the tokens again with the model's counters behind
+            # them, which is what the engine fetches
+            out += (jnp.concatenate([nxt, counted]),)
+        return out
 
     def _decode_block_paged_impl(self, params, pools, page_table,
                                  lengths, last_tokens, active_mask,
@@ -953,10 +989,11 @@ class LLMEngine:
 
         def body(carry, key):
             pools, lengths, last = carry
-            nxt, logps, pools, lengths = self._decode_paged_impl(
+            nxt, logps, pools, lengths, *fetch = self._decode_paged_impl(
                 params, pools, page_table, lengths, last, active_mask,
                 temps, top_ps, key, window_pages=window_pages)
-            return (pools, lengths, nxt), (nxt, logps)
+            return (pools, lengths, nxt), (fetch[0] if fetch else nxt,
+                                           logps)
 
         (pools, lengths, last), (toks, logps) = jax.lax.scan(
             body, (pools, lengths, last_tokens), keys)
@@ -1169,7 +1206,7 @@ class LLMEngine:
         self._set_page_row(scratch, pages)
         try:
             self._rng_key, sub = self._jax.random.split(self._rng_key)
-            _t, _l, self._pools, self._lengths = self._prefill_paged_jit(
+            _t, _l, self._pools, self._lengths, *_c = self._prefill_paged_jit(
                 self.params, self._pools, self._page_table,
                 self._lengths, jnp.asarray(tokens),
                 jnp.asarray(np.asarray([scratch], np.int32)),
@@ -1906,7 +1943,9 @@ class LLMEngine:
                     [r for r, _ in members], g)
                 if pbias is not None:
                     kw["bias"] = pbias
-                toks_dev, lps_dev, self._pools, self._lengths = \
+                if self._counted:
+                    kw["n_real"] = np.int32(g_real)
+                toks_dev, lps_dev, self._pools, self._lengths, *counted = \
                     self._prefill_paged_jit(
                         self.params, self._pools, self._page_table,
                         self._lengths, jnp.asarray(tokens),
@@ -1960,6 +1999,8 @@ class LLMEngine:
                 np.asarray([s for _, s in members], np.int32))
             self._last_tokens = self._last_tokens.at[real_slots].set(
                 toks_dev)
+            if self._counted:
+                toks_dev = counted[0]   # all rows, then the counters
         except BaseException as e:  # noqa: BLE001
             for req, slot in members:
                 self._free_slot_pages(slot)
@@ -2069,7 +2110,7 @@ class LLMEngine:
             self._start_fetch(toks_dev)
             if self.cfg.logprobs:
                 self._start_fetch(lps_dev)
-            inflight.append(("prefill_batch", [req], toks_dev,
+            inflight.append(("prefill_chunk", [req], toks_dev,
                              lps_dev if self.cfg.logprobs else None,
                              time.perf_counter_ns()))
 
@@ -2595,14 +2636,23 @@ class LLMEngine:
                 host = np.asarray(arr)
                 lps = np.asarray(lp_arr) if lp_arr is not None else None
         except BaseException as e:  # noqa: BLE001  device-side failure
-            targets = (list(payload) if kind == "prefill_batch"
+            targets = (list(payload) if kind != "decode"
                        else [r for _, r in payload])
             for req in targets:
                 if req.slot >= 0:
                     self._put(req, ("error", e))
                     self._release(req)
             return
-        if kind == "prefill_batch":
+        if self._counted and kind != "prefill_chunk":
+            # the paged step programs' tokens come with the model's
+            # counters behind them (one vector a call, summed over its
+            # layers; a block of decode steps brings one a step)
+            n = len(self._step_stats)
+            tail = host[..., -n:].reshape(-1, n).sum(0)
+            for name, v in zip(self._step_stats, tail):
+                st[name] += int(v)
+            host = host[..., :-n]
+        if kind != "decode":
             reqs = payload
             firsts = host.reshape(-1)
             flat_lps = lps.reshape(-1) if lps is not None else None
@@ -2828,6 +2878,8 @@ class LLMEngine:
                     self._last_tokens, mask, temps,
                     top_ps, sub, window_pages=window,
                     **akw)
+                if self._counted:
+                    *res, fetch = res
                 if pen is not None:
                     (toks, logps, self._pools,
                      self._lengths, self._pen_counts) = res
@@ -2835,6 +2887,8 @@ class LLMEngine:
                     (toks, logps, self._pools,
                      self._lengths) = res
                 last = toks
+                if self._counted:
+                    toks = fetch
                 block = 1
             for slot in self._active:
                 # KeyError here = an admission path forgot

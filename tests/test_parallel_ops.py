@@ -9,6 +9,7 @@ from ray_tpu.parallel.pipeline import (pipeline_apply, pipeline_reference,
                                        stack_stage_params)
 from ray_tpu.ops import (ring_attention, multi_head_attention,
                          moe_dispatch_combine, expert_capacity)
+from ray_tpu.ops.moe import moe_dropless, route
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +117,82 @@ class TestMoE:
         out = run(x, logits, ws)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-4)
+
+
+class TestMoEDropless:
+    """Dropless twins of TestMoE: the sorted, grouped path."""
+
+    @staticmethod
+    def _weights(rng, e=4, d=16, f=8):
+        return [jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+                for shape in ((e, d, f), (e, d, f), (e, f, d))]
+
+    @staticmethod
+    def _expert_fn(wg, wu, wd):
+        def fn(batch):
+            gate = jnp.einsum("ecd,edf->ecf", batch, wg)
+            up = jnp.einsum("ecd,edf->ecf", batch, wu)
+            return jnp.einsum("ecf,efd->ecd", jax.nn.silu(gate) * up, wd)
+        return fn
+
+    @pytest.mark.parametrize("routing", ["topk_softmax", "softmax_topk"])
+    def test_matches_capacity_path_when_nothing_overflows(self, rng,
+                                                          routing):
+        x = jnp.asarray(rng.randn(64, 16), jnp.float32)
+        logits = jnp.asarray(rng.randn(64, 4), jnp.float32)
+        wg, wu, wd = self._weights(rng)
+        ref, _ = moe_dispatch_combine(
+            x, logits, self._expert_fn(wg, wu, wd), k=2, capacity=128,
+            routing=routing)
+        weights, idx = route(logits, 2, routing)
+        out, stats = moe_dropless(x, weights, idx, wg, wu, wd)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+        assert stats[0] == 128 and stats[1] == 64 and stats[2] == 0
+
+    def test_masked_rows_are_given_to_no_expert(self, rng):
+        x = jnp.asarray(rng.randn(32, 16), jnp.float32)
+        logits = jnp.asarray(rng.randn(32, 4), jnp.float32)
+        wg, wu, wd = self._weights(rng)
+        weights, idx = route(logits, 2)
+        mask = jnp.arange(32) < 20
+        full, _ = moe_dropless(x, weights, idx, wg, wu, wd)
+        out, stats = moe_dropless(x, weights, idx, wg, wu, wd, mask)
+        np.testing.assert_array_equal(np.asarray(out[:20]),
+                                      np.asarray(full[:20]))
+        assert not np.asarray(out[20:]).any()
+        assert stats.tolist()[:3] == [40, 20, 12]
+
+    def test_tpu_kernel_matches_the_reference_lowering(self, rng):
+        """The TPU branch of grouped_matmul (megablox) in interpret
+        mode against jax.lax.ragged_dot, on the rows that belong to a
+        group; what lies behind the last group is nobody's."""
+        from ray_tpu.ops.moe import grouped_matmul
+        xs = jnp.asarray(rng.randn(256, 64), jnp.float32)
+        w = jnp.asarray(rng.randn(4, 64, 32), jnp.float32)
+        sizes = jnp.asarray([100, 0, 57, 43], jnp.int32)
+        want = grouped_matmul(xs, w, sizes)
+        got = grouped_matmul(xs, w, sizes, interpret=True)
+        np.testing.assert_allclose(np.asarray(got[:200]),
+                                   np.asarray(want[:200]), atol=1e-4)
+
+    def test_one_program_for_any_routing(self, rng):
+        """Static shapes: the same compiled program serves an even
+        routing and every row on the same two experts."""
+        wg, wu, wd = self._weights(rng)
+        x = jnp.asarray(rng.randn(32, 16), jnp.float32)
+
+        @jax.jit
+        def run(x, logits):
+            weights, idx = route(logits, 2)
+            return moe_dropless(x, weights, idx, wg, wu, wd)
+
+        _, even = run(x, jnp.asarray(rng.randn(32, 4), jnp.float32))
+        _, skew = run(x, jnp.tile(jnp.asarray([[5., 4., 0., 0.]]),
+                                  (32, 1)))
+        assert run._cache_size() == 1
+        assert skew.tolist() == [64, 32, 0, 32, 2]
+        assert even[3] < 32 and even[4] == 4
 
 
 class TestPipeline:

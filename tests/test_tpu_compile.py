@@ -88,3 +88,43 @@ def test_paged_decode_compiles_for_v5e(chip, page_size, width):
         sds((n_flat, hkv, d), jnp.bfloat16),
         sds((SLOTS, pages), jnp.int32),
         sds((SLOTS,), jnp.int32)).compile())
+
+
+@pytest.mark.parametrize("rows", [65, 2048], ids=["decode", "prefill"])
+def test_dropless_expert_layer_compiles_for_v5e(chip, rows, monkeypatch):
+    """OLMoE's expert layer at published widths (64 experts of 2048 x
+    1024, 8 a token): the grouped matmuls are Mosaic kernels (megablox,
+    the TPU branch of `grouped_matmul`) at the row counts of a decode
+    step and of a prefill group, and at 16 MHA heads of 128 (rep 1) the
+    paged kernel compiles too."""
+    from ray_tpu.ops.moe import moe_dropless, route
+    # the code under test asks which backend it runs on; the compile is
+    # for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    e, d, f, k = 64, 2048, 1024, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def layer(x, logits, wg, wu, wd, mask):
+        weights, idx = route(logits, k, "softmax_topk")
+        return moe_dropless(x, weights, idx, wg, wu, wd, mask)
+
+    text = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((rows, e), jnp.float32),
+        sds((e, d, f), jnp.bfloat16), sds((e, d, f), jnp.bfloat16),
+        sds((e, f, d), jnp.bfloat16), sds((rows,), bool)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+    if rows == 65:
+        pages, ps = 4096 // 64, 64
+        n_flat = (rows * 8 + 1) * ps
+
+        def decode(q, kf, vf, table, lengths):
+            return paged_decode_attention(q, kf, vf, table, lengths, ps,
+                                          interpret=False)
+        _assert_mosaic(jax.jit(decode).lower(
+            sds((rows, 16, 128), jnp.bfloat16),
+            sds((n_flat, 16, 128), jnp.bfloat16),
+            sds((n_flat, 16, 128), jnp.bfloat16),
+            sds((rows, pages), jnp.int32),
+            sds((rows,), jnp.int32)).compile())
