@@ -40,10 +40,13 @@ def _resolve_impl(impl: str, q: jax.Array, k: jax.Array, causal: bool,
         return "xla"
     if causal and q.shape[1] != k.shape[1]:
         return "xla"
-    # Measured on v5e (llama 254M train, seq 1024): XLA's fused attention
-    # beats the Pallas kernel end-to-end (36.6% vs 27.0% MFU) — XLA wins
-    # while the S x S logits still fit comfortably; flash pays off once
-    # attention is memory-bound at long sequence. Crossover ~2k.
+    # Forward + backward on one v5e, 16 query / 4 KV heads of 128, bf16,
+    # causal, device time (tools/flash_microbench.py; PERF.md, PR 27):
+    # at 4 x 2 048 xla 16.7 ms, dpa 13.1, pallas 3.75 (19.6 with PR 26's
+    # 128 x 128 tiles); at 2 x 4 096 xla 32.3, dpa 25.4, pallas 5.70
+    # (37.2). Below 2 048 one point since the kernel was re-tiled, at
+    # 2 x 1 000 with 8 heads of 64: xla 0.16 ms, pallas 0.30; the
+    # threshold is the old one (ROADMAP A1 (c) sweeps 256-1 024).
     if q.shape[1] < 2048:
         return "xla"
     return "pallas"

@@ -428,10 +428,6 @@ _declare("RAY_TPU_ATTN_IMPL", "str", "auto",
 _declare("RAY_TPU_PAGED_ATTN_IMPL", "str", "auto",
          "Paged-attention kernel selection (auto | gather | ...).",
          "ops")
-_declare("RAY_TPU_FLASH_BLOCK_Q", "int", 128,
-         "Flash-attention query block size.", "ops")
-_declare("RAY_TPU_FLASH_BLOCK_K", "int", 128,
-         "Flash-attention key block size.", "ops")
 _declare("RAY_TPU_POD_TYPE", "str", None,
          "TPU pod/accelerator type override (else "
          "TPU_ACCELERATOR_TYPE).", "topology")

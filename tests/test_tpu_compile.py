@@ -3,8 +3,9 @@
 The TPU compiler is installed next to the CPU backend and compiles for a
 chip that is described, not attached (on-chip-measurement guide §2.3).
 Interpret-mode tests cannot see what Mosaic refuses (tiling, VMEM
-budget); these can, at Llama-3.2-1B and Llama-3-8B attention widths, at
-a few seconds in all. Nothing runs, so nothing here is a result or a time.
+budget); these can, at Llama-3.2-1B and Llama-3-8B attention widths and
+at the training cell's share a chip, at half a minute in all. Nothing
+runs, so nothing here is a result or a time.
 """
 import os
 
@@ -66,6 +67,30 @@ def test_flash_attention_compiles_for_v5e(chip, fn, width):
     kv = jax.ShapeDtypeStruct((1, SEQ, hkv, d), jnp.bfloat16,
                               sharding=chip)
     _assert_mosaic(jax.jit(fn).lower(q, kv, kv).compile())
+
+
+# (batch, seq, Hq, Hkv, D, dtype): `mistral7b_train_fsdp2_tp2`'s share a
+# chip (the tiles the cell runs with), and sequences that are no
+# multiple of a tile at both head widths
+FLASH_SHAPES = {
+    "train_cell": (2, 4096, 16, 4, 128, jnp.bfloat16),
+    "ragged_1000x64": (1, 1000, 4, 4, 64, jnp.bfloat16),
+    "ragged_300_f32": (1, 300, 4, 2, 128, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+@pytest.mark.parametrize("fn", [_flash_fwd, _flash_bwd],
+                         ids=["fwd", "bwd"])
+def test_flash_attention_chosen_tiles_compile_for_v5e(chip, fn, shape):
+    """No tile is passed: what compiles is what `choose_blocks` picked,
+    under the VMEM limit the kernels ask for, so a tile Mosaic or VMEM
+    refuses fails here and not in a chip call."""
+    b, s, hq, hkv, d, dtype = FLASH_SHAPES[shape]
+    q = jax.ShapeDtypeStruct((b, s, hq, d), dtype, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), dtype, sharding=chip)
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= (1 if fn is _flash_fwd else 3)
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
