@@ -1843,12 +1843,9 @@ def phase_serve() -> dict:
                            pipeline_depth=int(os.environ.get(
                                "RAY_TPU_BENCH_ENGINE_DEPTH", "10")),
                            decode_block=int(os.environ.get(
-                               "RAY_TPU_BENCH_DECODE_BLOCK", "1")),
-                           # paged KV pool (r5): 8 slots' worth of
-                           # budget in 64-token pages; stats surface in
-                           # the phase result
-                           kv_page_size=int(os.environ.get(
-                               "RAY_TPU_BENCH_KV_PAGE", "64")))
+                               "RAY_TPU_BENCH_DECODE_BLOCK", "1")))
+    # KV pool at the defaults: 8 slots' worth of budget in 64-token
+    # pages; stats surface in the phase result
     engine = LLMEngine(model, params, ecfg)
     rng = np.random.RandomState(0)
 

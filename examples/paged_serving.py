@@ -1,11 +1,10 @@
 """Paged-KV serving: more concurrent sequences in the same HBM budget.
 
-The r5 engine replaces per-slot contiguous (max_slots x max_seq_len) KV
-buffers with a shared page pool (cfg.kv_page_size > 0; vLLM's
-PagedAttention re-designed TPU-first — static shapes, decode compiles
-once, a Pallas kernel reads pages directly on real TPU). Requests
-reserve only ceil((prompt + budget) / page_size) pages, so short
-requests stop stranding max_seq_len of HBM each, and a registered
+The engine keeps KV in a shared page pool (cfg.kv_page_size tokens a
+page; vLLM's PagedAttention re-designed TPU-first — static shapes, one
+decode program per page window, a Pallas kernel reads pages directly on
+real TPU). Requests reserve only ceil((prompt + budget) / page_size)
+pages, so a short request strands no max_seq_len of HBM, and a registered
 prefix is pinned SHARED pages: adopters reference its full pages for
 free and copy only the partial tail page.
 
@@ -29,7 +28,7 @@ def main():
     params = model.init_params(jax.random.PRNGKey(0))
 
     engine = LLMEngine(model, params, LLMEngineConfig(
-        max_slots=16,              # slot count no longer bounds HBM
+        max_slots=16,              # the pool bounds HBM, not the slots
         max_seq_len=256,
         prefill_buckets=(16, 32, 64),
         kv_page_size=16,           # pages of 16 tokens
@@ -44,8 +43,8 @@ def main():
     print(f"registered 45-token prefix -> "
           f"{engine.get_stats()['kv_pages']['pinned_prefix']} pinned pages")
 
-    # 12 concurrent short requests in a budget that would hold only
-    # 1024/256 = 4 contiguous slots
+    # 12 concurrent short requests in a budget of only
+    # 1024/256 = 4 sequences of max_seq_len
     results = {}
 
     def one(i):

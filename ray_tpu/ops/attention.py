@@ -62,10 +62,12 @@ def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
     lengths) with ck/cv (B, L, Hkv, D) and lengths (B,). Writes k/v at
     `positions` (B, S), attends causally over the written prefix, and
     returns (out (B, S, Hq, D), new_cache). Shared by every decoder in
-    the zoo (llama.py, gpt2.py) — the engine's serving contract.
+    the zoo (llama.py, gpt2.py): the (ck, cv, lengths) entry is the
+    model's own cache (Model.empty_cache).
 
-    A PagedKV cache entry routes to paged_cached_attention — same
-    semantics over a shared page pool. `impl` (the model's
+    A PagedKV cache entry — what the serving engine passes — routes to
+    paged_cached_attention: same semantics over a shared page pool.
+    `impl` (the model's
     cfg.attn_impl) governs the fresh-prefill fast path's attention
     router so a pinned implementation holds on every code path."""
     if isinstance(cache, PagedKV):
@@ -85,10 +87,11 @@ def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array, cache,
 
 
 def _attend_cached(q, ck, cv, positions, new_lengths, scale):
-    """Shared attention tail for the contiguous and paged cached paths:
-    length-valid mask + causal mask + GQA repeat + softmax(QK)V. ONE
-    implementation so the paged engine can never drift numerically from
-    the contiguous one (their token-identical contract is tested)."""
+    """Shared attention tail of cached_attention and of the page pool's
+    gather path: length-valid mask + causal mask + GQA repeat +
+    softmax(QK)V. ONE implementation, so the engine's pages can never
+    drift numerically from the model's own cache (their
+    token-identical contract is tested in tests/test_paged_kv.py)."""
     hq = q.shape[2]
     L = ck.shape[1]
     valid = jnp.arange(L)[None, :] < new_lengths[:, None]

@@ -121,6 +121,8 @@ class EngineSampler:
                  engine_cfg=None):
         from ..serve.llm import LLMEngine, LLMEngineConfig  # noqa: PLC0415
         if engine_cfg is None:
+            # KV pool at the defaults: 64-token pages, every slot can
+            # reach max_seq_len
             engine_cfg = LLMEngineConfig(
                 max_slots=min(16, max(2, cfg.group_size)),
                 max_seq_len=max_seq_len,

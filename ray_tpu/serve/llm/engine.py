@@ -3,15 +3,16 @@
 Reference parity: the fork's vLLM-style serving path (continuous batching,
 paged KV, streaming) — re-designed TPU-first:
 
-* Slot-based KV cache: one preallocated HBM buffer per layer of shape
-  (max_slots, max_seq_len, n_kv_heads, head_dim). Static shapes, so the
-  decode step compiles ONCE and every subsequent step reuses it.
+* Paged KV cache: one preallocated page pool per layer, flattened to
+  (n_pages * page_size, n_kv_heads, head_dim) token rows, plus a
+  (slots, pages_per_slot) page table. A slot reserves the pages its
+  prompt + budget need at admission. Static shapes, so the decode step
+  compiles once per power-of-two page window.
 * Continuous batching: ONE jitted decode step advances ALL active slots
   together (the MXU sees batch=max_slots matmuls, not per-request calls).
   Requests join/leave between steps with no recompile.
 * Prefill: prompts are padded to power-of-two buckets -> a handful of
-  compiles total; KV is written straight into the request's slot via
-  dynamic_update_slice.
+  compiles total; KV scatters straight into the request's pages.
 * Sampling (greedy / temperature / global top-k / per-request nucleus
   top-p) happens on-device inside the jitted step; only the sampled
   token ids (max_slots int32) cross to host per step. Per-request stop
@@ -89,8 +90,8 @@ class LLMEngineConfig:
     # classic one-token step.
     decode_block: int = 1
     # Waiting prompts that share a length bucket prefill TOGETHER in one
-    # jitted call of up to this many rows (padded to a power of two via a
-    # scratch cache slot) — one dispatch and one model pass instead of
+    # jitted call of up to this many rows (padded to a power of two via
+    # the scratch slot) — one dispatch and one model pass instead of
     # per-prompt calls. 1 disables batching.
     max_prefill_batch: int = 4
     # Chunked prefill (vLLM-style): prompts longer than this split into
@@ -109,25 +110,21 @@ class LLMEngineConfig:
     precompile: bool = False
     # Prefix caching (vLLM's automatic-prefix-caching, made explicit
     # and static-shape for TPU): register_prefix() prefills a shared
-    # prompt prefix ONCE into a dedicated KV buffer; submits carrying
-    # prefix_id adopt it with one on-device copy and prefill only
-    # their suffix. 0 disables (no buffer allocated).
-    # With kv_page_size > 0 there is NO dedicated buffer: a registered
-    # prefix is pinned shared pages in the pool; adoption shares its
-    # full pages by page-table reference (zero copy) and copies only
-    # the final partial page.
+    # prompt prefix ONCE into pinned pages of the pool; submits carrying
+    # prefix_id share its full pages by page-table reference (zero
+    # copy), copy only the final partial page and prefill only their
+    # suffix. 0 disables.
     max_prefixes: int = 0
-    # Paged KV cache (VERDICT r4 #4; vLLM's PagedAttention, TPU-first).
-    # 0 = legacy contiguous per-slot (max_slots x max_seq_len) buffers.
-    # >0 = a shared page pool: per-layer flat (n_pages * page_size)
-    # token rows + per-slot page tables (static shapes — decode still
-    # compiles once; see ops/attention.py:paged_cached_attention).
-    # Slots reserve ceil((prompt+budget)/page_size) pages at admission,
-    # so short requests no longer strand max_seq_len of HBM each and
-    # concurrency is bounded by the real token budget, not slot count.
-    kv_page_size: int = 0
+    # Tokens per page of the KV cache (vLLM's PagedAttention,
+    # TPU-first): a shared pool of per-layer flat (n_pages * page_size)
+    # token rows + per-slot page tables (static shapes; see
+    # ops/attention.py:paged_cached_attention). Slots reserve
+    # ceil((prompt+budget)/page_size) pages at admission, so a short
+    # request strands no max_seq_len of HBM and concurrency is bounded
+    # by the real token budget, not the slot count. Must be > 0.
+    kv_page_size: int = 64
     # Total pool budget in KV tokens (rounded up to whole pages).
-    # 0 = max_slots * max_seq_len (same HBM as the legacy layout).
+    # 0 = max_slots * max_seq_len (every slot can reach max_seq_len).
     kv_pool_tokens: int = 0
     # n-gram (prompt-lookup) speculative decoding: propose K tokens per
     # step by matching the trailing `ngram_order`-gram against the
@@ -363,7 +360,8 @@ class LLMEngine:
 
     `model` must follow the ray_tpu/models/llama.py contract:
     apply({"params": params}, tokens, cache=..., positions=...) ->
-    (logits, new_cache) with cache = [per-layer (k, v, lengths)].
+    (logits, new_cache) with cache = one entry per layer; the engine
+    passes ops/attention.py:PagedKV entries over its page pool.
     """
 
     def __init__(self, model, params, cfg: LLMEngineConfig):
@@ -390,54 +388,44 @@ class LLMEngine:
                 f"engine max_seq_len {cfg.max_seq_len} exceeds the "
                 f"model's max_seq_len {model_max}")
         S, L = cfg.max_slots, cfg.max_seq_len
-        self._paged = cfg.kv_page_size > 0
-        # +1 scratch slot when prefill batching is on: padding rows of a
-        # batched prefill write their KV there; it is never admitted, so
-        # its garbage never decodes. With batching off there is no
-        # scratch row (decode pays no extra-slot work). Paged engines
-        # always keep it (costs one page-table row, not a KV row): it
-        # anchors batch-padding writes AND prefix registration prefills.
-        self._n_slots = (S + 1 if (cfg.max_prefill_batch > 1
-                                   or self._paged) else S)
+        ps = cfg.kv_page_size
+        if ps <= 0:
+            raise ValueError(
+                f"kv_page_size must be > 0, got {ps}: the KV cache is a "
+                "page pool and the contiguous per-slot layout is gone")
+        # +1 scratch slot, never admitted, so its garbage never decodes
+        # (costs one page-table row, not a KV row): padding rows of a
+        # batched prefill write through its all-trash page row, and a
+        # prefix registration prefills through it.
+        self._n_slots = S + 1
         self._scratch_slot = S
-        if self._paged:
-            ps = cfg.kv_page_size
-            # per-slot gather width: whole pages covering max_seq_len
-            self._pages_per_slot = -(-L // ps)
-            pool_tokens = cfg.kv_pool_tokens or S * L
-            # the configured budget is honored exactly (rounded up to a
-            # page): oversized requests fail fast at submit() instead of
-            # silently inflating the pool
-            self._n_pages = max(1, -(-pool_tokens // ps))
-            self._trash_page = self._n_pages  # extra page: writes by
-            # released/padding slots land here and are never read valid
-            n_flat = (self._n_pages + 1) * ps
-            self._pools = [
-                (jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
-                           mcfg.dtype),
-                 jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
-                           mcfg.dtype))
-                for _ in range(mcfg.n_layers)]
-            self._page_table = jnp.full(
-                (self._n_slots, self._pages_per_slot),
-                self._trash_page, jnp.int32)
-            self._lengths = jnp.zeros((self._n_slots,), jnp.int32)
-            # host-side allocator
-            self._free_pages: List[int] = list(range(self._n_pages))
-            # slot -> (n_shared_prefix_pages, [all pages in table order])
-            self._slot_pages: Dict[int, tuple] = {}
-            self._prefix_pages: Dict[int, List[int]] = {}
-            self._pending_head: Optional[_Request] = None
-            self._page_hwm = 0      # peak pages in use (stats)
-            self._cache = None
-        else:
-            self._cache = [
-                (jnp.zeros((self._n_slots, L, mcfg.n_kv_heads,
-                            mcfg.head_dim), mcfg.dtype),
-                 jnp.zeros((self._n_slots, L, mcfg.n_kv_heads,
-                            mcfg.head_dim), mcfg.dtype),
-                 jnp.zeros((self._n_slots,), jnp.int32))
-                for _ in range(mcfg.n_layers)]
+        # per-slot gather width: whole pages covering max_seq_len
+        self._pages_per_slot = -(-L // ps)
+        pool_tokens = cfg.kv_pool_tokens or S * L
+        # the configured budget is honored exactly (rounded up to a
+        # page): oversized requests fail fast at submit() instead of
+        # silently inflating the pool
+        self._n_pages = max(1, -(-pool_tokens // ps))
+        self._trash_page = self._n_pages  # extra page: writes by
+        # released/padding slots land here and are never read valid
+        n_flat = (self._n_pages + 1) * ps
+        self._pools = [
+            (jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
+                       mcfg.dtype),
+             jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
+                       mcfg.dtype))
+            for _ in range(mcfg.n_layers)]
+        self._page_table = jnp.full(
+            (self._n_slots, self._pages_per_slot),
+            self._trash_page, jnp.int32)
+        self._lengths = jnp.zeros((self._n_slots,), jnp.int32)
+        # host-side allocator
+        self._free_pages: List[int] = list(range(self._n_pages))
+        # slot -> (n_shared_prefix_pages, [all pages in table order])
+        self._slot_pages: Dict[int, tuple] = {}
+        self._prefix_pages: Dict[int, List[int]] = {}
+        self._pending_head: Optional[_Request] = None
+        self._page_hwm = 0      # peak pages in use (stats)
         self._last_tokens = jnp.zeros((self._n_slots,), jnp.int32)
         self._free_slots = list(range(S))
         self._active: Dict[int, _Request] = {}
@@ -488,10 +476,10 @@ class LLMEngine:
         # the watchdog may put from their own threads
         self._outbox: collections.deque = collections.deque()
         # counters a model leaves per call (Mixtral: ops/moe.py MOE_STATS);
-        # the paged step programs put them behind the tokens they return,
-        # so they reach the host in the fetch the tokens need anyway
+        # the step programs put them behind the tokens they return, so
+        # they reach the host in the fetch the tokens need anyway
         self._step_stats = tuple(getattr(model, "step_stats", ()))
-        self._counted = self._paged and bool(self._step_stats)
+        self._counted = bool(self._step_stats)
         for name in self._step_stats:
             self.stats[name] = 0
         self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
@@ -530,68 +518,37 @@ class LLMEngine:
                 pass
         self._event = _event
 
-        # prefix cache: per layer (n_prefixes, L, Hkv, D) k/v + host-side
-        # token records; written by register_prefix, read (copied into a
-        # slot) at admission of prefix-carrying requests
-        self._prefix_cache = None
+        # registered prefixes: host-side token records; their KV lives
+        # in pinned pages of the pool (self._prefix_pages)
         self._prefixes: Dict[int, np.ndarray] = {}   # pid -> tokens
         self._prefix_counter = itertools.count()
-        if cfg.max_prefixes > 0 and not self._paged:
-            # +1 scratch row: precompile() warms fill/adopt/chunk paths
-            # by EXECUTING a dummy prefix'd request against it (AOT
-            # lower().compile() does not populate the jit call cache)
-            self._prefix_cache = [
-                (jnp.zeros((cfg.max_prefixes + 1, L, mcfg.n_kv_heads,
-                            mcfg.head_dim), mcfg.dtype),
-                 jnp.zeros((cfg.max_prefixes + 1, L, mcfg.n_kv_heads,
-                            mcfg.head_dim), mcfg.dtype))
-                for _ in range(mcfg.n_layers)]
-            self._prefix_fill_jit = jax.jit(
-                self._prefix_fill_impl, static_argnames=("pad_len",))
-            self._adopt_prefix_jit = jax.jit(
-                self._adopt_prefix_impl, donate_argnums=(0,))
 
         self._prefilling: collections.deque = collections.deque()
-        self._prefill_jit = jax.jit(
-            self._prefill_impl, static_argnames=("pad_len",),
-            donate_argnums=(1,))
-        self._prefill_chunk_jit = jax.jit(
-            self._prefill_chunk_impl,
-            static_argnames=("chunk", "sample"), donate_argnums=(1,))
-        self._prefill_batch_jit = jax.jit(
-            self._prefill_batch_impl, static_argnames=("pad_len",),
-            donate_argnums=(1,))
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=(1,))
-        self._verify_jit = jax.jit(self._verify_impl, donate_argnums=(1,))
-        self._decode_block_jit = (
-            jax.jit(self._decode_block_impl, donate_argnums=(1,))
+        self._prefill_paged_jit = jax.jit(
+            self._prefill_paged_impl, static_argnames=("pad_len",),
+            donate_argnums=(1, 3))
+        self._chunk_paged_jit = jax.jit(
+            self._chunk_paged_impl,
+            static_argnames=("chunk", "sample"), donate_argnums=(1, 3))
+        self._decode_paged_jit = jax.jit(
+            self._decode_paged_impl, donate_argnums=(1, 3),
+            static_argnames=("window_pages",))
+        self._verify_paged_jit = jax.jit(
+            self._verify_paged_impl, donate_argnums=(1, 3),
+            static_argnames=("window_pages",))
+        self._decode_block_paged_jit = (
+            jax.jit(self._decode_block_paged_impl,
+                    donate_argnums=(1, 3),
+                    static_argnames=("window_pages",))
             if cfg.decode_block > 1 else None)
-        if self._paged:
-            self._prefill_paged_jit = jax.jit(
-                self._prefill_paged_impl, static_argnames=("pad_len",),
-                donate_argnums=(1, 3))
-            self._chunk_paged_jit = jax.jit(
-                self._chunk_paged_impl,
-                static_argnames=("chunk", "sample"), donate_argnums=(1, 3))
-            self._decode_paged_jit = jax.jit(
-                self._decode_paged_impl, donate_argnums=(1, 3),
-                static_argnames=("window_pages",))
-            self._verify_paged_jit = jax.jit(
-                self._verify_paged_impl, donate_argnums=(1, 3),
-                static_argnames=("window_pages",))
-            self._decode_block_paged_jit = (
-                jax.jit(self._decode_block_paged_impl,
-                        donate_argnums=(1, 3),
-                        static_argnames=("window_pages",))
-                if cfg.decode_block > 1 else None)
-            # host mirror of each slot's device length: picks the
-            # power-of-2 page window covering the longest active
-            # sequence at decode-dispatch time
-            self._disp_len: Dict[int, int] = {}
-            self._copy_page_jit = jax.jit(self._copy_page_impl,
-                                          donate_argnums=(0,))
-        # register_prefix (paged) must mutate the pools on the engine
-        # loop thread — its dispatches donate them, so a concurrent
+        # host mirror of each slot's device length: picks the
+        # power-of-2 page window covering the longest active
+        # sequence at decode-dispatch time
+        self._disp_len: Dict[int, int] = {}
+        self._copy_page_jit = jax.jit(self._copy_page_impl,
+                                      donate_argnums=(0,))
+        # register_prefix must mutate the pools on the engine loop
+        # thread — its dispatches donate them, so a concurrent
         # public-API mutation would race a stale buffer. Commands queue
         # here and the loop executes them between steps.
         self._control_q: "queue_mod.Queue" = queue_mod.Queue()
@@ -703,150 +660,7 @@ class LLMEngine:
                                         axis=-1)[:, 0]
         return toks, logps
 
-    def _prefill_impl(self, params, cache, tokens, slot, true_len, temp,
-                      top_p, rng_key, pad_len: int, allow=None,
-                      bias=None):
-        """Run the prompt through the model writing KV into `slot`, and
-        sample the first generated token ON DEVICE (no host sync).
-        tokens: (1, pad_len); returns (token () int32, cache')."""
-        jnp = self._jnp
-        jax = self._jax
-        lax = jax.lax
-        # slice this slot's rows out of the big cache
-        small = []
-        for (ck, cv, lens) in cache:
-            k1 = lax.dynamic_slice_in_dim(ck, slot, 1, axis=0)
-            v1 = lax.dynamic_slice_in_dim(cv, slot, 1, axis=0)
-            small.append((k1, v1, jnp.zeros((1,), jnp.int32)))
-        positions = jnp.arange(pad_len)[None, :]
-        logits, new_small = self.model.apply(
-            {"params": params}, tokens, cache=small, positions=positions)
-        out_cache = []
-        for (ck, cv, lens), (k1, v1, _l1) in zip(cache, new_small):
-            ck = lax.dynamic_update_slice_in_dim(ck, k1, slot, axis=0)
-            cv = lax.dynamic_update_slice_in_dim(cv, v1, slot, axis=0)
-            lens = lens.at[slot].set(true_len)
-            out_cache.append((ck, cv, lens))
-        last = logits[0, true_len - 1]
-        toks, logps = self._sample_tokens(last[None, :], temp[None],
-                                          top_p[None], rng_key,
-                                          allow=allow, bias=bias)
-        return toks[0], logps[0], out_cache
-
-    def _prefill_chunk_impl(self, params, cache, tokens, slot, start,
-                            new_len, temp, top_p, rng_key,
-                            chunk: int, sample: bool, allow=None,
-                            bias=None):
-        """One chunk of a long prompt through the CACHED path: tokens
-        (1, chunk) written at positions [start, start+chunk); the slot's
-        length becomes `new_len` (start + true tokens in this chunk, so
-        tail padding of the final chunk stays invisible — pad queries
-        only ever attend pad keys and their outputs are discarded).
-        sample=True (final chunk) also samples the first generated token
-        from the last true position."""
-        jnp = self._jnp
-        jax = self._jax
-        lax = jax.lax
-        small = []
-        # The slot's true current length IS `start` — a reused slot's
-        # stored length would be stale from the previous occupant and
-        # leak its KV into the chunk's valid-mask.
-        l1 = jnp.reshape(start, (1,)).astype(jnp.int32)
-        for (ck, cv, lens) in cache:
-            k1 = lax.dynamic_slice_in_dim(ck, slot, 1, axis=0)
-            v1 = lax.dynamic_slice_in_dim(cv, slot, 1, axis=0)
-            small.append((k1, v1, l1))
-        positions = start + jnp.arange(chunk)[None, :]
-        logits, new_small = self.model.apply(
-            {"params": params}, tokens, cache=small, positions=positions)
-        out_cache = []
-        for (ck, cv, lens), (k1, v1, _l1) in zip(cache, new_small):
-            ck = lax.dynamic_update_slice_in_dim(ck, k1, slot, axis=0)
-            cv = lax.dynamic_update_slice_in_dim(cv, v1, slot, axis=0)
-            lens = lens.at[slot].set(new_len)
-            out_cache.append((ck, cv, lens))
-        if not sample:
-            return jnp.int32(0), jnp.float32(0), out_cache
-        last = logits[0, new_len - start - 1]
-        toks, logps = self._sample_tokens(last[None, :], temp[None],
-                                          top_p[None], rng_key,
-                                          allow=allow, bias=bias)
-        return toks[0], logps[0], out_cache
-
-    def _prefill_batch_impl(self, params, cache, tokens, slots, true_lens,
-                            temps, top_ps, rng_key, pad_len: int,
-                            allow=None, bias=None):
-        """Prefill G prompts of one length bucket in a single model pass.
-        tokens: (G, pad_len); slots/true_lens/temps: (G,). Padding rows
-        target the scratch slot. Returns (tokens (G,) int32, cache')."""
-        jnp = self._jnp
-        jax = self._jax
-        g = tokens.shape[0]
-        mcfg = self.model.cfg
-        small = [(jnp.zeros((g, pad_len, mcfg.n_kv_heads, mcfg.head_dim),
-                            mcfg.dtype),
-                  jnp.zeros((g, pad_len, mcfg.n_kv_heads, mcfg.head_dim),
-                            mcfg.dtype),
-                  jnp.zeros((g,), jnp.int32))
-                 for _ in range(mcfg.n_layers)]
-        positions = jnp.broadcast_to(jnp.arange(pad_len)[None, :],
-                                     (g, pad_len))
-        logits, new_small = self.model.apply(
-            {"params": params}, tokens, cache=small, positions=positions)
-        out_cache = []
-        for (ck, cv, lens), (k1, v1, _l1) in zip(cache, new_small):
-            # scatter each row's KV into its slot (duplicate scratch
-            # indices from padding rows are harmless: slot is inert)
-            ck = ck.at[slots, :pad_len].set(k1)
-            cv = cv.at[slots, :pad_len].set(v1)
-            lens = lens.at[slots].set(true_lens)
-            out_cache.append((ck, cv, lens))
-        last = logits[jnp.arange(g), true_lens - 1]          # (G, V)
-        toks, logps = self._sample_tokens(last, temps, top_ps, rng_key,
-                                          allow=allow, bias=bias)
-        return toks, logps, out_cache
-
-    def _prefix_fill_impl(self, params, prefix_cache, tokens, pid,
-                          pad_len: int):
-        """Prefill a registered prefix into row `pid` of the prefix KV
-        buffers. tokens: (1, pad_len). NOT donated: concurrent adopts
-        of other prefixes keep reading the old buffer safely."""
-        jnp = self._jnp
-        mcfg = self.model.cfg
-        small = [(jnp.zeros((1, pad_len, mcfg.n_kv_heads,
-                             mcfg.head_dim), mcfg.dtype),
-                  jnp.zeros((1, pad_len, mcfg.n_kv_heads,
-                             mcfg.head_dim), mcfg.dtype),
-                  jnp.zeros((1,), jnp.int32))
-                 for _ in range(mcfg.n_layers)]
-        positions = jnp.arange(pad_len)[None, :]
-        _logits, new_small = self.model.apply(
-            {"params": params}, tokens, cache=small,
-            positions=positions)
-        out = []
-        for (pk, pv), (k1, v1, _l) in zip(prefix_cache, new_small):
-            pk = pk.at[pid, :pad_len].set(k1[0])
-            pv = pv.at[pid, :pad_len].set(v1[0])
-            out.append((pk, pv))
-        return out
-
-    def _adopt_prefix_impl(self, cache, prefix_cache, slot, pid, plen):
-        """Copy prefix `pid`'s KV into `slot` and set its length to
-        `plen` — the whole point: a shared system prompt costs ONE
-        on-device copy per request instead of a re-prefill."""
-        jax = self._jax
-        lax = jax.lax
-        out = []
-        for (ck, cv, lens), (pk, pv) in zip(cache, prefix_cache):
-            row_k = lax.dynamic_slice_in_dim(pk, pid, 1, axis=0)
-            row_v = lax.dynamic_slice_in_dim(pv, pid, 1, axis=0)
-            ck = lax.dynamic_update_slice_in_dim(ck, row_k, slot, axis=0)
-            cv = lax.dynamic_update_slice_in_dim(cv, row_v, slot, axis=0)
-            lens = lens.at[slot].set(plen)
-            out.append((ck, cv, lens))
-        return out
-
-    # ---- paged-KV kernels (cfg.kv_page_size > 0) --------------------------
+    # ---- step programs over the page pool ---------------------------------
     def _paged_entries(self, pools, page_table, lengths):
         """Per-layer PagedKV cache entries over the shared pool. The
         gather/scatter happens INSIDE each layer's attention, so only
@@ -916,9 +730,18 @@ class LLMEngine:
                           tokens, slot, start, new_len, temp, top_p,
                           rng_key, chunk: int, sample: bool,
                           allow=None, bias=None):
-        """One chunk of a long prompt (paged): gathers the slot's full
-        page row (start is dynamic, so the attention window cannot be
-        statically narrowed the way bucketed prefill narrows it)."""
+        """One chunk of a long prompt: tokens (1, chunk) written at
+        positions [start, start+chunk); the slot's length becomes
+        `new_len` (start + true tokens in this chunk, so tail padding of
+        the final chunk stays invisible — pad queries only ever attend
+        pad keys and their outputs are discarded). The slot's true
+        current length IS `start` — a reused slot's stored length would
+        be stale from the previous occupant and leak its KV into the
+        chunk's valid-mask. Gathers the slot's full page row (start is
+        dynamic, so the attention window cannot be statically narrowed
+        the way bucketed prefill narrows it). sample=True (final chunk)
+        also samples the first generated token from the last true
+        position."""
         jnp = self._jnp
         jax = self._jax
         ps = self.cfg.kv_page_size
@@ -984,6 +807,10 @@ class LLMEngine:
                                  lengths, last_tokens, active_mask,
                                  temps, top_ps, rng_key,
                                  window_pages: int = 0):
+        """decode_block fused steps under one dispatch (lax.scan).
+        Returns (tokens (K, S), logps, pools', lengths', last_tokens').
+        Host-side termination decisions lag up to K-1 extra tokens;
+        drain guards discard them."""
         jax = self._jax
         keys = jax.random.split(rng_key, self.cfg.decode_block)
 
@@ -1016,39 +843,18 @@ class LLMEngine:
             out.append((k, v))
         return out
 
-    def _verify_impl(self, params, cache, last_tokens, proposals,
-                     active_mask, temps, top_ps, rng_key):
-        """n-gram speculation verify (contiguous cache): ONE forward of
-        [last, p1..pK] per slot; in-jit greedy prefix acceptance.
-        proposals (S, K) int32, -1 = no proposal at that offset.
-        Returns (out (S, K+1), n_emit (S,), logps (S, K+1), cache',
-        last') — emit out[s, :n_emit[s]]. Rejected positions' KV is
-        invisible (attention masks by length) and overwritten by later
-        writes at the same positions."""
-        jnp = self._jnp
-        jax = self._jax
-        K = proposals.shape[1]
-        old_lengths = cache[0][2]
-        toks_in = jnp.concatenate(
-            [last_tokens[:, None], jnp.maximum(proposals, 0)], axis=1)
-        positions = old_lengths[:, None] + jnp.arange(K + 1)[None, :]
-        logits, new_cache = self.model.apply(
-            {"params": params}, toks_in, cache=cache,
-            positions=positions)                       # (S, K+1, V)
-        out, n_emit, logps, last = self._verify_accept(
-            logits, proposals, last_tokens, active_mask, temps, top_ps,
-            rng_key)
-        new_len = old_lengths + n_emit
-        fixed = [(ck, cv, new_len) for (ck, cv, _l) in new_cache]
-        return out, n_emit, logps, fixed, last
-
     def _verify_paged_impl(self, params, pools, page_table, lengths,
                            last_tokens, proposals, active_mask, temps,
                            top_ps, rng_key, window_pages: int = 0):
-        """n-gram speculation verify over the page pool (see
-        _verify_impl). Accepted tokens always land in reserved pages
-        (acceptance <= remaining budget); overshoot writes may hit the
-        trash page, which is by-construction inert."""
+        """n-gram speculation verify: ONE forward of [last, p1..pK] per
+        slot; in-jit greedy prefix acceptance. proposals (S, K) int32,
+        -1 = no proposal at that offset. Returns (out (S, K+1), n_emit
+        (S,), logps (S, K+1), pools', lengths', last') — emit
+        out[s, :n_emit[s]]. Rejected positions' KV is invisible
+        (attention masks by length) and overwritten by later writes at
+        the same positions. Accepted tokens always land in reserved
+        pages (acceptance <= remaining budget); overshoot writes may hit
+        the trash page, which is by-construction inert."""
         jnp = self._jnp
         K = proposals.shape[1]
         if window_pages and window_pages < page_table.shape[1]:
@@ -1096,59 +902,13 @@ class LLMEngine:
         last = jnp.where(active_mask, last, last_tokens)
         return out, n_emit, logps, last
 
-    def _decode_impl(self, params, cache, last_tokens, active_mask,
-                     temps, top_ps, rng_key, allow=None, pen=None):
-        """One decode step for every slot. Returns (next_tokens (S,),
-        cache'). Inactive slots' lengths are restored so their state
-        never drifts."""
-        jnp = self._jnp
-        jax = self._jax
-        old_lengths = cache[0][2]
-        positions = old_lengths[:, None]  # (S, 1): write at current end
-        logits, new_cache = self.model.apply(
-            {"params": params}, last_tokens[:, None], cache=cache,
-            positions=positions)
-        logits = logits[:, 0, :]  # (S, V)
-        fixed = []
-        for (ck, cv, lens) in new_cache:
-            lens = jnp.where(active_mask, lens, old_lengths)
-            fixed.append((ck, cv, lens))
-        bias, new_counts = self._pen_bias(pen, last_tokens, active_mask)
-        nxt, logps = self._sample_tokens(logits, temps, top_ps, rng_key,
-                                         allow=allow, bias=bias)
-        nxt = jnp.where(active_mask, nxt, last_tokens)
-        if pen is not None:
-            return nxt, logps, fixed, new_counts
-        return nxt, logps, fixed
-
-    def _decode_block_impl(self, params, cache, last_tokens, active_mask,
-                           temps, top_ps, rng_key):
-        """decode_block fused steps under one dispatch (lax.scan).
-        Returns (tokens (K, S), cache', last_tokens'). Host-side
-        termination decisions lag up to K-1 extra tokens; drain guards
-        discard them."""
-        jax = self._jax
-        keys = jax.random.split(rng_key, self.cfg.decode_block)
-
-        def body(carry, key):
-            cache, last = carry
-            nxt, logps, cache = self._decode_impl(params, cache, last,
-                                                  active_mask, temps,
-                                                  top_ps, key)
-            return (cache, nxt), (nxt, logps)
-
-        (cache, last), (toks, logps) = jax.lax.scan(
-            body, (cache, last_tokens), keys)
-        return toks, logps, cache, last
-
     # ---- public API -------------------------------------------------------
     def register_prefix(self, prefix_ids) -> int:
         """Prefill a shared prompt prefix (e.g. a system prompt) once;
         returns a prefix_id for submit(prefix_id=...). Requires
-        cfg.max_prefixes > 0. Slots are append-only (static buffers):
-        registering more than max_prefixes raises. Thread-safe."""
-        if self._prefix_cache is None and not (
-                self._paged and self.cfg.max_prefixes > 0):
+        cfg.max_prefixes > 0. Prefix ids are append-only: registering
+        more than max_prefixes raises. Thread-safe."""
+        if self.cfg.max_prefixes <= 0:
             raise ValueError("engine built with max_prefixes=0")
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
         if prefix.size == 0:
@@ -1161,11 +921,7 @@ class LLMEngine:
         if pid >= self.cfg.max_prefixes:
             raise ValueError(
                 f"prefix slots exhausted ({self.cfg.max_prefixes})")
-        if self._paged:
-            self._run_on_loop(
-                lambda: self._register_prefix_paged(pid, prefix))
-        else:
-            self._fill_prefix_row(pid, prefix)
+        self._run_on_loop(lambda: self._register_prefix_paged(pid, prefix))
         return pid
 
     def _run_on_loop(self, fn) -> None:
@@ -1192,8 +948,8 @@ class LLMEngine:
     def _register_prefix_paged(self, pid: int, prefix: np.ndarray
                                ) -> None:
         """Prefill a prefix into freshly-allocated PINNED pages (loop
-        thread only). No dedicated buffers: the prefix lives in the
-        pool; adopters share its full pages by reference."""
+        thread only): the prefix lives in the pool; adopters share its
+        full pages by reference."""
         jnp = self._jnp
         ps = self.cfg.kv_page_size
         pages = self._alloc_pages(-(-prefix.size // ps))
@@ -1231,20 +987,6 @@ class LLMEngine:
         self._prefixes.pop(pid, None)
         if pages:
             self._free_pages.extend(pages)
-
-    def _fill_prefix_row(self, pid: int, prefix: np.ndarray) -> None:
-        """Fill buffer row `pid` (the scratch row included) under the
-        lock — the buffer swap is a read-modify-write; a concurrent
-        unsynchronized registration would silently drop one fill."""
-        pad = min(_next_pow2(prefix.size), self.cfg.max_seq_len)
-        tokens = np.zeros((1, pad), np.int32)
-        tokens[0, :prefix.size] = prefix
-        with self._lock:
-            self._prefix_cache = self._prefix_fill_jit(
-                self.params, self._prefix_cache,
-                self._jnp.asarray(tokens), self._jnp.int32(pid),
-                pad_len=pad)
-            self._prefixes[pid] = prefix
 
     def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
                temperature: float = 0.0, top_p: float = 1.0,
@@ -1319,7 +1061,7 @@ class LLMEngine:
                 raise ValueError(f"unknown prefix_id {prefix_id}")
             # prompt_ids is the SUFFIX; the engine re-attaches the
             # prefix tokens (for stop/position bookkeeping) but its KV
-            # is adopted by copy, never re-prefilled
+            # is adopted from the prefix's pages, never re-prefilled
             prompt = np.concatenate([prefix, prompt])
         elif not self._use_chunked(prompt.size):
             # chunked prompts bypass the buckets; all others must fit one
@@ -1331,13 +1073,12 @@ class LLMEngine:
                 raise ValueError(
                     f"prompt length {prompt.size} exceeds max_seq_len "
                     f"{self.cfg.max_seq_len}")
-        if self._paged:
-            ps = self.cfg.kv_page_size
-            if -(-(prompt.size + budget) // ps) > self._n_pages:
-                raise ValueError(
-                    f"request needs {-(-(prompt.size + budget) // ps)} "
-                    f"KV pages; pool has {self._n_pages} total — it "
-                    f"could never be admitted")
+        ps = self.cfg.kv_page_size
+        if -(-(prompt.size + budget) // ps) > self._n_pages:
+            raise ValueError(
+                f"request needs {-(-(prompt.size + budget) // ps)} "
+                f"KV pages; pool has {self._n_pages} total — it "
+                f"could never be admitted")
         req = _Request(request_id=f"req-{next(self._req_counter)}",
                        prompt=prompt, max_new_tokens=budget,
                        temperature=temperature, top_p=float(top_p),
@@ -1508,17 +1249,14 @@ class LLMEngine:
             for _ in self.stream(rid):
                 pass
         if self.cfg.max_prefixes > 0:
-            # Warm fill + adopt + the per-bucket chunk kernels by
-            # EXECUTING dummy prefix'd requests against the scratch
-            # prefix row (pid == max_prefixes — never handed out), one
-            # suffix length per reachable chunk width. AOT
+            # Warm registration + adoption + the per-bucket chunk
+            # kernels by EXECUTING dummy prefix'd requests against a
+            # scratch prefix (pid == max_prefixes — never handed out),
+            # one suffix length per reachable chunk width. AOT
             # lower().compile() would NOT populate the jit call cache.
             scratch = self.cfg.max_prefixes
-            if self._paged:
-                self._run_on_loop(lambda: self._register_prefix_paged(
-                    scratch, np.ones((2,), np.int32)))
-            else:
-                self._fill_prefix_row(scratch, np.ones((2,), np.int32))
+            self._run_on_loop(lambda: self._register_prefix_paged(
+                scratch, np.ones((2,), np.int32)))
             widths = ({self.cfg.prefill_chunk}
                       if self.cfg.prefill_chunk > 0 else
                       {b for b in self.cfg.prefill_buckets
@@ -1540,11 +1278,8 @@ class LLMEngine:
             for rid in warm:
                 for _ in self.stream(rid):
                     pass
-            if self._paged:
-                self._run_on_loop(
-                    lambda: self._unregister_prefix_paged(scratch))
-            else:
-                self._prefixes.pop(scratch, None)
+            self._run_on_loop(
+                lambda: self._unregister_prefix_paged(scratch))
             self.stats["prefix_tokens_saved"] = 0   # dummy adoptions
 
     def generate_sync(self, prompt_ids, max_new_tokens=None,
@@ -1572,16 +1307,15 @@ class LLMEngine:
                    "waiting": self._waiting.qsize(),
                    "prefilling": len(self._prefilling),
                    "free_slots": len(self._free_slots)}
-            if self._paged:
-                pinned = sum(len(p) for p in self._prefix_pages.values())
-                out["kv_pages"] = {
-                    "page_size": self.cfg.kv_page_size,
-                    "total": self._n_pages,
-                    "free": len(self._free_pages),
-                    "in_use": self._n_pages - len(self._free_pages),
-                    "pinned_prefix": pinned,
-                    "peak_in_use": self._page_hwm,
-                }
+            pinned = sum(len(p) for p in self._prefix_pages.values())
+            out["kv_pages"] = {
+                "page_size": self.cfg.kv_page_size,
+                "total": self._n_pages,
+                "free": len(self._free_pages),
+                "in_use": self._n_pages - len(self._free_pages),
+                "pinned_prefix": pinned,
+                "peak_in_use": self._page_hwm,
+            }
             samples = list(self._ttft_samples)
             tpots = sorted(self._tpot_samples)
         if tpots:
@@ -1740,7 +1474,7 @@ class LLMEngine:
         return slot
 
     def _admit_paged(self, req: _Request) -> str:
-        """Paged admission: reserve pages + a slot. Returns "ok",
+        """Admission: reserve pages + a slot. Returns "ok",
         "nopages" (hold the request), or "failed" (stream errored).
         Prefix-carrying requests share the prefix's full pages by
         page-table reference and copy only its partial last page."""
@@ -1815,7 +1549,7 @@ class LLMEngine:
         steps, preserving per-request emission order."""
         taken: List[tuple] = []
         while self._free_slots:
-            if self._paged and self._pending_head is not None:
+            if self._pending_head is not None:
                 req, self._pending_head = self._pending_head, None
             else:
                 try:
@@ -1834,62 +1568,31 @@ class LLMEngine:
                 self._shed_expired(req)
                 continue
             self._progress_ts = time.time()   # watchdog: admission
-            if self._paged:
-                outcome = self._admit_paged(req)
-                if outcome == "nopages":
-                    # hold the head request (FIFO — Queue has no
-                    # push-front) until releases replenish the pool
-                    self._pending_head = req
-                    if not getattr(req, "preempt_emitted", False):
-                        req.preempt_emitted = True
-                        self._event("llm_engine.request_preempt",
-                                    "KV page pool exhausted; holding "
-                                    "at admission", req=req)
-                    break
-                if outcome == "failed":
-                    continue
-                self._event("llm_engine.request_admit", req=req,
-                            slot=req.slot, prompt_len=int(
-                                req.prompt.size))
-                if req.prefix_id >= 0 or self._use_chunked(
-                        req.prompt.size):
-                    self._prefilling.append(req)
-                else:
-                    taken.append((self._bucket(req.prompt.size), req,
-                                  req.slot))
+            outcome = self._admit_paged(req)
+            if outcome == "nopages":
+                # hold the head request (FIFO — Queue has no
+                # push-front) until releases replenish the pool
+                self._pending_head = req
+                if not getattr(req, "preempt_emitted", False):
+                    req.preempt_emitted = True
+                    self._event("llm_engine.request_preempt",
+                                "KV page pool exhausted; holding "
+                                "at admission", req=req)
+                break
+            if outcome == "failed":
                 continue
-            slot = self._take_slot(req)
-            self._event("llm_engine.request_admit", req=req, slot=slot,
-                        prompt_len=int(req.prompt.size))
-            if req.prefix_id >= 0:
-                # adopt the registered prefix's KV with ONE on-device
-                # copy, then chunk-prefill only the suffix
-                plen = int(self._prefixes[req.prefix_id].size)
-                try:
-                    self._cache = self._adopt_prefix_jit(
-                        self._cache, self._prefix_cache,
-                        self._jnp.int32(slot),
-                        self._jnp.int32(req.prefix_id),
-                        self._jnp.int32(plen))
-                except BaseException as e:  # noqa: BLE001
-                    # same per-request containment as the sibling
-                    # dispatch paths: free the slot, error the stream
-                    self._free_slots.append(slot)
-                    req.slot = -1
-                    self._put(req, ("error", e))
-                    self._put(req, _END)
-                    continue
-                req.prefill_pos = plen
-                self.stats["prefix_tokens_saved"] = (
-                    self.stats.get("prefix_tokens_saved", 0) + plen)
+            self._event("llm_engine.request_admit", req=req,
+                        slot=req.slot, prompt_len=int(
+                            req.prompt.size))
+            if req.prefix_id >= 0 or self._use_chunked(
+                    req.prompt.size):
+                # an adopted prefix's suffix, or a long prompt: prefill
+                # in chunks interleaved with decode steps (one chunk per
+                # loop iteration)
                 self._prefilling.append(req)
-                continue
-            if self._use_chunked(req.prompt.size):
-                # long prompt: prefill in chunks interleaved with decode
-                # steps (one chunk per loop iteration)
-                self._prefilling.append(req)
-                continue
-            taken.append((self._bucket(req.prompt.size), req, slot))
+            else:
+                taken.append((self._bucket(req.prompt.size), req,
+                              req.slot))
         if not taken:
             return
         groups: Dict[int, List[tuple]] = {}
@@ -1902,14 +1605,8 @@ class LLMEngine:
                 with self._spans.span(
                         "engine.prefill_dispatch", bucket=pad_len,
                         group=len(group),
-                        group_padded=self._group_rows(len(group))):
+                        group_padded=_next_pow2(len(group))):
                     self._dispatch_prefill(inflight, pad_len, group)
-
-    def _group_rows(self, n: int) -> int:
-        """Rows of the prefill program that serves a group of n."""
-        if not self._paged and self.cfg.max_prefill_batch <= 1:
-            return 1
-        return _next_pow2(n)
 
     def _dispatch_prefill(self, inflight, pad_len: int, members) -> None:
         """One prefill call for `members` = [(req, slot), ...] of a
@@ -1917,84 +1614,41 @@ class LLMEngine:
         rows) so compile count stays O(buckets * log2(cap))."""
         jnp = self._jnp
         g_real = len(members)
-        g = self._group_rows(g_real)
+        g = _next_pow2(g_real)
         t_dispatch = time.time()
         try:
             self._rng_key, sub = self._jax.random.split(self._rng_key)
-            if self._paged:
-                # unified single/batched paged prefill: pad group size
-                # to a power of two; padding rows hit the scratch slot
-                # whose page row is all-trash
-                tokens = np.zeros((g, pad_len), np.int32)
-                slots = np.full((g,), self._scratch_slot, np.int32)
-                lens = np.ones((g,), np.int32)
-                temps = np.zeros((g,), np.float32)
-                top_ps = np.ones((g,), np.float32)
-                for i, (req, slot) in enumerate(members):
-                    tokens[i, :req.prompt.size] = req.prompt
-                    slots[i] = slot
-                    lens[i] = req.prompt.size
-                    temps[i] = req.temperature
-                    top_ps[i] = req.top_p
-                allow = self._guided_prefill_allow(
-                    [r for r, _ in members], g)
-                kw = {} if allow is None else {"allow": allow}
-                pbias = self._pen_prefill_bias(
-                    [r for r, _ in members], g)
-                if pbias is not None:
-                    kw["bias"] = pbias
-                if self._counted:
-                    kw["n_real"] = np.int32(g_real)
-                toks_dev, lps_dev, self._pools, self._lengths, *counted = \
-                    self._prefill_paged_jit(
-                        self.params, self._pools, self._page_table,
-                        self._lengths, jnp.asarray(tokens),
-                        jnp.asarray(slots), jnp.asarray(lens),
-                        jnp.asarray(temps), jnp.asarray(top_ps), sub,
-                        pad_len=pad_len, **kw)
-                toks_dev = toks_dev[:g_real]
-                lps_dev = lps_dev[:g_real]
-            elif g_real == 1 and self.cfg.max_prefill_batch <= 1:
-                req, slot = members[0]
-                tokens = np.zeros((1, pad_len), np.int32)
-                tokens[0, :req.prompt.size] = req.prompt
-                allow = self._guided_prefill_allow([req], 1)
-                kw = {} if allow is None else {"allow": allow}
-                pbias = self._pen_prefill_bias([req], 1)
-                if pbias is not None:
-                    kw["bias"] = pbias
-                tok_dev, lp_dev, self._cache = self._prefill_jit(
-                    self.params, self._cache, jnp.asarray(tokens),
-                    jnp.int32(slot), jnp.int32(req.prompt.size),
-                    jnp.float32(req.temperature),
-                    jnp.float32(req.top_p), sub, pad_len=pad_len, **kw)
-                toks_dev, lps_dev = tok_dev[None], lp_dev[None]
-            else:
-                tokens = np.zeros((g, pad_len), np.int32)
-                slots = np.full((g,), self._scratch_slot, np.int32)
-                lens = np.ones((g,), np.int32)
-                temps = np.zeros((g,), np.float32)
-                top_ps = np.ones((g,), np.float32)
-                for i, (req, slot) in enumerate(members):
-                    tokens[i, :req.prompt.size] = req.prompt
-                    slots[i] = slot
-                    lens[i] = req.prompt.size
-                    temps[i] = req.temperature
-                    top_ps[i] = req.top_p
-                allow = self._guided_prefill_allow(
-                    [r for r, _ in members], g)
-                kw = {} if allow is None else {"allow": allow}
-                pbias = self._pen_prefill_bias(
-                    [r for r, _ in members], g)
-                if pbias is not None:
-                    kw["bias"] = pbias
-                toks_dev, lps_dev, self._cache = self._prefill_batch_jit(
-                    self.params, self._cache, jnp.asarray(tokens),
+            # padding rows hit the scratch slot, whose page row is
+            # all-trash
+            tokens = np.zeros((g, pad_len), np.int32)
+            slots = np.full((g,), self._scratch_slot, np.int32)
+            lens = np.ones((g,), np.int32)
+            temps = np.zeros((g,), np.float32)
+            top_ps = np.ones((g,), np.float32)
+            for i, (req, slot) in enumerate(members):
+                tokens[i, :req.prompt.size] = req.prompt
+                slots[i] = slot
+                lens[i] = req.prompt.size
+                temps[i] = req.temperature
+                top_ps[i] = req.top_p
+            allow = self._guided_prefill_allow(
+                [r for r, _ in members], g)
+            kw = {} if allow is None else {"allow": allow}
+            pbias = self._pen_prefill_bias(
+                [r for r, _ in members], g)
+            if pbias is not None:
+                kw["bias"] = pbias
+            if self._counted:
+                kw["n_real"] = np.int32(g_real)
+            toks_dev, lps_dev, self._pools, self._lengths, *counted = \
+                self._prefill_paged_jit(
+                    self.params, self._pools, self._page_table,
+                    self._lengths, jnp.asarray(tokens),
                     jnp.asarray(slots), jnp.asarray(lens),
                     jnp.asarray(temps), jnp.asarray(top_ps), sub,
                     pad_len=pad_len, **kw)
-                toks_dev = toks_dev[:g_real]
-                lps_dev = lps_dev[:g_real]
+            toks_dev = toks_dev[:g_real]
+            lps_dev = lps_dev[:g_real]
             real_slots = jnp.asarray(
                 np.asarray([s for _, s in members], np.int32))
             self._last_tokens = self._last_tokens.at[real_slots].set(
@@ -2017,8 +1671,7 @@ class LLMEngine:
             int(req.prompt.size) for req, _ in members))
         for req, slot in members:
             req.prefill_dispatch_ms = dispatch_ms
-            if self._paged:
-                self._disp_len[slot] = req.prompt.size
+            self._disp_len[slot] = req.prompt.size
             self._active[slot] = req
         self._mask_dirty = True
         self._pen_coef_dirty = True
@@ -2067,19 +1720,10 @@ class LLMEngine:
                 kw["allow"] = self._guided_prefill_allow([req], 1)
             if is_last and req.logit_bias:
                 kw["bias"] = self._pen_prefill_bias([req], 1)
-            if self._paged:
-                tok_dev, lp_dev, self._pools, self._lengths = \
-                    self._chunk_paged_jit(
-                        self.params, self._pools, self._page_table,
-                        self._lengths, jnp.asarray(tokens),
-                        jnp.int32(req.slot), jnp.int32(start),
-                        jnp.int32(start + true),
-                        jnp.float32(req.temperature),
-                        jnp.float32(req.top_p), sub, chunk=C,
-                        sample=is_last, **kw)
-            else:
-                tok_dev, lp_dev, self._cache = self._prefill_chunk_jit(
-                    self.params, self._cache, jnp.asarray(tokens),
+            tok_dev, lp_dev, self._pools, self._lengths = \
+                self._chunk_paged_jit(
+                    self.params, self._pools, self._page_table,
+                    self._lengths, jnp.asarray(tokens),
                     jnp.int32(req.slot), jnp.int32(start),
                     jnp.int32(start + true),
                     jnp.float32(req.temperature),
@@ -2094,8 +1738,7 @@ class LLMEngine:
             self._put(req, _END)
             return
         req.prefill_pos = start + true
-        if self._paged:
-            self._disp_len[req.slot] = req.prefill_pos
+        self._disp_len[req.slot] = req.prefill_pos
         req.prefill_dispatch_ms += (time.time() - t_dispatch) * 1000
         self._count_prefill(C, 1, 1, true)
         self._progress_ts = time.time()   # watchdog: chunk advanced
@@ -2304,8 +1947,6 @@ class LLMEngine:
         """Return the slot's exclusive pages to the pool (shared prefix
         pages stay pinned) and point its row at the trash page so lagged
         decode writes can't corrupt a reused page."""
-        if not self._paged:
-            return
         entry = self._slot_pages.pop(slot, None)
         self._disp_len.pop(slot, None)
         if entry is None:
@@ -2608,8 +2249,7 @@ class LLMEngine:
             self.stats["decode_tokens_discarded"] += n - emitted
             self.stats["spec_accepted"] = (
                 self.stats.get("spec_accepted", 0) + max(0, emitted - 1))
-            if self._paged and req.slot == slot \
-                    and slot in self._disp_len:
+            if req.slot == slot and slot in self._disp_len:
                 # resync the window mirror to the true length (the
                 # dispatch bumped it by the K+1 upper bound)
                 self._disp_len[slot] = req.prompt.size + req.generated
@@ -2644,7 +2284,7 @@ class LLMEngine:
                     self._release(req)
             return
         if self._counted and kind != "prefill_chunk":
-            # the paged step programs' tokens come with the model's
+            # the step programs' tokens come with the model's
             # counters behind them (one vector a call, summed over its
             # layers; a block of decode steps brings one a step)
             n = len(self._step_stats)
@@ -2726,8 +2366,8 @@ class LLMEngine:
         span = self._spans.span
         with span("engine.control"):
             while True:
-                # control commands (paged prefix registration) run
-                # HERE so pool mutations never race a donated buffer
+                # control commands (prefix registration) run HERE so
+                # pool mutations never race a donated buffer
                 try:
                     fn, done = self._control_q.get_nowait()
                 except queue_mod.Empty:
@@ -2770,13 +2410,11 @@ class LLMEngine:
                         self._rng_key)
                     props = (self._spec_plan()
                              if spec_sync and allow is None else None)
-                    window = 0
-                    if self._paged:
-                        if props is not None:
-                            for slot in self._active:
-                                self._disp_len[slot] += \
-                                    self.cfg.ngram_speculation + 1
-                        window = self._decode_window_pages()
+                    if props is not None:
+                        for slot in self._active:
+                            self._disp_len[slot] += \
+                                self.cfg.ngram_speculation + 1
+                    window = self._decode_window_pages()
                     snapshot = list(self._active.items())
                     ready = True
         if ready:
@@ -2795,10 +2433,9 @@ class LLMEngine:
             m["occupancy"].set(
                 len(self._active) / max(1, self.cfg.max_slots),
                 tags=self._mtags)
-            if self._paged:
-                m["kv_util"].set(
-                    (self._n_pages - len(self._free_pages))
-                    / max(1, self._n_pages), tags=self._mtags)
+            m["kv_util"].set(
+                (self._n_pages - len(self._free_pages))
+                / max(1, self._n_pages), tags=self._mtags)
         if not inflight:
             self._in_dispatch = False
             self._hand_over()   # what admission errored, shed or ended
@@ -2831,21 +2468,11 @@ class LLMEngine:
         """Enqueue one decode (or speculative verify) program over all
         slots, start its fetch and append it to `inflight`."""
         if props is not None:
-            if self._paged:
-                out, n_emit, logps, self._pools, \
-                    self._lengths, last = \
-                    self._verify_paged_jit(
-                        self.params, self._pools,
-                        self._page_table, self._lengths,
-                        self._last_tokens, props, mask,
-                        temps, top_ps, sub,
-                        window_pages=window)
-            else:
-                out, n_emit, logps, self._cache, last = \
-                    self._verify_jit(
-                        self.params, self._cache,
-                        self._last_tokens, props, mask,
-                        temps, top_ps, sub)
+            out, n_emit, logps, self._pools, self._lengths, last = \
+                self._verify_paged_jit(
+                    self.params, self._pools, self._page_table,
+                    self._lengths, self._last_tokens, props, mask,
+                    temps, top_ps, sub, window_pages=window)
             self._last_tokens = last
             self._start_fetch(out)
             self._start_fetch(n_emit)
@@ -2858,64 +2485,38 @@ class LLMEngine:
                  (n_emit, logps if self.cfg.logprobs else None),
                  time.perf_counter_ns()))
             return
-        if self._paged:
+        if self._decode_block_paged_jit is not None \
+                and allow is None and pen is None:
+            toks, logps, self._pools, self._lengths, last = \
+                self._decode_block_paged_jit(
+                    self.params, self._pools, self._page_table,
+                    self._lengths, self._last_tokens, mask, temps,
+                    top_ps, sub, window_pages=window)
+            block = self.cfg.decode_block
+        else:
             akw = {} if allow is None else {"allow": allow}
             if pen is not None:
                 akw["pen"] = pen
-            if self._decode_block_paged_jit is not None \
-                    and allow is None and pen is None:
-                toks, logps, self._pools, self._lengths, \
-                    last = self._decode_block_paged_jit(
-                        self.params, self._pools,
-                        self._page_table, self._lengths,
-                        self._last_tokens, mask, temps,
-                        top_ps, sub, window_pages=window)
-                block = max(1, self.cfg.decode_block)
-            else:
-                res = self._decode_paged_jit(
-                    self.params, self._pools,
-                    self._page_table, self._lengths,
-                    self._last_tokens, mask, temps,
-                    top_ps, sub, window_pages=window,
-                    **akw)
-                if self._counted:
-                    *res, fetch = res
-                if pen is not None:
-                    (toks, logps, self._pools,
-                     self._lengths, self._pen_counts) = res
-                else:
-                    (toks, logps, self._pools,
-                     self._lengths) = res
-                last = toks
-                if self._counted:
-                    toks = fetch
-                block = 1
-            for slot in self._active:
-                # KeyError here = an admission path forgot
-                # to seed _disp_len; fail loudly — a silent
-                # 0 default would shrink the window and
-                # corrupt KV untraceably
-                self._disp_len[slot] += block
-        elif self._decode_block_jit is not None \
-                and allow is None and pen is None:
-            toks, logps, self._cache, last = \
-                self._decode_block_jit(
-                    self.params, self._cache,
-                    self._last_tokens, mask, temps, top_ps,
-                    sub)
-        else:
-            dkw = {} if allow is None else {"allow": allow}
+            res = self._decode_paged_jit(
+                self.params, self._pools, self._page_table,
+                self._lengths, self._last_tokens, mask, temps,
+                top_ps, sub, window_pages=window, **akw)
+            if self._counted:
+                *res, fetch = res
             if pen is not None:
-                dkw["pen"] = pen
-            res = self._decode_jit(
-                self.params, self._cache, self._last_tokens,
-                mask, temps, top_ps, sub, **dkw)
-            if pen is not None:
-                toks, logps, self._cache, \
-                    self._pen_counts = res
+                (toks, logps, self._pools, self._lengths,
+                 self._pen_counts) = res
             else:
-                toks, logps, self._cache = res
+                toks, logps, self._pools, self._lengths = res
             last = toks
+            if self._counted:
+                toks = fetch
+            block = 1
+        for slot in self._active:
+            # KeyError here = an admission path forgot to seed
+            # _disp_len; fail loudly — a silent 0 default would shrink
+            # the window and corrupt KV untraceably
+            self._disp_len[slot] += block
         self._last_tokens = last
         self._start_fetch(toks)
         if self.cfg.logprobs:

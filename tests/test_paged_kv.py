@@ -1,13 +1,13 @@
-"""Paged KV cache for the serve engine (VERDICT r4 #4).
+"""Paged KV cache of the serve engine.
 
-The engine's per-slot contiguous (max_slots x max_seq_len) KV buffers are
-replaced (cfg.kv_page_size > 0) by a shared page pool + per-slot page
-tables (ops/attention.py:paged_cached_attention — static shapes, decode
-still compiles once). These tests pin the three "done" criteria:
-token-identical output vs the contiguous cache, >2x concurrent sequences
-in the same KV budget with mixed-length requests, and page-pool stats.
-Prefix caching runs ON pages: full pages shared by reference, only the
-partial tail page copied.
+The engine keeps KV in a shared page pool + per-slot page tables
+(ops/attention.py:paged_cached_attention — static shapes, one decode
+program per page window). These tests pin: token-identical output vs a
+plain greedy loop over the model's own (k, v, lengths) cache, >2x
+concurrent sequences in the KV budget of max_slots x max_seq_len with
+mixed-length requests, and page-pool stats. Prefix caching runs ON
+pages: full pages shared by reference, only the partial tail page
+copied.
 """
 import threading
 import time
@@ -38,14 +38,31 @@ def _engine(tiny_llm, **overrides):
     return LLMEngine(model, params, LLMEngineConfig(**base))
 
 
-def test_paged_tokens_identical_to_contiguous(tiny_llm):
-    """Same prompts, greedy: the paged engine must emit token-for-token
-    what the contiguous-slot engine emits (attention math is identical
-    after the page gather)."""
+def _model_cache_greedy(tiny_llm, prompt, n_new, max_len=128):
+    """The reference, independent of the engine: a greedy loop over the
+    model's own cache (Model.empty_cache + cached_attention), one
+    sequence, the prompt unpadded and in one pass."""
+    import jax.numpy as jnp
+    model, params = tiny_llm
+    cache = model.empty_cache(1, max_len, dtype=jnp.float32)
+    toks = jnp.asarray(prompt, jnp.int32)[None, :]
+    pos = jnp.arange(toks.shape[1])[None, :]
+    out = []
+    for _ in range(n_new):
+        logits, cache = model.apply({"params": params}, toks, cache=cache,
+                                    positions=pos)
+        out.append(int(jnp.argmax(logits[0, -1])))
+        toks = jnp.asarray([[out[-1]]], jnp.int32)
+        pos = pos[:, -1:] + 1
+    return out
+
+
+def test_paged_tokens_identical_to_model_cache(tiny_llm):
+    """Same prompts, greedy: the engine must emit token-for-token what
+    a plain loop over the model's own cache emits (attention math is
+    identical after the page gather)."""
     prompts = [np.arange(1 + i, 6 + i * 3) % 128 for i in range(5)]
-    legacy = _engine(tiny_llm)
-    want = [legacy.generate_sync(p, max_new_tokens=8) for p in prompts]
-    legacy.shutdown()
+    want = [_model_cache_greedy(tiny_llm, p, 8) for p in prompts]
     paged = _engine(tiny_llm, kv_page_size=16)
     got = [paged.generate_sync(p, max_new_tokens=8) for p in prompts]
     stats = paged.get_stats()
@@ -68,10 +85,10 @@ def test_paged_concurrent_interleaved(tiny_llm):
 
 
 def test_paged_over2x_concurrency_same_budget(tiny_llm):
-    """The same KV token budget must hold >2x the sequences once pages
-    replace per-slot max_seq_len reservations. Legacy: 4 slots x 128 =
-    512 tokens, max 4 concurrent. Paged (512-token pool, page 16): a
-    16-token short request reserves 1 page, so 16+ can hold slots."""
+    """A KV token budget must hold >2x the sequences that per-slot
+    max_seq_len reservations would: 512 tokens are 4 x max_seq_len 128.
+    In a 512-token pool of 16-token pages a 16-token short request
+    reserves 1 page, so 16+ can hold slots."""
     eng = _engine(tiny_llm, kv_page_size=16, max_slots=16,
                   kv_pool_tokens=512, max_new_tokens_default=8)
     n_req = 16
@@ -100,7 +117,7 @@ def test_paged_over2x_concurrency_same_budget(tiny_llm):
     stats = eng.get_stats()
     eng.shutdown()
     # 8-token prompt + 8-token budget = 1 page each: all 16 fit at once
-    # in a budget that held only 4 contiguous slots (>2x = assert >8)
+    # in a budget of only 4 x max_seq_len (>2x = assert >8)
     assert max(peak) > 8, f"peak concurrency {max(peak)}"
     assert stats["kv_pages"]["peak_in_use"] <= stats["kv_pages"]["total"]
     assert stats["kv_pages"]["free"] == stats["kv_pages"]["total"]
@@ -145,14 +162,12 @@ def test_paged_prefix_shares_pages(tiny_llm):
     eng.shutdown()
 
 
-def test_paged_decode_block_and_pipeline_parity(tiny_llm):
+def test_paged_decode_block_and_pipeline_match_model_cache(tiny_llm):
     """decode_block>1 (lax.scan fused steps) + pipelined dispatch over
     the paged cache with windowed decode: token-identical to the
-    contiguous engine."""
+    model's own cache loop."""
     prompts = [np.arange(1 + i, 7 + i * 2) % 128 for i in range(4)]
-    legacy = _engine(tiny_llm)
-    want = [legacy.generate_sync(p, max_new_tokens=9) for p in prompts]
-    legacy.shutdown()
+    want = [_model_cache_greedy(tiny_llm, p, 9) for p in prompts]
     paged = _engine(tiny_llm, kv_page_size=16, decode_block=3,
                     pipeline_depth=4)
     got = [paged.generate_sync(p, max_new_tokens=9) for p in prompts]
@@ -160,13 +175,11 @@ def test_paged_decode_block_and_pipeline_parity(tiny_llm):
     assert got == want
 
 
-def test_paged_chunked_prefill_parity(tiny_llm):
-    """Long prompts through chunked prefill (paged) match the one-shot
-    bucket prefill (contiguous) token-for-token."""
+def test_paged_chunked_prefill_matches_model_cache(tiny_llm):
+    """A long prompt through chunked prefill matches the model's own
+    cache loop, which takes the prompt in one pass, token-for-token."""
     prompt = np.arange(3, 3 + 30) % 128
-    legacy = _engine(tiny_llm)
-    want = legacy.generate_sync(prompt, max_new_tokens=6)
-    legacy.shutdown()
+    want = _model_cache_greedy(tiny_llm, prompt, 6)
     paged = _engine(tiny_llm, kv_page_size=16, prefill_chunk=8)
     got = paged.generate_sync(prompt, max_new_tokens=6)
     paged.shutdown()
@@ -195,3 +208,30 @@ def test_paged_pinned_prefix_cannot_livelock_admission(tiny_llm):
     ok = eng.generate_sync(np.arange(2, 10) % 128, max_new_tokens=8)
     assert len(ok) == 8
     eng.shutdown()
+
+
+@pytest.mark.parametrize("page", [0, -16])
+def test_page_size_must_be_positive(tiny_llm, page):
+    """The page pool is the engine's one KV layout: a page size <= 0
+    is refused at construction."""
+    with pytest.raises(ValueError, match="kv_page_size must be > 0"):
+        _engine(tiny_llm, kv_page_size=page)
+
+
+def test_default_config_is_paged_with_scratch_slot(tiny_llm):
+    """An engine built with the default configuration keeps its KV in
+    pages of 64 tokens, pool = max_slots * max_seq_len, and has the
+    scratch slot behind the admissible ones."""
+    from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
+    model, params = tiny_llm
+    eng = LLMEngine(model, params, LLMEngineConfig(
+        max_seq_len=128, prefill_buckets=(16, 32)))
+    try:
+        pages = eng.get_stats()["kv_pages"]
+        assert pages["page_size"] == 64
+        assert pages["total"] == pages["free"] == 8 * 128 // 64
+        assert eng._n_slots == eng.cfg.max_slots + 1
+        assert eng._scratch_slot == eng.cfg.max_slots
+        assert len(eng.generate_sync(np.arange(2, 10), 4)) == 4
+    finally:
+        eng.shutdown()

@@ -47,21 +47,52 @@ def test_logit_bias_forces_and_blocks(model_params):
         eng.shutdown()
 
 
+def _margins_of_77(model_params, n):
+    """From the model's plain forward (no engine, no cache): by how much
+    the best other token leads token 77 at each of n greedy steps, (a)
+    on the path where 77 is emitted every step and (b) on the path where
+    77 is emitted once and never again."""
+    model, params = model_params
+
+    def logits_after(history):
+        toks = np.asarray(history, np.int32)[None, :]
+        out, _ = model.apply({"params": params}, toks)
+        return np.array(out[0, -1], np.float32)
+
+    def lead(logits):
+        others = np.delete(logits, 77)
+        return float(others.max() - logits[77])
+
+    forced = [lead(logits_after(list(PROMPT) + [77] * k))
+              for k in range(n)]
+    once, hist = [], list(PROMPT) + [77]
+    for _ in range(n - 1):
+        lg = logits_after(hist)
+        once.append(lead(lg))
+        lg[77] = -np.inf
+        hist.append(int(lg.argmax()))
+    return forced, once
+
+
 def test_presence_penalty_breaks_repetition(model_params):
-    """Calibrated on this fixture: bias +4.0 makes greedy emit token 77
-    every step (the natural top-1 margin at the first two positions is
-    between 2.5 and 4.0, so the old +2.5 calibration let the unbiased
-    tokens through); presence_penalty 2.0 must then allow 77 exactly
-    once and suppress it for the rest of a 5-token budget (position 6's
-    margin dips under 2.0, the OpenAI cap, so longer budgets re-admit
-    it legitimately)."""
+    """The bias on token 77 is calibrated on this fixture's own logits,
+    whatever build made the weights: large enough that greedy emits 77
+    at every step of a 5-token budget without a penalty, and less than
+    presence_penalty 2.0 (the OpenAI cap) above what 77 needs at the
+    steps after its first emission, so that the penalty must then allow
+    77 exactly once and suppress it for the rest of the budget."""
+    n = 5
+    forced, once = _margins_of_77(model_params, n)
+    lo, hi = max(forced), min(once) + 2.0
+    # room for the bf16 rounding between the plain forward and the
+    # engine's padded, paged programs
+    assert hi - lo > 0.25, (forced, once)
+    bias = {77: (lo + hi) / 2}
     eng = make_engine(model_params)
     try:
-        rep = eng.generate_sync(PROMPT, max_new_tokens=5,
-                                logit_bias={77: 4.0})
-        assert rep == [77] * 5  # calibration precondition
-        pen = eng.generate_sync(PROMPT, max_new_tokens=5,
-                                logit_bias={77: 4.0},
+        rep = eng.generate_sync(PROMPT, max_new_tokens=n, logit_bias=bias)
+        assert rep == [77] * n  # calibration precondition
+        pen = eng.generate_sync(PROMPT, max_new_tokens=n, logit_bias=bias,
                                 presence_penalty=2.0)
         assert pen[0] == 77          # first emission unaffected
         assert pen.count(77) == 1    # counted once -> suppressed after

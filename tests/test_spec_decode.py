@@ -43,9 +43,14 @@ def _baseline(model_params, prompt, n, **kw):
         eng.shutdown()
 
 
-def test_spec_token_identical_contiguous(model_params):
-    want = _baseline(model_params, REPETITIVE, 24)
-    eng = make_engine(model_params, spec=4)
+@pytest.mark.parametrize("kv", [
+    pytest.param({}, id="default_pool"),
+    pytest.param({"kv_page_size": 16, "kv_pool_tokens": 1024},
+                 id="page16_pool1024", marks=pytest.mark.slow),
+])
+def test_spec_token_identical(model_params, kv):
+    want = _baseline(model_params, REPETITIVE, 24, **kv)
+    eng = make_engine(model_params, spec=4, **kv)
     try:
         got = eng.generate_sync(REPETITIVE, max_new_tokens=24)
         assert got == want, (got, want)
@@ -70,20 +75,6 @@ def test_spec_fewer_dispatches_than_tokens(model_params):
         st = eng.get_stats()
         assert st.get("spec_steps", 0) > 0
         assert st["decode_steps"] < 24, st
-    finally:
-        eng.shutdown()
-
-
-@pytest.mark.slow
-def test_spec_token_identical_paged(model_params):
-    want = _baseline(model_params, REPETITIVE, 24, kv_page_size=16,
-                     kv_pool_tokens=1024)
-    eng = make_engine(model_params, spec=4, kv_page_size=16,
-                      kv_pool_tokens=1024)
-    try:
-        got = eng.generate_sync(REPETITIVE, max_new_tokens=24)
-        assert got == want, (got, want)
-        assert eng.get_stats().get("spec_accepted", 0) >= 0
     finally:
         eng.shutdown()
 

@@ -149,10 +149,10 @@ def test_grad_accum_step_on_tpu():
 
 
 def test_paged_kv_engine_on_tpu():
-    """r5: the paged KV pool on the real chip — token-identical to the
-    contiguous cache (greedy), prefix sharing on pages, pool stats.
-    Exercises the flat-pool scatter/gather lowering the CPU suite can
-    only interpret."""
+    """The paged KV pool on the real chip — token-identical (greedy) to
+    a plain loop over the model's own (k, v, lengths) cache, prefix
+    sharing on pages, pool stats. Exercises the flat-pool
+    scatter/gather lowering the CPU suite can only interpret."""
     from ray_tpu.models import Llama, LlamaConfig
     from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
 
@@ -163,13 +163,20 @@ def test_paged_kv_engine_on_tpu():
     params = model.init_params(jax.random.PRNGKey(0))
     prompts = [np.arange(1, 14 + 3 * i) for i in range(4)]
 
-    legacy = LLMEngine(model, params, LLMEngineConfig(
-        max_slots=4, max_seq_len=256, prefill_buckets=(32, 64)))
-    try:
-        want = [legacy.generate_sync(p, max_new_tokens=8)
-                for p in prompts]
-    finally:
-        legacy.shutdown()
+    def model_cache_greedy(prompt, n_new):
+        cache = model.empty_cache(1, 256, dtype=jnp.float32)
+        toks = jnp.asarray(prompt, jnp.int32)[None, :]
+        pos = jnp.arange(toks.shape[1])[None, :]
+        out = []
+        for _ in range(n_new):
+            logits, cache = model.apply({"params": params}, toks,
+                                        cache=cache, positions=pos)
+            out.append(int(jnp.argmax(logits[0, -1])))
+            toks = jnp.asarray([[out[-1]]], jnp.int32)
+            pos = pos[:, -1:] + 1
+        return out
+
+    want = [model_cache_greedy(p, 8) for p in prompts]
 
     paged = LLMEngine(model, params, LLMEngineConfig(
         max_slots=8, max_seq_len=256, prefill_buckets=(32, 64),
