@@ -199,10 +199,11 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return out, PagedKV(k_flat, v_flat, page_table, new_lengths,
                             page_size)
 
-    # Single-token decode fast path: the Pallas kernel reads pages
-    # DIRECTLY via scalar-prefetched page tables — no (B, L, Hkv, D)
-    # contiguous gather temp, and work scales with real sequence
-    # lengths. RAY_TPU_PAGED_ATTN_IMPL: auto|gather|pallas.
+    # Single-token decode fast path: the Pallas kernel copies each
+    # row's live pages out of the pool itself (page table in SMEM) —
+    # no (B, L, Hkv, D) contiguous gather temp, and work follows the
+    # pages a row holds, not the window. RAY_TPU_PAGED_ATTN_IMPL:
+    # auto|gather|pallas.
     impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
     if s == 1 and impl != "gather" and (
             impl == "pallas" or jax.default_backend() == "tpu"):

@@ -1,29 +1,38 @@
 """Pallas TPU kernel: single-token decode attention over a paged KV pool.
 
 The serve engine's paged cache (ops/attention.py:PagedKV) stores KV as
-flat token rows in a shared pool with per-sequence page tables. The
-XLA gather path gathers each sequence's pages into a contiguous
-(S, L, Hkv, D) view per layer per decode step — correct, but it
-materializes L*page_size rows of temp HBM traffic per layer even when
-sequences are short. This kernel reads the pages DIRECTLY:
+flat token rows `(n_flat, Hkv, D)` in a shared pool with per-sequence
+page tables. This kernel reads a sequence's pages where they lie and
+does work only for the pages the sequence has:
 
-  * the page table and lengths ride in SMEM via scalar prefetch
-    (pltpu.PrefetchScalarGridSpec), so each (sequence, page) grid step's
-    BlockSpec index_map picks the physical page — the indirection costs
-    an SMEM read, not an HBM gather;
-  * grid (S, P) accumulates flash-style (online softmax) across the
-    page dimension; pages past the sequence length are skipped whole
-    (pl.when), so work scales with the ACTUAL tokens, not the max;
-  * GQA is handled in-kernel (q reshaped to (Hkv, rep, D)) — the pool
-    is never head-expanded.
+  * the grid is one step a sequence. The pools stay in HBM
+    (`memory_space=ANY`); inside a step a loop walks the row's live
+    pages in blocks of `n` pages (`choose_pages_per_block`), each page
+    one contiguous DMA into a double-buffered VMEM block. The copies of
+    the next block (the next live row's first block included) are
+    started before the current block is computed, so an empty slot, the
+    scratch row and the dead tail of a decode window cost no copy and
+    no product: time follows `ceil(min(len, qpos + 1) / page_size)`
+    summed over rows, not rows x window;
+  * the page table, lengths and query positions ride in SMEM (scalar
+    prefetch); the table is flat so that SMEM pads nothing;
+  * the pool is viewed as `(pages, page_size * Hkv, D)`: a token's
+    heads are consecutive rows, which on the chip is the layout the pool
+    already has (Hkv a multiple of the 8-row tile and D of the 128
+    lanes: a bitcast, no copy; a narrower head or another head count
+    costs a copy of the pool a call). All heads go
+    through ONE product a block: `q (Hq, D) x block (T * Hkv, D)^T`
+    gives every query head against every (token, kv head) row and the
+    mask keeps the columns of the head's own group; `P x V` is the
+    same product the other way. The matrix unit is bound by loading the
+    block, not by the rows of `q`, so the crossed terms are free and
+    nothing is sliced, transposed or concatenated: GQA (`rep` 4) and
+    MHA (`rep` 1) are the same code;
+  * scores, running maximum, sum and accumulator are float32 (online
+    softmax across blocks), operands keep the pool's dtype.
 
 Decode is inference-only: no backward pass is defined (the training
 path never runs paged attention).
-
-Same vLLM-PagedAttention capability as the reference's GPU serving
-path, re-designed for Mosaic's tiling rules (blocks keep the pool's
-(page_size, Hkv, D) layout; the second-minor block dim equals the full
-array dim, which the (8, 128) tiling rule permits).
 """
 from __future__ import annotations
 
@@ -35,129 +44,216 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# what one block may take of VMEM: two buffers each for K and V, and
+# the float32 score tiles (scores, exponentials, mask, the cast copy)
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+# rows of (token, kv head) a block should hold before the fixed cost of
+# a loop turn (DMA issue, semaphore waits, a rescale of the accumulator)
+# stops showing
+_PREFERRED_ROWS = 4096
 
 
-def _decode_kernel(pt_ref, len_ref, qpos_ref, q_ref, k_ref, v_ref,
-                   o_ref, acc_ref, m_ref, l_ref, *,
-                   scale: float, page_size: int, n_kv: int, rep: int):
+def vmem_bytes(n: int, page_size: int, hq: int, hkv: int, d: int,
+               itemsize: int) -> int:
+    """VMEM one block of `n` pages needs (`d` a multiple of the 128
+    lanes): K and V double-buffered plus four float32 (Hq, rows) tiles
+    of scores."""
+    rows = n * page_size * hkv
+    return 4 * rows * d * itemsize + 4 * hq * rows * 4
+
+
+def choose_pages_per_block(n_pages: int, page_size: int, hq: int, hkv: int,
+                           d: int, dtype) -> int:
+    """Pages a block holds: the power of two whose (token, kv head)
+    rows come nearest `_PREFERRED_ROWS` from below, cut to the window
+    and halved until `vmem_bytes` fits VMEM_BUDGET_BYTES. A pure
+    function of what the call can observe; no knob."""
+    itemsize = jnp.dtype(dtype).itemsize
+    n = 1
+    while 2 * n * page_size * hkv <= _PREFERRED_ROWS and 2 * n <= n_pages:
+        n *= 2
+    while n > 1 and vmem_bytes(n, page_size, hq, hkv, d,
+                               itemsize) > VMEM_BUDGET_BYTES:
+        n //= 2
+    return n
+
+
+def _cdiv(a, b: int):
+    return jax.lax.div(a + (b - 1), jnp.int32(b))
+
+
+def _decode_kernel(pt_ref, len_ref, qpos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, sems, nxt_ref, slot_ref, *,
+                   scale: float, page_size: int, n_kv: int, rep: int,
+                   n_blk: int, n_rows: int, n_table: int):
     s = pl.program_id(0)
-    p = pl.program_id(1)
-    np_ = pl.num_programs(1)
+    blk_tokens = n_blk * page_size
+    hq, d = q_ref.shape[1:]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def seq_len(r):
+        # causal bound: keys at positions <= the query's own position
+        # AND < the sequence length — identical masking to
+        # _attend_cached, so a replay query at an EARLIER position
+        # (positions < lengths-1, e.g. speculative-decode verification)
+        # can't see future keys
+        # (and inside the window: the table has no page beyond it)
+        return jnp.minimum(jnp.minimum(len_ref[r], qpos_ref[r] + 1),
+                           n_table * page_size)
 
-    # causal bound: keys at positions <= the query's own position AND
-    # < the sequence length — identical masking to _attend_cached, so
-    # a replay query at an EARLIER position (positions < lengths-1,
-    # e.g. speculative-decode verification) can't see future keys
-    seq_len = jnp.minimum(len_ref[s], qpos_ref[s] + 1)
-    run = p * page_size < seq_len
+    def block_copies(r, b, slot, act):
+        """`act` (start or wait) on the copies of block `b` of row `r`:
+        its live pages only, K and V, into buffer `slot`."""
+        live = jnp.minimum(_cdiv(seq_len(r), page_size) - b * n_blk, n_blk)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                       # (Hq, D)
-        k = k_ref[0]                       # (ps, Hkv, D)
-        v = v_ref[0]
-        hq, d = q.shape
-        qg = q.reshape(n_kv, rep, d)
-        # per-kv-head scores: (rep, ps) each; stacked -> (Hq, ps)
-        parts = []
-        for h in range(n_kv):
-            sh = jax.lax.dot_general(
-                qg[h], k[:, h, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            parts.append(sh)               # (rep, ps)
-        scores = jnp.concatenate(parts, axis=0)        # (Hq, ps)
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        scores = jnp.where(pos < seq_len, scores, NEG_INF)
+        def page(i, carry):
+            src = pt_ref[r * n_table + b * n_blk + i]
+            for hbm, buf, j in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                act(pltpu.make_async_copy(
+                    hbm.at[src], buf.at[slot, i], sems.at[j, slot]))
+            return carry
+        jax.lax.fori_loop(0, live, page, 0)
 
-        m_prev = m_ref[:, :1]                           # (Hq, 1)
+    @pl.when(s == 0)
+    def _first():
+        # stale rows of a block are masked out of the scores, but in
+        # P x V a masked 0 times a NaN left in VMEM is a NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+        def scan(i, nxt):               # next live row after each row
+            r = n_rows - 1 - i
+            nxt_ref[r] = nxt
+            return jnp.where(seq_len(r) > 0, r, nxt)
+        first = jax.lax.fori_loop(0, n_rows, scan, jnp.int32(n_rows))
+        slot_ref[0] = 0
+
+        @pl.when(first < n_rows)
+        def _():
+            block_copies(first, 0, 0, lambda c: c.start())
+
+    length = seq_len(s)
+    n_blocks = _cdiv(length, blk_tokens)
+
+    # a column of a block is (token t, kv head h) at t * n_kv + h; a
+    # query head sees the columns of its own group
+    shape = (hq, blk_tokens * n_kv)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    own = jax.lax.rem(col, jnp.int32(n_kv)) == jax.lax.div(
+        row, jnp.int32(rep))
+
+    def block(b, carry):
+        m_prev, l_prev, acc = carry
+        slot = slot_ref[0]
+        last = b + 1 == n_blocks
+        nr = jnp.where(last, nxt_ref[s], s)
+        nb = jnp.where(last, 0, b + 1)
+
+        @pl.when(nr < n_rows)
+        def _():
+            block_copies(nr, nb, 1 - slot, lambda c: c.start())
+        block_copies(s, b, slot, lambda c: c.wait())
+        slot_ref[0] = 1 - slot
+
+        q = q_ref[0]                                    # (Hq, D)
+        k = kbuf[slot].reshape(shape[1], d)             # (T * Hkv, D)
+        v = vbuf[slot].reshape(shape[1], d)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        live = (length - b * blk_tokens) * n_kv         # columns < length
+        scores = jnp.where(own & (col < live), scores, NEG_INF)
         m_cur = jnp.max(scores, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        pexp = jnp.exp(scores - m_new)                  # (Hq, ps)
+        pexp = jnp.exp(scores - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            corr * l_ref[:, :1]
-            + jnp.sum(pexp, axis=1, keepdims=True), l_ref.shape)
-        pv_parts = []
-        pg = pexp.reshape(n_kv, rep, page_size)
-        for h in range(n_kv):
-            pv = jax.lax.dot_general(
-                pg[h].astype(v.dtype), v[:, h, :],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)     # (rep, D)
-            pv_parts.append(pv)
-        acc_ref[:] = (acc_ref[:] * corr
-                      + jnp.concatenate(pv_parts, axis=0))
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_new = corr * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # (Hq, D)
+        return m_new, l_new, acc * corr + pv
 
-    @pl.when(p == np_ - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+    _m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((hq, 1), NEG_INF, jnp.float32),
+         jnp.zeros((hq, 1), jnp.float32),
+         jnp.zeros((hq, d), jnp.float32)))
+    # a row with no key (an empty slot, the scratch row) gives zeros
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+# jitted so that a model's layers trace the kernel once a program, not
+# once a layer: 16 traces were 3.5 s of a decode program's first call on
+# the chip's host (PERF.md, PR 29)
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "scale", "interpret", "pages_per_block"))
 def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
                            page_size: int,
                            qpos=None,
                            scale: "float | None" = None,
-                           interpret: "bool | None" = None):
+                           interpret: "bool | None" = None,
+                           pages_per_block: "int | None" = None):
     """q: (S, Hq, D) one decode token per sequence (cache already holds
     its KV); k_flat/v_flat: (N_flat, Hkv, D) page pools; page_table:
     (S, P) int32; lengths: (S,) int32 — keys valid at positions
     < lengths. qpos: (S,) int32 query positions (causal bound: keys at
     positions <= qpos attend; default lengths-1, the decode-at-end
     case). interpret defaults to True only on the CPU backend.
-    Returns (S, Hq, D)."""
-    s_n, hq, d = q.shape
+    pages_per_block is for the microbenchmark and the tests; callers
+    leave it to `choose_pages_per_block`. Returns (S, Hq, D)."""
+    s_n, hq, d_model = q.shape
     n_flat, hkv, _ = k_flat.shape
     assert n_flat % page_size == 0, (n_flat, page_size)
     rep = hq // hkv
     if scale is None:
-        scale = d ** -0.5
-    n_pages = n_flat // page_size
-    kp = k_flat.reshape(n_pages, page_size, hkv, d)
-    vp = v_flat.reshape(n_pages, page_size, hkv, d)
+        scale = d_model ** -0.5
+    # Mosaic refuses a DMA out of an array narrower than a lane tile:
+    # heads under 128 wide are padded with zeros, which costs a copy of
+    # the pool a call (the pool's rows are padded to 128 lanes in HBM
+    # already; the cure is a pool that packs heads into lanes)
+    d = -(-d_model // 128) * 128
+    if d != d_model:
+        pad = ((0, 0), (0, 0), (0, d - d_model))
+        q, k_flat, v_flat = (jnp.pad(x, pad) for x in (q, k_flat, v_flat))
     P = page_table.shape[1]
     if qpos is None:
         qpos = lengths - 1
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    n_blk = pages_per_block or choose_pages_per_block(
+        P, page_size, hq, hkv, d, k_flat.dtype)
+    n_pages, page_rows = n_flat // page_size, page_size * hkv
 
     kernel = functools.partial(
-        _decode_kernel, scale=scale, page_size=page_size,
-        n_kv=hkv, rep=rep)
+        _decode_kernel, scale=scale, page_size=page_size, n_kv=hkv,
+        rep=rep, n_blk=n_blk, n_rows=s_n, n_table=P)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,             # page_table, lengths, qpos
-        grid=(s_n, P),
+        grid=(s_n,),
         in_specs=[
-            pl.BlockSpec((1, hq, d),
-                         lambda s, p, pt, ln, qp: (s, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda s, p, pt, ln, qp: (pt[s, p], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda s, p, pt, ln, qp: (pt[s, p], 0, 0, 0)),
+            pl.BlockSpec((1, hq, d), lambda s, pt, ln, qp: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hq, d),
-                               lambda s, p, pt, ln, qp: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, hq, d), lambda s, pt, ln, qp: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((hq, d), jnp.float32),
-            pltpu.VMEM((hq, 128), jnp.float32),
-            pltpu.VMEM((hq, 128), jnp.float32),
+            pltpu.VMEM((2, n_blk, page_rows, d), k_flat.dtype),
+            pltpu.VMEM((2, n_blk, page_rows, d), v_flat.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((s_n,), jnp.int32),     # next live row
+            pltpu.SMEM((1,), jnp.int32),       # buffer the next block reads
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, hq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(page_table, lengths, jnp.asarray(qpos, jnp.int32), q, kp, vp)
-
+            # a step starts the copies the next one waits for
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(page_table.reshape(-1), lengths, jnp.asarray(qpos, jnp.int32), q,
+      k_flat.reshape(n_pages, page_rows, d),
+      v_flat.reshape(n_pages, page_rows, d))
+    return out[..., :d_model]

@@ -461,6 +461,10 @@ class LLMEngine:
                       # pipeline_depth), or the slot was empty
                       "decode_slot_steps": 0, "decode_tokens_emitted": 0,
                       "decode_tokens_discarded": 0,
+                      # the decode kernel's pages, summed over decode
+                      # dispatches: rows x the window's pages, and the
+                      # pages the rows hold (what the kernel walks)
+                      "decode_pages_window": 0, "decode_pages_live": 0,
                       # prefill programs: rows and tokens asked for
                       # against what the padded (bucket x group) call ran
                       "prefill_calls": 0, "prefill_rows_real": 0,
@@ -1942,6 +1946,10 @@ class LLMEngine:
         row[:len(pages)] = pages
         self._page_table = self._page_table.at[slot].set(
             self._jnp.asarray(row))
+        if not pages:
+            # a row that holds no page holds no key: the decode kernel
+            # walks a row's length, so a stale one would cost its pages
+            self._lengths = self._lengths.at[slot].set(0)
 
     def _free_slot_pages(self, slot: int) -> None:
         """Return the slot's exclusive pages to the pool (shared prefix
@@ -2018,6 +2026,17 @@ class LLMEngine:
                 + max(1, self.cfg.decode_block))
         w = _next_pow2(-(-need // ps))
         return 0 if w >= self._pages_per_slot else w
+
+    def _count_decode_pages(self, window: int) -> None:
+        """The old kernel's grid against what the rows hold: every row
+        times the window's pages, and the pages under each slot's
+        tokens once this dispatch has written its own."""
+        ps = self.cfg.kv_page_size
+        new = max(1, self.cfg.decode_block)
+        self.stats["decode_pages_window"] += \
+            self._n_slots * (window or self._pages_per_slot)
+        self.stats["decode_pages_live"] += sum(
+            -(-(n + new) // ps) for n in self._disp_len.values())
 
     def _propose_ngram(self, req) -> "Optional[List[int]]":
         """Prompt-lookup proposal: the K tokens that followed the most
@@ -2415,6 +2434,7 @@ class LLMEngine:
                             self._disp_len[slot] += \
                                 self.cfg.ngram_speculation + 1
                     window = self._decode_window_pages()
+                    self._count_decode_pages(window)
                     snapshot = list(self._active.items())
                     ready = True
         if ready:

@@ -115,6 +115,39 @@ def test_paged_decode_compiles_for_v5e(chip, page_size, width):
         sds((SLOTS,), jnp.int32)).compile())
 
 
+# (Hq, Hkv, D, pages of 64 a slot): the full-width decode program of
+# each serve configuration (`window_pages` 0: Mistral's `max_seq_len`
+# 8 320, OLMoE's 4 096), 64 slots and the scratch row
+FULL_WIDTH = {"mistral7b": (32, 8, 128, 130), "olmoe7b": (16, 16, 128, 64)}
+
+
+@pytest.mark.parametrize("config", sorted(FULL_WIDTH))
+def test_paged_decode_full_width_compiles_for_v5e(chip, config):
+    """The block `choose_pages_per_block` picks compiles at the widest
+    window, and the kernel's view of the pool is the pool: no copy of
+    it anywhere in the program (a pool of 49 152 tokens is 96 / 192 MiB
+    a layer for K alone)."""
+    hq, hkv, d, pages = FULL_WIDTH[config]
+    rows, ps = 65, 64
+    n_flat = 49152 + ps
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(q, k_flat, v_flat, page_table, lengths):
+        return paged_decode_attention(q, k_flat, v_flat, page_table,
+                                      lengths, ps, interpret=False)
+
+    compiled = jax.jit(decode).lower(
+        sds((rows, hq, d), jnp.bfloat16),
+        sds((n_flat, hkv, d), jnp.bfloat16),
+        sds((n_flat, hkv, d), jnp.bfloat16),
+        sds((rows, pages), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("rows", [65, 2048], ids=["decode", "prefill"])
 def test_dropless_expert_layer_compiles_for_v5e(chip, rows, monkeypatch):
     """OLMoE's expert layer at published widths (64 experts of 2048 x

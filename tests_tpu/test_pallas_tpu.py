@@ -110,8 +110,9 @@ def test_attention_auto_resolves_to_working_kernel():
 
 
 def test_paged_decode_kernel_on_tpu(monkeypatch):
-    """r5: Mosaic lowering of the paged decode kernel (scalar-prefetch
-    page tables) at engine-like shapes, vs the XLA gather path."""
+    """Mosaic lowering of the paged decode kernel (its own page DMAs;
+    heads of 64 go through the lane pad) at engine-like shapes, vs the
+    XLA gather path."""
     import numpy as np
     from ray_tpu.ops.attention import PagedKV, paged_cached_attention
     from ray_tpu.ops.pallas.paged_attention import paged_decode_attention
