@@ -6,6 +6,7 @@ Train/Serve/RLlib examples, re-implemented TPU-first.
 from .llama import Llama, LlamaConfig
 from .gpt2 import GPT2, GPT2Config
 from .mixtral import Mixtral, MixtralConfig
+from .latent_moe import LatentMoE, LatentMoEConfig
 from .vit import ViT, ViTConfig
 from .clip import CLIP, CLIPConfig, contrastive_loss
 from .mlp import MLP, MLPConfig, ResNetLite
@@ -21,6 +22,8 @@ _REGISTRY = {
     "mixtral-8x7b": lambda **kw: Mixtral(MixtralConfig.mixtral_8x7b(**kw)),
     "olmoe-1b-7b": lambda **kw: Mixtral(MixtralConfig.olmoe_1b_7b(**kw)),
     "mixtral-debug": lambda **kw: Mixtral(MixtralConfig.debug(**kw)),
+    "sarvam-105b": lambda **kw: LatentMoE(LatentMoEConfig.sarvam_105b(**kw)),
+    "latent-moe-debug": lambda **kw: LatentMoE(LatentMoEConfig.debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),
     "clip-debug": lambda **kw: CLIP(CLIPConfig.debug(**kw)),
@@ -39,6 +42,7 @@ def register_model(name: str, builder) -> None:
 
 
 __all__ = ["Llama", "LlamaConfig", "GPT2", "GPT2Config", "Mixtral",
-           "MixtralConfig", "ViT", "ViTConfig", "CLIP", "CLIPConfig",
+           "MixtralConfig", "LatentMoE", "LatentMoEConfig", "ViT",
+           "ViTConfig", "CLIP", "CLIPConfig",
            "contrastive_loss", "MLP", "MLPConfig", "ResNetLite",
            "get_model", "register_model"]

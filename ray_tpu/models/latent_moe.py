@@ -1,0 +1,335 @@
+"""Latent-attention sparse-expert decoders (the `sarvam_mla` family:
+sarvam-105b), TPU-first.
+
+A block is latent attention followed by a dense SwiGLU MLP (the leading
+`first_dense` layers) or by an expert layer: routed SwiGLU experts beside
+a shared one.
+
+Latent attention, token at position p, h = RMSNorm(x):
+  q = W_q h, heads of [nope | rope]; each head's query takes a learned
+  RMSNorm (one weight shared by the heads), then its rope part is
+  rotated. [c | k_r] = W_dkv h; c takes a learned RMSNorm, k_r (ONE rope
+  key for all heads) is rotated. What is cached is [c | k_r], a token's
+  latent. Keys and values are [k_nope_h | v_h] = W_ukv,h c.
+Two forms of the same mathematics:
+  * expanded (the plain forward, and prefill: `cache.fresh`): k_h =
+    [k_nope_h | k_r], causal attention over the new tokens through
+    `ops.attention.uneven_head_attention`; the latents are written to
+    the pool;
+  * absorbed (decode against the pool, PagedLatent): q~_h = W_uk,h^T
+    q_nope_h, scores (q~_h . c + q_rope_h . k_r) x scale, o~_h = sum p c,
+    o_h = W_uv,h o~_h: multi-query attention over the latents, which the
+    Pallas kernel of ops/pallas/latent_attention.py reads where they lie.
+RoPE is YaRN's (`ops.rotary.yarn_frequencies`), the scale carries its
+m^2 (`yarn_softmax_scale`).
+
+The expert layer routes by sigmoid scores with a selection bias
+(`ops.moe.route`, "sigmoid_bias"): the router scores all `n_experts`,
+the layer HOLDS `expert_count` of them from `expert_first` (a chip's
+share of an expert-parallel deployment; default all) and computes
+`sum over selected experts held here of w_e E_e(h) + E_shared(h)`, the
+weights normalised over all selected wherever they live. On one chip
+the share runs without its exchange; nothing stands in for the absent
+experts (ops/moe.py, "A share").
+
+The pool row is the latent padded with zeros to whole 128-lane tiles
+(`cache_width`): Mosaic refuses a DMA slice that is no multiple of the
+lanes, and the pool's rows are padded so in HBM whatever their nominal
+width.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops import (apply_rotary, rms_norm, swiglu, yarn_frequencies,
+                   yarn_softmax_scale)
+from ..ops.attention import (PagedLatent, latent_cached_attention,
+                             uneven_head_attention)
+from ..ops.moe import MOE_STATS, moe_dropless, route
+from .llama import _LMHead
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    vocab_size: int = 262144
+    d_model: int = 4096
+    n_layers: int = 32
+    n_heads: int = 64
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    d_ff: int = 16384               # the dense layers' SwiGLU
+    first_dense: int = 1            # leading layers with a dense MLP
+    d_expert: int = 2048            # one expert's (and the shared) width
+    n_experts: int = 128            # the router's width
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scaling: float = 2.5
+    norm_topk_prob: bool = True
+    # the share of each expert layer held here: experts expert_first ..
+    # expert_first + expert_count - 1; None holds all n_experts
+    expert_first: int = 0
+    expert_count: Optional[int] = None
+    max_seq_len: int = 4096         # rows of the rope tables
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    # storage dtype of embeddings and matmul kernels; norm weights, the
+    # router and its bias stay float32
+    param_dtype: Any = jnp.float32
+    attn_impl: str = "auto"
+
+    def __post_init__(self):
+        held = self.experts_held
+        if not (0 <= self.expert_first
+                and self.expert_first + held <= self.n_experts):
+            raise ValueError(
+                f"experts {self.expert_first}..{self.expert_first + held} "
+                f"are not among the router's {self.n_experts}")
+        if self.experts_per_token > self.n_experts:
+            raise ValueError("experts_per_token exceeds n_experts")
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim must be even (RoPE pairs)")
+
+    @property
+    def experts_held(self) -> int:
+        return (self.n_experts if self.expert_count is None
+                else self.expert_count)
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def latent_width(self) -> int:
+        """What a token caches a layer: latent + rope key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def cache_width(self) -> int:
+        """A pool row: `latent_width` in whole 128-lane tiles."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        return yarn_softmax_scale(self.q_head_dim, self.rope_factor,
+                                  self.rope_mscale_all_dim)
+
+    @staticmethod
+    def sarvam_105b(**kw) -> "LatentMoEConfig":
+        """sarvam-105b as published (config.json, model_type sarvam_mla):
+        every default above."""
+        return LatentMoEConfig(**kw)
+
+    @staticmethod
+    def debug(**kw) -> "LatentMoEConfig":
+        return LatentMoEConfig(**{**dict(
+            vocab_size=256, d_model=64, n_layers=3, n_heads=4,
+            qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, kv_lora_rank=32,
+            d_ff=128, d_expert=32, n_experts=8, experts_per_token=2,
+            max_seq_len=128, rope_original_max_len=32), **kw})
+
+
+def _dense(cfg: LatentMoEConfig, features: int, name: str):
+    return nn.Dense(features, use_bias=False, name=name, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype)
+
+
+class LatentAttention(nn.Module):
+    cfg: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x, cos, sin, cache=None, positions=None):
+        cfg = self.cfg
+        b, s, _ = x.shape
+        h, dn, dr, dv, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                            cfg.v_head_dim, cfg.kv_lora_rank)
+        w_ukv = self.param("kv_up_kernel", nn.initializers.lecun_normal(),
+                           (r, h * (dn + dv)), cfg.param_dtype
+                           ).astype(cfg.dtype).reshape(r, h, dn + dv)
+        with jax.named_scope("mla.project"):
+            q = _dense(cfg, h * cfg.q_head_dim, "q_proj")(x).reshape(
+                b, s, h, cfg.q_head_dim)
+            q = rms_norm(q, self.param("q_norm", nn.initializers.ones,
+                                       (cfg.q_head_dim,)), cfg.norm_eps)
+            q_nope = q[..., :dn]
+            q_rope = apply_rotary(q[..., dn:], cos, sin, positions)
+            down = _dense(cfg, cfg.latent_width, "kv_down_proj")(x)
+            c = rms_norm(down[..., :r],
+                         self.param("kv_norm", nn.initializers.ones, (r,)),
+                         cfg.norm_eps)
+            k_rope = apply_rotary(down[..., None, r:], cos, sin, positions)
+            latent = jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)
+        scale = cfg.softmax_scale
+        if cache is None or cache.fresh:
+            with jax.named_scope("mla.expand"):
+                kv = jnp.einsum("bsc,chd->bshd", c, w_ukv)
+                k = jnp.concatenate(
+                    [kv[..., :dn],
+                     jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
+                q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            with jax.named_scope("mla.attend"):
+                out = uneven_head_attention(q, k, kv[..., dn:], scale,
+                                            cfg.attn_impl)
+            new_cache = None if cache is None else cache.write(
+                self._pool_row(latent), positions)
+        else:
+            with jax.named_scope("mla.absorb"):
+                q_abs = jnp.einsum("bshd,chd->bshc", q_nope,
+                                   w_ukv[..., :dn])
+                q = self._pool_row(
+                    jnp.concatenate([q_abs, q_rope], axis=-1))
+            with jax.named_scope("mla.attend"):
+                o_lat, new_cache = latent_cached_attention(
+                    q, self._pool_row(latent), cache, positions, scale,
+                    d_v=r)
+            with jax.named_scope("mla.expand"):
+                out = jnp.einsum("bshc,chd->bshd", o_lat, w_ukv[..., dn:])
+        out = _dense(cfg, cfg.d_model, "o_proj")(out.reshape(b, s, h * dv))
+        return out, new_cache
+
+    def _pool_row(self, x):
+        """Zero-pad the last axis from `latent_width` to `cache_width`."""
+        pad = self.cfg.cache_width - self.cfg.latent_width
+        return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+
+
+class _SwiGLU(nn.Module):
+    cfg: LatentMoEConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate = _dense(cfg, self.width, "gate_proj")(x)
+        up = _dense(cfg, self.width, "up_proj")(x)
+        return _dense(cfg, cfg.d_model, "down_proj")(swiglu(gate, up))
+
+
+class ShareMoE(nn.Module):
+    """Sigmoid-routed SwiGLU experts, of which this layer holds a share,
+    beside a shared expert every token passes through."""
+    cfg: LatentMoEConfig
+
+    @nn.compact
+    def __call__(self, x, row_mask=None):
+        cfg = self.cfg
+        b, s, d = x.shape
+        held, f = cfg.experts_held, cfg.d_expert
+        router_w = self.param("router_kernel", nn.initializers.normal(0.02),
+                              (d, cfg.n_experts))
+        # drawn non-zero (a tenth of the spread of the scores that normed
+        # activations give through a 0.02-normal router), so that
+        # selection by s + b and weighting by s can be told apart
+        router_b = self.param("router_bias", nn.initializers.normal(0.025),
+                              (cfg.n_experts,))
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("experts_gate_kernel", init, (held, d, f),
+                            cfg.param_dtype)
+        w_up = self.param("experts_up_kernel", init, (held, d, f),
+                          cfg.param_dtype)
+        w_down = self.param("experts_down_kernel", init, (held, f, d),
+                            cfg.param_dtype)
+        tokens = x.reshape(b * s, d).astype(cfg.dtype)
+        with jax.named_scope("moe.route"):
+            logits = jnp.einsum(
+                "gd,de->ge", tokens.astype(jnp.float32),
+                router_w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            weights, top_idx = route(
+                logits, cfg.experts_per_token, "sigmoid_bias",
+                cfg.norm_topk_prob, select_bias=router_b,
+                scale=cfg.routed_scaling)
+        out, stats = moe_dropless(
+            tokens, weights, top_idx, w_gate, w_up, w_down,
+            None if row_mask is None else row_mask.reshape(b * s),
+            first=cfg.expert_first, count=held)
+        self.sow("step_stats", "moe", stats)
+        # which experts each position chose, for a reference check
+        self.sow("routing", "top_idx",
+                 top_idx.reshape(b, s, cfg.experts_per_token))
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe.shared"):
+                out = out + _SwiGLU(cfg, cfg.n_shared_experts * f,
+                                    name="shared")(tokens)
+        return out.reshape(b, s, d).astype(cfg.dtype)
+
+
+class LatentMoEBlock(nn.Module):
+    cfg: LatentMoEConfig
+    dense: bool
+
+    @nn.compact
+    def __call__(self, x, cos, sin, cache=None, positions=None,
+                 row_mask=None):
+        cfg = self.cfg
+        attn_norm_w = self.param("attn_norm", nn.initializers.ones,
+                                 (cfg.d_model,))
+        mlp_norm_w = self.param("mlp_norm", nn.initializers.ones,
+                                (cfg.d_model,))
+        h, new_cache = LatentAttention(cfg, name="attention")(
+            rms_norm(x, attn_norm_w, cfg.norm_eps), cos, sin, cache,
+            positions)
+        x = x + h
+        h = rms_norm(x, mlp_norm_w, cfg.norm_eps)
+        if self.dense:
+            x = x + _SwiGLU(cfg, cfg.d_ff, name="mlp")(h)
+        else:
+            x = x + ShareMoE(cfg, name="moe")(h, row_mask)
+        return x, new_cache
+
+
+class LatentMoE(nn.Module):
+    """tokens (B, S) -> (logits, cache): the calling convention of Llama
+    and Mixtral, so that the serve engine is family agnostic. `cache` is
+    None (the plain forward, expanded form) or one PagedLatent a layer
+    (`paged_cache_spec`). Under `mutable=["step_stats"]` every expert
+    layer leaves the int32 vector `step_stats` names (ops/moe.py:
+    MOE_STATS), counted over the rows `row_mask` (B, S) marks as real."""
+    cfg: LatentMoEConfig
+    step_stats = MOE_STATS
+
+    @nn.compact
+    def __call__(self, tokens, cache=None, positions=None, row_mask=None):
+        cfg = self.cfg
+        x = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
+                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                     embedding_init=nn.initializers.normal(0.02))(tokens)
+        cos, sin = yarn_frequencies(
+            cfg.qk_rope_dim, cfg.max_seq_len, cfg.rope_theta,
+            factor=cfg.rope_factor,
+            original_max_len=cfg.rope_original_max_len,
+            beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow)
+        new_cache = []
+        for i in range(cfg.n_layers):
+            x, c = LatentMoEBlock(cfg, i < cfg.first_dense,
+                                  name=f"layer_{i}")(
+                x, cos, sin, None if cache is None else cache[i],
+                positions, row_mask)
+            new_cache.append(c)
+        x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                   (cfg.d_model,)), cfg.norm_eps)
+        logits = _LMHead(cfg.vocab_size, cfg.param_dtype,
+                         name="lm_head")(x)
+        return logits, (new_cache if cache is not None else None)
+
+    def init_params(self, rng, batch=1, seq=8):
+        return self.init(rng, jnp.zeros((batch, seq), jnp.int32))["params"]
+
+    def paged_cache_spec(self):
+        """(entry class, per layer the trailing shapes of its pool
+        arrays, dtype): one latent row a token a layer
+        (ops/attention.py:kv_cache_spec)."""
+        cfg = self.cfg
+        return PagedLatent, [((cfg.cache_width,),)] * cfg.n_layers, cfg.dtype

@@ -201,6 +201,10 @@ class Mixtral(nn.Module):
     vector `step_stats` names (ops/moe.py:MOE_STATS), counted over the
     rows `row_mask` (B, S) marks as real (all of them if None): the
     others are bucket padding or empty slots and are given to no expert.
+    `moe_assignments` counts the (row, expert) pairs that ran here; these
+    models hold every expert, so it equals `moe_routed_assignments`
+    (`moe_dropless` may also hold a share of a layer's experts: that is
+    models/latent_moe.py).
     """
     cfg: MixtralConfig
     step_stats = MOE_STATS

@@ -147,6 +147,12 @@ class PagedKV:
         return (jnp.take_along_axis(self.page_table, positions // ps,
                                     axis=1) * ps + positions % ps)
 
+    @property
+    def arrays(self):
+        """The pool's arrays of this layer, in the order of the
+        constructor's leading arguments (what the engine keeps)."""
+        return (self.k_flat, self.v_flat)
+
     def tree_flatten(self):
         return ((self.k_flat, self.v_flat, self.page_table,
                  self.lengths), (self.page_size, self.fresh))
@@ -154,6 +160,117 @@ class PagedKV:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
+
+
+@jax.tree_util.register_pytree_node_class
+class PagedLatent:
+    """Per-layer paged cache entry of a latent-attention layer: ONE
+    array `flat` (N_flat, W), a token's compressed key/value (the normed
+    latent, then the rotated rope key shared by all heads). Pages, page
+    table, lengths, `page_size` and `fresh` are PagedKV's, so the
+    engine's allocator and windows do not know the difference."""
+
+    flat_rows = PagedKV.flat_rows
+
+    def __init__(self, flat, page_table, lengths, page_size: int,
+                 fresh: bool = False):
+        self.flat = flat
+        self.page_table = page_table
+        self.lengths = lengths
+        self.page_size = page_size
+        self.fresh = fresh
+
+    @property
+    def arrays(self):
+        return (self.flat,)
+
+    def write(self, latent: jax.Array, positions: jax.Array
+              ) -> "PagedLatent":
+        """Scatter the new tokens' latents (B, S, W) into their pages;
+        the entry that results is never `fresh`."""
+        b, s, w = latent.shape
+        flat = self.flat.at[self.flat_rows(positions).reshape(-1)].set(
+            latent.astype(self.flat.dtype).reshape(b * s, w))
+        return PagedLatent(
+            flat, self.page_table,
+            jnp.maximum(self.lengths, positions[:, -1] + 1),
+            self.page_size)
+
+    def tree_flatten(self):
+        return ((self.flat, self.page_table, self.lengths),
+                (self.page_size, self.fresh))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
+def kv_cache_spec(model):
+    """What a model's per-layer paged cache is made of: (entry class,
+    per layer the trailing shapes of its pool arrays, dtype). A model
+    says so itself (`paged_cache_spec()`); every other decoder of the
+    zoo caches K and V of (n_kv_heads, head_dim) a token a layer."""
+    own = getattr(model, "paged_cache_spec", None)
+    if own is not None:
+        return own()
+    c = model.cfg
+    kv = (c.n_kv_heads, c.head_dim)
+    return PagedKV, [(kv, kv)] * c.n_layers, c.dtype
+
+
+def uneven_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                          scale: float, impl: str = "auto") -> jax.Array:
+    """Causal self-attention whose value heads are narrower than its
+    query/key heads (a latent-attention layer's expanded form: q, k
+    (B, S, H, dq), v (B, S, H, dv)); returns (B, S, H, dv). The einsum
+    route takes the widths as they are; a kernel route (flash, dpa)
+    wants one width, so q, k and v are padded with zeros to dq rounded
+    up to the 128 lanes (the scores and the first dv columns of the
+    result are unchanged) and the result is cut back."""
+    if _resolve_impl(impl, q, k, True, None) == "xla":
+        return multi_head_attention(q, k, v, causal=True, impl="xla",
+                                    scale=scale)
+    d = -(-q.shape[-1] // 128) * 128
+
+    def pad(x):
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, d - x.shape[-1]),))
+    return multi_head_attention(pad(q), pad(k), pad(v), causal=True,
+                                impl=impl, scale=scale)[..., :v.shape[-1]]
+
+
+def latent_cached_attention(q: jax.Array, latent: jax.Array,
+                            cache: PagedLatent, positions: jax.Array,
+                            scale: float, d_v: int):
+    """Absorbed-form latent attention against the page pool: multi-query
+    attention of q (B, S, H, W) over one W-wide key a token whose first
+    `d_v` columns are also the value. Writes the new tokens' `latent`
+    (B, S, W) at `positions` (B, S) first, attends causally over the
+    written prefix and returns (out (B, S, H, d_v), new entry).
+
+    Single-token steps on the TPU (or under RAY_TPU_PAGED_ATTN_IMPL=
+    pallas) run the Pallas kernel over the pool's live pages
+    (ops/pallas/latent_attention.py); everything else gathers the
+    sequence's pages and goes through `_attend_cached`, the same tail
+    PagedKV's gather route uses."""
+    b, s, _h, _w = q.shape
+    new = cache.write(latent, positions)
+    impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
+    if s == 1 and impl != "gather" and (
+            impl == "pallas" or jax.default_backend() == "tpu"):
+        from .pallas.latent_attention import (  # noqa: PLC0415
+            latent_decode_attention)
+        out = latent_decode_attention(
+            q[:, 0], new.flat, new.page_table, new.lengths,
+            new.page_size, d_v=d_v, qpos=positions[:, 0], scale=scale)
+        return out[:, None], new
+    ps = new.page_size
+    L = new.page_table.shape[1] * ps
+    gather_idx = (new.page_table[:, :, None] * ps
+                  + jnp.arange(ps)[None, None, :]).reshape(b, L)
+    ck = new.flat[gather_idx][:, :, None, :]              # (B, L, 1, W)
+    out = _attend_cached(q, ck, ck[..., :d_v], positions, new.lengths,
+                         scale)
+    return out, new
 
 
 def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,

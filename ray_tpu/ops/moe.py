@@ -15,9 +15,20 @@ Two ways from routed tokens to expert batches, both with static shapes:
   XLA lowers to an all-to-all when the expert dim is sharded over `ep`.
   Tokens over an expert's capacity C are dropped.
 
-`route` holds the two published conventions: Mixtral's (softmax over the
-selected logits) and OLMoE's (softmax over all experts, then the k
-largest, renormalised or not). Router maths is float32.
+`route` holds three published conventions: Mixtral's (softmax over the
+selected logits), OLMoE's (softmax over all experts, then the k
+largest, renormalised or not) and the aux-loss-free one of the
+latent-attention families (sigmoid scores, a learned bias that enters
+the selection only, weights renormalised over the selected and scaled).
+Router maths is float32.
+
+A share. `moe_dropless` may hold a share of a layer's experts (`first`,
+`count`: the chip's part of an expert-parallel deployment). The router
+still scores and selects over all experts; assignments to experts that
+live elsewhere are given to no group, exactly as the assignments of
+unreal rows are, and the result is this share's part of the sum. On
+one chip the layer runs without its exchange: nothing stands in for
+the absent experts.
 """
 from __future__ import annotations
 
@@ -28,12 +39,17 @@ import jax.numpy as jnp
 
 from .activations import swiglu
 
-ROUTINGS = ("topk_softmax", "softmax_topk")
+ROUTINGS = ("topk_softmax", "softmax_topk", "sigmoid_bias")
 
 # What a dropless expert layer counts in one call, in this order, as one
 # int32 vector (models sow it, the engine sums it over layers and steps).
+# `moe_assignments` counts the (row, expert) pairs that RAN HERE: real
+# rows on experts this layer holds, which is every routed pair unless
+# the layer holds a share. `moe_routed_assignments` is what the router
+# handed out (real rows x k), wherever the experts live.
 MOE_STATS = ("moe_assignments", "moe_rows", "moe_pad_rows",
-             "moe_expert_load_max", "moe_experts_touched")
+             "moe_expert_load_max", "moe_experts_touched",
+             "moe_routed_assignments")
 
 
 class MoEAux(NamedTuple):
@@ -49,14 +65,29 @@ def expert_capacity(n_tokens: int, n_experts: int, k: int,
 
 
 def route(router_logits: jax.Array, k: int, routing: str = "topk_softmax",
-          norm_topk_prob: bool = False):
+          norm_topk_prob: bool = False,
+          select_bias: Optional[jax.Array] = None, scale: float = 1.0):
     """router_logits: (G, E). Returns float32 weights (G, k) and expert
     indices (G, k).
 
     "topk_softmax" (Mixtral): the k largest logits, softmax over them.
     "softmax_topk" (OLMoE): softmax over all E, then the k largest
-    probabilities; they sum to less than 1 unless `norm_topk_prob`."""
+    probabilities; they sum to less than 1 unless `norm_topk_prob`.
+    "sigmoid_bias" (aux-loss-free balancing): scores s = sigmoid(logits);
+    the k experts with the largest s + `select_bias` (E,) are selected,
+    the bias entering the selection only; the weights are the selected
+    experts' s, divided by their sum if `norm_topk_prob`, times
+    `scale`."""
     logits = router_logits.astype(jnp.float32)
+    if routing == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if select_bias is None \
+            else scores + select_bias.astype(jnp.float32)
+        top_idx = jax.lax.top_k(biased, k)[1]
+        weights = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if norm_topk_prob:
+            weights = weights / weights.sum(-1, keepdims=True)
+        return weights * scale, top_idx
     if routing == "topk_softmax":
         top_logits, top_idx = jax.lax.top_k(logits, k)
         return jax.nn.softmax(top_logits, axis=-1), top_idx
@@ -108,23 +139,40 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
 
 def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,
                  w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                 row_mask: Optional[jax.Array] = None):
+                 row_mask: Optional[jax.Array] = None,
+                 first: int = 0, count: Optional[int] = None):
     """x: (G, d) tokens; weights, top_idx: (G, k) from `route`; w_gate,
     w_up: (E, d, f); w_down: (E, f, d). `row_mask` (G,) marks the real
     rows: the others (bucket padding, empty slots) are given to no
     expert, cost nothing in the grouped matmuls and come back as zeros.
+
+    `first`, `count` (static): the layer holds experts first ..
+    first + count - 1 of those `top_idx` names, and E = count. An
+    assignment to any other expert is given to no group and adds
+    nothing: the result is this share's part of the layer's sum. The
+    default holds all of them.
 
     Returns (out (G, d) in x's dtype, stats): stats is the int32 vector
     MOE_STATS names, counted over the real rows.
     """
     g, k = top_idx.shape
     e = w_gate.shape[0]
+    share = count is not None
+    if share and count != e:
+        raise ValueError(f"count={count} but the weights hold {e} experts")
     with jax.named_scope("moe.dispatch"):
         expert_of = top_idx.reshape(g * k).astype(jnp.int32)
+        here = None
+        if share:
+            expert_of = expert_of - first
+            here = (expert_of >= 0) & (expert_of < e)
         if row_mask is not None:
+            real = jnp.repeat(row_mask, k)
+            here = real if here is None else here & real
+        if here is not None:
             # expert E does not exist: those assignments sort behind
             # every group and belong to none
-            expert_of = jnp.where(jnp.repeat(row_mask, k), expert_of, e)
+            expert_of = jnp.where(here, expert_of, e)
         order = jnp.argsort(expert_of, stable=True)       # by expert
         group_sizes = jnp.zeros((e + 1,), jnp.int32).at[expert_of].add(
             1)[:e]
@@ -143,14 +191,18 @@ def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,
         back = jnp.zeros((g * k,), jnp.int32).at[order].set(
             jnp.arange(g * k, dtype=jnp.int32))
         y = ys[back].reshape(g, k, -1).astype(jnp.float32)
+        if share:
+            y = jnp.where(here.reshape(g, k, 1), y, 0.0)
         out = jnp.einsum("gkd,gk->gd", y, weights.astype(jnp.float32))
-        if row_mask is not None:
+        if row_mask is not None and not share:
             out = jnp.where(row_mask[:, None], out, 0.0)
         out = out.astype(x.dtype)
     rows = (jnp.int32(g) if row_mask is None
             else row_mask.sum().astype(jnp.int32))
-    stats = jnp.stack([rows * k, rows, g - rows, group_sizes.max(),
-                       (group_sizes > 0).sum().astype(jnp.int32)])
+    ran = here.sum().astype(jnp.int32) if share else rows * k
+    stats = jnp.stack([ran, rows, g - rows, group_sizes.max(),
+                       (group_sizes > 0).sum().astype(jnp.int32),
+                       rows * k])
     return out, stats
 
 

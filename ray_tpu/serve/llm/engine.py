@@ -4,10 +4,15 @@ Reference parity: the fork's vLLM-style serving path (continuous batching,
 paged KV, streaming) — re-designed TPU-first:
 
 * Paged KV cache: one preallocated page pool per layer, flattened to
-  (n_pages * page_size, n_kv_heads, head_dim) token rows, plus a
-  (slots, pages_per_slot) page table. A slot reserves the pages its
-  prompt + budget need at admission. Static shapes, so the decode step
-  compiles once per power-of-two page window.
+  (n_pages * page_size, *trailing) token rows, plus a
+  (slots, pages_per_slot) page table. A pool's arrays and their trailing
+  shapes come from the model's cache spec (ops/attention.py:
+  kv_cache_spec): K and V of (n_kv_heads, head_dim) for the Llama,
+  Mixtral and GPT-2 families, one latent row for a latent-attention
+  model. Pages, page table, allocator and windows do not know which.
+  A slot reserves the pages its prompt + budget need at admission.
+  Static shapes, so the decode step compiles once per power-of-two
+  page window.
 * Continuous batching: ONE jitted decode step advances ALL active slots
   together (the MXU sees batch=max_slots matmuls, not per-request calls).
   Requests join/leave between steps with no recompile.
@@ -409,12 +414,14 @@ class LLMEngine:
         self._trash_page = self._n_pages  # extra page: writes by
         # released/padding slots land here and are never read valid
         n_flat = (self._n_pages + 1) * ps
+        from ...ops.attention import kv_cache_spec  # noqa: PLC0415
+        self._entry_cls, trailing, pool_dtype = kv_cache_spec(model)
         self._pools = [
-            (jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
-                       mcfg.dtype),
-             jnp.zeros((n_flat, mcfg.n_kv_heads, mcfg.head_dim),
-                       mcfg.dtype))
-            for _ in range(mcfg.n_layers)]
+            tuple(jnp.zeros((n_flat, *t), pool_dtype) for t in layer)
+            for layer in trailing]
+        self._kv_bytes_per_token = sum(
+            int(np.prod(t)) for layer in trailing for t in layer
+        ) * jnp.dtype(pool_dtype).itemsize
         self._page_table = jnp.full(
             (self._n_slots, self._pages_per_slot),
             self._trash_page, jnp.int32)
@@ -665,13 +672,14 @@ class LLMEngine:
         return toks, logps
 
     # ---- step programs over the page pool ---------------------------------
-    def _paged_entries(self, pools, page_table, lengths):
-        """Per-layer PagedKV cache entries over the shared pool. The
-        gather/scatter happens INSIDE each layer's attention, so only
-        one layer's contiguous view is ever live at a time."""
-        from ...ops.attention import PagedKV  # noqa: PLC0415
-        return [PagedKV(k, v, page_table, lengths, self.cfg.kv_page_size)
-                for (k, v) in pools]
+    def _paged_entries(self, pools, page_table, lengths, fresh=False):
+        """Per-layer paged cache entries (the model's cache spec: PagedKV
+        or PagedLatent) over the shared pool. The gather/scatter happens
+        INSIDE each layer's attention, so only one layer's contiguous
+        view is ever live at a time."""
+        return [self._entry_cls(*arrays, page_table, lengths,
+                                self.cfg.kv_page_size, fresh)
+                for arrays in pools]
 
     def _apply_counted(self, params, tokens, entries, positions, row_mask):
         """model.apply for a step program. A model that declares
@@ -706,13 +714,11 @@ class LLMEngine:
         g = tokens.shape[0]
         rows = page_table[slots]                   # (G, P)
         rows_p = rows[:, :-(-pad_len // ps)]       # pages covering pad
-        from ...ops.attention import PagedKV  # noqa: PLC0415
         # fresh=True: pure prefill — attention runs straight over the
         # prompt (flash-eligible on TPU), no page gather; KV still
         # scatters into the pages
-        entries = [PagedKV(k, v, rows_p, jnp.zeros((g,), jnp.int32),
-                           ps, fresh=True)
-                   for (k, v) in pools]
+        entries = self._paged_entries(
+            pools, rows_p, jnp.zeros((g,), jnp.int32), fresh=True)
         positions = jnp.broadcast_to(jnp.arange(pad_len)[None, :],
                                      (g, pad_len))
         real = positions < true_lens[:, None]      # not bucket padding
@@ -720,7 +726,7 @@ class LLMEngine:
             real &= (jnp.arange(g) < n_real)[:, None]
         logits, new_entries, counted = self._apply_counted(
             params, tokens, entries, positions, real)
-        new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
+        new_pools = [e.arrays for e in new_entries]
         lengths = lengths.at[slots].set(true_lens)
         last = logits[jnp.arange(g), true_lens - 1]
         toks, logps = self._sample_tokens(last, temps, top_ps, rng_key,
@@ -748,16 +754,14 @@ class LLMEngine:
         position."""
         jnp = self._jnp
         jax = self._jax
-        ps = self.cfg.kv_page_size
         row = jax.lax.dynamic_slice_in_dim(page_table, slot, 1, axis=0)
-        from ...ops.attention import PagedKV  # noqa: PLC0415
         l1 = jnp.reshape(start, (1,)).astype(jnp.int32)
-        entries = [PagedKV(k, v, row, l1, ps) for (k, v) in pools]
+        entries = self._paged_entries(pools, row, l1)
         positions = start + jnp.arange(chunk)[None, :]
         logits, new_entries = self.model.apply(
             {"params": params}, tokens, cache=entries,
             positions=positions)
-        new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
+        new_pools = [e.arrays for e in new_entries]
         lengths = lengths.at[slot].set(new_len)
         if not sample:
             return jnp.int32(0), jnp.float32(0), new_pools, lengths
@@ -791,7 +795,7 @@ class LLMEngine:
             params, last_tokens[:, None], entries, positions,
             active_mask[:, None])
         logits = logits[:, 0, :]
-        new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
+        new_pools = [e.arrays for e in new_entries]
         new_lengths = jnp.where(active_mask, new_entries[0].lengths,
                                 lengths)
         bias, new_counts = self._pen_bias(pen, last_tokens, active_mask)
@@ -831,21 +835,17 @@ class LLMEngine:
         return toks, logps, pools, lengths, last
 
     def _copy_page_impl(self, pools, src_page, dst_page):
-        """Copy one page's k/v rows in every layer — the only device
-        copy prefix adoption pays (its final PARTIAL page; full pages
-        are shared by page-table reference)."""
+        """Copy one page's rows of every pool array in every layer —
+        the only device copy prefix adoption pays (its final PARTIAL
+        page; full pages are shared by page-table reference)."""
         lax = self._jax.lax
         ps = self.cfg.kv_page_size
-        out = []
-        for (k, v) in pools:
-            rk = lax.dynamic_slice_in_dim(k, src_page * ps, ps, axis=0)
-            rv = lax.dynamic_slice_in_dim(v, src_page * ps, ps, axis=0)
-            k = lax.dynamic_update_slice_in_dim(k, rk, dst_page * ps,
-                                                axis=0)
-            v = lax.dynamic_update_slice_in_dim(v, rv, dst_page * ps,
-                                                axis=0)
-            out.append((k, v))
-        return out
+
+        def copy(a):
+            rows = lax.dynamic_slice_in_dim(a, src_page * ps, ps, axis=0)
+            return lax.dynamic_update_slice_in_dim(a, rows, dst_page * ps,
+                                                   axis=0)
+        return [tuple(copy(a) for a in arrays) for arrays in pools]
 
     def _verify_paged_impl(self, params, pools, page_table, lengths,
                            last_tokens, proposals, active_mask, temps,
@@ -870,7 +870,7 @@ class LLMEngine:
         logits, new_entries = self.model.apply(
             {"params": params}, toks_in, cache=entries,
             positions=positions)
-        new_pools = [(e.k_flat, e.v_flat) for e in new_entries]
+        new_pools = [e.arrays for e in new_entries]
         out, n_emit, logps, last = self._verify_accept(
             logits, proposals, last_tokens, active_mask, temps, top_ps,
             rng_key)
@@ -1320,6 +1320,9 @@ class LLMEngine:
                 "pinned_prefix": pinned,
                 "peak_in_use": self._page_hwm,
             }
+            # bytes a cached token takes over all layers (the pool's
+            # rows as they are stored)
+            out["kv_bytes_per_token"] = self._kv_bytes_per_token
             samples = list(self._ttft_samples)
             tpots = sorted(self._tpot_samples)
         if tpots:

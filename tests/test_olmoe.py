@@ -149,7 +149,7 @@ def test_all_rows_on_the_same_experts(path):
     if path == "dropless":
         got, stats = moe_dropless(x, weights, idx, wg, wu, wd)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-        assert stats.tolist() == [g * k, g, 0, g, 2]
+        assert stats.tolist() == [g * k, g, 0, g, 2, g * k]
     else:
         def fn(b):
             return jnp.einsum("ecf,efd->ecd", jax.nn.silu(

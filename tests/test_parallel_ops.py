@@ -191,7 +191,7 @@ class TestMoEDropless:
         _, skew = run(x, jnp.tile(jnp.asarray([[5., 4., 0., 0.]]),
                                   (32, 1)))
         assert run._cache_size() == 1
-        assert skew.tolist() == [64, 32, 0, 32, 2]
+        assert skew.tolist() == [64, 32, 0, 32, 2, 64]
         assert even[3] < 32 and even[4] == 4
 
 

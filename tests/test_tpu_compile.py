@@ -186,3 +186,56 @@ def test_dropless_expert_layer_compiles_for_v5e(chip, rows, monkeypatch):
             sds((n_flat, 16, 128), jnp.bfloat16),
             sds((rows, pages), jnp.int32),
             sds((rows,), jnp.int32)).compile())
+
+
+@pytest.mark.parametrize("pages", [16, 64], ids=["window16", "full"])
+def test_latent_decode_compiles_for_v5e(chip, pages):
+    """sarvam-105b's absorbed decode attention at the cell's shapes (128
+    slots and the scratch row, 64 heads over a 640-wide pool row = 512 +
+    64 padded to whole lanes, a pool of 262 144 tokens): the block
+    `choose_pages_per_block` picks compiles, and the kernel reads the
+    pool where it lies (335 MB a layer: no copy anywhere)."""
+    from ray_tpu.ops.pallas.latent_attention import latent_decode_attention
+    rows, h, w, ps = 129, 64, 640, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(q, flat, table, lengths):
+        return latent_decode_attention(q, flat, table, lengths, ps,
+                                       d_v=512, scale=0.135,
+                                       interpret=False)
+    compiled = jax.jit(decode).lower(
+        sds((rows, h, w), jnp.bfloat16),
+        sds((262144 + ps, w), jnp.bfloat16),
+        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [129, 4096], ids=["decode", "prefill"])
+def test_expert_share_layer_compiles_for_v5e(chip, rows, monkeypatch):
+    """An expert layer that holds 32 of 128 experts (4 096 x 2 048, 8 a
+    token, biased sigmoid routing): assignments to the 96 experts held
+    elsewhere go to no group, and the grouped matmuls are Mosaic kernels
+    at the rows of a decode step and of a prefill group."""
+    from ray_tpu.ops.moe import moe_dropless, route
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    held, routed, d, f, k = 32, 128, 4096, 2048, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def layer(x, logits, bias, wg, wu, wd, mask):
+        weights, idx = route(logits, k, "sigmoid_bias", True,
+                             select_bias=bias, scale=2.5)
+        return moe_dropless(x, weights, idx, wg, wu, wd, mask,
+                            first=32, count=held)
+
+    text = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((rows, routed), jnp.float32),
+        sds((routed,), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16), sds((rows,), bool)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
