@@ -37,6 +37,17 @@ paged KV, streaming) — re-designed TPU-first:
   serve replica) is woken by ONE call_soon_threadsafe per loop iteration
   that carries every request's items — a thread woken per token would
   take the interpreter lock from this loop at its next JAX call.
+* One enqueue and one fetch a dispatch: the state the step programs
+  share (lengths, last tokens, the sampling key) changes only INSIDE
+  them and is handed from one to the next, donated. What the host
+  decided since the last dispatch (page rows, length resets, the active
+  mask, temperatures, top-p, a prefill group's prompts) rides into the
+  next program, whichever kind it is, as ONE int32 vector of fixed
+  layout (`_pack`), uploaded by the call's own argument path. No eager
+  JAX call stands between two programs: each would hand the interpreter
+  lock to the consumers' threads and put a tiny program into the
+  device's queue. stats["runtime_calls"] counts every call the loop
+  makes into the runtime.
 * Spans and counters (observability/profiler.py:SpanTable, always on):
   the loop's phases are `engine.*` spans on the engine thread — self
   times in get_stats()["spans"], annotations in a profiler capture —
@@ -53,7 +64,7 @@ import queue as queue_mod
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -208,6 +219,25 @@ class _Request:
 
 
 _END = ("__end__", None)
+
+
+class _StepState(NamedTuple):
+    """What one step program hands to the next (device arrays, donated
+    with each call): every slot's cached length and last sampled token,
+    and the sampling key. Nothing else writes them."""
+    lengths: Any        # (n_slots,) int32
+    last_tokens: Any    # (n_slots,) int32
+    key: Any            # PRNG key; each program splits it once
+
+
+def _pack(*parts) -> np.ndarray:
+    """The host's arguments of one step program as one int32 vector (one
+    upload): int32 parts as they are, float32 parts as their bits.
+    LLMEngine._unpack takes it apart inside the program."""
+    flat = [np.asarray(p).reshape(-1) for p in parts]
+    assert all(a.dtype in (np.int32, np.float32) for a in flat), \
+        [a.dtype for a in flat]
+    return np.concatenate([a.view(np.int32) for a in flat])
 
 _engine_ids = itertools.count()
 _metrics_singletons = None
@@ -422,10 +452,20 @@ class LLMEngine:
         self._kv_bytes_per_token = sum(
             int(np.prod(t)) for layer in trailing for t in layer
         ) * jnp.dtype(pool_dtype).itemsize
-        self._page_table = jnp.full(
+        # the page table lives on the host: every program gets the rows
+        # it reads as they stand at its dispatch, which is the order the
+        # device runs them in
+        self._page_table = np.full(
             (self._n_slots, self._pages_per_slot),
-            self._trash_page, jnp.int32)
-        self._lengths = jnp.zeros((self._n_slots,), jnp.int32)
+            self._trash_page, np.int32)
+        self._state = _StepState(
+            jnp.zeros((self._n_slots,), jnp.int32),
+            jnp.zeros((self._n_slots,), jnp.int32),
+            jax.random.PRNGKey(0))
+        # slot -> the length its row restarts from (0, or an adopted
+        # prefix's): applied by the next program before it reads
+        # lengths, then forgotten
+        self._len_edits: Dict[int, int] = {}
         # host-side allocator
         self._free_pages: List[int] = list(range(self._n_pages))
         # slot -> (n_shared_prefix_pages, [all pages in table order])
@@ -433,17 +473,15 @@ class LLMEngine:
         self._prefix_pages: Dict[int, List[int]] = {}
         self._pending_head: Optional[_Request] = None
         self._page_hwm = 0      # peak pages in use (stats)
-        self._last_tokens = jnp.zeros((self._n_slots,), jnp.int32)
         self._free_slots = list(range(S))
         self._active: Dict[int, _Request] = {}
         self._waiting: "queue_mod.Queue[_Request]" = queue_mod.Queue()
         self._requests: Dict[str, _Request] = {}
         self._req_counter = itertools.count()
         self._lock = threading.Lock()
-        self._rng_key = jax.random.PRNGKey(0)
-        self._mask_dev = None
-        self._temps_dev = None
-        self._top_ps_dev = None
+        # (active mask, temperatures, top-p) over the slots, rebuilt
+        # when the active set changed
+        self._mask_temps = None
         self._guided_allow_buf = None
         self._guided_prev = None
         self._spec_idle = 0
@@ -453,7 +491,7 @@ class LLMEngine:
         self._pen_counts = None
         self._pen_static = None
         self._pen_seeded: Dict[int, str] = {}
-        self._pen_coef_dev = None
+        self._pen_coef = None
         self._pen_coef_dirty = True
         self._mask_dirty = True
         self._shutdown = threading.Event()
@@ -481,7 +519,12 @@ class LLMEngine:
                       # something and the items they carried (awaitable
                       # consumers), tokens put on a blocking queue
                       "deliver_batches": 0, "deliver_items": 0,
-                      "deliver_blocking_tokens": 0}
+                      "deliver_blocking_tokens": 0,
+                      # calls the loop made into the JAX runtime: step
+                      # programs, fetch starts, whatever else (_step,
+                      # _start_fetch, _runtime): 2 a dispatch when
+                      # nothing eager is on the path
+                      "runtime_calls": 0}
         # (sink, item) pairs for awaitable consumers since the last
         # _hand_over; appends and poplefts are atomic, so abort() and
         # the watchdog may put from their own threads
@@ -535,21 +578,23 @@ class LLMEngine:
         self._prefix_counter = itertools.count()
 
         self._prefilling: collections.deque = collections.deque()
+        # the step programs: (params, pools, state, ctl, ...) ->
+        # (fetch, logps, pools', state', ...); pools and state donated
         self._prefill_paged_jit = jax.jit(
-            self._prefill_paged_impl, static_argnames=("pad_len",),
-            donate_argnums=(1, 3))
+            self._prefill_paged_step, static_argnames=("pad_len",),
+            donate_argnums=(1, 2))
         self._chunk_paged_jit = jax.jit(
-            self._chunk_paged_impl,
-            static_argnames=("chunk", "sample"), donate_argnums=(1, 3))
+            self._chunk_paged_step,
+            static_argnames=("chunk", "sample"), donate_argnums=(1, 2))
         self._decode_paged_jit = jax.jit(
-            self._decode_paged_impl, donate_argnums=(1, 3),
+            self._decode_paged_step, donate_argnums=(1, 2),
             static_argnames=("window_pages",))
         self._verify_paged_jit = jax.jit(
-            self._verify_paged_impl, donate_argnums=(1, 3),
+            self._verify_paged_step, donate_argnums=(1, 2),
             static_argnames=("window_pages",))
         self._decode_block_paged_jit = (
-            jax.jit(self._decode_block_paged_impl,
-                    donate_argnums=(1, 3),
+            jax.jit(self._decode_block_paged_step,
+                    donate_argnums=(1, 2),
                     static_argnames=("window_pages",))
             if cfg.decode_block > 1 else None)
         # host mirror of each slot's device length: picks the
@@ -558,6 +603,8 @@ class LLMEngine:
         self._disp_len: Dict[int, int] = {}
         self._copy_page_jit = jax.jit(self._copy_page_impl,
                                       donate_argnums=(0,))
+        self._pen_seed_jit = jax.jit(self._pen_seed_impl,
+                                     donate_argnums=(0, 1))
         # register_prefix must mutate the pools on the engine loop
         # thread — its dispatches donate them, so a concurrent
         # public-API mutation would race a stale buffer. Commands queue
@@ -906,6 +953,134 @@ class LLMEngine:
         last = jnp.where(active_mask, last, last_tokens)
         return out, n_emit, logps, last
 
+    # ---- the step programs as the loop dispatches them --------------------
+    # Each takes the carried state and ONE int32 vector `ctl` from the
+    # host (_pack; its first n_slots words are always the length edits),
+    # does first what the host used to do with eager calls between two
+    # programs, runs its *_impl and returns (fetch, logps, pools',
+    # state', ...): `fetch` is the one vector the host reads back.
+    def _unpack(self, ctl, *spec):
+        """Inside a program: `ctl` cut into its parts; spec entries are
+        (shape, dtype) in _pack's order, float32 parts bitcast back."""
+        jnp, lax = self._jnp, self._jax.lax
+        out, at = [], 0
+        for shape, dtype in spec:
+            n = int(np.prod(shape))
+            part = ctl[at:at + n].reshape(shape)
+            at += n
+            if dtype == jnp.float32:
+                part = lax.bitcast_convert_type(part, jnp.float32)
+            out.append(part)
+        assert at == ctl.shape[0], (at, ctl.shape)
+        return out
+
+    def _begin_step(self, state, ctl, *spec):
+        """What every program does first: the lengths the host reset
+        since the last dispatch (ctl's first n_slots words, -1 = leave),
+        and the split the host used to make for each dispatch: the
+        chain of keys and subkeys is the chain of that eager split, in
+        the order of dispatches. Returns (lengths, last_tokens, key,
+        sub) and the rest of `ctl` cut by `spec`."""
+        jnp = self._jnp
+        edits, *parts = self._unpack(ctl, ((self._n_slots,), jnp.int32),
+                                     *spec)
+        lengths = jnp.where(edits >= 0, edits, state.lengths)
+        key, sub = self._jax.random.split(state.key)
+        return (lengths, state.last_tokens, key, sub), parts
+
+    def _begin_slots_step(self, state, ctl, window_pages: int):
+        """_begin_step of the programs over all slots (_decode_ctl)."""
+        jnp = self._jnp
+        S = self._n_slots
+        W = window_pages or self._pages_per_slot
+        carried, (mask, temps, top_ps, table) = self._begin_step(
+            state, ctl, ((S,), jnp.int32), ((S,), jnp.float32),
+            ((S,), jnp.float32), ((S, W), jnp.int32))
+        return (*carried, mask != 0, temps, top_ps, table)
+
+    def _prefill_paged_step(self, params, pools, state, ctl, pad_len: int,
+                            allow=None, bias=None):
+        """_prefill_paged_impl for a group of g rows (g from ctl's own
+        length); the first n_real are prompts, and their first tokens
+        go into last_tokens here. fetch: all g rows' tokens (the host
+        reads the first n_real), a counting model's counters behind."""
+        jnp = self._jnp
+        S, P = self._n_slots, self._pages_per_slot
+        g = (ctl.shape[0] - S - 1 - S * P) // (4 + pad_len)
+        (lengths, last_tokens, key, sub), (
+            n_real, slots, lens, temps, top_ps, table, tokens) = \
+            self._begin_step(
+                state, ctl, ((), jnp.int32), ((g,), jnp.int32),
+                ((g,), jnp.int32), ((g,), jnp.float32),
+                ((g,), jnp.float32), ((S, P), jnp.int32),
+                ((g, pad_len), jnp.int32))
+        toks, logps, pools, lengths, *counted = self._prefill_paged_impl(
+            params, pools, table, lengths, tokens, slots, lens, temps,
+            top_ps, sub, pad_len=pad_len, allow=allow, bias=bias,
+            n_real=n_real if self._counted else None)
+        # group padding (the scratch slot's rows) writes nowhere
+        real = jnp.where(jnp.arange(g) < n_real, slots, S)
+        last_tokens = last_tokens.at[real].set(toks, mode="drop")
+        return (counted[0] if counted else toks, logps, pools,
+                _StepState(lengths, last_tokens, key))
+
+    def _chunk_paged_step(self, params, pools, state, ctl, chunk: int,
+                          sample: bool, allow=None, bias=None):
+        """_chunk_paged_impl; the final chunk's token goes into
+        last_tokens here. fetch and logps are (1,)."""
+        jnp = self._jnp
+        S, P = self._n_slots, self._pages_per_slot
+        (lengths, last_tokens, key, sub), (
+            slot, start, new_len, temp, top_p, table, tokens) = \
+            self._begin_step(
+                state, ctl, ((), jnp.int32), ((), jnp.int32),
+                ((), jnp.int32), ((), jnp.float32), ((), jnp.float32),
+                ((S, P), jnp.int32), ((1, chunk), jnp.int32))
+        tok, logp, pools, lengths = self._chunk_paged_impl(
+            params, pools, table, lengths, tokens, slot, start, new_len,
+            temp, top_p, sub, chunk=chunk, sample=sample, allow=allow,
+            bias=bias)
+        if sample:
+            last_tokens = last_tokens.at[slot].set(tok)
+        return (tok[None], logp[None], pools,
+                _StepState(lengths, last_tokens, key))
+
+    def _decode_paged_step(self, params, pools, state, ctl,
+                           window_pages: int = 0, allow=None, pen=None):
+        """_decode_paged_impl over the window's columns of the table.
+        Returns (fetch, logps, pools', state'[, penalty counts'])."""
+        lengths, last_tokens, key, sub, mask, temps, top_ps, table = \
+            self._begin_slots_step(state, ctl, window_pages)
+        nxt, logps, pools, lengths, *rest = self._decode_paged_impl(
+            params, pools, table, lengths, last_tokens, mask, temps,
+            top_ps, sub, allow=allow, pen=pen)
+        fetch = rest.pop() if self._counted else nxt
+        return (fetch, logps, pools, _StepState(lengths, nxt, key), *rest)
+
+    def _decode_block_paged_step(self, params, pools, state, ctl,
+                                 window_pages: int = 0):
+        lengths, last_tokens, key, sub, mask, temps, top_ps, table = \
+            self._begin_slots_step(state, ctl, window_pages)
+        toks, logps, pools, lengths, last = self._decode_block_paged_impl(
+            params, pools, table, lengths, last_tokens, mask, temps,
+            top_ps, sub)
+        return toks, logps, pools, _StepState(lengths, last, key)
+
+    def _verify_paged_step(self, params, pools, state, ctl, proposals,
+                           window_pages: int = 0):
+        """Returns (out, logps, pools', state', n_emit)."""
+        lengths, last_tokens, key, sub, mask, temps, top_ps, table = \
+            self._begin_slots_step(state, ctl, window_pages)
+        out, n_emit, logps, pools, lengths, last = self._verify_paged_impl(
+            params, pools, table, lengths, last_tokens, proposals, mask,
+            temps, top_ps, sub)
+        return out, logps, pools, _StepState(lengths, last, key), n_emit
+
+    def _pen_seed_impl(self, counts, static_bias, slot, row):
+        """A penalized request took `slot`: its generated-token counts
+        restart at zero and `row` is its static logit bias."""
+        return (counts.at[slot].set(0), static_bias.at[slot].set(row))
+
     # ---- public API -------------------------------------------------------
     def register_prefix(self, prefix_ids) -> int:
         """Prefill a shared prompt prefix (e.g. a system prompt) once;
@@ -954,7 +1129,6 @@ class LLMEngine:
         """Prefill a prefix into freshly-allocated PINNED pages (loop
         thread only): the prefix lives in the pool; adopters share its
         full pages by reference."""
-        jnp = self._jnp
         ps = self.cfg.kv_page_size
         pages = self._alloc_pages(-(-prefix.size // ps))
         if pages is None:
@@ -965,14 +1139,11 @@ class LLMEngine:
         tokens[0, :prefix.size] = prefix
         self._set_page_row(scratch, pages)
         try:
-            self._rng_key, sub = self._jax.random.split(self._rng_key)
-            _t, _l, self._pools, self._lengths, *_c = self._prefill_paged_jit(
-                self.params, self._pools, self._page_table,
-                self._lengths, jnp.asarray(tokens),
-                jnp.asarray(np.asarray([scratch], np.int32)),
-                jnp.asarray(np.asarray([prefix.size], np.int32)),
-                jnp.zeros((1,), jnp.float32), jnp.ones((1,), jnp.float32),
-                sub, pad_len=pad)
+            self._step(self._prefill_paged_jit, (
+                np.int32(1), np.asarray([scratch], np.int32),
+                np.asarray([prefix.size], np.int32),
+                np.zeros((1,), np.float32), np.ones((1,), np.float32),
+                self._page_table, tokens), pad_len=pad)
         except BaseException:
             self._free_pages.extend(pages)
             raise
@@ -1485,7 +1656,6 @@ class LLMEngine:
         "nopages" (hold the request), or "failed" (stream errored).
         Prefix-carrying requests share the prefix's full pages by
         page-table reference and copy only its partial last page."""
-        jnp = self._jnp
         ps = self.cfg.kv_page_size
         need_total = self._pages_needed(req)
         # Unservable guard: pinned prefix pages never return to the
@@ -1513,9 +1683,10 @@ class LLMEngine:
             slot = self._take_slot(req)
             if plen % ps:
                 try:
-                    self._pools = self._copy_page_jit(
-                        self._pools, jnp.int32(prefix_pages[n_shared]),
-                        jnp.int32(excl[0]))
+                    self._pools = self._runtime(
+                        self._copy_page_jit, self._pools,
+                        np.int32(prefix_pages[n_shared]),
+                        np.int32(excl[0]))
                 except BaseException as e:  # noqa: BLE001
                     self._free_pages.extend(excl)
                     self._free_slots.append(slot)
@@ -1525,8 +1696,7 @@ class LLMEngine:
                     return "failed"
             all_pages = prefix_pages[:n_shared] + excl
             self._slot_pages[slot] = (n_shared, all_pages)
-            self._set_page_row(slot, all_pages)
-            self._lengths = self._lengths.at[slot].set(plen)
+            self._set_page_row(slot, all_pages, length=plen)
             self._disp_len[slot] = plen
             req.prefill_pos = plen
             self.stats["prefix_tokens_saved"] = (
@@ -1537,14 +1707,14 @@ class LLMEngine:
             return "nopages"
         slot = self._take_slot(req)
         self._slot_pages[slot] = (0, pages)
-        self._set_page_row(slot, pages)
-        # reset the slot's device length NOW: a reused slot's stale
-        # length would aim inactive decode-steps' garbage writes at an
-        # arbitrary position — under a narrowed decode window the
+        # the slot's device length restarts WITH its new row (the next
+        # program applies both before it reads either): a reused slot's
+        # stale length would aim inactive decode-steps' garbage writes
+        # at an arbitrary position — under a narrowed decode window the
         # clamped scatter could then corrupt the NEW occupant's pages.
         # With length 0, garbage always lands exactly where the next
         # prefill/chunk write goes (overwritten before any read).
-        self._lengths = self._lengths.at[slot].set(0)
+        self._set_page_row(slot, pages, length=0)
         self._disp_len[slot] = 0
         return "ok"
 
@@ -1619,12 +1789,10 @@ class LLMEngine:
         """One prefill call for `members` = [(req, slot), ...] of a
         shared bucket; group size pads to a power of two (scratch slot
         rows) so compile count stays O(buckets * log2(cap))."""
-        jnp = self._jnp
         g_real = len(members)
         g = _next_pow2(g_real)
         t_dispatch = time.time()
         try:
-            self._rng_key, sub = self._jax.random.split(self._rng_key)
             # padding rows hit the scratch slot, whose page row is
             # all-trash
             tokens = np.zeros((g, pad_len), np.int32)
@@ -1645,23 +1813,12 @@ class LLMEngine:
                 [r for r, _ in members], g)
             if pbias is not None:
                 kw["bias"] = pbias
-            if self._counted:
-                kw["n_real"] = np.int32(g_real)
-            toks_dev, lps_dev, self._pools, self._lengths, *counted = \
-                self._prefill_paged_jit(
-                    self.params, self._pools, self._page_table,
-                    self._lengths, jnp.asarray(tokens),
-                    jnp.asarray(slots), jnp.asarray(lens),
-                    jnp.asarray(temps), jnp.asarray(top_ps), sub,
-                    pad_len=pad_len, **kw)
-            toks_dev = toks_dev[:g_real]
-            lps_dev = lps_dev[:g_real]
-            real_slots = jnp.asarray(
-                np.asarray([s for _, s in members], np.int32))
-            self._last_tokens = self._last_tokens.at[real_slots].set(
-                toks_dev)
-            if self._counted:
-                toks_dev = counted[0]   # all rows, then the counters
+            # all g rows come back (then a counting model's counters);
+            # the drain reads the first g_real
+            toks_dev, lps_dev, _ = self._step(
+                self._prefill_paged_jit, (
+                    np.int32(g_real), slots, lens, temps, top_ps,
+                    self._page_table, tokens), pad_len=pad_len, **kw)
         except BaseException as e:  # noqa: BLE001
             for req, slot in members:
                 self._free_slot_pages(slot)
@@ -1705,7 +1862,6 @@ class LLMEngine:
     def _dispatch_chunk(self, inflight) -> None:
         """Advance the oldest chunk-prefilling request by ONE chunk. The
         final chunk samples the first token and activates the slot."""
-        jnp = self._jnp
         req = self._prefilling[0]
         if req.aborted:
             # cancelled mid-chunk-prefill: drop remaining chunks, free
@@ -1721,21 +1877,17 @@ class LLMEngine:
         tokens[0, :true] = req.prompt[start:start + true]
         t_dispatch = time.time()
         try:
-            self._rng_key, sub = self._jax.random.split(self._rng_key)
             kw = {}
             if is_last and req.fsm is not None:
                 kw["allow"] = self._guided_prefill_allow([req], 1)
             if is_last and req.logit_bias:
                 kw["bias"] = self._pen_prefill_bias([req], 1)
-            tok_dev, lp_dev, self._pools, self._lengths = \
-                self._chunk_paged_jit(
-                    self.params, self._pools, self._page_table,
-                    self._lengths, jnp.asarray(tokens),
-                    jnp.int32(req.slot), jnp.int32(start),
-                    jnp.int32(start + true),
-                    jnp.float32(req.temperature),
-                    jnp.float32(req.top_p), sub, chunk=C,
-                    sample=is_last, **kw)
+            toks_dev, lps_dev, _ = self._step(
+                self._chunk_paged_jit, (
+                    np.int32(req.slot), np.int32(start),
+                    np.int32(start + true), np.float32(req.temperature),
+                    np.float32(req.top_p), self._page_table, tokens),
+                chunk=C, sample=is_last, **kw)
         except BaseException as e:  # noqa: BLE001
             self._prefilling.popleft()
             self._free_slot_pages(req.slot)
@@ -1752,11 +1904,9 @@ class LLMEngine:
         if is_last:
             self._prefilling.popleft()
             self.stats["prefills"] += 1
-            self._last_tokens = self._last_tokens.at[req.slot].set(tok_dev)
             self._active[req.slot] = req
             self._mask_dirty = True
             self._pen_coef_dirty = True
-            toks_dev, lps_dev = tok_dev[None], lp_dev[None]
             self._start_fetch(toks_dev)
             if self.cfg.logprobs:
                 self._start_fetch(lps_dev)
@@ -1764,8 +1914,29 @@ class LLMEngine:
                              lps_dev if self.cfg.logprobs else None,
                              time.perf_counter_ns()))
 
-    @staticmethod
-    def _start_fetch(arr):
+    def _runtime(self, fn, *args):
+        """A call of the loop into the JAX runtime that is no step
+        program (the prefix page copy, a penalty row's seeding)."""
+        self.stats["runtime_calls"] += 1
+        return fn(*args)
+
+    def _step(self, program, parts, *args, **kw):
+        """Enqueue one step program on the carried state. `parts` are
+        its host arguments in its own _unpack order; the pending length
+        edits go in front and are forgotten once the program is queued.
+        Returns (fetch, logps, what else the program returns)."""
+        edits = np.full((self._n_slots,), -1, np.int32)
+        for slot, n in self._len_edits.items():
+            edits[slot] = n
+        self.stats["runtime_calls"] += 1
+        fetch, logps, self._pools, self._state, *rest = program(
+            self.params, self._pools, self._state, _pack(edits, *parts),
+            *args, **kw)
+        self._len_edits.clear()
+        return fetch, logps, rest
+
+    def _start_fetch(self, arr):
+        self.stats["runtime_calls"] += 1
         try:
             arr.copy_to_host_async()
         except (AttributeError, NotImplementedError):
@@ -1943,21 +2114,29 @@ class LLMEngine:
         self._page_hwm = max(self._page_hwm, in_use)
         return pages
 
-    def _set_page_row(self, slot: int, pages: "List[int]") -> None:
-        """Write a slot's page-table row (unused entries -> trash)."""
-        row = np.full((self._pages_per_slot,), self._trash_page, np.int32)
+    def _set_page_row(self, slot: int, pages: "List[int]",
+                      length: Optional[int] = None) -> None:
+        """Write a slot's page-table row (unused entries -> trash) and,
+        where given, the length its sequence restarts from. Both reach
+        the device with the next program dispatched, whichever kind,
+        before it reads either: programs queued earlier keep the rows
+        they were dispatched with and run first."""
+        row = self._page_table[slot]
+        row[:] = self._trash_page
         row[:len(pages)] = pages
-        self._page_table = self._page_table.at[slot].set(
-            self._jnp.asarray(row))
         if not pages:
             # a row that holds no page holds no key: the decode kernel
             # walks a row's length, so a stale one would cost its pages
-            self._lengths = self._lengths.at[slot].set(0)
+            length = 0
+        if length is not None:
+            self._len_edits[slot] = length
 
     def _free_slot_pages(self, slot: int) -> None:
         """Return the slot's exclusive pages to the pool (shared prefix
         pages stay pinned) and point its row at the trash page so lagged
-        decode writes can't corrupt a reused page."""
+        decode writes can't corrupt a reused page: whoever is given
+        these pages next is dispatched after this edit, and every
+        program dispatched from here on sees the trash row."""
         entry = self._slot_pages.pop(slot, None)
         self._disp_len.pop(slot, None)
         if entry is None:
@@ -1985,11 +2164,11 @@ class LLMEngine:
 
     def _release(self, req: _Request):
         # Slot bookkeeping FIRST, end marker LAST: putting _END wakes the
-        # consumer thread, and _set_page_row's jax dispatch below drops
-        # the GIL — publishing completion before the slot leaves _active
-        # let clients observe (and act on) a request that looked finished
-        # while still holding engine state (soak regression: a drained
-        # request lingering in _active with its slot already re-freed).
+        # consumer thread — publishing completion before the slot leaves
+        # _active let clients observe (and act on) a request that looked
+        # finished while still holding engine state (soak regression: a
+        # drained request lingering in _active with its slot already
+        # re-freed).
         # The finally guarantees the consumer ALWAYS unblocks, even if a
         # bookkeeping dispatch raises.
         try:
@@ -2069,15 +2248,16 @@ class LLMEngine:
         for i, r in enumerate(reqs):
             if r.fsm is not None:
                 A[i] = r.fsm.allowed(r.fsm_state)
-        return self._jnp.asarray(A)
+        return A
 
     def _guided_decode_allow(self):
         """(S, V) bool mask over all slots for one decode step; None
         when no active request is guided (the unguided decode call then
         stays byte-identical to the ungated build). The host buffer is
         kept across steps and only rows whose FSM state moved are
-        rewritten — per step the unavoidable cost is the H2D transfer,
-        not a fresh (S, V) allocation + full rebuild."""
+        rewritten — per step the cost is one copy (the program's own
+        upload may alias what it is given, so it never gets the buffer
+        that the next step rewrites), not a full rebuild."""
         guided = {slot: r for slot, r in self._active.items()
                   if r.fsm is not None}
         if not guided:
@@ -2103,10 +2283,10 @@ class LLMEngine:
                 buf[slot] = r.fsm.allowed(r.fsm_state)
                 prev[slot] = key
         self._guided_prev = prev
-        return self._jnp.asarray(buf)
+        return buf.copy()
 
     def _spec_plan(self):
-        """(proposals (S, K) int32 device array, host counts) for one
+        """Proposals (S, K) int32 for one
         speculative verify step, or None when no active slot proposes
         anything or any slot is too close to max_seq_len (the verify
         forward writes K+1 positions). Spec-eligible requests exist
@@ -2132,7 +2312,7 @@ class LLMEngine:
             self._spec_idle += 1
             return None
         self._spec_idle = 0
-        return self._jnp.asarray(props)
+        return props
 
     def _spec_sync_active(self) -> bool:
         """True when speculation wants synchronous stepping (any
@@ -2167,39 +2347,40 @@ class LLMEngine:
         return any(self._req_has_pen(r) for r in self._active.values())
 
     def _pen_args(self):
-        """(counts, static_bias, presence, freq) device tuple for one
-        decode step, or None when no active request uses penalties.
-        Seeds count/static rows exactly once per slot assignment (the
-        engine loop is the only mutator, and always holds the LATEST
-        counts array — prior ones were donated)."""
+        """(counts, static_bias, presence, freq) for one decode step
+        (the first two device state, the coefficients host rows), or
+        None when no active request uses penalties. Seeds count/static
+        rows exactly once per slot assignment, each by one small program
+        (the engine loop is the only mutator, and always holds the
+        LATEST arrays — prior ones were donated)."""
         if not self._pen_active():
             return None
-        jnp = self._jnp
         V = int(self.model.cfg.vocab_size)
         S = self._n_slots
         if self._pen_counts is None:
-            self._pen_counts = jnp.zeros((S, V), jnp.int32)
-            self._pen_static = jnp.zeros((S, V), jnp.float32)
+            jnp = self._jnp
+            self._pen_counts = self._runtime(jnp.zeros, (S, V), jnp.int32)
+            self._pen_static = self._runtime(jnp.zeros, (S, V),
+                                             jnp.float32)
         for slot, r in self._active.items():
             if self._pen_seeded.get(slot) == r.request_id:
                 continue
             self._pen_seeded[slot] = r.request_id
-            self._pen_counts = self._pen_counts.at[slot].set(0)
-            self._pen_static = self._pen_static.at[slot].set(
-                jnp.asarray(self._bias_row(r, V)))
+            self._pen_counts, self._pen_static = self._runtime(
+                self._pen_seed_jit, self._pen_counts, self._pen_static,
+                np.int32(slot), self._bias_row(r, V))
         for slot in [sl for sl in self._pen_seeded
                      if sl not in self._active]:
             del self._pen_seeded[slot]
-        if self._pen_coef_dirty or self._pen_coef_dev is None:
+        if self._pen_coef_dirty or self._pen_coef is None:
             pres = np.zeros((S,), np.float32)
             freq = np.zeros((S,), np.float32)
             for slot, r in self._active.items():
                 pres[slot] = r.presence_penalty
                 freq[slot] = r.frequency_penalty
-            self._pen_coef_dev = (jnp.asarray(pres), jnp.asarray(freq))
+            self._pen_coef = (pres, freq)
             self._pen_coef_dirty = False
-        pres_dev, freq_dev = self._pen_coef_dev
-        return (self._pen_counts, self._pen_static, pres_dev, freq_dev)
+        return (self._pen_counts, self._pen_static, *self._pen_coef)
 
     def _pen_prefill_bias(self, reqs, g: int):
         """(g, V) static logit_bias rows for a prefill group's first
@@ -2211,25 +2392,26 @@ class LLMEngine:
         B = np.zeros((g, V), np.float32)
         for i, r in enumerate(reqs):
             B[i] = self._bias_row(r, V)
-        return self._jnp.asarray(B)
+        return B
 
-    def _device_mask_temps(self):
-        """(active_mask, temps, top_ps) as device arrays, rebuilt only
-        when the active set changed — not every step."""
-        if self._mask_dirty or self._mask_dev is None:
+    def _decode_ctl(self, window: int) -> tuple:
+        """A decode or verify program's host arguments: (active mask,
+        temperatures, top-p) over the slots, rebuilt only when the
+        active set changed, and the window's columns of the page table
+        as they stand now."""
+        if self._mask_dirty or self._mask_temps is None:
             S = self._n_slots
-            mask = np.zeros((S,), bool)
+            mask = np.zeros((S,), np.int32)
             temps = np.zeros((S,), np.float32)
             top_ps = np.ones((S,), np.float32)
             for slot, req in self._active.items():
-                mask[slot] = True
+                mask[slot] = 1
                 temps[slot] = req.temperature
                 top_ps[slot] = req.top_p
-            self._mask_dev = self._jnp.asarray(mask)
-            self._temps_dev = self._jnp.asarray(temps)
-            self._top_ps_dev = self._jnp.asarray(top_ps)
+            self._mask_temps = (mask, temps, top_ps)
             self._mask_dirty = False
-        return self._mask_dev, self._temps_dev, self._top_ps_dev
+        return (*self._mask_temps,
+                self._page_table[:, :window or self._pages_per_slot])
 
     def _drain_verify(self, snapshot, out_dev, ne_lp):
         """Emit a speculative verify step's 1..K+1 tokens per slot.
@@ -2427,9 +2609,6 @@ class LLMEngine:
                     # guided traffic with results in flight waits for
                     # the drain below: the next mask depends on tokens
                     # the host hasn't seen yet
-                    mask, temps, top_ps = self._device_mask_temps()
-                    self._rng_key, sub = self._jax.random.split(
-                        self._rng_key)
                     props = (self._spec_plan()
                              if spec_sync and allow is None else None)
                     if props is not None:
@@ -2446,7 +2625,7 @@ class LLMEngine:
                       step=self._decode_dispatches, active=len(snapshot),
                       window_pages=window):
                 self._dispatch_decode(inflight, snapshot, props, allow,
-                                      pen, mask, temps, top_ps, sub, window)
+                                      pen, window)
         with span("engine.bookkeep"):
             m = self._m = _engine_metrics()
             m["active"].set(float(len(self._active)),
@@ -2487,16 +2666,13 @@ class LLMEngine:
         self._in_dispatch = False
 
     def _dispatch_decode(self, inflight, snapshot, props, allow, pen,
-                         mask, temps, top_ps, sub, window: int) -> None:
+                         window: int) -> None:
         """Enqueue one decode (or speculative verify) program over all
         slots, start its fetch and append it to `inflight`."""
+        ctl = self._decode_ctl(window)
         if props is not None:
-            out, n_emit, logps, self._pools, self._lengths, last = \
-                self._verify_paged_jit(
-                    self.params, self._pools, self._page_table,
-                    self._lengths, self._last_tokens, props, mask,
-                    temps, top_ps, sub, window_pages=window)
-            self._last_tokens = last
+            out, logps, (n_emit,) = self._step(
+                self._verify_paged_jit, ctl, props, window_pages=window)
             self._start_fetch(out)
             self._start_fetch(n_emit)
             if self.cfg.logprobs:
@@ -2510,37 +2686,23 @@ class LLMEngine:
             return
         if self._decode_block_paged_jit is not None \
                 and allow is None and pen is None:
-            toks, logps, self._pools, self._lengths, last = \
-                self._decode_block_paged_jit(
-                    self.params, self._pools, self._page_table,
-                    self._lengths, self._last_tokens, mask, temps,
-                    top_ps, sub, window_pages=window)
+            toks, logps, _ = self._step(
+                self._decode_block_paged_jit, ctl, window_pages=window)
             block = self.cfg.decode_block
         else:
             akw = {} if allow is None else {"allow": allow}
             if pen is not None:
                 akw["pen"] = pen
-            res = self._decode_paged_jit(
-                self.params, self._pools, self._page_table,
-                self._lengths, self._last_tokens, mask, temps,
-                top_ps, sub, window_pages=window, **akw)
-            if self._counted:
-                *res, fetch = res
+            toks, logps, rest = self._step(
+                self._decode_paged_jit, ctl, window_pages=window, **akw)
             if pen is not None:
-                (toks, logps, self._pools, self._lengths,
-                 self._pen_counts) = res
-            else:
-                toks, logps, self._pools, self._lengths = res
-            last = toks
-            if self._counted:
-                toks = fetch
+                self._pen_counts, = rest
             block = 1
         for slot in self._active:
             # KeyError here = an admission path forgot to seed
             # _disp_len; fail loudly — a silent 0 default would shrink
             # the window and corrupt KV untraceably
             self._disp_len[slot] += block
-        self._last_tokens = last
         self._start_fetch(toks)
         if self.cfg.logprobs:
             self._start_fetch(logps)

@@ -320,7 +320,7 @@ def _decode_text(name: str):
         s = 5
         return jax.jit(eng._decode_paged_impl,
                        static_argnames=("window_pages",)).lower(
-            params, eng._pools, eng._page_table, eng._lengths,
+            params, eng._pools, eng._page_table, eng._state.lengths,
             jnp.zeros((s,), jnp.int32), jnp.ones((s,), bool),
             jnp.zeros((s,), jnp.float32), jnp.ones((s,), jnp.float32),
             jax.random.PRNGKey(0), window_pages=2).as_text()

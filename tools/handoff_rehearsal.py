@@ -17,8 +17,11 @@ at each of its JAX calls:
              SSE chunks, stream_start / stream_next on one loop (all of
              the chip's path but the actor call's reply)
 
-Prints, per consumer, decode steps per second and the self time of each
-`engine.*` phase in ms per decode step (deltas of get_stats()["spans"]).
+Prints, per consumer, decode steps per second, the engine thread's calls
+into the JAX runtime per decode step (get_stats()["runtime_calls"]: two a
+dispatch when nothing eager stands between two programs) and the self
+time of each `engine.*` phase in ms per decode step (deltas of
+get_stats()["spans"]).
 These are CPU numbers of a toy: they rank host-loop designs and are
 never a device metric (PERF.md section 5).
 
@@ -191,6 +194,8 @@ def run(rep, name: str, seconds: float, ramp_s: float) -> dict:
     for k in ("deliver_batches", "deliver_items",
               "deliver_blocking_tokens"):
         row[k] = s1[k] - s0[k]
+    row["calls_per_step"] = (s1["runtime_calls"]
+                             - s0["runtime_calls"]) / max(1, steps)
     return row
 
 
@@ -206,7 +211,7 @@ def main() -> None:
     warm = run(rep, "poll", 12.0, 12.0)
     print(f"warm-up compiled {sum(eng.get_stats()['compiles'].values())} "
           f"programs ({warm['compiles']} in its second half)")
-    print(f"{'consumer':<10}{'steps/s':>9}{'occ':>6}"
+    print(f"{'consumer':<10}{'steps/s':>9}{'occ':>6}{'calls/step':>12}"
           + "".join(f"{p:>17}" for p in PHASES) + f"{'sum':>8}"
           + f"{'items/batch':>13}{'blocking':>10}{'compiles':>10}")
     for name in args.consumers.split(","):
@@ -214,6 +219,7 @@ def main() -> None:
         total = sum(r[p] for p in PHASES)
         per = r["deliver_items"] / max(1, r["deliver_batches"])
         print(f"{name:<10}{r['steps_per_s']:>9.1f}{r['occupancy']:>6.2f}"
+              f"{r['calls_per_step']:>12.2f}"
               + "".join(f"{r[p]:>17.2f}" for p in PHASES)
               + f"{total:>8.1f}{per:>13.1f}"
               + f"{r['deliver_blocking_tokens']:>10d}"
