@@ -7,6 +7,7 @@ from .llama import Llama, LlamaConfig
 from .gpt2 import GPT2, GPT2Config
 from .mixtral import Mixtral, MixtralConfig
 from .latent_moe import LatentMoE, LatentMoEConfig
+from .hybrid import Hybrid, HybridConfig
 from .vit import ViT, ViTConfig
 from .clip import CLIP, CLIPConfig, contrastive_loss
 from .mlp import MLP, MLPConfig, ResNetLite
@@ -24,6 +25,8 @@ _REGISTRY = {
     "mixtral-debug": lambda **kw: Mixtral(MixtralConfig.debug(**kw)),
     "sarvam-105b": lambda **kw: LatentMoE(LatentMoEConfig.sarvam_105b(**kw)),
     "latent-moe-debug": lambda **kw: LatentMoE(LatentMoEConfig.debug(**kw)),
+    "olmo-hybrid-7b": lambda **kw: Hybrid(HybridConfig.olmo_hybrid_7b(**kw)),
+    "hybrid-debug": lambda **kw: Hybrid(HybridConfig.debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),
     "clip-debug": lambda **kw: CLIP(CLIPConfig.debug(**kw)),
@@ -42,7 +45,8 @@ def register_model(name: str, builder) -> None:
 
 
 __all__ = ["Llama", "LlamaConfig", "GPT2", "GPT2Config", "Mixtral",
-           "MixtralConfig", "LatentMoE", "LatentMoEConfig", "ViT",
+           "MixtralConfig", "LatentMoE", "LatentMoEConfig", "Hybrid",
+           "HybridConfig", "ViT",
            "ViTConfig", "CLIP", "CLIPConfig",
            "contrastive_loss", "MLP", "MLPConfig", "ResNetLite",
            "get_model", "register_model"]

@@ -48,8 +48,8 @@ import jax.numpy as jnp
 
 from ..ops import (apply_rotary, rms_norm, swiglu, yarn_frequencies,
                    yarn_softmax_scale)
-from ..ops.attention import (PagedLatent, latent_cached_attention,
-                             uneven_head_attention)
+from ..ops.attention import (LayerCache, PagedLatent,
+                             latent_cached_attention, uneven_head_attention)
 from ..ops.moe import MOE_STATS, moe_dropless, route
 from .llama import _LMHead
 
@@ -328,8 +328,8 @@ class LatentMoE(nn.Module):
         return self.init(rng, jnp.zeros((batch, seq), jnp.int32))["params"]
 
     def paged_cache_spec(self):
-        """(entry class, per layer the trailing shapes of its pool
-        arrays, dtype): one latent row a token a layer
+        """One latent row a token a layer
         (ops/attention.py:kv_cache_spec)."""
         cfg = self.cfg
-        return PagedLatent, [((cfg.cache_width,),)] * cfg.n_layers, cfg.dtype
+        return [LayerCache(PagedLatent, ((cfg.cache_width,),),
+                           (cfg.dtype,))] * cfg.n_layers

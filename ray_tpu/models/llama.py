@@ -135,8 +135,10 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, s, cfg.n_heads, hd)
         k = k.reshape(b, s, cfg.n_kv_heads, hd)
         v = v.reshape(b, s, cfg.n_kv_heads, hd)
-        q = apply_rotary(q, cos, sin, positions)
-        k = apply_rotary(k, cos, sin, positions)
+        if cos is not None:     # None: a model without positions in
+            #                     its attention (models/hybrid.py)
+            q = apply_rotary(q, cos, sin, positions)
+            k = apply_rotary(k, cos, sin, positions)
 
         new_cache = None
         if cache is None:

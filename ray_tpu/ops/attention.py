@@ -11,7 +11,7 @@ dependency in its model code (e.g. rllib models and train examples).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -205,17 +205,100 @@ class PagedLatent:
         return cls(*children, *aux)
 
 
-def kv_cache_spec(model):
-    """What a model's per-layer paged cache is made of: (entry class,
-    per layer the trailing shapes of its pool arrays, dtype). A model
-    says so itself (`paged_cache_spec()`); every other decoder of the
-    zoo caches K and V of (n_kv_heads, head_dim) a token a layer."""
+@jax.tree_util.register_pytree_node_class
+class SlotState:
+    """Per-layer cache entry of a layer whose memory is a fixed-size
+    recurrent state a sequence (a linear-attention layer) and not
+    something a token: its pool arrays are indexed by SLOT,
+    `(n_slots, *trailing)`, and know nothing of pages.
+
+    arrays: the layer's pool arrays (for ops/gated_deltanet.py: the
+      float32 state and the convolution's last inputs).
+    slots: (B,) int32, the pool row of each sequence of the call, or
+      None where the call's rows ARE the pool's rows (a decode step
+      over every slot).
+    n_new: (B,) int32, how many of the call's S new positions of each
+      sequence are real: a prompt's true length inside its bucket, a
+      chunk's true tokens, 1 or 0 for a decoding or an idle row. The
+      layer freezes its state past them.
+    restart: (B,) bool or None, the sequences that begin with this
+      call (a prompt's first chunk): they start from the zero state,
+      whatever the slot's previous occupant left.
+    `fresh` is STATIC: every sequence begins with this call and nothing
+    is read (a whole prefill).
+    """
+
+    def __init__(self, *fields, fresh: bool = False):
+        *arrays, slots, n_new, restart = fields
+        self.arrays = tuple(arrays)
+        self.slots = slots
+        self.n_new = n_new
+        self.restart = restart
+        self.fresh = fresh
+
+    def read(self):
+        """The sequences' states as the call starts."""
+        if self.fresh:
+            return tuple(jnp.zeros((self.n_new.shape[0], *a.shape[1:]),
+                                   a.dtype) for a in self.arrays)
+        rows = self.arrays if self.slots is None else tuple(
+            a[self.slots] for a in self.arrays)
+        if self.restart is None:
+            return rows
+        return tuple(jnp.where(
+            self.restart.reshape(-1, *(1,) * (r.ndim - 1)), 0, r)
+            for r in rows)
+
+    def write(self, *new) -> "SlotState":
+        """The entry after the call: each sequence's arrays put back in
+        its slot (padding rows of a group all aim at the scratch slot;
+        which of them lands there does not matter)."""
+        if self.slots is None:
+            arrays = tuple(n.astype(a.dtype)
+                           for a, n in zip(self.arrays, new))
+        else:
+            arrays = tuple(a.at[self.slots].set(n.astype(a.dtype))
+                           for a, n in zip(self.arrays, new))
+        return SlotState(*arrays, self.slots, self.n_new, None)
+
+    def tree_flatten(self):
+        return ((*self.arrays, self.slots, self.n_new, self.restart),
+                (self.fresh,))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, fresh=aux[0])
+
+
+class LayerCache(NamedTuple):
+    """What ONE layer caches: its entry class, the trailing shapes and
+    dtypes of its pool arrays, and what indexes the pool: a token
+    (through the page table: `(n_flat, *shape)`) or, `by_slot`, a
+    sequence's slot (`(n_slots, *shape)`)."""
+    entry: type
+    shapes: tuple
+    dtypes: tuple
+    by_slot: bool = False
+
+
+def kv_cache_spec(model) -> "list[LayerCache]":
+    """What a model caches, one `LayerCache` a layer. A model says so
+    itself (`paged_cache_spec()`); every other decoder of the zoo caches
+    K and V of (n_kv_heads, head_dim) a token a layer. Three kinds so
+    far: `PagedKV` and `PagedLatent`, indexed by token through the page
+    table, and `SlotState`, a fixed-size recurrent state a sequence,
+    indexed by slot (models/hybrid.py's linear layers). The serving
+    engine builds its pools from this list; a model with a `by_slot`
+    layer is refused prefix caching and speculation there by name
+    (a prefix would be a state snapshot, a rejected proposal a
+    rollback), and `get_stats()` reports `state_bytes_per_slot`,
+    `decode_state_rows_window` and `decode_state_rows_live` for it."""
     own = getattr(model, "paged_cache_spec", None)
     if own is not None:
         return own()
     c = model.cfg
     kv = (c.n_kv_heads, c.head_dim)
-    return PagedKV, [(kv, kv)] * c.n_layers, c.dtype
+    return [LayerCache(PagedKV, (kv, kv), (c.dtype, c.dtype))] * c.n_layers
 
 
 def uneven_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -293,12 +376,22 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     n_pages_per_seq = page_table.shape[1]
     L = n_pages_per_seq * page_size
 
+    # a pool laid out for more KV heads than the layer has (a model
+    # whose head count fills no whole 8-row tile declares it rounded
+    # up, so that the decode kernel's view of the pool is a bitcast
+    # and not a copy of it a call): the extra heads are zeros at the end
+    hkv, extra = k.shape[2], k_flat.shape[1] - k.shape[2]
+    k_new, v_new = k, v
+    if extra:
+        pad = ((0, 0), (0, 0), (0, extra), (0, 0))
+        k_new, v_new = jnp.pad(k, pad), jnp.pad(v, pad)
+
     # scatter the new tokens' k/v into their flat pool rows
     flat_pos = cache.flat_rows(positions)                     # (B, S)
     k_flat = k_flat.at[flat_pos.reshape(-1)].set(
-        k.astype(k_flat.dtype).reshape(b * s, *k.shape[2:]))
+        k_new.astype(k_flat.dtype).reshape(b * s, *k_new.shape[2:]))
     v_flat = v_flat.at[flat_pos.reshape(-1)].set(
-        v.astype(v_flat.dtype).reshape(b * s, *v.shape[2:]))
+        v_new.astype(v_flat.dtype).reshape(b * s, *v_new.shape[2:]))
     new_lengths = jnp.maximum(lengths, positions[:, -1] + 1)
 
     if cache.fresh \
@@ -326,9 +419,16 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             impl == "pallas" or jax.default_backend() == "tpu"):
         from .pallas.paged_attention import (  # noqa: PLC0415
             paged_decode_attention)
+        q1 = q[:, 0]
+        if extra:
+            # query heads for the pool's zero heads: they attend zeros
+            # and are cut off again
+            q1 = jnp.pad(q1, ((0, 0), (0, extra * (hq // hkv)), (0, 0)))
         out = paged_decode_attention(
-            q[:, 0], k_flat, v_flat, page_table, new_lengths,
+            q1, k_flat, v_flat, page_table, new_lengths,
             page_size, qpos=positions[:, 0], scale=scale)
+        if extra:
+            out = out[:, :hq]
         return out[:, None], PagedKV(
             k_flat, v_flat, page_table, new_lengths, page_size)
 
@@ -338,6 +438,8 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   ).reshape(b, L)                             # (B, L)
     ck = k_flat[gather_idx]                                   # (B,L,Hkv,D)
     cv = v_flat[gather_idx]
+    if extra:
+        ck, cv = ck[:, :, :hkv], cv[:, :, :hkv]
     out = _attend_cached(q, ck, cv, positions, new_lengths, scale)
     return out, PagedKV(k_flat, v_flat, page_table, new_lengths,
                         page_size)

@@ -1,0 +1,234 @@
+"""Gated DeltaNet: a linear-attention layer with a fixed recurrent state
+(Yang, Kautz, Hatamizadeh, arXiv:2412.06464), in the two forms a serving
+engine needs, over the same projections.
+
+A head with key width d_k and value width d_v keeps a state S (d_v x
+d_k) and, for token t with a decay alpha_t in (0, 1] and a writing
+strength beta_t:
+
+    S_t = alpha_t S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+    o_t = S_t q_t
+
+q and k are L2-normalised over the head's d_k (q also times d_k^-1/2),
+both after a depthwise causal convolution of width K and a SiLU;
+alpha_t = exp(g_t), g_t = -exp(A_log) softplus(a_t + dt_bias);
+beta_t = sigmoid(b_t), times 2 where negative eigenvalues are allowed.
+
+The state is held TRANSPOSED, heads side by side: `(B, d_k, H * d_v)`
+float32. That is the layout of the serving engine's per-slot pool: its
+last two dimensions fill whole (8, 128) tiles at the published widths
+(96 x 5 760), where a (d_v, d_k) = (192, 96) tile a head would be padded
+by a third, and a decode step is elementwise work along it
+(ops/pallas/gdn_decode.py).
+
+  * `chunk_scan`: a whole sequence in chunks of `chunk` tokens, the
+    paper's WY form with the decay folded in: inside a chunk
+    everything is matrix products (the inverse of a unit lower
+    triangular matrix by block forward substitution), across chunks a
+    `lax.scan` carries the state. Prefill and the plain forward.
+  * `step`: one token, three passes over the state in plain XLA; the
+    fused kernel of ops/pallas/gdn_decode.py makes it one.
+  * `recurrent`: the recurrence itself, token by token in a `lax.scan`;
+    what the two forms are tested against.
+
+Padded positions are frozen by the caller: g = 0 (alpha = 1) and
+beta = 0 leave the state as it was, so the state after a padded row is
+the state at its true length (`freeze`). The convolution's tail, the
+last K - 1 inputs, is taken at the true length too (`causal_conv`).
+Products whose operands are float32 are asked for at the highest
+precision: on a TPU the default would round the state to bfloat16 at
+every use.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+F32 = jnp.float32
+
+
+def gates(a: jax.Array, b: jax.Array, a_log: jax.Array,
+          dt_bias: jax.Array, allow_neg_eigval: bool
+          ) -> Tuple[jax.Array, jax.Array]:
+    """(g, beta) float32 from the two per-head projections a, b
+    (..., H): g = log alpha <= 0, beta in (0, 1) or (0, 2)."""
+    g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
+        a.astype(F32) + dt_bias.astype(F32))
+    beta = jax.nn.sigmoid(b.astype(F32))
+    return g, 2.0 * beta if allow_neg_eigval else beta
+
+
+def freeze(g: jax.Array, beta: jax.Array, real: Optional[jax.Array]
+           ) -> Tuple[jax.Array, jax.Array]:
+    """Positions that are not `real` (..., broadcast over heads) leave
+    the state untouched: alpha = 1, beta = 0."""
+    if real is None:
+        return g, beta
+    real = real[..., None]
+    return jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+
+
+def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+
+
+def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
+                n_new: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal convolution then SiLU. u (B, S, C) new inputs,
+    w (K, C) with w[0] on the current token, tail (B, K - 1, C) the
+    inputs before them (None: zeros). Returns (SiLU(conv) (B, S, C) in
+    u's dtype, the new tail): the K - 1 inputs up to each row's true
+    length `n_new` (B,) (None: S), so a row with no real token keeps
+    its tail."""
+    b, s, c = u.shape
+    k = w.shape[0]
+    if tail is None:
+        tail = jnp.zeros((b, k - 1, c), u.dtype)
+    cat = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    wf = w.astype(F32)
+    out = sum(wf[j] * cat[:, k - 1 - j:k - 1 - j + s].astype(F32)
+              for j in range(k))
+    if n_new is None:
+        new_tail = cat[:, s:]
+    else:
+        idx = n_new[:, None] + jnp.arange(k - 1)[None, :]       # (B, K-1)
+        new_tail = jnp.take_along_axis(cat, idx[:, :, None], axis=1)
+    return jax.nn.silu(out).astype(u.dtype), new_tail
+
+
+def _heads_last(state: jax.Array, h: int) -> jax.Array:
+    """(B, d_k, H * d_v) -> (B, H, d_k, d_v)."""
+    b, dk, hv = state.shape
+    return state.reshape(b, dk, h, hv // h).transpose(0, 2, 1, 3)
+
+
+def _heads_flat(s4: jax.Array) -> jax.Array:
+    """(B, H, d_k, d_v) -> (B, d_k, H * d_v)."""
+    b, h, dk, dv = s4.shape
+    return s4.transpose(0, 2, 1, 3).reshape(b, dk, h * dv)
+
+
+def recurrent(q, k, v, g, beta, state=None):
+    """The recurrence, token by token. q, k (B, S, H, d_k) normalised,
+    v (B, S, H, d_v), g, beta (B, S, H), state (B, d_k, H * d_v) or
+    None (zeros). Returns (o (B, S, H, d_v) float32, final state)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    s0 = (jnp.zeros((b, h, dk, dv), F32) if state is None
+          else _heads_last(state.astype(F32), h))
+
+    def body(st, xs):
+        qt, kt, vt, gt, bt = xs                     # (B, H, .)
+        st = st * jnp.exp(gt)[..., None, None]
+        kv = jnp.einsum("bhkv,bhk->bhv", st, kt, precision=_HI)
+        st = st + kt[..., None] * (bt[..., None] * (vt - kv))[..., None, :]
+        return st, jnp.einsum("bhkv,bhk->bhv", st, qt, precision=_HI)
+
+    xs = tuple(jnp.moveaxis(x.astype(F32), 1, 0) for x in (q, k, v, g, beta))
+    final, o = jax.lax.scan(body, s0, xs)
+    return jnp.moveaxis(o, 0, 1), _heads_flat(final)
+
+
+def step(q, k, v, g, beta, state):
+    """One token in plain XLA. q, k (B, H, d_k), v (B, H, d_v), g, beta
+    (B, H), state (B, d_k, H * d_v) float32. Returns (o (B, H, d_v)
+    float32, new state)."""
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    s4 = state.reshape(b, dk, h, dv) * jnp.exp(g)[:, None, :, None]
+    kv = jnp.einsum("bkhv,bhk->bhv", s4, k, precision=_HI)
+    u = beta[..., None] * (v.astype(F32) - kv)
+    s4 = s4 + jnp.swapaxes(k, 1, 2)[..., None] * u[:, None]
+    o = jnp.einsum("bkhv,bhk->bhv", s4, q, precision=_HI)
+    return o, s4.reshape(b, dk, h * dv)
+
+
+def _unit_lower_inverse(strict: jax.Array) -> jax.Array:
+    """(I + N)^-1 for N strictly lower triangular (..., C, C), by block
+    forward substitution from the diagonal outward: with the inverses
+    X11, X22 of two neighbouring diagonal blocks of width b known, the
+    block under the diagonal of their 2b-wide block's inverse is
+    -X22 N21 X11; log2(C) rounds of two products. Every
+    intermediate is a block of the true inverse, which stays bounded
+    for the delta rule's N (beta k.k <= 2). The series sum_k (-N)^k is
+    the same matrix in exact arithmetic and useless in float32: keys
+    after a SiLU all point one way, N's entries are ~0.5 of one sign,
+    and its powers pass 1e30 before they cancel."""
+    c = strict.shape[-1]
+    n = 1 << max(c - 1, 0).bit_length()
+    a = jnp.pad(strict, [(0, 0)] * (strict.ndim - 2) + [(0, n - c)] * 2)
+    x = jnp.broadcast_to(jnp.eye(n, dtype=strict.dtype), a.shape)
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    b = 1
+    while b < n:
+        # x is block diagonal in blocks of b: x N21 x is nonzero only in
+        # the odd blocks' rows under the even blocks before them
+        block = jnp.arange(n) // b
+        under = (block[:, None] % 2 == 1) \
+            & (block[None, :] == block[:, None] - 1)
+        x = x - mm(mm(x, jnp.where(under, a, 0.0)), x)
+        b *= 2
+    return x[..., :c, :c]
+
+
+def chunk_scan(q, k, v, g, beta, state=None, chunk: int = 64):
+    """The same function as `recurrent`, chunk by chunk (same arguments
+    and results). With Gamma_t the decay from the chunk's start to t,
+    M[t, s] = (Gamma_t / Gamma_s) k_t . k_s for s < t and T = (I +
+    diag(beta) M)^-1:
+        W   = T (beta V) - T (beta Gamma K) S_0       the written rows
+        O   = (Gamma Q) S_0 + (Q K^T * Gamma_t / Gamma_s, s <= t) W
+        S_C = Gamma_C S_0 + (K Gamma_C / Gamma)^T W
+    Any length: the sequence is padded with frozen positions to a whole
+    number of chunks."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(chunk, s)
+    n = -(-s // c)
+    pad = n * c - s
+
+    def chunks(x):                      # (B, S, H, ...) -> (B, H, n, c, ...)
+        x = x.astype(F32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape(b, n, c, *x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    mm = functools.partial(jnp.einsum, precision=_HI)
+    gc = jnp.cumsum(g, axis=-1)                               # (B,H,n,c)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    # exp only where t >= s: above the diagonal the difference is >= 0
+    # and could overflow
+    ratio = jnp.where(lower, jnp.exp(jnp.where(
+        lower, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    kb = k * beta[..., None]
+    strict = jnp.tril(mm("bhntk,bhnsk->bhnts", kb, k) * ratio, -1)
+    t_inv = _unit_lower_inverse(strict)
+    v_w = mm("bhnts,bhnsv->bhntv", t_inv, v * beta[..., None])
+    k_w = mm("bhnts,bhnsk->bhntk", t_inv, kb * jnp.exp(gc)[..., None])
+    attn = mm("bhntk,bhnsk->bhnts", q, k) * ratio
+    q_in = q * jnp.exp(gc)[..., None]
+    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]
+    total = jnp.exp(gc[..., -1])                              # (B,H,n)
+    s0 = (jnp.zeros((b, h, dk, dv), F32) if state is None
+          else _heads_last(state.astype(F32), h))
+
+    def body(st, xs):
+        v_w, k_w, attn, q_in, k_out, total = xs
+        w = v_w - mm("bhtk,bhkv->bhtv", k_w, st)
+        o = mm("bhtk,bhkv->bhtv", q_in, st) + mm("bhts,bhsv->bhtv", attn, w)
+        st = st * total[..., None, None] + mm("bhtk,bhtv->bhkv", k_out, w)
+        return st, o
+
+    xs = tuple(jnp.moveaxis(x, 2, 0)
+               for x in (v_w, k_w, attn, q_in, k_out, total))
+    final, o = jax.lax.scan(body, s0, xs)                     # (n,B,H,c,dv)
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * c, dv)[:, :, :s]
+    return jnp.swapaxes(o, 1, 2), _heads_flat(final)

@@ -1,0 +1,127 @@
+"""Pallas TPU kernel: one decode step of the Gated DeltaNet recurrence
+over the serving engine's per-slot state pool (ops/gated_deltanet.py).
+
+    S <- alpha S (I - beta k k^T) + beta v k^T ,   o = S q
+
+In plain XLA that is three passes over S (S k, the rank-one update,
+S q): the state read three times and written once. Here one grid step
+takes one slot's whole state `(d_k, H * d_v)` float32 (S transposed,
+the heads side by side in the lanes: 96 x 5 760 = 2.2 MB at the
+published widths, every (8, 128) tile full) into VMEM, does the step
+and writes it back IN PLACE (`input_output_aliases`): read once,
+written once, the copies of the next slot running under the work on
+this one.
+
+Inside a step the heads are walked in groups whose columns are whole
+128-lane tiles (two heads of 192 = 384 columns). A head's k and q are
+columns of `(d_k, H)` inputs broadcast along the lanes, so with kx, qx
+the group's (d_k, columns) expansions and a, b, v its rows of decay,
+beta and value:
+
+    Sd = S * a ; u = b * (v - sum_k Sd * kx) ; S' = Sd + kx * u
+    o  = sum_k S' * qx
+
+All of it elementwise work and sublane sums in float32; no matrix unit.
+
+Rows that are not decoding (an empty slot, a slot between two chunks of
+its prompt, the scratch row) come with alpha = 1, beta = 0 and k = 0
+from the caller and are WRITTEN THROUGH UNCHANGED: the grid is every
+row of the pool, which is what the engine counts as
+`decode_state_rows_window` beside the `decode_state_rows_live` that
+moved. Its name on a trace's `XLA Ops` line is `gdn_decode_step`.
+Inference only: no backward pass.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# one slot's state in and out, double-buffered, at the published widths:
+# 4 x 2.2 MB, over the 16 MiB a kernel gets unasked
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def heads_per_group(n_heads: int, d_v: int) -> int:
+    """Heads walked together: the fewest whose columns are whole lane
+    tiles, or all of them where the heads do not divide so."""
+    n = 128 // math.gcd(d_v, 128)
+    return n if n_heads % n == 0 else n_heads
+
+
+def _kernel(qt_ref, kt_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
+            n_heads: int, d_v: int, group: int):
+    d_k = s_ref.shape[1]
+    width = group * d_v
+    lane = jax.lax.broadcasted_iota(jnp.int32, (d_k, width), 1)
+    qt, kt = qt_ref[0], kt_ref[0]                         # (d_k, H)
+
+    def expand(cols, h0):
+        """(d_k, width): head h0 + j's column over its d_v lanes."""
+        out = jnp.broadcast_to(cols[:, h0:h0 + 1], (d_k, width))
+        for j in range(1, group):
+            out = jnp.where(lane >= j * d_v, jnp.broadcast_to(
+                cols[:, h0 + j:h0 + j + 1], (d_k, width)), out)
+        return out
+
+    for gi in range(n_heads // group):
+        cols = slice(gi * width, (gi + 1) * width)
+        v = rows_ref[0, 0:1, cols]
+        a = rows_ref[0, 1:2, cols]
+        b = rows_ref[0, 2:3, cols]
+        kx = expand(kt, gi * group)
+        sd = s_ref[0, :, cols] * a
+        u = b * (v - jnp.sum(sd * kx, axis=0, keepdims=True))
+        new = sd + kx * u
+        s_out_ref[0, :, cols] = new
+        o_ref[0, :, cols] = jnp.sum(new * expand(qt, gi * group), axis=0,
+                                    keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_decode_step(q, k, v, g, beta, state, interpret=None):
+    """q, k (B, H, d_k) normalised, v (B, H, d_v), g = log alpha and
+    beta (B, H), state (B, d_k, H * d_v) float32, updated in place.
+    A row to leave alone comes with g = 0 and beta = 0. Returns
+    (o (B, H, d_v) float32, new state). interpret defaults to True only
+    on the CPU backend."""
+    b, h, d_k = q.shape
+    d_v = v.shape[-1]
+    hv = h * d_v
+    assert state.shape == (b, d_k, hv) and state.dtype == jnp.float32, \
+        (state.shape, state.dtype)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    f32 = jnp.float32
+    # a frozen row's k is zeroed too: 0 x a garbage k stays 0
+    k = jnp.where((beta != 0)[..., None], k.astype(f32), 0.0)
+    rows = jnp.stack([
+        v.astype(f32).reshape(b, hv),
+        jnp.repeat(jnp.exp(g.astype(f32)), d_v, axis=-1),
+        jnp.repeat(beta.astype(f32), d_v, axis=-1)], axis=1)   # (B, 3, HV)
+    kernel = functools.partial(_kernel, n_heads=h, d_v=d_v,
+                               group=heads_per_group(h, d_v))
+    row3 = lambda i: (i, 0, 0)                                # noqa: E731
+    o, new_state = pl.pallas_call(
+        kernel,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, d_k, h), row3),
+                  pl.BlockSpec((1, d_k, h), row3),
+                  pl.BlockSpec((1, 3, hv), row3),
+                  pl.BlockSpec((1, d_k, hv), row3)],
+        out_specs=[pl.BlockSpec((1, 1, hv), row3),
+                   pl.BlockSpec((1, d_k, hv), row3)],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, hv), f32),
+                   jax.ShapeDtypeStruct((b, d_k, hv), f32)],
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="gdn_decode_step",
+    )(jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k, 1, 2), rows, state)
+    return o.reshape(b, h, d_v), new_state

@@ -5,11 +5,19 @@ paged KV, streaming) — re-designed TPU-first:
 
 * Paged KV cache: one preallocated page pool per layer, flattened to
   (n_pages * page_size, *trailing) token rows, plus a
-  (slots, pages_per_slot) page table. A pool's arrays and their trailing
-  shapes come from the model's cache spec (ops/attention.py:
-  kv_cache_spec): K and V of (n_kv_heads, head_dim) for the Llama,
-  Mixtral and GPT-2 families, one latent row for a latent-attention
-  model. Pages, page table, allocator and windows do not know which.
+  (slots, pages_per_slot) page table. A pool's arrays, their trailing
+  shapes and dtypes come from the model's cache spec, one entry a LAYER
+  (ops/attention.py:kv_cache_spec): K and V of (n_kv_heads, head_dim)
+  for the Llama, Mixtral and GPT-2 families, one latent row for a
+  latent-attention model, both indexed by token through the page table;
+  and, for a linear-attention layer, a fixed recurrent state a SLOT,
+  (slots + 1, *trailing), that no page knows of (models/hybrid.py).
+  Pages, page table, allocator and windows cover the paged layers and
+  do not know which kind they hold. A model with slot state refuses
+  prefix caching and speculation by name (a prefix would be a state
+  snapshot, a rejected proposal a rollback), and get_stats() adds
+  state_bytes_per_slot, decode_state_rows_window and
+  decode_state_rows_live beside kv_bytes_per_token (paged layers only).
   A slot reserves the pages its prompt + budget need at admission.
   Static shapes, so the decode step compiles once per power-of-two
   page window.
@@ -445,13 +453,48 @@ class LLMEngine:
         # released/padding slots land here and are never read valid
         n_flat = (self._n_pages + 1) * ps
         from ...ops.attention import kv_cache_spec  # noqa: PLC0415
-        self._entry_cls, trailing, pool_dtype = kv_cache_spec(model)
+        # one LayerCache a layer: a pool indexed by token through the
+        # page table, (n_flat, *shape), or by slot, (n_slots, *shape):
+        # the scratch slot takes padding rows' writes there as the
+        # trash page does here
+        self._cache_spec = kv_cache_spec(model)
         self._pools = [
-            tuple(jnp.zeros((n_flat, *t), pool_dtype) for t in layer)
-            for layer in trailing]
-        self._kv_bytes_per_token = sum(
-            int(np.prod(t)) for layer in trailing for t in layer
-        ) * jnp.dtype(pool_dtype).itemsize
+            tuple(jnp.zeros((self._n_slots if c.by_slot else n_flat, *t),
+                            d) for t, d in zip(c.shapes, c.dtypes))
+            for c in self._cache_spec]
+
+        def row_bytes(by_slot):
+            return sum(int(np.prod(t)) * jnp.dtype(d).itemsize
+                       for c in self._cache_spec if c.by_slot == by_slot
+                       for t, d in zip(c.shapes, c.dtypes))
+        self._kv_bytes_per_token = row_bytes(False)
+        self._state_bytes_per_slot = row_bytes(True)
+        self._n_state_layers = sum(c.by_slot for c in self._cache_spec)
+        # the layer whose entry carries the sequences' lengths out of a
+        # decode step: the first that pages
+        paged = [i for i, c in enumerate(self._cache_spec) if not c.by_slot]
+        if not paged:
+            raise ValueError(
+                "every layer of this model keeps per-slot state and none "
+                "pages: the sequences' lengths ride on a paged layer's "
+                "entry: not supported")
+        self._len_layer = paged[0]
+        if self._n_state_layers:
+            # a prefix of a recurrent layer is a snapshot of its state,
+            # not pages to share, and a rejected proposal would need the
+            # state rolled back: neither exists (ROADMAP B9)
+            if cfg.max_prefixes > 0:
+                raise ValueError(
+                    "prefix caching (max_prefixes, register_prefix, "
+                    "cached_prefixes) copies pages; this model keeps "
+                    "per-slot recurrent state, whose prefix would be a "
+                    "state snapshot: not supported")
+            if cfg.ngram_speculation > 0:
+                raise ValueError(
+                    "n-gram speculation (ngram_speculation) verifies "
+                    "proposals in one forward and drops the rejected; "
+                    "this model keeps per-slot recurrent state, which "
+                    "cannot be rolled back: not supported")
         # the page table lives on the host: every program gets the rows
         # it reads as they stand at its dispatch, which is the order the
         # device runs them in
@@ -525,6 +568,13 @@ class LLMEngine:
                       # _start_fetch, _runtime): 2 a dispatch when
                       # nothing eager is on the path
                       "runtime_calls": 0}
+        if self._n_state_layers:
+            # per-slot state rows of decode dispatches, summed over the
+            # layers that keep one: rows the step read and wrote (every
+            # row of the pool: an idle row is written through
+            # unchanged) and rows that were decoding
+            self.stats["decode_state_rows_window"] = 0
+            self.stats["decode_state_rows_live"] = 0
         # (sink, item) pairs for awaitable consumers since the last
         # _hand_over; appends and poplefts are atomic, so abort() and
         # the watchdog may put from their own threads
@@ -719,14 +769,20 @@ class LLMEngine:
         return toks, logps
 
     # ---- step programs over the page pool ---------------------------------
-    def _paged_entries(self, pools, page_table, lengths, fresh=False):
-        """Per-layer paged cache entries (the model's cache spec: PagedKV
-        or PagedLatent) over the shared pool. The gather/scatter happens
-        INSIDE each layer's attention, so only one layer's contiguous
-        view is ever live at a time."""
-        return [self._entry_cls(*arrays, page_table, lengths,
-                                self.cfg.kv_page_size, fresh)
-                for arrays in pools]
+    def _paged_entries(self, pools, page_table, lengths, fresh=False,
+                       slots=None, n_new=None, restart=None):
+        """Per-layer cache entries over the shared pools, as the model's
+        cache spec has them: a paged entry (PagedKV, PagedLatent) over
+        the call's rows of the page table, or a SlotState over the
+        call's `slots` (None: every slot in order) with `n_new` real
+        new positions a row, `restart`ing the rows that begin there.
+        The gather/scatter happens INSIDE each layer, so only one
+        layer's contiguous view is ever live at a time."""
+        return [c.entry(*arrays, slots, n_new, restart, fresh=fresh)
+                if c.by_slot
+                else c.entry(*arrays, page_table, lengths,
+                             self.cfg.kv_page_size, fresh)
+                for c, arrays in zip(self._cache_spec, pools)]
 
     def _apply_counted(self, params, tokens, entries, positions, row_mask):
         """model.apply for a step program. A model that declares
@@ -765,7 +821,8 @@ class LLMEngine:
         # prompt (flash-eligible on TPU), no page gather; KV still
         # scatters into the pages
         entries = self._paged_entries(
-            pools, rows_p, jnp.zeros((g,), jnp.int32), fresh=True)
+            pools, rows_p, jnp.zeros((g,), jnp.int32), fresh=True,
+            slots=slots, n_new=true_lens)
         positions = jnp.broadcast_to(jnp.arange(pad_len)[None, :],
                                      (g, pad_len))
         real = positions < true_lens[:, None]      # not bucket padding
@@ -803,7 +860,10 @@ class LLMEngine:
         jax = self._jax
         row = jax.lax.dynamic_slice_in_dim(page_table, slot, 1, axis=0)
         l1 = jnp.reshape(start, (1,)).astype(jnp.int32)
-        entries = self._paged_entries(pools, row, l1)
+        entries = self._paged_entries(
+            pools, row, l1, slots=jnp.reshape(slot, (1,)),
+            n_new=jnp.reshape(new_len - start, (1,)),
+            restart=jnp.reshape(start == 0, (1,)))
         positions = start + jnp.arange(chunk)[None, :]
         logits, new_entries = self.model.apply(
             {"params": params}, tokens, cache=entries,
@@ -836,15 +896,18 @@ class LLMEngine:
         jnp = self._jnp
         if window_pages and window_pages < page_table.shape[1]:
             page_table = page_table[:, :window_pages]
-        entries = self._paged_entries(pools, page_table, lengths)
+        entries = self._paged_entries(
+            pools, page_table, lengths,
+            n_new=active_mask.astype(jnp.int32)
+            if self._n_state_layers else None)
         positions = lengths[:, None]
         logits, new_entries, counted = self._apply_counted(
             params, last_tokens[:, None], entries, positions,
             active_mask[:, None])
         logits = logits[:, 0, :]
         new_pools = [e.arrays for e in new_entries]
-        new_lengths = jnp.where(active_mask, new_entries[0].lengths,
-                                lengths)
+        new_lengths = jnp.where(
+            active_mask, new_entries[self._len_layer].lengths, lengths)
         bias, new_counts = self._pen_bias(pen, last_tokens, active_mask)
         nxt, logps = self._sample_tokens(logits, temps, top_ps, rng_key,
                                          allow=allow, bias=bias)
@@ -1087,6 +1150,11 @@ class LLMEngine:
         returns a prefix_id for submit(prefix_id=...). Requires
         cfg.max_prefixes > 0. Prefix ids are append-only: registering
         more than max_prefixes raises. Thread-safe."""
+        if self._n_state_layers:
+            raise ValueError(
+                "register_prefix copies pages; this model keeps per-slot "
+                "recurrent state, whose prefix would be a state "
+                "snapshot: not supported")
         if self.cfg.max_prefixes <= 0:
             raise ValueError("engine built with max_prefixes=0")
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
@@ -1494,6 +1562,9 @@ class LLMEngine:
             # bytes a cached token takes over all layers (the pool's
             # rows as they are stored)
             out["kv_bytes_per_token"] = self._kv_bytes_per_token
+            # bytes a sequence's recurrent state takes over the layers
+            # that keep one (0: every layer pages)
+            out["state_bytes_per_slot"] = self._state_bytes_per_slot
             samples = list(self._ttft_samples)
             tpots = sorted(self._tpot_samples)
         if tpots:
@@ -2219,6 +2290,11 @@ class LLMEngine:
             self._n_slots * (window or self._pages_per_slot)
         self.stats["decode_pages_live"] += sum(
             -(-(n + new) // ps) for n in self._disp_len.values())
+        if self._n_state_layers:
+            self.stats["decode_state_rows_window"] += \
+                self._n_slots * self._n_state_layers
+            self.stats["decode_state_rows_live"] += \
+                len(self._active) * self._n_state_layers
 
     def _propose_ngram(self, req) -> "Optional[List[int]]":
         """Prompt-lookup proposal: the K tokens that followed the most

@@ -310,10 +310,10 @@ def _decode_text(name: str):
     params = model.init_params(jax.random.PRNGKey(0))
     eng = _engine(model, params, prefill_buckets=(16, 32))
     try:
-        cls, trailing, _dtype = kv_cache_spec(model)
         c = model.cfg
-        assert cls is PagedKV
-        assert trailing == [((c.n_kv_heads, c.head_dim),) * 2] * c.n_layers
+        kv = (c.n_kv_heads, c.head_dim)
+        assert kv_cache_spec(model) == [
+            (PagedKV, (kv, kv), (c.dtype, c.dtype), False)] * c.n_layers
         assert all(len(layer) == 2 and layer[0].shape == layer[1].shape
                    == (33 * 8, c.n_kv_heads, c.head_dim)
                    for layer in eng._pools)
@@ -353,7 +353,7 @@ def test_get_model_builds_the_published_and_the_debug_shape():
             big.cache_width, big.experts_held) == (4096, 32, 64, 576, 640,
                                                    128)
     assert big.softmax_scale == pytest.approx(0.13523, abs=5e-6)
-    cls, trailing, _ = kv_cache_spec(get_model("latent-moe-debug"))
-    assert cls is PagedLatent and trailing == [((128,),)] * 3
+    assert kv_cache_spec(get_model("latent-moe-debug")) == [
+        (PagedLatent, ((128,),), (jnp.bfloat16,), False)] * 3
     with pytest.raises(ValueError, match="are not among"):
         LatentMoEConfig.debug(expert_first=6, expert_count=4)

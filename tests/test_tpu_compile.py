@@ -239,3 +239,50 @@ def test_expert_share_layer_compiles_for_v5e(chip, rows, monkeypatch):
         sds((held, f, d), jnp.bfloat16), sds((rows,), bool)
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+
+
+def test_gdn_decode_step_compiles_for_v5e(chip):
+    """Olmo-Hybrid-7B's decode step of the recurrence at the cell's
+    shapes (64 slots and the scratch row, 30 heads, keys of 96, values
+    of 192): one slot's float32 state (96 x 5 760) a grid step, updated
+    in place: the state is aliased, and the program holds no second
+    copy of the pool (144 MB a layer)."""
+    from ray_tpu.ops.pallas.gdn_decode import gdn_decode_step
+    rows, h, dk, dv = 65, 30, 96, 192
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(q, k, v, g, beta, state):
+        return gdn_decode_step(q, k, v, g, beta, state, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        sds((rows, h, dk)), sds((rows, h, dk)), sds((rows, h, dv)),
+        sds((rows, h)), sds((rows, h)),
+        sds((rows, dk, h * dv))).compile()
+    _assert_mosaic(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * dk * h * dv * 4
+    assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_paged_decode_over_a_pool_laid_out_for_32_heads_compiles(chip):
+    """Olmo-Hybrid-7B's full layers: 30 KV heads fill no whole 8-row
+    tile, so the pool is declared for 32 (models/hybrid.py:
+    kv_pool_heads) and the kernel's view of it is the pool: no copy of
+    its 672 MB a layer anywhere in the program."""
+    rows, ps, pages, heads = 65, 64, 64, 32
+    n_flat = 81920 + ps
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(q, k_flat, v_flat, page_table, lengths):
+        return paged_decode_attention(q, k_flat, v_flat, page_table,
+                                      lengths, ps, interpret=False)
+    compiled = jax.jit(decode).lower(
+        sds((rows, heads, 128), jnp.bfloat16),
+        sds((n_flat, heads, 128), jnp.bfloat16),
+        sds((n_flat, heads, 128), jnp.bfloat16),
+        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
