@@ -30,15 +30,22 @@ paged KV, streaming) — re-designed TPU-first:
   top-p) happens on-device inside the jitted step; only the sampled
   token ids (max_slots int32) cross to host per step. Per-request stop
   token ids terminate a stream like EOS.
-* Pipelined host loop: the loop runs `pipeline_depth` decode steps AHEAD
-  of the host-side token fetch, with device->host copies started
-  asynchronously (`copy_to_host_async`) at dispatch time. The device
-  never waits on the host between steps, and fetch latency overlaps
-  with compute. Prefills
-  dispatch back-to-back with no sync in between; the first token is
-  sampled on-device inside the prefill and drains through the same
-  pipeline. Termination decisions lag by `pipeline_depth` steps — at
-  most that many wasted (discarded) tokens per finished request.
+* Pipelined host loop: the loop dispatches step programs AHEAD of the
+  host-side token fetch (device->host copies start at dispatch time,
+  `copy_to_host_async`) and drains the oldest result once more than a
+  target number are in flight. The target is derived, not set
+  (`_InflightDepth`): enough programs that the device's queue never
+  runs empty while the host does its own work of an iteration,
+  1 + ceil(host time / device time a program) from what the loop
+  measures, at least 2, at most `pipeline_depth` (the ceiling); 0 when
+  the next step needs the last one's tokens on the host. Prefills go
+  into the same queue; a request's first token is sampled on-device
+  inside the prefill and comes out when the programs queued before it
+  have run, so every program of depth beyond what feeds the device is
+  one device step of waiting for each first token, and one discarded
+  row for each finished request (termination is decided at drain
+  time). stats["decode_inflight_target_sum"] / ["..._n"] is the mean
+  target over decode dispatches.
 * Hand-off to the consumers: a request's tokens, errors and end marker
   go to its sink. A consumer thread parks on a bounded queue
   (stream_detailed); a consumer on an event loop (astream_detailed, the
@@ -68,6 +75,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import itertools
+import math
 import queue as queue_mod
 import threading
 import time
@@ -99,14 +107,13 @@ class LLMEngineConfig:
     eos_token_id: Optional[int] = None
     max_new_tokens_default: int = 64
     top_k: int = 0                  # 0 = full softmax sampling
-    # Decode steps dispatched ahead of the host-side token fetch. The
-    # steady-state step period is roughly fetch_latency/(depth+1) (each
-    # iteration drains the entry dispatched `depth` steps ago), so depth
-    # trades termination lag (≤ depth*decode_block discarded tokens per
-    # finished request) against hiding device->host latency. Measured
-    # on a v5e (PERF.md, PR 24): at 10 a first token waits ~195 ms for
-    # its turn, 3-7 % of decode rows are discarded, and the fetch never
-    # blocks (the host loop is the slower side): ROADMAP A3.
+    # CEILING of the programs the loop keeps in flight ahead of the
+    # host-side token fetch; at most this many x decode_block discarded
+    # tokens per finished request. The depth in force is what the loop
+    # derives from its own measurements (_InflightDepth: 2-3 on a v5e
+    # in every serve cell, PERF.md section 6, PR 35). The 10 was sized
+    # for a slow device->host transport that is gone; held as a fixed
+    # depth it made every first token wait ten device steps.
     pipeline_depth: int = 10
     # Decode steps fused into ONE dispatch via lax.scan: each dispatch
     # emits decode_block tokens per slot, dividing per-token host work
@@ -246,6 +253,97 @@ def _pack(*parts) -> np.ndarray:
     assert all(a.dtype in (np.int32, np.float32) for a in flat), \
         [a.dtype for a in flat]
     return np.concatenate([a.view(np.int32) for a in flat])
+
+
+class _InflightDepth:
+    """How many dispatched programs the loop leaves in flight when it
+    stops draining: 1 + ceil(H / P), at least FLOOR, at most the
+    ceiling the configuration gives (`pipeline_depth`).
+
+    H is a cautious reading of the host's own time an iteration (the
+    loop's wall time less what it waited for the device): the largest
+    of the last `block` to 2 x `block` iterations, so that an
+    iteration that dispatched a prefill group or lost the interpreter
+    lock is covered. Each reading is first cut to CLIP x the usual one
+    (a moving mean, which learns the cut value), so that a stall of the
+    whole machine for seconds lifts the depth by a step or two for a
+    moment and is not learnt. P is the device's time a program: the
+    interval between the returns of two fetches that had to wait, over
+    the programs drained in it (a moving mean, cut the same way). While
+    no fetch waits the host is the slower side: depth feeds the device
+    no better, every result held back is lag, and the floor applies.
+    O(1) an iteration and no clock of its own: the loop hands in what
+    it measured (`fetched`, `iterated`, `idle`)."""
+    FLOOR = 2
+    CLIP = 4.0
+    WAITED_NS = 200_000     # a fetch that was ready returns in ~20 us
+
+    def __init__(self, block: int = 32):
+        self.block = block
+        self.host_ns = 0.0          # the usual host time an iteration
+        self.period_ns = 0.0        # the device's time a program
+        # this block's and the last block's: largest cut host time,
+        # fetches that waited
+        self._peak = [0.0, 0.0]
+        self._waits = [0, 0]
+        self._n = 0                 # iterations into this block
+        self._waited_at = 0         # return of the last fetch that waited
+        self._since = 0             # programs drained since then
+
+    def _mean(self, mean: float, x: float) -> tuple:
+        """(the moving mean after x cut to CLIP x the mean, x so cut)."""
+        if mean <= 0:
+            return x, x
+        x = min(x, self.CLIP * mean)
+        return mean + (x - mean) / 8, x
+
+    def fetched(self, wait_ns: int, now_ns: int) -> None:
+        """A drained program's fetch returned at `now_ns` after
+        `wait_ns` in np.asarray."""
+        self._since += 1
+        if wait_ns < self.WAITED_NS:
+            return
+        if self._waited_at:
+            self.period_ns, _ = self._mean(
+                self.period_ns, (now_ns - self._waited_at) / self._since)
+        self._waited_at, self._since = now_ns, 0
+        self._waits[0] += 1
+
+    def idle(self) -> None:
+        """Nothing is in flight: the device's queue ran empty, so the
+        next wait opens a new interval."""
+        self._waited_at = 0
+
+    def iterated(self, host_ns: int) -> None:
+        """One iteration with work in flight took `host_ns` of the
+        host's own time."""
+        self.host_ns, cut = self._mean(self.host_ns, float(host_ns))
+        if cut > self._peak[0]:
+            self._peak[0] = cut
+        self._n += 1
+        if self._n >= self.block:
+            self._n = 0
+            self._peak = [0.0, self._peak[0]]
+            self._waits = [0, self._waits[0]]
+
+    def target(self, ceiling: int, active: bool = True,
+               need_sync: bool = False) -> int:
+        if need_sync or not active:
+            # guided masks / n-gram proposals need the previous step's
+            # tokens on the host; nothing active: drain fully
+            return 0
+        depth = self.FLOOR
+        if self.period_ns > 0 and self._waits[0] + self._waits[1]:
+            depth = max(depth,
+                        1 + math.ceil(max(self._peak) / self.period_ns))
+        return min(depth, ceiling)
+
+    def readings(self, ceiling: int) -> Dict[str, float]:
+        return {"target": self.target(ceiling),
+                "host_peak_ms": max(self._peak) / 1e6,
+                "host_usual_ms": self.host_ns / 1e6,
+                "device_period_ms": self.period_ns / 1e6}
+
 
 _engine_ids = itertools.count()
 _metrics_singletons = None
@@ -546,7 +644,7 @@ class LLMEngine:
                       # decode rows: steps x max_slots of them ran; a
                       # row's token is emitted, or discarded (its slot
                       # was released, reused or over budget: the lag of
-                      # pipeline_depth), or the slot was empty
+                      # the programs in flight), or the slot was empty
                       "decode_slot_steps": 0, "decode_tokens_emitted": 0,
                       "decode_tokens_discarded": 0,
                       # the decode kernel's pages, summed over decode
@@ -567,7 +665,11 @@ class LLMEngine:
                       # programs, fetch starts, whatever else (_step,
                       # _start_fetch, _runtime): 2 a dispatch when
                       # nothing eager is on the path
-                      "runtime_calls": 0}
+                      "runtime_calls": 0,
+                      # the in-flight target in force at each decode
+                      # dispatch (_InflightDepth), summed and counted
+                      "decode_inflight_target_sum": 0,
+                      "decode_inflight_target_n": 0}
         if self._n_state_layers:
             # per-slot state rows of decode dispatches, summed over the
             # layers that keep one: rows the step read and wrote (every
@@ -589,6 +691,8 @@ class LLMEngine:
         self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
         self._slot_freed_ns: Dict[int, int] = {}    # slot -> _release
         self._decode_dispatches = 0     # the `step` of a decode's span
+        self._depth = _InflightDepth()
+        self._waited_ns = 0     # in fetches, this iteration of the loop
         # TTFT breakdown (VERDICT r4 ask): queue wait vs prefill
         # dispatch (compile on a bucket's first use) vs emit lag.
         self._ttft_samples: collections.deque = collections.deque(
@@ -1565,6 +1669,9 @@ class LLMEngine:
             # bytes a sequence's recurrent state takes over the layers
             # that keep one (0: every layer pages)
             out["state_bytes_per_slot"] = self._state_bytes_per_slot
+            # what the in-flight target is derived from, as it reads now
+            out["inflight_depth"] = self._depth.readings(
+                self.cfg.pipeline_depth)
             samples = list(self._ttft_samples)
             tpots = sorted(self._tpot_samples)
         if tpots:
@@ -2497,9 +2604,11 @@ class LLMEngine:
         ne_dev, lp_dev = ne_lp
         try:
             with self._spans.span("engine.drain_wait"):
+                t0 = time.perf_counter_ns()
                 out = np.asarray(out_dev)
                 n_emit = np.asarray(ne_dev)
                 lps = np.asarray(lp_dev) if lp_dev is not None else None
+                self._fetched(t0)
         except BaseException as e:  # noqa: BLE001
             for slot, req in snapshot:
                 if req.slot == slot:
@@ -2538,10 +2647,18 @@ class LLMEngine:
             if req.generated >= req.max_new_tokens or full:
                 self._release(req)
 
+    def _fetched(self, t0: int) -> None:
+        """A drained program's results are on the host; the fetch began
+        at `t0`. What it waited is the device's side of this iteration."""
+        now = time.perf_counter_ns()
+        self._waited_ns += now - t0
+        self._depth.fetched(now - t0, now)
+
     def _drain_one(self, inflight):
         """Fetch the oldest in-flight result and emit its tokens.
-        Termination/EOS checks happen here, `pipeline_depth` steps behind
-        dispatch; lagged tokens for finished/reused slots are discarded
+        Termination/EOS checks happen here, as many steps behind
+        dispatch as are in flight (_InflightDepth); lagged tokens for
+        finished/reused slots are discarded
         by the (req.slot == slot, generated < budget) guards; each is
         counted in stats["decode_tokens_discarded"]. Runs inside the
         loop's `engine.emit` span: only the fetch that blocks on the
@@ -2553,8 +2670,10 @@ class LLMEngine:
         spans, st = self._spans, self.stats
         try:
             with spans.span("engine.drain_wait"):
+                t0 = time.perf_counter_ns()
                 host = np.asarray(arr)
                 lps = np.asarray(lp_arr) if lp_arr is not None else None
+                self._fetched(t0)
         except BaseException as e:  # noqa: BLE001  device-side failure
             targets = (list(payload) if kind != "decode"
                        else [r for _, r in payload])
@@ -2644,6 +2763,7 @@ class LLMEngine:
         """One iteration of the engine loop, each phase in its span (the
         caller holds `engine.loop`; self times, so the phases sum to it)."""
         span = self._spans.span
+        began, self._waited_ns = time.perf_counter_ns(), 0
         with span("engine.control"):
             while True:
                 # control commands (prefix registration) run HERE so
@@ -2717,15 +2837,17 @@ class LLMEngine:
         if not inflight:
             self._in_dispatch = False
             self._hand_over()   # what admission errored, shed or ended
+            self._depth.idle()
             with span("engine.idle_sleep"):
                 time.sleep(0.002)
             return
-        # stay `pipeline_depth` steps ahead while decoding;
-        # drain fully once nothing is active
-        target = self.cfg.pipeline_depth if self._active else 0
-        if need_sync:
-            target = 0  # guided masks / n-gram proposals need
-            #             the previous step's tokens on host
+        # stay as far ahead as keeps the device fed (_InflightDepth),
+        # at most `pipeline_depth` programs
+        target = self._depth.target(self.cfg.pipeline_depth,
+                                    bool(self._active), need_sync)
+        if ready:
+            self.stats["decode_inflight_target_sum"] += target
+            self.stats["decode_inflight_target_n"] += 1
         emitted = self.stats["tokens_generated"]
         with span("engine.emit"):
             try:
@@ -2740,6 +2862,8 @@ class LLMEngine:
         # put, whichever phase put it
         self._hand_over()
         self._in_dispatch = False
+        self._depth.iterated(
+            time.perf_counter_ns() - began - self._waited_ns)
 
     def _dispatch_decode(self, inflight, snapshot, props, allow, pen,
                          window: int) -> None:

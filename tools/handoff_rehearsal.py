@@ -1,7 +1,8 @@
 """Size a change to the engine's host loop on a CPU before any chip time.
 
 A toy Llama (d_model 32, 1 layer, vocabulary 512) behind the serving
-cells' engine shape (64 slots, 64-token pages, pipeline_depth 10), 64
+cells' engine shape (64 slots, 64-token pages, pipeline_depth 10 as the
+ceiling of what the loop keeps in flight), 64
 streams kept open, and five ways of consuming them. The engine does the
 same work in every row; what differs is how many threads a token wakes,
 and with it how long the engine thread waits for the interpreter lock
@@ -19,7 +20,8 @@ at each of its JAX calls:
 
 Prints, per consumer, decode steps per second, the engine thread's calls
 into the JAX runtime per decode step (get_stats()["runtime_calls"]: two a
-dispatch when nothing eager stands between two programs) and the self
+dispatch when nothing eager stands between two programs), the mean
+number of programs the loop left in flight (`depth`) and the self
 time of each `engine.*` phase in ms per decode step (deltas of
 get_stats()["spans"]).
 These are CPU numbers of a toy: they rank host-loop designs and are
@@ -196,6 +198,10 @@ def run(rep, name: str, seconds: float, ramp_s: float) -> dict:
         row[k] = s1[k] - s0[k]
     row["calls_per_step"] = (s1["runtime_calls"]
                              - s0["runtime_calls"]) / max(1, steps)
+    row["depth"] = ((s1["decode_inflight_target_sum"]
+                     - s0["decode_inflight_target_sum"])
+                    / max(1, s1["decode_inflight_target_n"]
+                          - s0["decode_inflight_target_n"]))
     return row
 
 
@@ -212,14 +218,15 @@ def main() -> None:
     print(f"warm-up compiled {sum(eng.get_stats()['compiles'].values())} "
           f"programs ({warm['compiles']} in its second half)")
     print(f"{'consumer':<10}{'steps/s':>9}{'occ':>6}{'calls/step':>12}"
-          + "".join(f"{p:>17}" for p in PHASES) + f"{'sum':>8}"
+          f"{'depth':>7}" + "".join(f"{p:>17}" for p in PHASES)
+          + f"{'sum':>8}"
           + f"{'items/batch':>13}{'blocking':>10}{'compiles':>10}")
     for name in args.consumers.split(","):
         r = run(rep, name, args.seconds, args.ramp)
         total = sum(r[p] for p in PHASES)
         per = r["deliver_items"] / max(1, r["deliver_batches"])
         print(f"{name:<10}{r['steps_per_s']:>9.1f}{r['occupancy']:>6.2f}"
-              f"{r['calls_per_step']:>12.2f}"
+              f"{r['calls_per_step']:>12.2f}{r['depth']:>7.2f}"
               + "".join(f"{r[p]:>17.2f}" for p in PHASES)
               + f"{total:>8.1f}{per:>13.1f}"
               + f"{r['deliver_blocking_tokens']:>10d}"
