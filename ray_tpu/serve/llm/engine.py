@@ -66,9 +66,17 @@ paged KV, streaming) — re-designed TPU-first:
 * Spans and counters (observability/profiler.py:SpanTable, always on):
   the loop's phases are `engine.*` spans on the engine thread — self
   times in get_stats()["spans"], annotations in a profiler capture —
-  and each request's stamps (`request.*`, `slot.refill`,
+  and each request's stamps (`request.*`, `slot.refill*`,
   `stream.deliver`) are table rows with no annotation, so that a
   per-stream event never takes a device idle gap from the loop's.
+  Every row has its CPU time beside its wall time: the difference is
+  how long the thread stood still in it (the device in
+  `engine.drain_wait`, the sleep in `engine.idle_sleep`, elsewhere the
+  interpreter lock or a block inside the runtime). The `runtime.*` rows
+  time the loop's runtime calls one by one, `step.release` the
+  destruction of the donated leaves after a step, `gc.pause*` the
+  collector, and get_stats()["threads"] reads the CPU clocks of the two
+  threads that share the lock: this one and the consumers' event loop.
 """
 from __future__ import annotations
 
@@ -84,7 +92,7 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
-from ...observability.profiler import SpanTable
+from ...observability.profiler import SpanTable, thread_clocks
 from ...util import knobs
 
 # every phase of _engine_loop; seeded in the table so that a reader of
@@ -96,7 +104,16 @@ _LOOP_SPANS = ("engine.loop", "engine.control", "engine.admit",
                "engine.bookkeep", "engine.idle_sleep")
 _REQUEST_SPANS = ("request.ingress", "request.inflight_prefill",
                   "request.inflight_decode", "slot.refill",
-                  "stream.deliver")
+                  "slot.refill.starved", "stream.deliver")
+# the loop's calls into the JAX runtime, timed one by one where
+# stats["runtime_calls"] counts them (_step, _start_fetch, _runtime):
+# rows added around the call, inside whichever phase makes it
+_RUNTIME_SPANS = ("runtime.step", "runtime.fetch_start", "runtime.other")
+# what no call shows: after a step call the carried pools and state are
+# rebound to its results, and the last references to the leaves that
+# were donated into it go. The runtime destroys each leaf then: most of
+# a dispatch phase's time on the chip (PERF.md section 6, PR 37)
+_STEP_SPANS = ("step.release",)
 
 
 @dataclass
@@ -206,6 +223,8 @@ class _Request:
     prefix_id: int = -1             # registered-prefix KV to adopt
     prefill_pos: int = 0            # next prompt index (chunked prefill)
     submit_ts: float = field(default_factory=time.time)
+    # the same moment on the clock `slot.refill` is taken on
+    submit_ns: int = field(default_factory=time.perf_counter_ns)
     admit_ts: Optional[float] = None       # slot assigned
     prefill_dispatch_ms: float = 0.0       # host time in the prefill
                                            # call (compile on first use)
@@ -688,7 +707,12 @@ class LLMEngine:
         self._counted = bool(self._step_stats)
         for name in self._step_stats:
             self.stats[name] = 0
-        self._spans = SpanTable(_LOOP_SPANS + _REQUEST_SPANS)
+        self._spans = SpanTable(
+            _LOOP_SPANS + _REQUEST_SPANS + _RUNTIME_SPANS + _STEP_SPANS)
+        self._spans.watch_gc()      # until shutdown()
+        # the threads astream_detailed was called on (the replica's
+        # actor loop): get_stats()["threads"]["consumers"]
+        self._consumer_threads: set = set()
         self._slot_freed_ns: Dict[int, int] = {}    # slot -> _release
         self._decode_dispatches = 0     # the `step` of a decode's span
         self._depth = _InflightDepth()
@@ -1507,6 +1531,11 @@ class LLMEngine:
             raise KeyError(request_id)
         sink = _LoopSink(asyncio.get_running_loop(), request_id,
                          self._outbox)
+        me = threading.current_thread()     # the loop's, as we run on it
+        if me not in self._consumer_threads:
+            # one that has ended has taken its clock with it
+            self._consumer_threads = {
+                t for t in self._consumer_threads if t.is_alive()} | {me}
         with req.sink_lock:
             old = req.sink
             if old.loop is not None:
@@ -1689,6 +1718,8 @@ class LLMEngine:
                 k: v for k, v in medians.items() if v is not None}
         out["prefill_compile_ms"] = dict(self._prefill_compile_ms)
         out["spans"] = self._spans.snapshot()
+        out["threads"] = thread_clocks(engine=[self._loop_thread],
+                                       consumers=self._consumer_threads)
         compiles = self._spans.compiles()
         out["compiles"] = {k: v[0] for k, v in compiles.items()}
         out["compile_ns"] = {k: v[1] for k, v in compiles.items()}
@@ -1699,6 +1730,7 @@ class LLMEngine:
 
     def shutdown(self):
         self._shutdown.set()
+        self._spans.unwatch_gc()
 
     # ---- wedged-engine watchdog -------------------------------------------
     @property
@@ -1820,13 +1852,19 @@ class LLMEngine:
 
     def _take_slot(self, req: _Request) -> int:
         """Admission into a free slot; `slot.refill` is how long the
-        slot stood empty since its last _release."""
+        slot stood empty since its last _release, `slot.refill.starved`
+        the part of that before this request was submitted (0: it was
+        waiting here and the admission pass was late; all of it: the
+        engine had nothing to put into the slot)."""
         slot = self._free_slots.pop()
         req.slot = slot
         req.admit_ts = time.time()
         freed = self._slot_freed_ns.pop(slot, None)
         if freed is not None:
-            self._spans.add("slot.refill", time.perf_counter_ns() - freed)
+            now = time.perf_counter_ns()
+            self._spans.add("slot.refill", now - freed)
+            self._spans.add("slot.refill.starved",
+                            max(0, min(req.submit_ns, now) - freed))
         return slot
 
     def _admit_paged(self, req: _Request) -> str:
@@ -2096,7 +2134,7 @@ class LLMEngine:
         """A call of the loop into the JAX runtime that is no step
         program (the prefix page copy, a penalty row's seeding)."""
         self.stats["runtime_calls"] += 1
-        return fn(*args)
+        return self._spans.call("runtime.other", fn, *args)
 
     def _step(self, program, parts, *args, **kw):
         """Enqueue one step program on the carried state. `parts` are
@@ -2106,17 +2144,25 @@ class LLMEngine:
         edits = np.full((self._n_slots,), -1, np.int32)
         for slot, n in self._len_edits.items():
             edits[slot] = n
+        ctl = _pack(edits, *parts)
         self.stats["runtime_calls"] += 1
-        fetch, logps, self._pools, self._state, *rest = program(
-            self.params, self._pools, self._state, _pack(edits, *parts),
-            *args, **kw)
+        fetch, logps, pools, state, *rest = self._spans.call(
+            "runtime.step", program, self.params, self._pools,
+            self._state, ctl, *args, **kw)
+        self._spans.call("step.release", self._carry, pools, state)
         self._len_edits.clear()
         return fetch, logps, rest
+
+    def _carry(self, pools, state):
+        """Rebind the carried pools and state to a step's results. The
+        leaves they held were donated into that step; here their last
+        references go and the runtime destroys each (`step.release`)."""
+        self._pools, self._state = pools, state
 
     def _start_fetch(self, arr):
         self.stats["runtime_calls"] += 1
         try:
-            arr.copy_to_host_async()
+            self._spans.call("runtime.fetch_start", arr.copy_to_host_async)
         except (AttributeError, NotImplementedError):
             pass  # fetch happens synchronously at drain time instead
 

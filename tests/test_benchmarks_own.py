@@ -6,6 +6,9 @@ arithmetic or its manifest fails here, on the CPU, before any chip run."""
 import glob
 import importlib
 import os
+import sys
+
+import pytest
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _seen = {}
@@ -142,14 +145,45 @@ def test_the_manifest_reports_it_in_the_serve_cells_and_only_there(  # noqa: F81
 
 def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa: F811,E501
     """The accepted case's rules over every `stats_delta` file there is
-    (it stops at the nine it was written beside): a span or counter
-    misspelt in a metric file would read None for ever. Each such file
-    is a metric of the manifest."""
+    (it stops at the nine it was written beside), widened by what later
+    PRs record: a path is index 0, 1 or 3 (count, wall, CPU) of a span
+    the engine seeds, a `decode_*` / `prefill_*` counter, or a number
+    that `get_stats()` reports of a fresh engine with one stream open
+    on an event loop. A span or counter misspelt in a metric file would
+    read None for ever. Each such file is a metric of the manifest."""
+    import asyncio
     import json
+    import jax
     from benchmarks import run as runmod
-    from ray_tpu.serve.llm import engine
+    from ray_tpu.models import Llama, LlamaConfig
+    from ray_tpu.observability.profiler import GC_SPANS
+    from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig, engine
+    readers = os.path.join(_BENCH, "readers")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    import stats_delta
     stats_run = globals()["RUN"]
-    seeded = set(engine._LOOP_SPANS + engine._REQUEST_SPANS)
+    seeded = set(engine._LOOP_SPANS + engine._REQUEST_SPANS
+                 + engine._RUNTIME_SPANS + engine._STEP_SPANS + GC_SPANS)
+    model = Llama(LlamaConfig(vocab_size=64, d_model=16, n_layers=1,
+                              n_heads=2, n_kv_heads=1, d_ff=32,
+                              max_seq_len=64, remat=False))
+    eng = LLMEngine(model, model.init_params(jax.random.PRNGKey(0)),
+                    LLMEngineConfig(max_slots=2, max_seq_len=64,
+                                    prefill_buckets=(16,), kv_page_size=16))
+
+    async def fresh_stats():
+        stream = eng.astream_detailed(eng.submit([1, 2, 3],
+                                                 max_new_tokens=2))
+        try:
+            return eng.get_stats()
+        finally:
+            await stream.aclose()
+    try:
+        fresh = asyncio.run(fresh_stats())
+    finally:
+        eng.shutdown()
+    assert seeded <= set(fresh["spans"])
     whole = runmod.load_manifest()
     listed = {m["name"] for m in whole["end_to_end"] + whole["per_layer"]}
     found = []
@@ -161,11 +195,87 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
         found.append(name[:-5])
         for path in spec["args"]["num"] + spec["args"]["den"]:
             if path[0] == "spans":
-                assert path[1] in seeded and path[2] in (0, 1), (name, path)
+                assert path[1] in seeded and path[2] in (0, 1, 3), (
+                    name, path)
             else:
-                assert len(path) == 1 and path[0].startswith(
-                    ("decode_", "prefill_")), (name, path)
+                assert (len(path) == 1 and path[0].startswith(
+                    ("decode_", "prefill_"))) \
+                    or stats_delta._at(fresh, path) is not None, (
+                        name, path)
         got = runmod.read_metric(_BENCH, name[:-5], stats_run)
         assert got is None or isinstance(got, float), name
-    assert len(found) >= 10 and set(found) <= listed
+    assert len(found) >= 19 and set(found) <= listed
     assert "decode_live_state_share" in found
+
+
+_NEW_IN_PR_37 = {
+    "engine_step_call_ms": 4.0, "engine_fetch_start_ms": 0.5,
+    "engine_step_release_ms": 7.0,
+    "engine_runtime_blocked_share": 40.0,
+    "engine_host_cpu_ms_per_step": 6.0, "engine_thread_cpu_share": 50.0,
+    "consumer_loop_cpu_share": 30.0, "replica_gc_pause_share": 1.0,
+    "slot_refill_starved_share": 75.0}
+
+
+def _window(with_rows=True):
+    """Two readings of `get_stats()` one 10 s window apart: 1 000 decode
+    steps, each a program call of 4 ms (2.4 of them on the CPU), 7 ms
+    to release the leaves donated into it and a fetch start of 0.5 ms
+    (0.3), 6 ms of CPU a step over the host's
+    phases, the two threads at 50 % and 30 %, 100 ms of collections,
+    four refills of 100 ms of which 75 starved."""
+    def reading(k):
+        ms = 1_000_000
+        spans = {name: [k, 0, 0, k * 500 * 1_000] for name in (
+            "engine.loop", "engine.control", "engine.admit",
+            "engine.prefill_dispatch", "engine.chunk_dispatch",
+            "engine.decode_prep", "engine.decode_dispatch", "engine.emit",
+            "engine.bookkeep", "engine.deliver")}
+        spans["engine.decode_dispatch"][3] = k * 1_500 * 1_000
+        spans["slot.refill"] = [4 * k // 1000, k * 400 * 1_000, 0, 0]
+        out = {"decode_steps": k, "spans": spans}
+        if with_rows:
+            spans["runtime.step"] = [k, 4 * k * ms, 0, int(2.4 * k * ms)]
+            spans["runtime.fetch_start"] = [k, k * ms // 2, 0,
+                                            int(0.3 * k * ms)]
+            spans["runtime.other"] = [0, 0, 0, 0]
+            spans["step.release"] = [k, 7 * k * ms, 0, 2 * k * ms]
+            spans["gc.pause"] = [k // 10, k * 100 * 1_000, 0, 0]
+            spans["slot.refill.starved"] = [4 * k // 1000,
+                                            k * 300 * 1_000, 0, 0]
+            out["threads"] = {"engine": 5 * k * ms, "consumers": 3 * k * ms,
+                              "wall_ns": 10 * k * ms}
+        else:
+            for row in spans.values():
+                del row[3]              # a tree before the fourth column
+        return out
+    return {"stats0": reading(1_000), "stats1": reading(2_000)}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_37))
+def test_a_metric_of_the_engine_threads_time_reads_its_rows(name):
+    """Each file new in PR 37: its number from a window of known rows,
+    nothing from a parent that has no such row, column or clock, its
+    entry in the manifest as the issue gave it."""
+    from benchmarks import run as runmod
+    assert runmod.read_metric(_BENCH, name, _window()) == pytest.approx(
+        _NEW_IN_PR_37[name])
+    assert runmod.read_metric(_BENCH, name, _window(False)) is None
+    assert runmod.read_metric(_BENCH, name, {}) is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    serve = [w["name"] for w in whole["workloads"]
+             if _runner_of(whole, w["name"]).startswith("serve_http")]
+    refill, = [m for m in whole["per_layer"]
+               if m["name"] == "slot_refill_ms"]
+    assert entry["workloads"] == (
+        refill["workloads"] if name == "slot_refill_starved_share"
+        else serve)
+    assert (entry["moves"], entry["better"]) == ("out_tok_s", "lower")
+    assert entry["layer"].startswith(
+        "service" if name == "consumer_loop_cpu_share"
+        else "engine host loop")
+    without = dict(whole, per_layer=[m for m in whole["per_layer"]
+                                     if m["name"] not in _NEW_IN_PR_37])
+    assert whole["per_layer"][:len(without["per_layer"])] \
+        == without["per_layer"], "new entries go behind the accepted ones"

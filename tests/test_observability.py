@@ -347,8 +347,8 @@ def test_profiler_trace_and_timing(tmp_path):
         with table.step("timed-step", i):
             state, m = step(state, batch)
             m["loss"].block_until_ready()
-    n, total_ns, max_ns = table.snapshot()["timed-step"]
-    assert n == 3 and 0 < max_ns <= total_ns
+    n, total_ns, max_ns, cpu_ns = table.snapshot()["timed-step"]
+    assert n == 3 and 0 < max_ns <= total_ns and 0 < cpu_ns <= total_ns
     assert table.snapshot()["demo-step"][0] == 1
 
 

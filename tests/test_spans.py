@@ -4,6 +4,8 @@ the engine loop's phases, the counters at the same boundaries, each
 request's stamps, and the proxy-to-engine receipt time. The counts are
 exact on the CPU; no time read here is a device metric.
 """
+import asyncio
+import gc
 import glob
 import json
 import sys
@@ -15,7 +17,8 @@ import urllib.request
 import numpy as np
 import pytest
 
-from ray_tpu.observability.profiler import SpanTable
+from ray_tpu.observability.profiler import (GC_SPANS, SpanTable,
+                                            thread_clocks)
 
 PROMPT_LENS = (10, 12, 9, 11)       # one bucket of 16, one group of 4
 NEW_TOKENS = 8
@@ -29,10 +32,125 @@ def test_span_counts_total_and_max():
         with t.span("work", bucket=16):
             time.sleep(ms / 1000)
     rows = t.snapshot()
-    assert rows["seeded"] == [0, 0, 0]
-    n, total, longest = rows["work"]
+    assert rows["seeded"] == [0, 0, 0, 0]
+    n, total, longest, cpu = rows["work"]
     assert n == 2 and total >= 4_000_000
     assert 3_000_000 <= longest < total
+    # a sleeping span stands still: next to no CPU beside 4 ms of wall
+    assert 0 <= cpu < 1_000_000
+
+
+def _burn(ms):
+    """Hold this thread's CPU for `ms` of its own clock."""
+    end = time.thread_time_ns() + ms * 1_000_000
+    while time.thread_time_ns() < end:
+        pass
+
+
+def test_cpu_self_time_is_work_and_never_more_than_wall():
+    t = SpanTable()
+    for _ in range(200):
+        with t.span("empty"):
+            pass
+    with t.span("works"):
+        _burn(20)
+    with t.span("both"):
+        _burn(10)
+        time.sleep(0.02)
+    rows = t.snapshot()
+    for name, (n, total, _longest, cpu) in rows.items():
+        assert 0 <= cpu <= total, name      # the CPU reads lie inside
+    assert rows["works"][3] >= 20_000_000
+    assert 10_000_000 <= rows["both"][3] < rows["both"][1] - 15_000_000
+
+
+def test_a_childs_cpu_is_taken_off_its_parent():
+    t = SpanTable()
+    with t.span("outer"):
+        _burn(10)
+        with t.span("inner"):
+            _burn(30)
+            with t.span("asleep"):
+                time.sleep(0.02)
+    rows = t.snapshot()
+    assert 30_000_000 <= rows["inner"][3] < 39_000_000
+    assert 10_000_000 <= rows["outer"][3] < 19_000_000
+    assert rows["asleep"][3] < 1_000_000 < 20_000_000 <= rows["asleep"][1]
+    # an interval stamped elsewhere brings its own CPU, or none
+    t.add("stamped", 5_000, 2_000)
+    t.add("stamped", 7_000)
+    assert t.snapshot()["stamped"] == [2, 12_000, 7_000, 2_000]
+
+
+def test_call_times_one_call_without_nesting():
+    t = SpanTable()
+    with t.span("phase"):
+        assert t.call("timed", _burn, 10) is None
+        with pytest.raises(ZeroDivisionError):
+            t.call("timed", lambda: 1 / 0)
+    rows = t.snapshot()
+    n, total, _longest, cpu = rows["timed"]
+    assert n == 2 and 10_000_000 <= cpu <= total
+    # nothing was taken off the span around the calls
+    assert rows["phase"][3] >= cpu and rows["phase"][1] >= total
+
+
+def test_a_collection_shows_in_the_gc_rows_until_unwatched():
+    t = SpanTable()
+    t.watch_gc()
+    try:
+        assert all(t.snapshot()[name] == [0, 0, 0, 0] for name in GC_SPANS)
+        t.watch_gc()                            # one hook, however often
+        assert gc.callbacks.count(t._on_gc) == 1
+        gc.collect(0)
+        young = t.snapshot()
+        assert young["gc.pause"][0] >= 1
+        full0 = young["gc.pause.full"][0]
+        gc.collect()
+        rows = t.snapshot()
+        assert rows["gc.pause.full"][0] == full0 + 1
+        assert rows["gc.pause"][0] >= young["gc.pause"][0] + 1
+        assert 0 < rows["gc.pause.full"][2] <= rows["gc.pause"][1]
+
+        # a collection may start on a thread that holds the very row
+        # (a reader inside snapshot()): its pause is added all the same
+        def collect_holding_the_row():
+            with t._rows["gc.pause"].lock:
+                gc.collect()
+        th = threading.Thread(target=collect_holding_the_row, daemon=True)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+        assert t.snapshot()["gc.pause.full"][0] == full0 + 2
+    finally:
+        t.unwatch_gc()
+    assert t._on_gc not in gc.callbacks
+    before = t.snapshot()
+    gc.collect()
+    assert t.snapshot() == before
+
+
+def test_thread_clocks_leave_out_what_they_cannot_read():
+    done = threading.Event()
+    worker = threading.Thread(target=lambda: (_burn(20), done.wait(10)))
+    worker.start()
+    try:
+        first = thread_clocks(me=[threading.current_thread()], nobody=[])
+        _burn(10)
+        while thread_clocks(w=[worker])["w"] < 20_000_000:
+            time.sleep(0.001)       # its clock, read from this thread
+        second = thread_clocks(me=[threading.current_thread()],
+                               both=[threading.current_thread(), worker])
+    finally:
+        done.set()
+        worker.join(timeout=10)
+    assert not worker.is_alive()
+    assert set(first) == {"me", "wall_ns"}      # no thread: no key
+    assert second["me"] - first["me"] >= 10_000_000
+    assert second["both"] >= second["me"] + 20_000_000
+    assert second["wall_ns"] - first["wall_ns"] >= 10_000_000
+    # a thread that has ended has taken its clock with it
+    assert set(thread_clocks(gone=[worker])) == {"wall_ns"}
 
 
 def test_nested_spans_store_self_time():
@@ -106,8 +224,8 @@ def test_adds_from_more_threads_than_cores_lose_nothing():
             assert not th.is_alive()
     finally:
         sys.setswitchinterval(old)
-    n, total, longest = t.snapshot()["stamp"]
-    assert n == per_thread * n_threads
+    n, total, longest, cpu = t.snapshot()["stamp"]
+    assert n == per_thread * n_threads and cpu == 0
     assert total == n_threads * sum(1 + i % 7 for i in range(per_thread))
     assert longest == 7
 
@@ -260,6 +378,95 @@ def test_engine_phases_sum_to_the_loops_wall_time(drained):
     assert spans["engine.idle_sleep"][0] > 0
 
 
+def test_runtime_rows_count_the_loops_runtime_calls(drained):
+    from ray_tpu.serve.llm.engine import _RUNTIME_SPANS
+    spans, st = drained["spans"], drained["stats"]
+    assert sum(spans[name][0] for name in _RUNTIME_SPANS) \
+        == st["runtime_calls"] > 0
+    # a program and a fetch a dispatch, and nothing eager between them
+    assert spans["runtime.step"][0] == spans["runtime.fetch_start"][0] \
+        == drained["dispatches"] + 1
+    assert spans["runtime.other"][0] == 0
+    # the donated leaves are released once a step call, outside its row
+    assert spans["step.release"][0] == spans["runtime.step"][0]
+    assert 0 < spans["step.release"][3] <= spans["step.release"][1]
+    for name in _RUNTIME_SPANS:
+        assert 0 <= spans[name][3] <= spans[name][1], name
+    # the calls lie inside the dispatch phases and took nothing off them
+    inside = sum(spans[name][1]
+                 for name in _RUNTIME_SPANS + ("step.release",))
+    assert 0 < inside <= (spans["engine.prefill_dispatch"][1]
+                          + spans["engine.decode_dispatch"][1])
+    # every phase has its CPU beside its wall time. A span's CPU reads
+    # lie inside its wall reads, so a phase with no child holds
+    # cpu <= wall whatever it does; what a child's reads cost falls to
+    # its parent's CPU, so `engine.loop` (a dozen children and next to
+    # no work of its own) is held only through the sum
+    from ray_tpu.serve.llm.engine import _LOOP_SPANS
+    for name in set(_LOOP_SPANS) - {"engine.loop", "engine.emit"}:
+        assert 0 <= spans[name][3] <= spans[name][1], name
+    assert 0 < sum(spans[name][3] for name in _LOOP_SPANS) \
+        <= sum(spans[name][1] for name in _LOOP_SPANS)
+    assert spans["engine.idle_sleep"][3] < 0.5 * spans["engine.idle_sleep"][1]
+    assert spans["engine.decode_dispatch"][3] > 0
+
+
+def test_a_fresh_engine_seeds_every_row_and_its_threads(tiny_llm):
+    from ray_tpu.serve.llm.engine import (_LOOP_SPANS, _REQUEST_SPANS,
+                                          _RUNTIME_SPANS, _STEP_SPANS)
+    eng = _engine(tiny_llm)
+    try:
+        st = eng.get_stats()
+        assert set(st["spans"]) >= set(
+            _LOOP_SPANS + _REQUEST_SPANS + _RUNTIME_SPANS + _STEP_SPANS
+            + GC_SPANS)
+        assert {"runtime.step", "runtime.fetch_start", "runtime.other",
+                "step.release", "gc.pause", "gc.pause.full",
+                "slot.refill.starved"} <= set(st["spans"])
+        assert all(len(row) == 4 for row in st["spans"].values())
+        # nobody streams from an event loop yet: no key, no guess
+        assert set(st["threads"]) == {"engine", "wall_ns"}
+
+        async def consume():
+            rid = eng.submit(np.arange(1, 9), max_new_tokens=3)
+            return [tok async for tok, _lp in eng.astream_detailed(rid)]
+        loop_thread_cpu = {}
+
+        def on_a_loop():
+            assert len(asyncio.run(consume())) == 3
+            loop_thread_cpu.update(eng.get_stats()["threads"])
+        th = threading.Thread(target=on_a_loop)
+        th.start()
+        th.join(timeout=60)
+        assert not th.is_alive()
+        assert set(loop_thread_cpu) == {"engine", "consumers", "wall_ns"}
+        assert loop_thread_cpu["consumers"] > 0
+        assert loop_thread_cpu["engine"] >= st["threads"]["engine"]
+        assert loop_thread_cpu["wall_ns"] > st["threads"]["wall_ns"]
+        # that loop's thread has ended and taken its clock with it
+        assert set(eng.get_stats()["threads"]) == {"engine", "wall_ns"}
+    finally:
+        eng.shutdown()
+
+
+def test_the_engine_counts_collections_until_shutdown(tiny_llm):
+    eng = _engine(tiny_llm)
+    try:
+        before = eng.get_stats()["spans"]
+        gc.collect()
+        after = eng.get_stats()["spans"]
+        for name in GC_SPANS:
+            assert after[name][0] >= before[name][0] + 1, name
+            assert after[name][1] > before[name][1], name
+        assert after["gc.pause.full"][2] > 0
+    finally:
+        eng.shutdown()
+    assert eng._spans._on_gc not in gc.callbacks
+    after = eng._spans.snapshot()
+    gc.collect()
+    assert eng._spans.snapshot()["gc.pause"] == after["gc.pause"]
+
+
 def test_compiles_are_named_by_the_phase_that_compiled(drained):
     st = drained["stats"]
     assert st["compiles"]["engine.prefill_dispatch"] >= 1
@@ -301,6 +508,34 @@ def test_slot_refill_counts_each_reuse_of_a_slot(tiny_llm):
     assert spans["slot.refill"][1] > 0
 
 
+def test_slot_refill_is_split_at_the_requests_arrival(tiny_llm):
+    eng = _engine(tiny_llm, max_slots=1)
+    try:
+        # the second request waits in the engine while the first decodes:
+        # the freed slot stood empty for the engine's own reasons
+        rids = [eng.submit(np.arange(1, 9), max_new_tokens=3)
+                for _ in range(2)]
+        for rid in rids:
+            assert len(list(eng.stream(rid))) == 3
+        spans = eng.get_stats()["spans"]
+        assert spans["slot.refill"][0] == 1 and spans["slot.refill"][1] > 0
+        assert spans["slot.refill.starved"][:3] == [1, 0, 0]
+        # the third arrives 50 ms after the slot fell free: the slot
+        # starved for that long, and was refilled soon after
+        while eng.get_stats()["free_slots"] < 1:
+            time.sleep(0.005)
+        time.sleep(0.05)
+        assert len(list(eng.stream(
+            eng.submit(np.arange(1, 9), max_new_tokens=3)))) == 3
+        spans = eng.get_stats()["spans"]
+    finally:
+        eng.shutdown()
+    refill, starved = spans["slot.refill"], spans["slot.refill.starved"]
+    assert refill[0] == starved[0] == 2
+    assert 50_000_000 <= starved[1] == starved[2] <= refill[2]
+    assert starved[1] <= refill[1]
+
+
 def test_ingress_is_recorded_only_for_a_stamped_submit(tiny_llm):
     eng = _engine(tiny_llm)
     list(eng.stream(eng.submit(np.arange(1, 9), max_new_tokens=2)))
@@ -309,7 +544,7 @@ def test_ingress_is_recorded_only_for_a_stamped_submit(tiny_llm):
                                recv_ts=time.time() - 0.05)))
     st = eng.get_stats()
     eng.shutdown()
-    n, total, _ = st["spans"]["request.ingress"]
+    n, total, _, _ = st["spans"]["request.ingress"]
     assert n == 1 and 50_000_000 <= total < 5_000_000_000
     assert st["ttft_breakdown_p50_ms"]["ingress_ms"] >= 50.0
 
@@ -354,7 +589,7 @@ def test_ingress_is_stamped_by_the_proxy_and_read_by_the_engine(rt):
                         raise
                     time.sleep(0.25)
         st = handle.stats.remote(None).result(timeout_s=60)
-        n, total, longest = st["spans"]["request.ingress"]
+        n, total, longest, _ = st["spans"]["request.ingress"]
         assert n == 2 and 0 < longest <= total < 60_000_000_000
         assert st["ttft_breakdown_p50_ms"]["ingress_ms"] > 0
         assert st["spans"]["stream.deliver"][0] == 6
