@@ -159,10 +159,17 @@ class LatentAttention(nn.Module):
                            (r, h * (dn + dv)), cfg.param_dtype
                            ).astype(cfg.dtype).reshape(r, h, dn + dv)
         with jax.named_scope("mla.project"):
-            q = _dense(cfg, h * cfg.q_head_dim, "q_proj")(x).reshape(
-                b, s, h, cfg.q_head_dim)
-            q = rms_norm(q, self.param("q_norm", nn.initializers.ones,
-                                       (cfg.q_head_dim,)), cfg.norm_eps)
+            # the product is pinned flat before it is cut into heads: left
+            # free, the TPU compiler folds the cut into heads of 192 (no
+            # whole number of 128 lanes) into the product's result layout
+            # and copies the whole kernel column-major in HBM to match,
+            # 100 MB a layer a step (tests/test_tpu_compile.py:
+            # test_latent_attention_copies_no_parameter_in_hbm)
+            q = jax.lax.optimization_barrier(
+                _dense(cfg, h * cfg.q_head_dim, "q_proj")(x))
+            q = rms_norm(q.reshape(b, s, h, cfg.q_head_dim),
+                         self.param("q_norm", nn.initializers.ones,
+                                    (cfg.q_head_dim,)), cfg.norm_eps)
             q_nope = q[..., :dn]
             q_rope = apply_rotary(q[..., dn:], cos, sin, positions)
             down = _dense(cfg, cfg.latent_width, "kv_down_proj")(x)

@@ -11,6 +11,8 @@ the other families' pools and decode programs against what they were.
 import collections
 import dataclasses
 import hashlib
+import json
+import os
 import re
 
 import jax
@@ -104,6 +106,29 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(
                                atol=1e-3)
 
 
+def test_rows_check_decomposes_the_check_at_the_rehearsal_size(tmp_path):
+    """tools/rows_check.py on the sarvam configuration's `rehearse` size
+    (8 slots and the scratch row): the engine's tokens are `model.apply`'s
+    at the engine's row count, and on the CPU the 1-row programs' too
+    (the benchmark's own and the tool's), so all five comparisons read
+    the same gap and pass."""
+    from tools import rows_check
+    out = tmp_path / "rows.json"
+    assert rows_check.main([
+        "--config", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "configs", "sarvam-105b-serve-ep4-l6.json"),
+        "--seed", "5", "--rows", "9", "--prompt-len", "40",
+        "--new-tokens", "6", "--rehearse", "--out", str(out)]) == 0
+    got = json.loads(out.read_text())
+    assert len(got["tokens"]["engine"]) == 6 and got["engine_repeatable"]
+    assert got["engine_is_rows_n"] and got["rows_n_is_rows_1"]
+    assert got["same_experts_pair_share_rows_n_vs_1"] == 1.0
+    judged = [got[k] for k in got if "_following_" in k]
+    assert len(judged) == 5 and all(j["ok"] for j in judged)
+    assert len({j["argmax_gap_rel"] for j in judged}) == 1
+
+
 # (b) absorbed form = expanded form on one layer --------------------------
 def test_absorbed_form_is_the_expanded_form():
     cfg = LatentMoEConfig.debug(dtype=jnp.float32)
@@ -129,6 +154,44 @@ def test_absorbed_form_is_the_expanded_form():
     np.testing.assert_allclose(jnp.concatenate(steps, 1), expanded,
                                rtol=1e-4, atol=1e-5)
     assert entry.lengths.tolist() == [s, s]
+
+
+@pytest.mark.parametrize("form", ["prefill", "decode"])
+def test_the_pinned_query_product_is_the_unpinned_one(form, monkeypatch):
+    """`q_proj`'s product is pinned flat by an `optimization_barrier` (so
+    that the TPU compiler does not re-lay the kernel out in HBM a layer a
+    step: tests/test_tpu_compile.py): the barrier is the identity, in a
+    whole prefill and in a decode step against the pool."""
+    cfg = LatentMoEConfig.debug(dtype=jnp.float32)
+    layer = LatentAttention(cfg)
+    b, ps, n_pages = 2, 4, 3
+    s = 11 if form == "prefill" else 1
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((b, s, cfg.d_model)), jnp.float32)
+    cos, sin = yarn_frequencies(cfg.qk_rope_dim, 64, cfg.rope_theta,
+                                factor=cfg.rope_factor,
+                                original_max_len=cfg.rope_original_max_len)
+    params = layer.init(jax.random.PRNGKey(0), x, cos, sin)
+    start = 0 if form == "prefill" else 7
+    entry = PagedLatent(
+        jnp.asarray(rng.standard_normal(
+            ((b * n_pages + 1) * ps, cfg.cache_width)), jnp.float32),
+        jnp.arange(b * n_pages, dtype=jnp.int32).reshape(b, n_pages),
+        jnp.full((b,), start, jnp.int32), ps, fresh=form == "prefill")
+    positions = start + jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32),
+                                         (b, s))
+
+    def call():
+        out, new = layer.apply(params, x, cos, sin, entry, positions)
+        return out, new.flat
+    # (a new lambda a trace: make_jaxpr keeps what it traced of `call`)
+    pinned = call()
+    assert "optimization_barrier" in str(jax.make_jaxpr(lambda: call())())
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda q: q)
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(lambda: call())())
+    for got, want in zip(pinned, call()):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 # (c) the shares add up ---------------------------------------------------

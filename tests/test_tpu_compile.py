@@ -7,7 +7,9 @@ budget); these can, at Llama-3.2-1B and Llama-3-8B attention widths and
 at the training cell's share a chip, at half a minute in all. Nothing
 runs, so nothing here is a result or a time.
 """
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -211,6 +213,76 @@ def test_latent_decode_compiles_for_v5e(chip, pages):
         sds((rows, pages), jnp.int32), sds((rows,), jnp.int32)).compile()
     _assert_mosaic(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def parameter_copies_in_hbm(compiled, at_least=32 * 2 ** 20):
+    """The `copy(` / `copy-start(` operations of a compiled program whose
+    operand is an entry of the argument named `params` of `at_least`
+    bytes or more and whose result stays in HBM (its layout names no
+    `S(1)`: a copy into the fast memory ahead of a product is that
+    product's one read of the kernel, not a second one)."""
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \(?(\w+)\[([\d,]*)\]\{([^}]*)\}.* "
+                      r"copy(?:-start)?\(%params", line)
+        if m is None or "S(1)" in m.group(3):
+            continue
+        width = re.search(r"\d+", m.group(1))
+        size = math.prod(map(int, m.group(2).split(","))) * (
+            int(width.group()) // 8 if width else 1)
+        if size >= at_least:
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill", "prefill_1x1024"])
+def test_latent_attention_copies_no_parameter_in_hbm(chip, form,
+                                                     monkeypatch):
+    """sarvam-105b's attention layer at its published widths, in bf16,
+    against the cell's donated pool of 262 144 tokens: a decode step
+    (128 slots and the scratch row, 64 pages a row) and a whole prefill
+    (2 rows x 2 048 and 1 x 1 024, `fresh`) read every kernel in the
+    layout the parameter is held in. Left free to lay `q_proj`'s product
+    out by heads of 192, the compiler copied the kernel (4 096 x 12 288,
+    101 MB) column-major into an HBM temporary a layer a decode step,
+    and in a prefill of one row of 128 or 1 024 (not of 2 x 512 or 2 x
+    2 048); the product is pinned flat (models/latent_moe.py:
+    LatentAttention)."""
+    from ray_tpu.models.latent_moe import LatentAttention, LatentMoEConfig
+    from ray_tpu.ops.attention import PagedLatent
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LatentMoEConfig.sarvam_105b(dtype=jnp.bfloat16,
+                                      param_dtype=jnp.bfloat16)
+    attn = LatentAttention(cfg)
+    rows, s, pages = {"decode": (129, 1, 64), "prefill": (2, 2048, 32),
+                      "prefill_1x1024": (1, 1024, 16)}[form]
+    ps, pool = 64, 262144
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def call(params, x, cos, sin, flat, table, lengths, positions):
+        out, new = attn.apply(
+            {"params": params}, x, cos, sin,
+            PagedLatent(flat, table, lengths, ps, fresh=form != "decode"),
+            positions)
+        return out, new.flat
+    rope = sds((cfg.max_seq_len, cfg.qk_rope_dim // 2), jnp.float32)
+    args = (sds((rows, s, cfg.d_model), jnp.bfloat16), rope, rope,
+            sds((pool + ps, cfg.cache_width), jnp.bfloat16),
+            sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
+            sds((rows, s), jnp.int32))
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda *a: attn.init(jax.random.PRNGKey(0), *a)["params"],
+            *args[:3], None))
+    compiled = jax.jit(call, donate_argnums=(4,)).lower(
+        params, *args).compile()
+    assert parameter_copies_in_hbm(compiled) == []
+    if form == "decode":
+        _assert_mosaic(compiled)
+        assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
 @pytest.mark.parametrize("rows", [129, 4096], ids=["decode", "prefill"])
