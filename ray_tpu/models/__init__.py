@@ -27,6 +27,8 @@ _REGISTRY = {
     "latent-moe-debug": lambda **kw: LatentMoE(LatentMoEConfig.debug(**kw)),
     "olmo-hybrid-7b": lambda **kw: Hybrid(HybridConfig.olmo_hybrid_7b(**kw)),
     "hybrid-debug": lambda **kw: Hybrid(HybridConfig.debug(**kw)),
+    "lfm2-24b-a2b": lambda **kw: Hybrid(HybridConfig.lfm2_24b_a2b(**kw)),
+    "lfm2-moe-debug": lambda **kw: Hybrid(HybridConfig.lfm2_debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),
     "clip-debug": lambda **kw: CLIP(CLIPConfig.debug(**kw)),

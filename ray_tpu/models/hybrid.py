@@ -1,13 +1,15 @@
-"""Hybrid decoders: a mixer a layer, chosen by `layer_types` (the
-`olmo_hybrid` family: Olmo-Hybrid-7B), TPU-first.
+"""Hybrid decoders: a mixer a layer, chosen by `layer_types`, and a
+feed-forward a layer, chosen by the layer's index, TPU-first. Two
+families: `olmo_hybrid` (Olmo-Hybrid-7B) and `lfm2_moe` (LFM2-24B-A2B).
 
-A block is a mixer and a SwiGLU MLP around the residual stream. The
+A block is a mixer and a feed-forward around the residual stream. The
 mixer of layer i is what `layer_types[i]` names:
 
-  * "full_attention": `LlamaAttention` (models/llama.py) with its
-    `qk_norm` arm (RMSNorm over the whole projected q and k) and no
-    rotation (the family's `rope_theta` is null: its full layers see
-    no positions);
+  * "full_attention": `LlamaAttention` (models/llama.py). `olmo_hybrid`
+    uses its `qk_norm` arm over the whole projected q and k and no
+    rotation (the family's `rope_theta` is null: its full layers see no
+    positions); `lfm2_moe` normalises each head's width of q and of k
+    (`qk_norm="head"`) and then rotates (`rope_theta`);
   * "linear_attention": `GatedDeltaNet`, the layer of
     ops/gated_deltanet.py. With x the block's input, H heads of key
     width d_k and value width d_v:
@@ -21,14 +23,32 @@ mixer of layer i is what `layer_types[i]` names:
     against the engine's per-slot state in the one-token form (the
     Pallas kernel of ops/pallas/gdn_decode.py on the TPU). Projections,
     convolution and output in the activations' dtype, the state and
-    everything that touches it in float32.
+    everything that touches it in float32;
+  * "conv": `ShortConv`, a gated short convolution of width K =
+    `conv_kernel` over the model's width d, no bias, no activation:
+        [B | C | X] = W_in x                      three blocks of d
+        z = B * X
+        c_t = sum_j w_j z_{t-j}, j = 0..K-1       depthwise, causal
+        y = W_out (C * c)
+    Its memory is z at the sequence's last K - 1 positions.
 
-Each sub-layer's OUTPUT is normalised, as the OLMo 2 and 3 family
-does: h = x + Norm(Mixer(x)), y = h + Norm(MLP(h)).
+The feed-forward of layer i is a dense SwiGLU (`LlamaMLP`, width `d_ff`)
+where `n_dense_layers` is None or i < `n_dense_layers`, and otherwise
+the expert layer of models/latent_moe.py (`ShareMoE`: sigmoid scores, a
+selection bias, `n_experts` SwiGLU experts of width `d_expert`,
+`experts_per_token` a token, no shared expert), which leaves the
+`step_stats` counters of ops/moe.py.
+
+Where the norms stand is the family's (`pre_norm`): `olmo_hybrid`
+normalises each sub-layer's OUTPUT, as the OLMo 2 and 3 family does:
+h = x + Norm(Mixer(x)), y = h + Norm(FF(h)); `lfm2_moe` its input:
+h = x + Mixer(Norm(x)), y = h + FF(Norm(h)).
 
 What a layer caches it says itself (`paged_cache_spec`): a full layer
-pages K and V a token, a linear layer keeps a state and the
-convolution's last K - 1 inputs a SLOT (ops/attention.py:SlotState).
+pages K and V a token (heads narrower than 128 lanes packed side by
+side: ops/attention.py:packed_kv_shape), a linear layer keeps a state
+and the convolution's last K - 1 inputs a SLOT, a conv layer its last
+K - 1 inputs a slot (ops/attention.py:SlotState).
 """
 from __future__ import annotations
 
@@ -39,13 +59,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import rms_norm
+from ..ops import rms_norm, rope_frequencies
 from ..ops import gated_deltanet as gdn
-from ..ops.attention import LayerCache, PagedKV, SlotState
+from ..ops.attention import (LayerCache, PagedKV, SlotState,
+                             packed_kv_shape)
+from ..ops.moe import MOE_STATS
 from ..util import knobs
+from .latent_moe import ShareMoE
 from .llama import LlamaAttention, LlamaMLP, _LMHead, _proj
 
-LINEAR, FULL = "linear_attention", "full_attention"
+LINEAR, FULL, CONV = "linear_attention", "full_attention", "conv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +89,24 @@ class HybridConfig:
     linear_chunk: int = 64          # tokens a chunk of the chunkwise form
     max_seq_len: int = 65536
     norm_eps: float = 1e-6
-    qk_norm: bool = True
+    # LlamaAttention reads these two: True, the whole projected q and k;
+    # "head", each head's width. None: no rotation
+    qk_norm: "bool | str" = True
+    rope_theta: Optional[float] = None
+    conv_kernel: int = 3            # K of the "conv" layers
+    # False: each sub-layer's output is normalised; True: its input
+    pre_norm: bool = False
+    tie_embeddings: bool = False    # the head is the embedding
+    # leading layers with a dense SwiGLU of d_ff; the layers after them
+    # are expert layers (models/latent_moe.py:ShareMoE reads the fields
+    # below). None: every layer is dense
+    n_dense_layers: Optional[int] = None
+    d_expert: int = 1536
+    n_experts: int = 64
+    experts_per_token: int = 4
+    norm_topk_prob: bool = True
+    route_norm_eps: float = 1e-6
+    routed_scaling: float = 1.0
     dtype: Any = jnp.bfloat16
     # storage dtype of embeddings and matmul kernels; norm weights,
     # A_log and dt_bias stay float32
@@ -82,11 +122,12 @@ class HybridConfig:
         else:
             object.__setattr__(self, "layer_types",
                                tuple(self.layer_types))
-        bad = set(self.layer_types) - {LINEAR, FULL}
+        bad = set(self.layer_types) - {LINEAR, FULL, CONV}
         if bad or len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types must name {self.n_layers} layers as "
-                f"{LINEAR!r} or {FULL!r}; got {self.layer_types}")
+                f"{LINEAR!r}, {FULL!r} or {CONV!r}; got "
+                f"{self.layer_types}")
         if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
             raise ValueError("d_model / n_heads / n_kv_heads do not divide")
 
@@ -106,9 +147,39 @@ class HybridConfig:
         return self.linear_n_heads * (2 * self.linear_key_dim
                                       + self.linear_value_dim)
 
+    def dense_ff(self, i: int) -> bool:
+        """Whether layer i's feed-forward is the dense SwiGLU."""
+        return self.n_dense_layers is None or i < self.n_dense_layers
+
+    # every expert of a layer is held here and none is shared (what
+    # ShareMoE asks)
+    expert_first = 0
+    n_shared_experts = 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts
+
     @staticmethod
     def olmo_hybrid_7b(**kw) -> "HybridConfig":
         return HybridConfig(**kw)
+
+    @staticmethod
+    def lfm2_24b_a2b(**kw) -> "HybridConfig":
+        """LFM2-24B-A2B as published (config.json, model_type lfm2_moe):
+        40 layers, a full-attention layer at every index that is 2
+        modulo 4 and gated short convolutions between them; fewer
+        `n_layers` keep the first of them."""
+        n = kw.get("n_layers", 40)
+        return HybridConfig(**{**dict(
+            vocab_size=65536, d_model=2048, n_layers=n,
+            layer_types=tuple(FULL if i % 4 == 2 else CONV
+                              for i in range(n)),
+            n_heads=32, n_kv_heads=8, d_ff=11776, conv_kernel=3,
+            n_dense_layers=2, d_expert=1536, n_experts=64,
+            experts_per_token=4, norm_topk_prob=True, routed_scaling=1.0,
+            max_seq_len=128000, norm_eps=1e-5, qk_norm="head",
+            rope_theta=1e6, pre_norm=True, tie_embeddings=True), **kw})
 
     @staticmethod
     def debug(**kw) -> "HybridConfig":
@@ -116,6 +187,13 @@ class HybridConfig:
             vocab_size=256, d_model=64, n_layers=4, n_heads=4,
             n_kv_heads=4, d_ff=128, linear_n_heads=4, linear_key_dim=8,
             linear_value_dim=16, linear_chunk=8, max_seq_len=256), **kw})
+
+    @staticmethod
+    def lfm2_debug(**kw) -> "HybridConfig":
+        return HybridConfig.lfm2_24b_a2b(**{**dict(
+            vocab_size=256, d_model=64, n_layers=5, n_heads=4,
+            n_kv_heads=2, d_ff=128, n_dense_layers=1, d_expert=32,
+            n_experts=8, experts_per_token=2, max_seq_len=256), **kw})
 
 
 def _uniform(bound: float):
@@ -200,26 +278,72 @@ class GatedDeltaNet(nn.Module):
         return gdn.step(q, k, v, g, beta, state)
 
 
+class ShortConv(nn.Module):
+    """The gated short convolution (module docstring). Its cache entry
+    is a SlotState of one array, z at the sequence's last K - 1
+    positions, carried by the function GatedDeltaNet's convolution
+    uses."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache: Optional[SlotState] = None):
+        cfg = self.cfg
+        d = cfg.d_model
+        with jax.named_scope("shortconv.project"):
+            b_gate, c_gate, u = jnp.split(
+                _proj(cfg, 3 * d, "in_proj")(x), 3, axis=-1)
+            z = b_gate * u
+        conv_w = self.param(
+            "conv_kernel", _uniform(cfg.conv_kernel ** -0.5),
+            (cfg.conv_kernel, d), cfg.param_dtype)
+        tail = n_new = None
+        if cache is not None:
+            (tail,) = cache.read()
+            n_new = cache.n_new
+        with jax.named_scope("shortconv.conv"):
+            c, tail = gdn.causal_conv(z, conv_w, tail, n_new,
+                                      activation=None)
+        with jax.named_scope("shortconv.out"):
+            y = _proj(cfg, d, "out_proj")(c_gate * c)
+        return y, (None if cache is None else cache.write(tail))
+
+
 class HybridBlock(nn.Module):
     cfg: HybridConfig
     kind: str
+    dense: bool = True
 
     @nn.compact
-    def __call__(self, x, cache=None, positions=None):
+    def __call__(self, x, cos=None, sin=None, cache=None, positions=None,
+                 row_mask=None):
         cfg = self.cfg
         mixer_w = self.param("attn_norm", nn.initializers.ones,
                              (cfg.d_model,))
         mlp_w = self.param("mlp_norm", nn.initializers.ones,
                            (cfg.d_model,))
-        if self.kind == FULL:
-            # cos = sin = None: no rotation
-            h, new_cache = LlamaAttention(cfg, name="attention")(
-                x, None, None, cache, positions)
+
+        def mixer(x):
+            if self.kind == FULL:
+                # cos = sin = None: no rotation
+                return LlamaAttention(cfg, name="attention")(
+                    x, cos, sin, cache, positions)
+            if self.kind == CONV:
+                return ShortConv(cfg, name="conv")(x, cache)
+            return GatedDeltaNet(cfg, name="linear_attention")(x, cache)
+
+        def ff(x):
+            if self.dense:
+                return LlamaMLP(cfg, name="mlp")(x)
+            return ShareMoE(cfg, name="moe")(x, row_mask)
+
+        if cfg.pre_norm:
+            h, new_cache = mixer(rms_norm(x, mixer_w, cfg.norm_eps))
+            x = x + h
+            x = x + ff(rms_norm(x, mlp_w, cfg.norm_eps))
         else:
-            h, new_cache = GatedDeltaNet(cfg, name="linear_attention")(
-                x, cache)
-        x = x + rms_norm(h, mixer_w, cfg.norm_eps)
-        x = x + rms_norm(LlamaMLP(cfg, name="mlp")(x), mlp_w, cfg.norm_eps)
+            h, new_cache = mixer(x)
+            x = x + rms_norm(h, mixer_w, cfg.norm_eps)
+            x = x + rms_norm(ff(x), mlp_w, cfg.norm_eps)
         return x, new_cache
 
 
@@ -227,42 +351,67 @@ class Hybrid(nn.Module):
     """tokens (B, S) -> (logits, cache): Llama's calling convention, so
     that the serve engine is family agnostic. `cache` is None (the plain
     forward: every layer starts from nothing) or one entry a layer as
-    `paged_cache_spec` says."""
+    `paged_cache_spec` says. A model with expert layers declares
+    `step_stats` (ops/moe.py:MOE_STATS) and, under
+    `mutable=["step_stats"]`, every expert layer leaves that vector,
+    counted over the rows `row_mask` (B, S) marks as real."""
     cfg: HybridConfig
 
-    @nn.compact
-    def __call__(self, tokens, cache=None, positions=None):
+    @property
+    def step_stats(self):
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
-                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                     embedding_init=nn.initializers.normal(0.02))(tokens)
+        return () if cfg.dense_ff(cfg.n_layers - 1) else MOE_STATS
+
+    @nn.compact
+    def __call__(self, tokens, cache=None, positions=None, row_mask=None):
+        cfg = self.cfg
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
+                         dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                         embedding_init=nn.initializers.normal(0.02))
+        x = embed(tokens)
+        cos = sin = None
+        if cfg.rope_theta is not None:
+            cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                        cfg.rope_theta)
         new_cache = []
         for i, kind in enumerate(cfg.layer_types):
-            x, c = HybridBlock(cfg, kind, name=f"layer_{i}")(
-                x, None if cache is None else cache[i], positions)
+            x, c = HybridBlock(cfg, kind, cfg.dense_ff(i),
+                               name=f"layer_{i}")(
+                x, cos, sin, None if cache is None else cache[i],
+                positions, row_mask)
             new_cache.append(c)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.d_model,)), cfg.norm_eps)
-        logits = _LMHead(cfg.vocab_size, cfg.param_dtype,
-                         name="lm_head")(x)
+        if cfg.tie_embeddings:
+            # bf16 operands, float32 accumulation (models/llama.py)
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                embed.embedding.astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = _LMHead(cfg.vocab_size, cfg.param_dtype,
+                             name="lm_head")(x)
         return logits, (new_cache if cache is not None else None)
 
     def init_params(self, rng, batch=1, seq=8):
         return self.init(rng, jnp.zeros((batch, seq), jnp.int32))["params"]
 
     def paged_cache_spec(self):
-        """A full layer pages K and V of (kv_pool_heads, head_dim) a
-        token; a linear layer keeps, a slot, the float32 state
-        (d_k, H x d_v) and the convolution's last K - 1 inputs
-        (ops/attention.py:kv_cache_spec)."""
+        """A full layer pages K and V of `packed_kv_shape(kv_pool_heads,
+        head_dim)` a token; a linear layer keeps, a slot, the float32
+        state (d_k, H x d_v) and the convolution's last K - 1 inputs; a
+        conv layer its last K - 1 inputs (ops/attention.py:
+        kv_cache_spec)."""
         cfg = self.cfg
-        kv = (cfg.kv_pool_heads, cfg.head_dim)
-        full = LayerCache(PagedKV, (kv, kv), (cfg.dtype, cfg.dtype))
-        linear = LayerCache(
-            SlotState,
-            ((cfg.linear_key_dim,
-              cfg.linear_n_heads * cfg.linear_value_dim),
-             (cfg.linear_conv_kernel - 1, cfg.conv_width)),
-            (jnp.float32, cfg.dtype), by_slot=True)
-        return [full if kind == FULL else linear
-                for kind in cfg.layer_types]
+        kv = packed_kv_shape(cfg.kv_pool_heads, cfg.head_dim)
+        by_kind = {
+            FULL: LayerCache(PagedKV, (kv, kv), (cfg.dtype, cfg.dtype)),
+            LINEAR: LayerCache(
+                SlotState,
+                ((cfg.linear_key_dim,
+                  cfg.linear_n_heads * cfg.linear_value_dim),
+                 (cfg.linear_conv_kernel - 1, cfg.conv_width)),
+                (jnp.float32, cfg.dtype), by_slot=True),
+            CONV: LayerCache(
+                SlotState, ((cfg.conv_kernel - 1, cfg.d_model),),
+                (cfg.dtype,), by_slot=True)}
+        return [by_kind[kind] for kind in cfg.layer_types]

@@ -72,6 +72,7 @@ class LatentMoEConfig:
     n_shared_experts: int = 1
     routed_scaling: float = 2.5
     norm_topk_prob: bool = True
+    route_norm_eps: float = 0.0     # added to the sum that normalises
     # the share of each expert layer held here: experts expert_first ..
     # expert_first + expert_count - 1; None holds all n_experts
     expert_first: int = 0
@@ -226,8 +227,10 @@ class _SwiGLU(nn.Module):
 
 class ShareMoE(nn.Module):
     """Sigmoid-routed SwiGLU experts, of which this layer holds a share,
-    beside a shared expert every token passes through."""
-    cfg: LatentMoEConfig
+    beside a shared expert every token passes through (none where
+    `n_shared_experts` is 0). `cfg` is any config with the fields read
+    here (models/hybrid.py's has them too)."""
+    cfg: Any
 
     @nn.compact
     def __call__(self, x, row_mask=None):
@@ -257,7 +260,7 @@ class ShareMoE(nn.Module):
             weights, top_idx = route(
                 logits, cfg.experts_per_token, "sigmoid_bias",
                 cfg.norm_topk_prob, select_bias=router_b,
-                scale=cfg.routed_scaling)
+                scale=cfg.routed_scaling, norm_eps=cfg.route_norm_eps)
         out, stats = moe_dropless(
             tokens, weights, top_idx, w_gate, w_up, w_down,
             None if row_mask is None else row_mask.reshape(b * s),

@@ -55,9 +55,11 @@ class LlamaConfig:
     # (ops/quant.py QuantDense; params from quantize_llama_params).
     # Serving-only: int8 kernels are not trained.
     quant: Optional[str] = None
-    # RMSNorm over the whole projected q vector and over the whole
+    # True: RMSNorm over the whole projected q vector and over the whole
     # projected k vector, before the rotation (OLMoE's q_norm / k_norm).
-    qk_norm: bool = False
+    # "head": over each head's width, one weight shared by the heads
+    # (models/hybrid.py's short-convolution family).
+    qk_norm: "bool | str" = False
 
     def __post_init__(self):
         if self.d_model % self.n_heads:
@@ -125,16 +127,21 @@ class LlamaAttention(nn.Module):
         q = _proj(cfg, cfg.n_heads * hd, "q_proj")(x)
         k = _proj(cfg, cfg.n_kv_heads * hd, "k_proj")(x)
         v = _proj(cfg, cfg.n_kv_heads * hd, "v_proj")(x)
-        if cfg.qk_norm:
+        b, s, _ = x.shape
+        if cfg.qk_norm is True:
             q = rms_norm(q, self.param("q_norm", nn.initializers.ones,
                                        (cfg.n_heads * hd,)), cfg.norm_eps)
             k = rms_norm(k, self.param("k_norm", nn.initializers.ones,
                                        (cfg.n_kv_heads * hd,)),
                          cfg.norm_eps)
-        b, s, _ = x.shape
         q = q.reshape(b, s, cfg.n_heads, hd)
         k = k.reshape(b, s, cfg.n_kv_heads, hd)
         v = v.reshape(b, s, cfg.n_kv_heads, hd)
+        if cfg.qk_norm == "head":
+            q = rms_norm(q, self.param("q_norm", nn.initializers.ones,
+                                       (hd,)), cfg.norm_eps)
+            k = rms_norm(k, self.param("k_norm", nn.initializers.ones,
+                                       (hd,)), cfg.norm_eps)
         if cos is not None:     # None: a model without positions in
             #                     its attention (models/hybrid.py)
             q = apply_rotary(q, cos, sin, positions)

@@ -116,7 +116,9 @@ class PagedKV:
 
     k_flat/v_flat: (N_flat, Hkv, D) — the shared page pool, flattened to
       token rows; N_flat = (n_pages [+ trash]) * page_size. Every
-      sequence in the batch reads/writes the SAME pool.
+      sequence in the batch reads/writes the SAME pool. Heads narrower
+      than 128 lanes lie packed, (N_flat, rows, 128): `packed_kv_shape`
+      (paged_cached_attention tells the two apart by the last axis).
     page_table: (B, P) int32 — page ids backing each sequence, in order;
       logical position p of row b lives at flat row
       page_table[b, p // page_size] * page_size + p % page_size.
@@ -281,23 +283,40 @@ class LayerCache(NamedTuple):
     by_slot: bool = False
 
 
+def packed_kv_shape(n_kv_heads: int, head_dim: int) -> "tuple[int, int]":
+    """A token's K (or V) as a page pool holds it. Heads of 128 lanes or
+    more lie a head a row, `(n_kv_heads, head_dim)`. Narrower heads
+    (`head_dim` dividing 128) are PACKED: 128 // head_dim of them side
+    by side in one 128-lane row, in head order, the last row filled up
+    with zero heads: `(ceil(n_kv_heads / pack), 128)`. On the chip a
+    row of fewer than 128 lanes is padded to them in HBM and the
+    decode kernel cannot copy out of it, so an unpacked pool of narrow
+    heads costs its padding and a padded copy of itself a call; a
+    packed pool is held as it is counted and the kernel's view of it is
+    the pool (ops/pallas/paged_attention.py)."""
+    pack = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return (-(-n_kv_heads // pack), pack * head_dim)
+
+
 def kv_cache_spec(model) -> "list[LayerCache]":
     """What a model caches, one `LayerCache` a layer. A model says so
     itself (`paged_cache_spec()`); every other decoder of the zoo caches
-    K and V of (n_kv_heads, head_dim) a token a layer. Three kinds so
-    far: `PagedKV` and `PagedLatent`, indexed by token through the page
-    table, and `SlotState`, a fixed-size recurrent state a sequence,
-    indexed by slot (models/hybrid.py's linear layers). The serving
-    engine builds its pools from this list; a model with a `by_slot`
-    layer is refused prefix caching and speculation there by name
-    (a prefix would be a state snapshot, a rejected proposal a
-    rollback), and `get_stats()` reports `state_bytes_per_slot`,
-    `decode_state_rows_window` and `decode_state_rows_live` for it."""
+    K and V of `packed_kv_shape(n_kv_heads, head_dim)` a token a layer.
+    Three kinds so far: `PagedKV` and `PagedLatent`, indexed by token
+    through the page table, and `SlotState`, a fixed-size state a
+    sequence, indexed by slot (models/hybrid.py: a linear-attention
+    layer's recurrent state and convolution tail, a short-convolution
+    layer's last inputs). The serving engine builds its pools from this
+    list; a model with a `by_slot` layer is refused prefix caching and
+    speculation there by name (a prefix would be a state snapshot, a
+    rejected proposal a rollback), and `get_stats()` reports
+    `state_bytes_per_slot`, `decode_state_rows_window` and
+    `decode_state_rows_live` for it."""
     own = getattr(model, "paged_cache_spec", None)
     if own is not None:
         return own()
     c = model.cfg
-    kv = (c.n_kv_heads, c.head_dim)
+    kv = packed_kv_shape(c.n_kv_heads, c.head_dim)
     return [LayerCache(PagedKV, (kv, kv), (c.dtype, c.dtype))] * c.n_layers
 
 
@@ -376,11 +395,16 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     n_pages_per_seq = page_table.shape[1]
     L = n_pages_per_seq * page_size
 
-    # a pool laid out for more KV heads than the layer has (a model
-    # whose head count fills no whole 8-row tile declares it rounded
-    # up, so that the decode kernel's view of the pool is a bitcast
-    # and not a copy of it a call): the extra heads are zeros at the end
-    hkv, extra = k.shape[2], k_flat.shape[1] - k.shape[2]
+    # a pool row holds `pack` heads side by side (packed_kv_shape: 1
+    # unless heads are narrower than 128 lanes), and the pool may be
+    # laid out for more KV heads than the layer has (a last packed row
+    # filled up; a model whose head count fills no whole 8-row tile
+    # declares it rounded up, so that the decode kernel's view of the
+    # pool is a bitcast and not a copy of it a call): the extra heads
+    # are zeros at the end
+    pool_row = k_flat.shape[1:]
+    pack = pool_row[1] // d
+    hkv, extra = k.shape[2], pool_row[0] * pack - k.shape[2]
     k_new, v_new = k, v
     if extra:
         pad = ((0, 0), (0, 0), (0, extra), (0, 0))
@@ -389,9 +413,9 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # scatter the new tokens' k/v into their flat pool rows
     flat_pos = cache.flat_rows(positions)                     # (B, S)
     k_flat = k_flat.at[flat_pos.reshape(-1)].set(
-        k_new.astype(k_flat.dtype).reshape(b * s, *k_new.shape[2:]))
+        k_new.astype(k_flat.dtype).reshape(b * s, *pool_row))
     v_flat = v_flat.at[flat_pos.reshape(-1)].set(
-        v_new.astype(v_flat.dtype).reshape(b * s, *v_new.shape[2:]))
+        v_new.astype(v_flat.dtype).reshape(b * s, *pool_row))
     new_lengths = jnp.maximum(lengths, positions[:, -1] + 1)
 
     if cache.fresh \
@@ -436,10 +460,8 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     gather_idx = (page_table[:, :, None] * page_size
                   + jnp.arange(page_size)[None, None, :]
                   ).reshape(b, L)                             # (B, L)
-    ck = k_flat[gather_idx]                                   # (B,L,Hkv,D)
-    cv = v_flat[gather_idx]
-    if extra:
-        ck, cv = ck[:, :, :hkv], cv[:, :, :hkv]
+    ck = k_flat[gather_idx].reshape(b, L, -1, d)[:, :, :hkv]  # (B,L,Hkv,D)
+    cv = v_flat[gather_idx].reshape(b, L, -1, d)[:, :, :hkv]
     out = _attend_cached(q, ck, cv, positions, new_lengths, scale)
     return out, PagedKV(k_flat, v_flat, page_table, new_lengths,
                         page_size)

@@ -10,7 +10,9 @@ strength beta_t:
     o_t = S_t q_t
 
 q and k are L2-normalised over the head's d_k (q also times d_k^-1/2),
-both after a depthwise causal convolution of width K and a SiLU;
+both after a depthwise causal convolution of width K and a SiLU
+(`causal_conv`, which the short-convolution layer of models/hybrid.py
+shares, without the SiLU);
 alpha_t = exp(g_t), g_t = -exp(A_log) softplus(a_t + dt_bias);
 beta_t = sigmoid(b_t), times 2 where negative eigenvalues are allowed.
 
@@ -78,11 +80,13 @@ def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
-                n_new: Optional[jax.Array] = None
+                n_new: Optional[jax.Array] = None,
+                activation=jax.nn.silu
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal convolution then SiLU. u (B, S, C) new inputs,
+    """Depthwise causal convolution, then `activation` (None: nothing;
+    models/hybrid.py's short-convolution layer). u (B, S, C) new inputs,
     w (K, C) with w[0] on the current token, tail (B, K - 1, C) the
-    inputs before them (None: zeros). Returns (SiLU(conv) (B, S, C) in
+    inputs before them (None: zeros). Returns (the result (B, S, C) in
     u's dtype, the new tail): the K - 1 inputs up to each row's true
     length `n_new` (B,) (None: S), so a row with no real token keeps
     its tail."""
@@ -99,7 +103,9 @@ def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
     else:
         idx = n_new[:, None] + jnp.arange(k - 1)[None, :]       # (B, K-1)
         new_tail = jnp.take_along_axis(cat, idx[:, :, None], axis=1)
-    return jax.nn.silu(out).astype(u.dtype), new_tail
+    if activation is not None:
+        out = activation(out)
+    return out.astype(u.dtype), new_tail
 
 
 def _heads_last(state: jax.Array, h: int) -> jax.Array:

@@ -66,7 +66,8 @@ def expert_capacity(n_tokens: int, n_experts: int, k: int,
 
 def route(router_logits: jax.Array, k: int, routing: str = "topk_softmax",
           norm_topk_prob: bool = False,
-          select_bias: Optional[jax.Array] = None, scale: float = 1.0):
+          select_bias: Optional[jax.Array] = None, scale: float = 1.0,
+          norm_eps: float = 0.0):
     """router_logits: (G, E). Returns float32 weights (G, k) and expert
     indices (G, k).
 
@@ -76,8 +77,8 @@ def route(router_logits: jax.Array, k: int, routing: str = "topk_softmax",
     "sigmoid_bias" (aux-loss-free balancing): scores s = sigmoid(logits);
     the k experts with the largest s + `select_bias` (E,) are selected,
     the bias entering the selection only; the weights are the selected
-    experts' s, divided by their sum if `norm_topk_prob`, times
-    `scale`."""
+    experts' s, divided by their sum plus `norm_eps` if
+    `norm_topk_prob`, times `scale`."""
     logits = router_logits.astype(jnp.float32)
     if routing == "sigmoid_bias":
         scores = jax.nn.sigmoid(logits)
@@ -86,7 +87,7 @@ def route(router_logits: jax.Array, k: int, routing: str = "topk_softmax",
         top_idx = jax.lax.top_k(biased, k)[1]
         weights = jnp.take_along_axis(scores, top_idx, axis=-1)
         if norm_topk_prob:
-            weights = weights / weights.sum(-1, keepdims=True)
+            weights = weights / (weights.sum(-1, keepdims=True) + norm_eps)
         return weights * scale, top_idx
     if routing == "topk_softmax":
         top_logits, top_idx = jax.lax.top_k(logits, k)

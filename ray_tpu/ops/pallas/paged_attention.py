@@ -19,8 +19,13 @@ does work only for the pages the sequence has:
   * the pool is viewed as `(pages, page_size * Hkv, D)`: a token's
     heads are consecutive rows, which on the chip is the layout the pool
     already has (Hkv a multiple of the 8-row tile and D of the 128
-    lanes: a bitcast, no copy; a narrower head or another head count
-    costs a copy of the pool a call). All heads go
+    lanes: a bitcast, no copy; another head count costs a copy of the
+    pool a call). Heads narrower than 128 lanes come PACKED, `pack` of
+    them side by side in a pool row of 128 lanes
+    (ops/attention.py:packed_kv_shape), and the same plan runs with a
+    pool row standing for a KV head: each query head is spread into
+    the lanes of its KV head with zeros beside it, and its own lanes
+    are cut out of the result. All heads go
     through ONE product a block: `q (Hq, D) x block (T * Hkv, D)^T`
     gives every query head against every (token, kv head) row and the
     mask keeps the columns of the head's own group; `P x V` is the
@@ -202,18 +207,28 @@ def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
     pages_per_block is for the microbenchmark and the tests; callers
     leave it to `choose_pages_per_block`. Returns (S, Hq, D)."""
     s_n, hq, d_model = q.shape
-    n_flat, hkv, _ = k_flat.shape
+    n_flat, hkv, d_pool = k_flat.shape
     assert n_flat % page_size == 0, (n_flat, page_size)
-    rep = hq // hkv
     if scale is None:
         scale = d_model ** -0.5
-    # Mosaic refuses a DMA out of an array narrower than a lane tile:
-    # heads under 128 wide are padded with zeros, which costs a copy of
-    # the pool a call (the pool's rows are padded to 128 lanes in HBM
-    # already; the cure is a pool that packs heads into lanes)
-    d = -(-d_model // 128) * 128
-    if d != d_model:
-        pad = ((0, 0), (0, 0), (0, d - d_model))
+    # a packed pool (ops/attention.py:packed_kv_shape): a pool row is
+    # 128 lanes holding `pack` narrow heads side by side, and the plan
+    # below runs unchanged with a pool row standing for a KV head: a
+    # query head lies in the lanes of its KV head with zeros beside it,
+    # so its scores are exact (the matrix unit is bound by loading the
+    # block, the zeros' products are free), and of the result's lanes
+    # its own are taken
+    pack = d_pool // d_model
+    if pack > 1:
+        q = _spread_over_lanes(q, hkv, pack)
+    rep = hq // hkv
+    # an unpacked pool of heads under 128 wide (a caller of its own:
+    # the engine's pools are packed): Mosaic refuses a DMA out of an
+    # array narrower than a lane tile, so q, K and V are padded with
+    # zeros, which costs a copy of the pool a call
+    d = -(-d_pool // 128) * 128
+    if d != d_pool:
+        pad = ((0, 0), (0, 0), (0, d - d_pool))
         q, k_flat, v_flat = (jnp.pad(x, pad) for x in (q, k_flat, v_flat))
     P = page_table.shape[1]
     if qpos is None:
@@ -256,4 +271,26 @@ def paged_decode_attention(q, k_flat, v_flat, page_table, lengths,
     )(page_table.reshape(-1), lengths, jnp.asarray(qpos, jnp.int32), q,
       k_flat.reshape(n_pages, page_rows, d),
       v_flat.reshape(n_pages, page_rows, d))
+    if pack > 1:
+        return _own_lanes(out, hkv, pack)
     return out[..., :d_model]
+
+
+def _spread_over_lanes(q, rows: int, pack: int):
+    """q (S, Hq, D) -> (S, Hq, pack * D) for a packed pool of `rows`
+    rows a token: the query heads of KV head h (row h // pack, place
+    h % pack) lie at lanes [place * D, (place + 1) * D), zeros
+    elsewhere."""
+    s_n, hq, d = q.shape
+    q = q.reshape(s_n, rows, pack, hq // (rows * pack), 1, d)
+    place = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+    return (q * place).reshape(s_n, hq, pack * d)
+
+
+def _own_lanes(out, rows: int, pack: int):
+    """The inverse cut of `_spread_over_lanes` on the kernel's result
+    (S, Hq, pack * D): each head's own D lanes."""
+    s_n, hq, wide = out.shape
+    d = wide // pack
+    out = out.reshape(s_n, rows, pack, hq // (rows * pack), pack, d)
+    return jnp.einsum("srjgjd->srjgd", out).reshape(s_n, hq, d)

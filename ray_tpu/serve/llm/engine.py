@@ -8,16 +8,19 @@ paged KV, streaming) — re-designed TPU-first:
   (slots, pages_per_slot) page table. A pool's arrays, their trailing
   shapes and dtypes come from the model's cache spec, one entry a LAYER
   (ops/attention.py:kv_cache_spec): K and V of (n_kv_heads, head_dim)
-  for the Llama, Mixtral and GPT-2 families, one latent row for a
-  latent-attention model, both indexed by token through the page table;
-  and, for a linear-attention layer, a fixed recurrent state a SLOT,
+  for the Llama, Mixtral and GPT-2 families (heads narrower than 128
+  lanes packed side by side in 128-lane rows: packed_kv_shape), one
+  latent row for a latent-attention model, both indexed by token
+  through the page table; and, for a linear-attention or a
+  short-convolution layer, a fixed-size state a SLOT,
   (slots + 1, *trailing), that no page knows of (models/hybrid.py).
   Pages, page table, allocator and windows cover the paged layers and
   do not know which kind they hold. A model with slot state refuses
   prefix caching and speculation by name (a prefix would be a state
   snapshot, a rejected proposal a rollback), and get_stats() adds
   state_bytes_per_slot, decode_state_rows_window and
-  decode_state_rows_live beside kv_bytes_per_token (paged layers only).
+  decode_state_rows_live beside kv_bytes_per_token (paged layers only,
+  the bytes a token takes as the pools are laid out).
   A slot reserves the pages its prompt + budget need at admission.
   Static shapes, so the decode step compiles once per power-of-two
   page window.

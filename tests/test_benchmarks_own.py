@@ -275,7 +275,82 @@ def test_a_metric_of_the_engine_threads_time_reads_its_rows(name):
     assert entry["layer"].startswith(
         "service" if name == "consumer_loop_cpu_share"
         else "engine host loop")
-    without = dict(whole, per_layer=[m for m in whole["per_layer"]
-                                     if m["name"] not in _NEW_IN_PR_37])
-    assert whole["per_layer"][:len(without["per_layer"])] \
-        == without["per_layer"], "new entries go behind the accepted ones"
+    # behind the entries accepted before them (a later PR's entries go
+    # behind these in turn)
+    first = min(i for i, m in enumerate(whole["per_layer"])
+                if m["name"] in _NEW_IN_PR_37)
+    assert not {m["name"] for m in whole["per_layer"][:first]} \
+        & set(_NEW_IN_PR_37), "new entries go behind the accepted ones"
+    assert {m["name"] for m in whole["per_layer"][first:first + 9]} \
+        == set(_NEW_IN_PR_37)
+
+
+_NEW_IN_PR_40 = {"expert_matmul_roofline.lfm2moe": "experts",
+                 "paged_kernel_roofline.packed": "paged_kernel",
+                 "decode_step_roofline.lfm2moe": "step"}
+_LFM2_CELL = "lfm2moe24b_decode_sat"
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_40))
+def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
+        name):
+    """Each file names a reader module with a `read` that takes the
+    file's arguments, a decode program the engine has and, where it
+    sums a kernel's time, a kernel the program calls by that name; its
+    entry lists the one cell, behind every accepted entry."""
+    import inspect
+    import json
+    import re
+    from benchmarks import run as runmod
+    from ray_tpu.ops.pallas import paged_attention
+    from ray_tpu.serve.llm.engine import LLMEngine
+    with open(os.path.join(_BENCH, "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    readers = os.path.join(_BENCH, "readers")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    read = importlib.import_module(spec["reader"]).read
+    accepted = inspect.signature(read).parameters
+    assert set(spec["args"]) <= set(accepted), (name, spec["args"])
+    assert spec["args"]["what"] == _NEW_IN_PR_40[name]
+    assert re.search(spec["args"]["module_re"],
+                     "jit_" + LLMEngine._decode_paged_step.__name__)
+    kernel = spec["args"].get("name_re")
+    if _NEW_IN_PR_40[name] == "paged_kernel":
+        assert re.search(kernel,
+                         paged_attention.paged_decode_attention.__name__)
+    elif _NEW_IN_PR_40[name] == "experts":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        assert re.search(kernel, gmm.__name__)
+    else:
+        assert kernel is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == [_LFM2_CELL]
+    assert whole["workloads"][-1]["name"] == _LFM2_CELL
+    assert whole["configs"][-1]["name"] == whole["workloads"][-1]["config"]
+    names = [m["name"] for m in whole["per_layer"]]
+    assert set(names[-3:]) == set(_NEW_IN_PR_40)
+
+
+def test_the_lfm2moe_cell_is_in_what_every_saturated_serve_cell_reports():
+    """Every per-layer list that names the two saturated cells before
+    it names this one too, at its end; the expert counters' and the
+    slot state's lists as well; and no list of another model's cost
+    arithmetic does."""
+    from benchmarks import run as runmod
+    whole = runmod.load_manifest()
+    for m in whole["end_to_end"] + whole["per_layer"]:
+        w = m.get("workloads", [])
+        if {"sarvam105b_decode_sat", "olmohybrid7b_decode_sat"} <= set(w):
+            assert w[-1] == _LFM2_CELL, m["name"]
+    by_name = {m["name"]: m for m in whole["per_layer"]}
+    for name in ("moe_dev_share", "moe_expert_load_max_over_mean",
+                 "moe_pad_row_share", "decode_live_state_share"):
+        assert by_name[name]["workloads"][-1] == _LFM2_CELL, name
+    for name in ("paged_kernel_roofline", "decode_step_roofline",
+                 "expert_matmul_roofline", "decode_step_roofline.moe",
+                 "expert_matmul_roofline.share", "latent_kernel_roofline",
+                 "gdn_kernel_roofline", "paged_kernel_roofline.hybrid",
+                 "moe_local_assignment_share"):
+        assert _LFM2_CELL not in by_name[name]["workloads"], name

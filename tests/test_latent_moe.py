@@ -357,29 +357,42 @@ def test_a_sliced_vocabulary_stays_inside_the_slice():
 
 
 # (i) the other families are what they were -------------------------------
-# sha256 of the lowered text of the engine's decode program (debug
-# shapes, 4 slots, window 2 pages), taken on the commit before the pools
-# followed the model's cache spec
+# sha256 of the lowered text of the engine's decode program (4 slots,
+# window 2 pages). "llama-wide" has heads of 128 and lowers, letter for
+# letter, what the commit before PR 40 lowered: heads that fill the 128
+# lanes keep the path they had. The debug models' heads are 16 wide:
+# since PR 40 their pool rows hold eight heads side by side
+# (ops/attention.py:packed_kv_shape), which is a pad and three reshapes
+# a layer more than the programs PR 31 pinned (860 and 875 operations);
+# they are pinned as they lower now.
 PARENT_DECODE_TEXT = {
+    "llama-wide":
+        "56b36e53317b79016c6b708bf35854790157a00b096b0090d208454b138e9f8d",
     "llama-debug":
-        "9242cbccbac728e52e3d02d1140eb32a8315266ffdd555214009b304e365f012",
+        "c534f6c0fb8b17a7a46432eb9362b139fe398ff88adc32c995473d52ae42beaa",
     "gpt2-debug":
-        "c284d74bf1d5307e246362f4cce31792c2f5eb42d5abd2d2fa8886fa54503ef1",
+        "b0d5eb74347c444ed17f7fb7489b35ff382a197ae81e043a9791538afc6d3810",
 }
 
 
 def _decode_text(name: str):
-    model = get_model(name)
+    from ray_tpu.models import Llama, LlamaConfig
+    from ray_tpu.ops.attention import packed_kv_shape
+    model = Llama(LlamaConfig(
+        vocab_size=256, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        d_ff=128, max_seq_len=128)) if name == "llama-wide" \
+        else get_model(name)
     params = model.init_params(jax.random.PRNGKey(0))
     eng = _engine(model, params, prefill_buckets=(16, 32))
     try:
         c = model.cfg
-        kv = (c.n_kv_heads, c.head_dim)
+        kv = packed_kv_shape(c.n_kv_heads, c.head_dim)
+        assert kv == ((c.n_kv_heads, c.head_dim) if c.head_dim == 128
+                      else (1, 128))
         assert kv_cache_spec(model) == [
             (PagedKV, (kv, kv), (c.dtype, c.dtype), False)] * c.n_layers
         assert all(len(layer) == 2 and layer[0].shape == layer[1].shape
-                   == (33 * 8, c.n_kv_heads, c.head_dim)
-                   for layer in eng._pools)
+                   == (33 * 8, *kv) for layer in eng._pools)
         s = 5
         return jax.jit(eng._decode_paged_impl,
                        static_argnames=("window_pages",)).lower(
@@ -402,12 +415,14 @@ def test_mixtral_lowers_the_decode_program_it_had_plus_one_counter():
     """The sixth counter (`moe_routed_assignments` = rows x k) is one
     multiply by a constant and one more operand of the counters' stack
     in each of the two expert layers; nothing else of the program
-    moved (1 209 operations on the parent commit)."""
+    moved (1 209 operations on the parent commit), until PR 40 packed
+    the pool rows of its 16-wide heads: seven operations a layer more
+    (two constants and a pad for K and for V, the reshapes)."""
     ops = collections.Counter(re.findall(r"(?:stablehlo|chlo)\.\w+",
                                          _decode_text("mixtral-debug")))
-    assert sum(ops.values()) == 1209 + 6
+    assert sum(ops.values()) == 1209 + 6 + 14
     assert (ops["stablehlo.multiply"], ops["stablehlo.constant"],
-            ops["stablehlo.broadcast_in_dim"]) == (43, 215, 331)
+            ops["stablehlo.broadcast_in_dim"]) == (43, 215 + 4, 331)
 
 
 def test_get_model_builds_the_published_and_the_debug_shape():

@@ -358,3 +358,45 @@ def test_paged_decode_over_a_pool_laid_out_for_32_heads_compiles(chip):
         sds((rows, pages), jnp.int32), sds((rows,), jnp.int32)).compile()
     _assert_mosaic(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("width", ["lfm2", "llama1b"])
+def test_a_packed_pool_of_narrow_heads_is_held_and_read_as_it_is(
+        chip, width, monkeypatch):
+    """Heads of 64 packed two to a 128-lane row
+    (ops/attention.py:packed_kv_shape): a decode step's write into the
+    pool and the paged kernel over it, at LFM2-24B-A2B's full layers
+    (32 query over 8 KV heads, 129 rows, a pool of 262 144 tokens) and
+    at Llama-3.2-1B's heads. The pool takes its counted bytes in HBM
+    (256 MiB an array at 4 rows of 128 lanes a token: no padding to
+    lanes or to 8-row tiles), is updated in place, and the kernel's
+    view of it is the pool: no temporary of its size."""
+    from ray_tpu.ops.attention import (PagedKV, packed_kv_shape,
+                                       paged_cached_attention)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hq, hkv, d = {"lfm2": (32, 8, 64), "llama1b": WIDTHS["llama1b"]}[width]
+    rows, ps, pages = 129, 64, 64
+    n_flat = 262144 + ps
+    row = packed_kv_shape(hkv, d)
+    assert row == (4, 128)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(q, k, v, k_flat, v_flat, table, lengths):
+        out, new = paged_cached_attention(
+            q, k, v, PagedKV(k_flat, v_flat, table, lengths, ps),
+            lengths[:, None])
+        return out, new.k_flat, new.v_flat
+    pool = sds((n_flat, *row), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        sds((rows, 1, hq, d), jnp.bfloat16),
+        sds((rows, 1, hkv, d), jnp.bfloat16),
+        sds((rows, 1, hkv, d), jnp.bfloat16), pool, pool,
+        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    mem = compiled.memory_analysis()
+    counted = 2 * n_flat * 4 * 128 * 2
+    assert counted <= mem.argument_size_in_bytes < counted + 4 * 2 ** 20
+    assert mem.alias_size_in_bytes >= counted
+    assert mem.temp_size_in_bytes < 2 ** 20
