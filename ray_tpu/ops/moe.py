@@ -115,6 +115,31 @@ def router_aux(router_logits: jax.Array, top_idx: jax.Array) -> MoEAux:
 
 # rows of a grouped matmul come in multiples of the kernel's row tile
 _ROW_TILE = 128
+# the kernel's k and n tiles are cut here where the cut divides the side
+_SIDE_TILE = 1024
+# and are at most this wide where it does not: 1 536 x 1 024 in bf16 is
+# 3 MB a buffer of the weights' tile, two of them in flight, under the
+# 16 MiB of VMEM the kernel is given
+_SIDE_TILE_MAX = 1536
+
+
+def _side_tile(side: int) -> int:
+    if side <= _SIDE_TILE or side % _SIDE_TILE == 0:
+        return min(side, _SIDE_TILE)
+    return next((t for t in range(_SIDE_TILE_MAX, 0, -_ROW_TILE)
+                 if side % t == 0), _SIDE_TILE)
+
+
+def gmm_tiling(k: int, n: int) -> tuple:
+    """(row, k, n) tiles of megablox `gmm` for experts of k x n, from
+    the shape alone, each side by itself: a side up to 1 024 is one
+    tile and a multiple of 1 024 is cut at 1 024 (the cut every side
+    had before PR 41: OLMoE's 2 048 x 1 024 and sarvam-105b's 4 096 x
+    2 048 keep their programs); any other side gets its largest divisor
+    that is a multiple of 128 and at most 1 536 (LFM2's 1 536: whole),
+    and only a side with no such divisor is cut at 1 024 with a ragged
+    last tile (toy widths)."""
+    return _ROW_TILE, _side_tile(k), _side_tile(n)
 
 
 def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
@@ -123,19 +148,25 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
     group_sizes: (E,) int32. Row i of group e comes back as xs[i] @ w[e];
     rows behind the last group hold nothing meaningful.
 
-    On the TPU this is megablox's `gmm` (jax.experimental.pallas.ops.tpu):
-    at OLMoE's widths it read 1.22 ms for a decode step's three matmuls
-    (65 rows x 8, 64 experts; 0.98 ms is the weights at the chip's
-    bandwidth) where XLA's own lowering of `jax.lax.ragged_dot` read 2.08,
-    and 3.37 against 4.03 ms at 2 048 rows x 8 (PERF.md, PR 26). Off the
-    TPU `ragged_dot` is the reference lowering."""
+    On the TPU this is megablox's `gmm` (jax.experimental.pallas.ops.tpu)
+    at the tiles `gmm_tiling` derives from k and n, whole tiles wherever
+    the side has a divisor: a tile that hangs over the matrix (n) costs
+    the whole tile's product for part of its bytes, and one that hangs
+    over k is masked, both operands through float32 and a select, before
+    a whole product. One layer's three matmuls at a decode step's rows,
+    64 experts, bf16 (`python -m tools.gmm_microbench`, PERF.md, PR 41):
+    LFM2's 2 048 x 1 536 at 128 rows x 4 read 1.98 ms cut at 1 024 and
+    1.75 ms on tiles of 1 024 x 1 536 (gate, up) and 1 536 x 1 024
+    (down), 1.47 ms being the weights at the chip's bandwidth; OLMoE's
+    2 048 x 1 024 at 65 rows x 8 read 1.21 ms (0.98 ms the weights),
+    where XLA's own lowering of `jax.lax.ragged_dot` reads 1.43, and
+    3.87 at LFM2's. Off the TPU `ragged_dot` is the reference lowering."""
     if jax.default_backend() != "tpu" and not interpret:
         return jax.lax.ragged_dot(xs, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm  # noqa: PLC0415
     _e, k, n = w.shape
     return gmm(xs, w, group_sizes, preferred_element_type=xs.dtype,
-               tiling=(_ROW_TILE, min(k, 1024), min(n, 1024)),
-               interpret=interpret)
+               tiling=gmm_tiling(k, n), interpret=interpret)
 
 
 def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,

@@ -163,18 +163,61 @@ class TestMoEDropless:
         assert not np.asarray(out[20:]).any()
         assert stats.tolist()[:3] == [40, 20, 12]
 
-    def test_tpu_kernel_matches_the_reference_lowering(self, rng):
+    @pytest.mark.parametrize("k,n,sizes", [
+        (64, 32, [100, 0, 57, 43]),
+        (1536, 2048, [0, 77]),
+        (2048, 1536, [91, 0]),
+    ], ids=["toy", "lfm2_down", "lfm2_up"])
+    def test_tpu_kernel_matches_the_reference_lowering(self, rng, k, n,
+                                                       sizes):
         """The TPU branch of grouped_matmul (megablox) in interpret
         mode against jax.lax.ragged_dot, on the rows that belong to a
-        group; what lies behind the last group is nobody's."""
+        group; what lies behind the last group is nobody's. At LFM2's
+        expert widths the tiles are `gmm_tiling`'s, one of them 1 536
+        wide."""
         from ray_tpu.ops.moe import grouped_matmul
-        xs = jnp.asarray(rng.randn(256, 64), jnp.float32)
-        w = jnp.asarray(rng.randn(4, 64, 32), jnp.float32)
-        sizes = jnp.asarray([100, 0, 57, 43], jnp.int32)
+        live = sum(sizes)
+        xs = jnp.asarray(rng.randn(-(-live // 128) * 128, k), jnp.float32)
+        w = jnp.asarray(rng.randn(len(sizes), k, n), jnp.float32)
+        sizes = jnp.asarray(sizes, jnp.int32)
         want = grouped_matmul(xs, w, sizes)
         got = grouped_matmul(xs, w, sizes, interpret=True)
-        np.testing.assert_allclose(np.asarray(got[:200]),
-                                   np.asarray(want[:200]), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(got[:live]),
+                                   np.asarray(want[:live]),
+                                   atol=1e-4 * (k / 64) ** 0.5)
+
+    @pytest.mark.parametrize("k,n,tiles", [
+        # a multiple of 1 024 is cut at 1 024, as before PR 41: OLMoE's
+        # and sarvam-105b's programs are the programs they were
+        (2048, 1024, (1024, 1024)), (1024, 2048, (1024, 1024)),
+        (4096, 2048, (1024, 1024)), (2048, 4096, (1024, 1024)),
+        # LFM2's 1 536: one whole tile, no ragged or masked one
+        (2048, 1536, (1024, 1536)), (1536, 2048, (1536, 1024)),
+        # the largest divisor that is a multiple of 128, up to 1 536
+        (2560, 1408, (1280, 1408)), (11776, 2048, (512, 1024)),
+        # up to 1 024 a side is one tile; no such divisor: the old cut
+        (64, 32, (64, 32)), (768, 1000, (768, 1000)),
+        (1100, 2048, (1024, 1024)), (2048, 1100, (1024, 1024)),
+    ])
+    def test_gmm_tiles_follow_the_shape(self, k, n, tiles):
+        from ray_tpu.ops.moe import gmm_tiling
+        assert gmm_tiling(k, n) == (128, *tiles)
+
+    @pytest.mark.parametrize("side,want", [
+        (1536, [512, 768, 1024, 1536]), (2048, [1024, 2048]),
+        (1024, [1024]), (64, [64]), (1100, [1024, 1100]),
+    ])
+    def test_microbenchmark_tries_the_cut_the_divisors_and_the_whole(
+            self, side, want):
+        from tools.gmm_microbench import candidate_tiles
+        assert candidate_tiles(side, [512, 768, 1536]) == want
+
+    def test_microbenchmark_draws_distinct_experts_a_row(self):
+        from tools.gmm_microbench import draw_group_sizes
+        sizes = draw_group_sizes(np.random.RandomState(0), 128, 64, 4, 0.25)
+        assert sizes.sum() == 128 * 4 and sizes.max() <= 128
+        every = draw_group_sizes(np.random.RandomState(0), 16, 4, 4, 3.0)
+        assert every.tolist() == [16] * 4
 
     def test_one_program_for_any_routing(self, rng):
         """Static shapes: the same compiled program serves an even

@@ -157,11 +157,13 @@ def test_dropless_expert_layer_compiles_for_v5e(chip, rows, monkeypatch):
     the TPU branch of `grouped_matmul`) at the row counts of a decode
     step and of a prefill group, and at 16 MHA heads of 128 (rep 1) the
     paged kernel compiles too."""
-    from ray_tpu.ops.moe import moe_dropless, route
+    from ray_tpu.ops.moe import gmm_tiling, moe_dropless, route
     # the code under test asks which backend it runs on; the compile is
     # for the described chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     e, d, f, k = 64, 2048, 1024, 8
+    # the tiles of before PR 41: the same programs
+    assert gmm_tiling(d, f) == gmm_tiling(f, d) == (128, 1024, 1024)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -285,24 +287,37 @@ def test_latent_attention_copies_no_parameter_in_hbm(chip, form,
         assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
-@pytest.mark.parametrize("rows", [129, 4096], ids=["decode", "prefill"])
-def test_expert_share_layer_compiles_for_v5e(chip, rows, monkeypatch):
+@pytest.mark.parametrize("family,rows", [
+    ("sarvam", 129), ("sarvam", 4096), ("lfm2", 128), ("lfm2", 2048),
+], ids=["decode", "prefill", "lfm2_decode", "lfm2_prefill"])
+def test_expert_share_layer_compiles_for_v5e(chip, family, rows,
+                                             monkeypatch):
     """An expert layer that holds 32 of 128 experts (4 096 x 2 048, 8 a
     token, biased sigmoid routing): assignments to the 96 experts held
     elsewhere go to no group, and the grouped matmuls are Mosaic kernels
-    at the rows of a decode step and of a prefill group."""
-    from ray_tpu.ops.moe import moe_dropless, route
+    at the rows of a decode step and of a prefill group. LFM2-24B-A2B's
+    layer holds all 64 of its experts (2 048 x 1 536, 4 a token): its
+    tiles divide 1 536 (`gmm_tiling`: one 1 536 wide, 3 MB a buffer of
+    the weights' tile) and the compile fits the chip's VMEM; sarvam's
+    are the 1 024 x 1 024 they were."""
+    from ray_tpu.ops.moe import gmm_tiling, moe_dropless, route
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    held, routed, d, f, k = 32, 128, 4096, 2048, 8
+    held, routed, first, d, f, k, scale, up, down = {
+        "sarvam": (32, 128, 32, 4096, 2048, 8, 2.5,
+                   (1024, 1024), (1024, 1024)),
+        "lfm2": (64, 64, 0, 2048, 1536, 4, 1.0,
+                 (1024, 1536), (1536, 1024))}[family]
+    assert (gmm_tiling(d, f), gmm_tiling(f, d)) == ((128, *up),
+                                                    (128, *down))
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     def layer(x, logits, bias, wg, wu, wd, mask):
         weights, idx = route(logits, k, "sigmoid_bias", True,
-                             select_bias=bias, scale=2.5)
+                             select_bias=bias, scale=scale)
         return moe_dropless(x, weights, idx, wg, wu, wd, mask,
-                            first=32, count=held)
+                            first=first, count=held)
 
     text = jax.jit(layer).lower(
         sds((rows, d), jnp.bfloat16), sds((rows, routed), jnp.float32),
