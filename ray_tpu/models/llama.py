@@ -117,6 +117,62 @@ def _proj(cfg: LlamaConfig, features: int, name: str):
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype)
 
 
+class _Kernel(nn.Module):
+    """The kernel of `_proj`'s nn.Dense of the same name, at the same
+    place of the parameter tree, for a caller that multiplies itself."""
+    features: int
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        return self.param("kernel", nn.linear.default_kernel_init,
+                          (x.shape[-1], self.features), self.param_dtype)
+
+
+def _tp_route(cfg: LlamaConfig, shape, matmuls: int):
+    """The mesh for parallel/collective_matmul.py where a sharded train
+    step runs these projections of (B, S, D) activations overlapped with
+    their collective, else None: `_proj`'s plain layers (serve, one
+    device, `tp` of 1)."""
+    if cfg.quant is not None:
+        return None
+    from ..parallel.sharding import tp_matmul_route  # noqa: PLC0415
+    return tp_matmul_route(shape, matmuls)
+
+
+def _column_proj(cfg: LlamaConfig, x, chunks: bool = False,
+                 **features: int):
+    """x through the column-parallel projections `name=features` that
+    share it, in order. On the overlapped route x comes sharded over the
+    sequence and is gathered once for all of them; `chunks` lets each
+    product be the tuple of row chunks the gather delivers, for
+    elementwise ops on the way to `_row_proj`."""
+    mesh = _tp_route(cfg, x.shape, len(features))
+    if mesh is None:
+        return [_proj(cfg, f, name)(x) for name, f in features.items()]
+    from ..parallel.collective_matmul import allgather_matmul  # noqa: PLC0415
+    kernels = [_Kernel(f, cfg.param_dtype, name=name)(x).astype(cfg.dtype)
+               for name, f in features.items()]
+    return allgather_matmul(x.astype(cfg.dtype), kernels, mesh,
+                            chunks=chunks)
+
+
+def _row_proj(cfg: LlamaConfig, x, features: int, name: str):
+    """x (or the chunks `_column_proj` made of it) through the
+    row-parallel projection `name`. On the overlapped route the sum over
+    `tp` comes back sharded over the sequence."""
+    parts = x if isinstance(x, tuple) else (x,)
+    b, _, f = parts[0].shape
+    mesh = _tp_route(cfg, (b, sum(p.shape[1] for p in parts), f), 1)
+    if mesh is None:
+        return _proj(cfg, features, name)(x)
+    from ..parallel.collective_matmul import matmul_reducescatter  # noqa: PLC0415
+    kernel = _Kernel(features, cfg.param_dtype, name=name)(parts[0])
+    return matmul_reducescatter(
+        jax.tree.map(lambda p: p.astype(cfg.dtype), x),
+        kernel.astype(cfg.dtype), mesh)
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
 
@@ -124,9 +180,9 @@ class LlamaAttention(nn.Module):
     def __call__(self, x, cos, sin, cache=None, positions=None):
         cfg = self.cfg
         hd = cfg.head_dim
-        q = _proj(cfg, cfg.n_heads * hd, "q_proj")(x)
-        k = _proj(cfg, cfg.n_kv_heads * hd, "k_proj")(x)
-        v = _proj(cfg, cfg.n_kv_heads * hd, "v_proj")(x)
+        q, k, v = _column_proj(cfg, x, q_proj=cfg.n_heads * hd,
+                               k_proj=cfg.n_kv_heads * hd,
+                               v_proj=cfg.n_kv_heads * hd)
         b, s, _ = x.shape
         if cfg.qk_norm is True:
             q = rms_norm(q, self.param("q_norm", nn.initializers.ones,
@@ -158,7 +214,7 @@ class LlamaAttention(nn.Module):
                                               impl=cfg.attn_impl)
 
         out = out.reshape(b, s, cfg.n_heads * hd)
-        out = _proj(cfg, cfg.d_model, "o_proj")(out)
+        out = _row_proj(cfg, out, cfg.d_model, "o_proj")
         return out, new_cache
 
 
@@ -168,9 +224,10 @@ class LlamaMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = _proj(cfg, cfg.d_ff, "gate_proj")(x)
-        up = _proj(cfg, cfg.d_ff, "up_proj")(x)
-        return _proj(cfg, cfg.d_model, "down_proj")(swiglu(gate, up))
+        gate, up = _column_proj(cfg, x, chunks=True, gate_proj=cfg.d_ff,
+                                up_proj=cfg.d_ff)
+        return _row_proj(cfg, jax.tree.map(swiglu, gate, up), cfg.d_model,
+                         "down_proj")
 
 
 class LlamaBlock(nn.Module):
@@ -247,7 +304,9 @@ class Llama(nn.Module):
             new_cache.append(c)
         final_w = self.param("final_norm", nn.initializers.ones,
                              (cfg.d_model,))
-        x = rms_norm(x, final_w, cfg.norm_eps)
+        # the head multiplies whole sequences, whatever the blocks kept
+        x = constrain_activations(rms_norm(x, final_w, cfg.norm_eps),
+                                  gathered=True)
         if cfg.tie_embeddings:
             # bf16 operands + fp32 accumulation: fp32-quality logits at
             # bf16 MXU speed (casting both sides to fp32 would force slow

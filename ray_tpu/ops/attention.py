@@ -467,6 +467,21 @@ def paged_cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         page_size)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "spec", "causal", "scale"))
+def _sharded_flash(q, k, v, *, mesh, spec, causal, scale):
+    """The flash kernel inside a sharded train step: per device on its
+    share of batch and heads (GQA groups stay whole: q and kv heads split
+    over tp at the same boundaries). Jitted so that a model's layers
+    share one trace of the kernels, their backward and the shard_map
+    around them (two thirds of the training cell's 8 s of tracing were
+    here, once a layer); the compiled program is the same."""
+    from .pallas.flash_attention import flash_attention  # noqa: PLC0415
+    flash = functools.partial(flash_attention, causal=causal, scale=scale)
+    return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          *, causal: bool = True,
                          segment_ids: Optional[jax.Array] = None,
@@ -481,17 +496,12 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # no fallback: a kernel Mosaic refuses fails the caller's compile
         from ..parallel.sharding import attention_shard_spec  # noqa: PLC0415
         from .pallas.flash_attention import flash_attention  # noqa: PLC0415
-        flash = functools.partial(flash_attention, causal=causal,
-                                  scale=scale)
         sharded = attention_shard_spec(k.shape)
         if sharded is None:
-            return flash(q, k, v)
-        # inside a sharded train step the kernel runs per device on its
-        # share of batch and heads (GQA groups stay whole: q and kv
-        # heads split over tp at the same boundaries)
+            return flash_attention(q, k, v, causal=causal, scale=scale)
         mesh, spec = sharded
-        return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=False)(q, k, v)
+        return _sharded_flash(q, k, v, mesh=mesh, spec=spec, causal=causal,
+                              scale=scale)
     if impl == "dpa":
         # jax.nn.dot_product_attention: XLA's own fused attention,
         # which on TPU can lower to the compiler's flash kernel —

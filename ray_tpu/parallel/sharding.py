@@ -15,7 +15,12 @@ Conventions for decoder transformers (ray_tpu/models/*):
   norms      (d,)              -> P(None)             replicated
 Activations: batch over ("dp","fsdp"), sequence over "sp", model dim
 unsharded (tp acts on weights; XLA keeps activations tp-sharded between the
-column/row pair without materializing the full hidden).
+column/row pair without materializing the full hidden). Where the mesh has
+tp > 1 (and no sp) and the sequence divides, the residual stream between a
+block's projections is sharded over the sequence on "tp" as well, and the
+projections run as collective_matmul.py's overlapped pair
+(tp_matmul_route): the sum over tp is then a reduce-scatter and an
+all-gather, each hidden behind its own matmul.
 """
 from __future__ import annotations
 
@@ -159,31 +164,67 @@ def shard_pytree(params, mesh: Mesh, rules: Optional[ShardingRules] = None):
 # pin, XLA propagates the embed table's fsdp sharding of d_model into the
 # hidden states and the backward pays an involuntary full
 # rematerialization re-sharding them against the batch-sharded residual.
-_ACTIVATION_MESH: "list[Optional[Mesh]]" = [None]
+_ACTIVE: "list[Optional[activation_mesh]]" = [None]
 
 
 class activation_mesh:
     """Context manager: make `mesh` visible to constrain_activations
-    during tracing of a step function."""
+    during tracing of a step function. `tp_overlapped_matmuls` counts the
+    projections that took tp_matmul_route's route while it was open."""
 
     def __init__(self, mesh: Optional[Mesh]):
         self.mesh = mesh
+        self.tp_overlapped_matmuls = 0
 
     def __enter__(self):
-        self._prev = _ACTIVATION_MESH[0]
-        _ACTIVATION_MESH[0] = self.mesh
-        return self.mesh
+        self._prev = _ACTIVE[0]
+        _ACTIVE[0] = self
+        return self
 
     def __exit__(self, *exc):
-        _ACTIVATION_MESH[0] = self._prev
+        _ACTIVE[0] = self._prev
         return False
 
 
-def constrain_activations(x, *, seq_axis: Optional[str] = "sp"):
+def _mesh() -> Optional[Mesh]:
+    return _ACTIVE[0].mesh if _ACTIVE[0] is not None else None
+
+
+def _tp_seq_sharded(mesh: Mesh, shape: Tuple[int, ...]) -> bool:
+    """Whether (B, S, D) activations of this shape live sharded over the
+    sequence on `tp` between a block's projections: `tp` > 1 and the
+    sequence divides. Under `sp` > 1 the sequence is sharded already and
+    stays as it was (a ring over both axes is not built)."""
+    tp = mesh.shape.get("tp", 1)
+    return (len(shape) == 3 and tp > 1 and mesh.shape.get("sp", 1) == 1
+            and shape[1] % tp == 0)
+
+
+def tp_matmul_route(shape: Tuple[int, ...],
+                    matmuls: int = 1) -> Optional[Mesh]:
+    """The mesh on which `matmuls` tensor-parallel projections of (B, S,
+    D) activations should run as parallel/collective_matmul.py's
+    overlapped pair (the sequence sharded over `tp` on the side of the
+    model dim), or None where they stay plain matmuls that the SPMD
+    partitioner sums: outside an activation_mesh context, with `tp` of
+    1, under `sp`, or where the sequence does not divide by `tp`. Chosen
+    from the mesh and the shape alone; each route given is counted."""
+    mesh = _mesh()
+    if mesh is None or not _tp_seq_sharded(mesh, shape):
+        return None
+    _ACTIVE[0].tp_overlapped_matmuls += matmuls
+    return mesh
+
+
+def constrain_activations(x, *, seq_axis: Optional[str] = "sp",
+                          gathered: bool = False):
     """Pin (B, S, D) activations to batch over (dp, fsdp), sequence over
-    sp, model dim replicated — the convention in this module's header. A
-    no-op outside an activation_mesh context (single-device, serve)."""
-    mesh = _ACTIVATION_MESH[0]
+    sp, model dim replicated — the convention in this module's header.
+    Where the blocks' projections take tp_matmul_route's route the
+    residual stream's sequence is over `tp` as well; `gathered` asks for
+    it whole on every `tp` device, as the head multiplies it. A no-op
+    outside an activation_mesh context (single-device, serve)."""
+    mesh = _mesh()
     if mesh is None or getattr(x, "ndim", 0) < 3:
         return x
     data = tuple(a for a in ("dp", "fsdp")
@@ -191,6 +232,8 @@ def constrain_activations(x, *, seq_axis: Optional[str] = "sp"):
                  x.shape[0] % mesh.shape[a] == 0)
     seq = (seq_axis if seq_axis and mesh.shape.get(seq_axis, 1) > 1
            and x.shape[1] % mesh.shape[seq_axis] == 0 else None)
+    if not gathered and _tp_seq_sharded(mesh, x.shape):
+        seq = "tp"
     spec = P(data if data else None, seq)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
@@ -204,7 +247,7 @@ def attention_shard_spec(kv_shape: Tuple[int, ...]):
     cannot be automatically partitioned"): the caller wraps it in
     jax.shard_map with this spec, and each device runs the kernel on its
     own rows and heads."""
-    mesh = _ACTIVATION_MESH[0]
+    mesh = _mesh()
     if mesh is None or mesh.size == 1:
         return None
     return mesh, _clip_to_mesh(P(("dp", "fsdp"), None, "tp", None),

@@ -72,6 +72,15 @@ class SpmdStep:
     mesh: Mesh
     state_shardings: Any
     batch_shardings: Any
+    # what the last trace of step_fn counted (empty until it is traced)
+    traced: Dict[str, int] = dataclasses.field(default_factory=dict)
+
+    @property
+    def tp_overlapped_matmuls(self) -> Optional[int]:
+        """Projections of the traced step that run overlapped with their
+        `tp` collective (parallel/collective_matmul.py): 7 a Llama layer
+        where the mesh has `tp` > 1 and the sequence divides, else 0."""
+        return self.traced.get("tp_overlapped_matmuls")
 
     def __call__(self, state, batch):
         return self.step_fn(state, batch)
@@ -100,9 +109,11 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         return jax.value_and_grad(
             lambda p: loss_fn(p, batch), has_aux=True)(params)
 
+    traced: Dict[str, int] = {}
+
     def raw_step(state: TrainState, batch):
         from ..parallel.sharding import activation_mesh  # noqa: PLC0415
-        with activation_mesh(mesh):
+        with activation_mesh(mesh) as active:
             if accum_steps <= 1:
                 (_loss, metrics), grads = _value_and_grad(state.params,
                                                           batch)
@@ -159,6 +170,7 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                     if "ppl" in metrics and "loss" in metrics:
                         metrics["ppl"] = jnp.exp(
                             jnp.minimum(metrics["loss"], 20.0))
+        traced["tp_overlapped_matmuls"] = active.tp_overlapped_matmuls
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         metrics = dict(metrics)
@@ -200,7 +212,7 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             in_shardings=(state_sh, bshard),
             out_shardings=(state_sh, metric_sh),
             donate_argnums=(0,) if donate_state else ())
-        return state, SpmdStep(step_fn, mesh, state_sh, bshard)
+        return state, SpmdStep(step_fn, mesh, state_sh, bshard, traced)
 
     return init_fn
 
