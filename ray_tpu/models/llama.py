@@ -7,7 +7,9 @@ examples). Design notes:
   * GQA attention, RoPE, RMSNorm, SwiGLU — all bf16 compute, fp32 norms.
   * Pure-functional KV cache (pytree in/out) so the serve engine can jit
     prefill/decode separately with static shapes.
-  * Optional `remat` applies jax.checkpoint per block (HBM <-> FLOPs trade).
+  * Optional `remat` applies jax.checkpoint per block (HBM <-> FLOPs trade);
+    `remat_policy` says what a block keeps: by default the attention
+    half's residuals by name, so that the backward reruns the MLP only.
   * Module names line up with ray_tpu.parallel.sharding DEFAULT_RULES, so
     tp/fsdp PartitionSpecs attach without model surgery.
 """
@@ -19,10 +21,23 @@ from typing import Any, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (rms_norm, apply_rotary, rope_frequencies,
                    cached_attention,
                    multi_head_attention, swiglu)
+from ..ops.attention import ATTN_RESIDUALS, attention_residuals
+
+# the residual stream after attention, x + o_proj(out): with it kept the
+# backward reruns neither o_proj nor the ring that sums it over `tp`
+_ATTN_RESID = "attn_resid"
+# LlamaConfig.remat_policy -> jax.checkpoint's policy
+_REMAT_POLICIES = {
+    "attention": jax.checkpoint_policies.save_only_these_names(
+        *ATTN_RESIDUALS, _ATTN_RESID),
+    "full": None,
+    "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,12 +53,22 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     remat: bool = False
-    # "full": recompute everything in backward (max HBM savings, ~1.33x
-    # FLOPs). "dots": jax.checkpoint saves matmul outputs and
-    # recomputes only the cheap elementwise ops — most of the memory
-    # win at a fraction of the recompute (the >=1B single-chip MFU
-    # lever once grad accumulation keeps micro-batches small).
-    remat_policy: str = "full"
+    # What a rematted block keeps for its backward beside its input:
+    # "attention" (the default): the attention half's residuals, by name
+    #   (_REMAT_POLICIES: q, k and v after the rotation, the attention's
+    #   output and the flash kernel's logsumexp, the residual stream
+    #   after o_proj; 118 MB a layer a chip at Mistral-7B's widths, 2
+    #   rows x 4 096 and tp=2). The backward reruns the norms and the
+    #   MLP only, whose gate / up products (117 MB each at that shape)
+    #   are too large to keep: the most speed that fits where "dots"
+    #   does not.
+    # "full": nothing; the whole forward runs again (~1.33x FLOPs). For
+    #   a caller at the memory limit.
+    # "dots": every matmul output (jax.checkpoint_policies.
+    #   dots_with_no_batch_dims_saveable); only elementwise ops rerun.
+    #   Where micro-batches are small enough (grad accumulation on one
+    #   chip) for the MLP's products to fit.
+    remat_policy: str = "attention"
     dtype: Any = jnp.bfloat16
     # Storage dtype of the big parameter tensors (embeddings + matmul
     # kernels). fp32 default; bf16 halves parameter HBM — the knob that
@@ -77,9 +102,9 @@ class LlamaConfig:
         if self.quant not in (None, "int8"):
             raise ValueError(f"quant={self.quant!r}; valid: None, "
                              f"'int8'")
-        if self.remat_policy not in ("full", "dots"):
+        if self.remat_policy not in _REMAT_POLICIES:
             raise ValueError(f"remat_policy={self.remat_policy!r}; "
-                             f"valid: 'full', 'dots'")
+                             f"valid: {sorted(_REMAT_POLICIES)}")
 
     @property
     def head_dim(self) -> int:
@@ -243,7 +268,7 @@ class LlamaBlock(nn.Module):
         h, new_cache = LlamaAttention(cfg, name="attention")(
             rms_norm(x, attn_norm_w, cfg.norm_eps), cos, sin, cache,
             positions)
-        x = x + h
+        x = checkpoint_name(x + h, _ATTN_RESID)
         x = x + LlamaMLP(cfg, name="mlp")(
             rms_norm(x, mlp_norm_w, cfg.norm_eps))
         return x, new_cache
@@ -277,7 +302,8 @@ class Llama(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          embedding_init=nn.initializers.normal(0.02))
-        from ..parallel.sharding import constrain_activations  # noqa: PLC0415
+        from ..parallel.sharding import (constrain_activations,  # noqa: PLC0415
+                                         count_saved_residuals)
         # Pin the residual stream to batch/sequence sharding right at the
         # embed: the (vocab, d) table is (tp, fsdp)-sharded, and without
         # the pin XLA carries the table's d-sharding into the hiddens and
@@ -291,10 +317,13 @@ class Llama(nn.Module):
         # "layer_{i}/..." under both classes, so one weight pytree serves
         # train and serve.
         if cfg.remat and cache is None:
-            policy = (jax.checkpoint_policies
-                      .dots_with_no_batch_dims_saveable
-                      if cfg.remat_policy == "dots" else None)
-            block_cls = nn.remat(LlamaBlock, policy=policy)
+            block_cls = nn.remat(LlamaBlock,
+                                 policy=_REMAT_POLICIES[cfg.remat_policy])
+            if cfg.remat_policy == "attention":
+                # a block's q and k have x's rows, which is all that
+                # the attention's route is chosen from
+                count_saved_residuals(cfg.n_layers * (1 + len(
+                    attention_residuals(x, x, impl=cfg.attn_impl))))
         else:
             block_cls = LlamaBlock
         for i in range(cfg.n_layers):

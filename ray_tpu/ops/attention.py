@@ -11,12 +11,26 @@ dependency in its model code (e.g. rllib models and train examples).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..util import knobs
+
+# What training attention leaves for its backward, by name
+# (jax.ad_checkpoint.checkpoint_name): q, k and v as the attention takes
+# them, its output, and the flash kernel's row logsumexp. A rematted
+# block whose policy is save_only_these_names(...) keeps them and does
+# not run the attention (nor the projections and the rotation in front
+# of it) a second time; under any other policy, and outside
+# jax.checkpoint, a name is an identity that lowers to nothing. The
+# flash kernel names its own residuals (pallas/flash_attention.py:
+# _flash_vjp_fwd); the XLA and dpa routes name the first four here and
+# recompute their softmax.
+ATTN_RESIDUALS = ("attn_q", "attn_k", "attn_v", "attn_out", "attn_lse")
+_ATTN_OUT = ATTN_RESIDUALS[3]
 
 
 def causal_attention_mask(seq_len: int, dtype=jnp.bool_) -> jax.Array:
@@ -482,6 +496,17 @@ def _sharded_flash(q, k, v, *, mesh, spec, causal, scale):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
+def attention_residuals(q: jax.Array, k: jax.Array, *,
+                        causal: bool = True, segment_ids=None,
+                        impl: str = "auto") -> Tuple[str, ...]:
+    """The names of ATTN_RESIDUALS that this call of multi_head_attention
+    gives values to: all five on the flash kernel's route, no logsumexp
+    on the others."""
+    if _resolve_impl(impl, q, k, causal, segment_ids) == "pallas":
+        return ATTN_RESIDUALS
+    return ATTN_RESIDUALS[:-1]
+
+
 def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          *, causal: bool = True,
                          segment_ids: Optional[jax.Array] = None,
@@ -489,7 +514,8 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          scale: Optional[float] = None) -> jax.Array:
     """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) with Hq % Hkv == 0 (GQA).
 
-    Returns (B, Sq, Hq, D).
+    Returns (B, Sq, Hq, D). Operands and result carry ATTN_RESIDUALS'
+    names for a remat policy to keep.
     """
     impl = _resolve_impl(impl, q, k, causal, segment_ids)
     if impl == "pallas":
@@ -502,6 +528,8 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         mesh, spec = sharded
         return _sharded_flash(q, k, v, mesh=mesh, spec=spec, causal=causal,
                               scale=scale)
+    q, k, v = (checkpoint_name(x, name)
+               for x, name in zip((q, k, v), ATTN_RESIDUALS))
     if impl == "dpa":
         # jax.nn.dot_product_attention: XLA's own fused attention,
         # which on TPU can lower to the compiler's flash kernel —
@@ -512,8 +540,8 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError(
                 "impl='dpa' supports only self-attention without "
                 "segment_ids; use impl='xla' for packed/cached shapes")
-        return jax.nn.dot_product_attention(
-            q, k, v, is_causal=causal, scale=scale)
+        return checkpoint_name(jax.nn.dot_product_attention(
+            q, k, v, is_causal=causal, scale=scale), _ATTN_OUT)
 
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -540,4 +568,5 @@ def multi_head_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v),
+                           _ATTN_OUT)

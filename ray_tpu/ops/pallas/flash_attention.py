@@ -34,8 +34,11 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..attention import ATTN_RESIDUALS
 
 NEG_INF = -1e30
 LANES = 128
@@ -422,6 +425,12 @@ def _flash(q, k, v, scale, causal, blocks, interpret):
 
 def _flash_vjp_fwd(q, k, v, scale, causal, blocks, interpret):
     out, lse = _flash_fwd(q, k, v, scale, causal, blocks.fwd, interpret)
+    # the backward's residuals carry ops/attention.py's names, so that a
+    # jax.checkpoint policy can keep them (save_only_these_names) and
+    # the rematted forward does not run this kernel a second time;
+    # identities under any other policy and outside jax.checkpoint
+    q, k, v, out, lse = (checkpoint_name(x, name) for x, name in
+                         zip((q, k, v, out, lse), ATTN_RESIDUALS))
     return out, (q, k, v, out, lse)
 
 

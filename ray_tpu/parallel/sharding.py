@@ -170,11 +170,14 @@ _ACTIVE: "list[Optional[activation_mesh]]" = [None]
 class activation_mesh:
     """Context manager: make `mesh` visible to constrain_activations
     during tracing of a step function. `tp_overlapped_matmuls` counts the
-    projections that took tp_matmul_route's route while it was open."""
+    projections that took tp_matmul_route's route while it was open,
+    `remat_saved_residuals` the named values that models said their
+    rematted blocks keep (count_saved_residuals)."""
 
     def __init__(self, mesh: Optional[Mesh]):
         self.mesh = mesh
         self.tp_overlapped_matmuls = 0
+        self.remat_saved_residuals = 0
 
     def __enter__(self):
         self._prev = _ACTIVE[0]
@@ -214,6 +217,14 @@ def tp_matmul_route(shape: Tuple[int, ...],
         return None
     _ACTIVE[0].tp_overlapped_matmuls += matmuls
     return mesh
+
+
+def count_saved_residuals(n: int) -> None:
+    """A model's word, while a step is traced, that a rematted block's
+    policy keeps `n` values by name for the backward (models/llama.py);
+    nothing outside an activation_mesh context."""
+    if _ACTIVE[0] is not None:
+        _ACTIVE[0].remat_saved_residuals += n
 
 
 def constrain_activations(x, *, seq_axis: Optional[str] = "sp",

@@ -82,6 +82,15 @@ class SpmdStep:
         where the mesh has `tp` > 1 and the sequence divides, else 0."""
         return self.traced.get("tp_overlapped_matmuls")
 
+    @property
+    def remat_saved_residuals(self) -> Optional[int]:
+        """Values the traced step's rematted blocks keep by name for the
+        backward instead of recomputing them: 6 a Llama layer under
+        `remat_policy="attention"` where the flash kernel runs (q, k, v,
+        its output and logsumexp, the residual stream after attention),
+        5 on the XLA route, 0 under any other policy or without remat."""
+        return self.traced.get("remat_saved_residuals")
+
     def __call__(self, state, batch):
         return self.step_fn(state, batch)
 
@@ -171,6 +180,7 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                         metrics["ppl"] = jnp.exp(
                             jnp.minimum(metrics["loss"], 20.0))
         traced["tp_overlapped_matmuls"] = active.tp_overlapped_matmuls
+        traced["remat_saved_residuals"] = active.remat_saved_residuals
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         metrics = dict(metrics)

@@ -423,7 +423,10 @@ def test_a_packed_pool_of_narrow_heads_is_held_and_read_as_it_is(
     assert mem.temp_size_in_bytes < 2 ** 20
 
 
-def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(topology, monkeypatch):
+@pytest.mark.parametrize("policy,kept,rings,flash_calls,temp_gib", [
+    ("attention", 6, 10, 3, 1.30), ("full", 0, 11, 4, 1.36)])
+def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(
+        topology, monkeypatch, policy, kept, rings, flash_calls, temp_gib):
     """ONE layer of the training cell's step (published Mistral-7B
     widths, `MeshSpec(fsdp=2, tp=2)`, 4 x 4 096 tokens, float32
     parameters, remat) compiled for the four described chips: the
@@ -432,10 +435,14 @@ def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(topology, monkeypatch):
     (parallel/collective_matmul.py), no all-reduce of a block's
     activations over `tp` is left, the optimizer reads every kernel's
     gradient in the parameter's own layout, and the temporaries stay
-    near what they were with the all-reduces (1.32 GiB; 1.27 at the
-    parent of PR 44 for the same shape, 1.62 before the MLP's chunks
-    went unassembled). Keeps a later change from bringing the exposed
-    sum back."""
+    near what they were with the all-reduces (1.32 GiB under "full";
+    1.27 at the parent of PR 44 for the same shape, 1.62 before the
+    MLP's chunks went unassembled). Keeps a later change from bringing
+    the exposed sum back. Under the default policy (PR 45) the backward
+    keeps the attention half's residuals: the flash forward is in the
+    program once, o_proj's ring is not rerun, and the temporaries read
+    1.26 GiB (the peak is at the end of the backward, where the kept
+    values are gone)."""
     from ray_tpu.models import Llama, LlamaConfig
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.train.optim import make_optimizer, warmup_cosine
@@ -446,7 +453,8 @@ def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(topology, monkeypatch):
     seq, batch = 4096, 4
     cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=1,
                       n_heads=32, n_kv_heads=8, d_ff=14336, max_seq_len=seq,
-                      rope_theta=1e6, remat=True, param_dtype=jnp.float32)
+                      rope_theta=1e6, remat=True, remat_policy=policy,
+                      param_dtype=jnp.float32)
     mesh = build_mesh(MeshSpec(fsdp=2, tp=2), devices=topology.devices)
     tx = make_optimizer("adamw", schedule=warmup_cosine(3e-4, 100, 10 ** 5),
                         grad_clip=1.0)
@@ -467,6 +475,7 @@ def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(topology, monkeypatch):
                                   sharding=step.batch_shardings["tokens"])
     compiled = step.step_fn.lower(state, {"tokens": tokens}).compile()
     assert step.tp_overlapped_matmuls == 7
+    assert step.remat_saved_residuals == kept
     text = compiled.as_text()
     # the embedding's lookup sums bf16[4,S,4096] over all four chips and
     # stays (ISSUE 44, out of scope); a block's sum was bf16[2,S,4096]
@@ -476,13 +485,25 @@ def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(topology, monkeypatch):
     assert not block_sums, block_sums
     starts = len(re.findall(r" collective-permute-start\(", text))
     dones = len(re.findall(r" collective-permute-done\(", text))
-    # forward 4, rematted forward 3, backward 4, beside the flash
-    # kernel's three
-    assert starts == dones and starts >= 11 + 3, (starts, dones)
+    assert starts == dones, (starts, dones)
+    # a ring at `tp` 2 is one permute of half the rows: forward 4,
+    # backward 4, and the rematted forward's 3 under "full" (the gathers
+    # in front of q / k / v and of gate / up, o_proj's scatter; down_proj's
+    # feeds nothing the backward reads). With the attention half kept
+    # o_proj's goes; the gather in front of q / k / v stays without its
+    # matmuls, because the half it delivers is what q / k / v's kernels'
+    # gradients multiply and is not kept (ROADMAP A7 (c))
+    ring = r"= \(bf16\[2,%d,4096\][^=]* collective-permute-start\(" % (
+        seq // 2)
+    assert len(re.findall(ring, text)) == rings
+    # the flash kernels: forward, dQ, dK/dV, and the forward again where
+    # the backward reruns it
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == flash_calls
     # a kernel's gradient formed the other way round makes the optimizer
     # transpose the parameter and both moments (collective_matmul._dot)
     relaid = re.findall(r"= f32\[\d+,\d+\]\{0,1\S* copy\(", text)
     assert not relaid, relaid
     assert "tpu_custom_call" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp <= 1.36 * 2 ** 30, temp / 2 ** 30
+    assert temp <= temp_gib * 2 ** 30, temp / 2 ** 30
