@@ -3,21 +3,12 @@
 Reference parity: the fork's vLLM-style serving path (continuous batching,
 paged KV, streaming) — re-designed TPU-first:
 
-* Paged KV cache: one preallocated page pool per layer, flattened to
-  (n_pages * page_size, *trailing) token rows, plus a
-  (slots, pages_per_slot) page table. A pool's arrays, their trailing
-  shapes and dtypes come from the model's cache spec, one entry a LAYER
-  (ops/attention.py:kv_cache_spec): K and V of (n_kv_heads, head_dim)
-  for the Llama, Mixtral and GPT-2 families (heads narrower than 128
-  lanes packed side by side in 128-lane rows: packed_kv_shape), one
-  latent row for a latent-attention model, both indexed by token
-  through the page table; and, for a linear-attention or a
-  short-convolution layer, a fixed-size state a SLOT,
-  (slots + 1, *trailing), that no page knows of (models/hybrid.py).
-  Pages, page table, allocator and windows cover the paged layers and
-  do not know which kind they hold. A model with slot state refuses
-  prefix caching and speculation by name (a prefix would be a state
-  snapshot, a rejected proposal a rollback), and get_stats() adds
+* Paged KV cache (serve/llm/pages.py owns it: pools' shapes, pages,
+  page table, lengths, windows): a pool per layer as the model's cache
+  spec has it, indexed by token through the page table or, for a
+  layer that keeps a fixed-size state, by slot. A model with slot state
+  refuses prefix caching and speculation by name (a prefix would be a
+  state snapshot, a rejected proposal a rollback), and get_stats() adds
   state_bytes_per_slot, decode_state_rows_window and
   decode_state_rows_live beside kv_bytes_per_token (paged layers only,
   the bytes a token takes as the pools are laid out).
@@ -550,93 +541,37 @@ class LLMEngine:
             raise ValueError(
                 f"engine max_seq_len {cfg.max_seq_len} exceeds the "
                 f"model's max_seq_len {model_max}")
-        S, L = cfg.max_slots, cfg.max_seq_len
-        ps = cfg.kv_page_size
-        if ps <= 0:
-            raise ValueError(
-                f"kv_page_size must be > 0, got {ps}: the KV cache is a "
-                "page pool and the contiguous per-slot layout is gone")
-        # +1 scratch slot, never admitted, so its garbage never decodes
-        # (costs one page-table row, not a KV row): padding rows of a
-        # batched prefill write through its all-trash page row, and a
-        # prefix registration prefills through it.
-        self._n_slots = S + 1
-        self._scratch_slot = S
-        # per-slot gather width: whole pages covering max_seq_len
-        self._pages_per_slot = -(-L // ps)
-        pool_tokens = cfg.kv_pool_tokens or S * L
-        # the configured budget is honored exactly (rounded up to a
-        # page): oversized requests fail fast at submit() instead of
-        # silently inflating the pool
-        self._n_pages = max(1, -(-pool_tokens // ps))
-        self._trash_page = self._n_pages  # extra page: writes by
-        # released/padding slots land here and are never read valid
-        n_flat = (self._n_pages + 1) * ps
         from ...ops.attention import kv_cache_spec  # noqa: PLC0415
-        # one LayerCache a layer: a pool indexed by token through the
-        # page table, (n_flat, *shape), or by slot, (n_slots, *shape):
-        # the scratch slot takes padding rows' writes there as the
-        # trash page does here
-        self._cache_spec = kv_cache_spec(model)
-        self._pools = [
-            tuple(jnp.zeros((self._n_slots if c.by_slot else n_flat, *t),
-                            d) for t, d in zip(c.shapes, c.dtypes))
-            for c in self._cache_spec]
-
-        def row_bytes(by_slot):
-            return sum(int(np.prod(t)) * jnp.dtype(d).itemsize
-                       for c in self._cache_spec if c.by_slot == by_slot
-                       for t, d in zip(c.shapes, c.dtypes))
-        self._kv_bytes_per_token = row_bytes(False)
-        self._state_bytes_per_slot = row_bytes(True)
-        self._n_state_layers = sum(c.by_slot for c in self._cache_spec)
-        # the layer whose entry carries the sequences' lengths out of a
-        # decode step: the first that pages
-        paged = [i for i, c in enumerate(self._cache_spec) if not c.by_slot]
-        if not paged:
+        from .pages import PageAllocator  # noqa: PLC0415
+        # where the cached tokens live; the pools it sizes are carried
+        # here, donated from step to step
+        self._pages = PageAllocator(
+            kv_cache_spec(model), cfg.max_slots, cfg.max_seq_len,
+            cfg.kv_page_size, cfg.kv_pool_tokens)
+        self._n_slots = self._pages.n_slots
+        self._scratch_slot = self._pages.scratch_slot
+        if cfg.max_prefixes > 0 and (why := self._pages.refuses("share")):
             raise ValueError(
-                "every layer of this model keeps per-slot state and none "
-                "pages: the sequences' lengths ride on a paged layer's "
-                "entry: not supported")
-        self._len_layer = paged[0]
-        if self._n_state_layers:
-            # a prefix of a recurrent layer is a snapshot of its state,
-            # not pages to share, and a rejected proposal would need the
-            # state rolled back: neither exists (ROADMAP B9)
-            if cfg.max_prefixes > 0:
-                raise ValueError(
-                    "prefix caching (max_prefixes, register_prefix, "
-                    "cached_prefixes) copies pages; this model keeps "
-                    "per-slot recurrent state, whose prefix would be a "
-                    "state snapshot: not supported")
-            if cfg.ngram_speculation > 0:
-                raise ValueError(
-                    "n-gram speculation (ngram_speculation) verifies "
-                    "proposals in one forward and drops the rejected; "
-                    "this model keeps per-slot recurrent state, which "
-                    "cannot be rolled back: not supported")
-        # the page table lives on the host: every program gets the rows
-        # it reads as they stand at its dispatch, which is the order the
-        # device runs them in
-        self._page_table = np.full(
-            (self._n_slots, self._pages_per_slot),
-            self._trash_page, np.int32)
+                "prefix caching (max_prefixes, register_prefix, "
+                "cached_prefixes) copies pages; " + why)
+        if cfg.ngram_speculation > 0 \
+                and (why := self._pages.refuses("roll_back")):
+            raise ValueError(
+                "n-gram speculation (ngram_speculation) verifies "
+                "proposals in one forward and drops the rejected; " + why)
+        self._pools = self._pages.new_pools()
+        # what the benchmark's sampler thread reads for the rooflines'
+        # live context (benchmarks/harness/replica.py): the allocator's
+        # own mirror, read-only
+        self._disp_len = self._pages.dispatched_lengths
+        # tokens a decode dispatch appends to each active slot
+        self._decode_new = max(1, cfg.decode_block)
         self._state = _StepState(
             jnp.zeros((self._n_slots,), jnp.int32),
             jnp.zeros((self._n_slots,), jnp.int32),
             jax.random.PRNGKey(0))
-        # slot -> the length its row restarts from (0, or an adopted
-        # prefix's): applied by the next program before it reads
-        # lengths, then forgotten
-        self._len_edits: Dict[int, int] = {}
-        # host-side allocator
-        self._free_pages: List[int] = list(range(self._n_pages))
-        # slot -> (n_shared_prefix_pages, [all pages in table order])
-        self._slot_pages: Dict[int, tuple] = {}
-        self._prefix_pages: Dict[int, List[int]] = {}
         self._pending_head: Optional[_Request] = None
-        self._page_hwm = 0      # peak pages in use (stats)
-        self._free_slots = list(range(S))
+        self._free_slots = list(range(cfg.max_slots))
         self._active: Dict[int, _Request] = {}
         self._waiting: "queue_mod.Queue[_Request]" = queue_mod.Queue()
         self._requests: Dict[str, _Request] = {}
@@ -692,7 +627,7 @@ class LLMEngine:
                       # dispatch (_InflightDepth), summed and counted
                       "decode_inflight_target_sum": 0,
                       "decode_inflight_target_n": 0}
-        if self._n_state_layers:
+        if self._pages.n_state_layers:
             # per-slot state rows of decode dispatches, summed over the
             # layers that keep one: rows the step read and wrote (every
             # row of the pool: an idle row is written through
@@ -754,7 +689,7 @@ class LLMEngine:
         self._event = _event
 
         # registered prefixes: host-side token records; their KV lives
-        # in pinned pages of the pool (self._prefix_pages)
+        # in pinned pages of the pool (the allocator's)
         self._prefixes: Dict[int, np.ndarray] = {}   # pid -> tokens
         self._prefix_counter = itertools.count()
 
@@ -778,11 +713,7 @@ class LLMEngine:
                     donate_argnums=(1, 2),
                     static_argnames=("window_pages",))
             if cfg.decode_block > 1 else None)
-        # host mirror of each slot's device length: picks the
-        # power-of-2 page window covering the longest active
-        # sequence at decode-dispatch time
-        self._disp_len: Dict[int, int] = {}
-        self._copy_page_jit = jax.jit(self._copy_page_impl,
+        self._copy_page_jit = jax.jit(self._pages.copy_page,
                                       donate_argnums=(0,))
         self._pen_seed_jit = jax.jit(self._pen_seed_impl,
                                      donate_argnums=(0, 1))
@@ -900,21 +831,6 @@ class LLMEngine:
         return toks, logps
 
     # ---- step programs over the page pool ---------------------------------
-    def _paged_entries(self, pools, page_table, lengths, fresh=False,
-                       slots=None, n_new=None, restart=None):
-        """Per-layer cache entries over the shared pools, as the model's
-        cache spec has them: a paged entry (PagedKV, PagedLatent) over
-        the call's rows of the page table, or a SlotState over the
-        call's `slots` (None: every slot in order) with `n_new` real
-        new positions a row, `restart`ing the rows that begin there.
-        The gather/scatter happens INSIDE each layer, so only one
-        layer's contiguous view is ever live at a time."""
-        return [c.entry(*arrays, slots, n_new, restart, fresh=fresh)
-                if c.by_slot
-                else c.entry(*arrays, page_table, lengths,
-                             self.cfg.kv_page_size, fresh)
-                for c, arrays in zip(self._cache_spec, pools)]
-
     def _apply_counted(self, params, tokens, entries, positions, row_mask):
         """model.apply for a step program. A model that declares
         `step_stats` is told which rows are real and returns, summed
@@ -944,14 +860,13 @@ class LLMEngine:
         (given for a model that counts its rows): the first n_real rows
         are prompts, the rest group padding."""
         jnp = self._jnp
-        ps = self.cfg.kv_page_size
         g = tokens.shape[0]
-        rows = page_table[slots]                   # (G, P)
-        rows_p = rows[:, :-(-pad_len // ps)]       # pages covering pad
+        rows_p = self._pages.narrow(               # pages covering pad
+            page_table[slots], self._pages.pages_needed(pad_len))
         # fresh=True: pure prefill — attention runs straight over the
         # prompt (flash-eligible on TPU), no page gather; KV still
         # scatters into the pages
-        entries = self._paged_entries(
+        entries = self._pages.entries(
             pools, rows_p, jnp.zeros((g,), jnp.int32), fresh=True,
             slots=slots, n_new=true_lens)
         positions = jnp.broadcast_to(jnp.arange(pad_len)[None, :],
@@ -991,7 +906,7 @@ class LLMEngine:
         jax = self._jax
         row = jax.lax.dynamic_slice_in_dim(page_table, slot, 1, axis=0)
         l1 = jnp.reshape(start, (1,)).astype(jnp.int32)
-        entries = self._paged_entries(
+        entries = self._pages.entries(
             pools, row, l1, slots=jnp.reshape(slot, (1,)),
             n_new=jnp.reshape(new_len - start, (1,)),
             restart=jnp.reshape(start == 0, (1,)))
@@ -1025,12 +940,11 @@ class LLMEngine:
         XLA-gather path's analog of the Pallas kernel's page skipping.
         """
         jnp = self._jnp
-        if window_pages and window_pages < page_table.shape[1]:
-            page_table = page_table[:, :window_pages]
-        entries = self._paged_entries(
+        page_table = self._pages.narrow(page_table, window_pages)
+        entries = self._pages.entries(
             pools, page_table, lengths,
             n_new=active_mask.astype(jnp.int32)
-            if self._n_state_layers else None)
+            if self._pages.n_state_layers else None)
         positions = lengths[:, None]
         logits, new_entries, counted = self._apply_counted(
             params, last_tokens[:, None], entries, positions,
@@ -1038,7 +952,7 @@ class LLMEngine:
         logits = logits[:, 0, :]
         new_pools = [e.arrays for e in new_entries]
         new_lengths = jnp.where(
-            active_mask, new_entries[self._len_layer].lengths, lengths)
+            active_mask, new_entries[self._pages.len_layer].lengths, lengths)
         bias, new_counts = self._pen_bias(pen, last_tokens, active_mask)
         nxt, logps = self._sample_tokens(logits, temps, top_ps, rng_key,
                                          allow=allow, bias=bias)
@@ -1075,19 +989,6 @@ class LLMEngine:
             body, (pools, lengths, last_tokens), keys)
         return toks, logps, pools, lengths, last
 
-    def _copy_page_impl(self, pools, src_page, dst_page):
-        """Copy one page's rows of every pool array in every layer —
-        the only device copy prefix adoption pays (its final PARTIAL
-        page; full pages are shared by page-table reference)."""
-        lax = self._jax.lax
-        ps = self.cfg.kv_page_size
-
-        def copy(a):
-            rows = lax.dynamic_slice_in_dim(a, src_page * ps, ps, axis=0)
-            return lax.dynamic_update_slice_in_dim(a, rows, dst_page * ps,
-                                                   axis=0)
-        return [tuple(copy(a) for a in arrays) for arrays in pools]
-
     def _verify_paged_impl(self, params, pools, page_table, lengths,
                            last_tokens, proposals, active_mask, temps,
                            top_ps, rng_key, window_pages: int = 0):
@@ -1102,9 +1003,8 @@ class LLMEngine:
         the trash page, which is by-construction inert."""
         jnp = self._jnp
         K = proposals.shape[1]
-        if window_pages and window_pages < page_table.shape[1]:
-            page_table = page_table[:, :window_pages]
-        entries = self._paged_entries(pools, page_table, lengths)
+        page_table = self._pages.narrow(page_table, window_pages)
+        entries = self._pages.entries(pools, page_table, lengths)
         toks_in = jnp.concatenate(
             [last_tokens[:, None], jnp.maximum(proposals, 0)], axis=1)
         positions = lengths[:, None] + jnp.arange(K + 1)[None, :]
@@ -1186,10 +1086,10 @@ class LLMEngine:
         """_begin_step of the programs over all slots (_decode_ctl)."""
         jnp = self._jnp
         S = self._n_slots
-        W = window_pages or self._pages_per_slot
         carried, (mask, temps, top_ps, table) = self._begin_step(
             state, ctl, ((S,), jnp.int32), ((S,), jnp.float32),
-            ((S,), jnp.float32), ((S, W), jnp.int32))
+            ((S,), jnp.float32),
+            (self._pages.rows_shape(window_pages), jnp.int32))
         return (*carried, mask != 0, temps, top_ps, table)
 
     def _prefill_paged_step(self, params, pools, state, ctl, pad_len: int,
@@ -1199,7 +1099,7 @@ class LLMEngine:
         go into last_tokens here. fetch: all g rows' tokens (the host
         reads the first n_real), a counting model's counters behind."""
         jnp = self._jnp
-        S, P = self._n_slots, self._pages_per_slot
+        S, P = self._pages.rows_shape()
         g = (ctl.shape[0] - S - 1 - S * P) // (4 + pad_len)
         (lengths, last_tokens, key, sub), (
             n_real, slots, lens, temps, top_ps, table, tokens) = \
@@ -1223,7 +1123,7 @@ class LLMEngine:
         """_chunk_paged_impl; the final chunk's token goes into
         last_tokens here. fetch and logps are (1,)."""
         jnp = self._jnp
-        S, P = self._n_slots, self._pages_per_slot
+        S, P = self._pages.rows_shape()
         (lengths, last_tokens, key, sub), (
             slot, start, new_len, temp, top_p, table, tokens) = \
             self._begin_step(
@@ -1281,11 +1181,8 @@ class LLMEngine:
         returns a prefix_id for submit(prefix_id=...). Requires
         cfg.max_prefixes > 0. Prefix ids are append-only: registering
         more than max_prefixes raises. Thread-safe."""
-        if self._n_state_layers:
-            raise ValueError(
-                "register_prefix copies pages; this model keeps per-slot "
-                "recurrent state, whose prefix would be a state "
-                "snapshot: not supported")
+        if why := self._pages.refuses("share"):
+            raise ValueError("register_prefix copies pages; " + why)
         if self.cfg.max_prefixes <= 0:
             raise ValueError("engine built with max_prefixes=0")
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
@@ -1328,39 +1225,30 @@ class LLMEngine:
         """Prefill a prefix into freshly-allocated PINNED pages (loop
         thread only): the prefix lives in the pool; adopters share its
         full pages by reference."""
-        ps = self.cfg.kv_page_size
-        pages = self._alloc_pages(-(-prefix.size // ps))
-        if pages is None:
+        if not self._pages.pin_prefix(pid, prefix.size):
             raise ValueError("page pool exhausted registering prefix")
-        scratch = self._scratch_slot
         pad = min(_next_pow2(prefix.size), self.cfg.max_seq_len)
         tokens = np.zeros((1, pad), np.int32)
         tokens[0, :prefix.size] = prefix
-        self._set_page_row(scratch, pages)
         try:
             self._step(self._prefill_paged_jit, (
-                np.int32(1), np.asarray([scratch], np.int32),
+                np.int32(1), np.asarray([self._scratch_slot], np.int32),
                 np.asarray([prefix.size], np.int32),
                 np.zeros((1,), np.float32), np.ones((1,), np.float32),
-                self._page_table, tokens), pad_len=pad)
+                self._pages.rows(), tokens), pad_len=pad)
         except BaseException:
-            self._free_pages.extend(pages)
+            self._pages.unpin_prefix(pid)
             raise
         finally:
-            # scratch row back to all-trash: batch-padding rows write
-            # through it and must never touch the pinned prefix pages
-            self._set_page_row(scratch, [])
-        self._prefix_pages[pid] = pages
+            self._pages.reset_scratch()
         self._prefixes[pid] = prefix
 
     def _unregister_prefix_paged(self, pid: int) -> None:
         """Free a prefix's pinned pages (loop thread; internal — only
         safe once no active slot shares them, e.g. precompile's warm
         prefix after its streams drain)."""
-        pages = self._prefix_pages.pop(pid, None)
+        self._pages.unpin_prefix(pid)
         self._prefixes.pop(pid, None)
-        if pages:
-            self._free_pages.extend(pages)
 
     def submit(self, prompt_ids, max_new_tokens: Optional[int] = None,
                temperature: float = 0.0, top_p: float = 1.0,
@@ -1447,12 +1335,9 @@ class LLMEngine:
                 raise ValueError(
                     f"prompt length {prompt.size} exceeds max_seq_len "
                     f"{self.cfg.max_seq_len}")
-        ps = self.cfg.kv_page_size
-        if -(-(prompt.size + budget) // ps) > self._n_pages:
-            raise ValueError(
-                f"request needs {-(-(prompt.size + budget) // ps)} "
-                f"KV pages; pool has {self._n_pages} total — it "
-                f"could never be admitted")
+        never = self._pages.unservable(prompt.size + budget, pins=False)
+        if never:
+            raise ValueError(never)
         req = _Request(request_id=f"req-{next(self._req_counter)}",
                        prompt=prompt, max_new_tokens=budget,
                        temperature=temperature, top_p=float(top_p),
@@ -1686,21 +1571,14 @@ class LLMEngine:
                    "waiting": self._waiting.qsize(),
                    "prefilling": len(self._prefilling),
                    "free_slots": len(self._free_slots)}
-            pinned = sum(len(p) for p in self._prefix_pages.values())
-            out["kv_pages"] = {
-                "page_size": self.cfg.kv_page_size,
-                "total": self._n_pages,
-                "free": len(self._free_pages),
-                "in_use": self._n_pages - len(self._free_pages),
-                "pinned_prefix": pinned,
-                "peak_in_use": self._page_hwm,
-            }
+            out["kv_pages"] = self._pages.kv_pages()
             # bytes a cached token takes over all layers (the pool's
             # rows as they are stored)
-            out["kv_bytes_per_token"] = self._kv_bytes_per_token
+            out["kv_bytes_per_token"] = self._pages.kv_bytes_per_token
             # bytes a sequence's recurrent state takes over the layers
             # that keep one (0: every layer pages)
-            out["state_bytes_per_slot"] = self._state_bytes_per_slot
+            out["state_bytes_per_slot"] = \
+                self._pages.state_bytes_per_slot
             # what the in-flight target is derived from, as it reads now
             out["inflight_depth"] = self._depth.readings(
                 self.cfg.pipeline_depth)
@@ -1875,67 +1753,38 @@ class LLMEngine:
         "nopages" (hold the request), or "failed" (stream errored).
         Prefix-carrying requests share the prefix's full pages by
         page-table reference and copy only its partial last page."""
-        ps = self.cfg.kv_page_size
-        need_total = self._pages_needed(req)
-        # Unservable guard: pinned prefix pages never return to the
-        # pool, so a request needing more than (total - pinned [- shared
-        # pages it adopts]) could park in _pending_head FOREVER and
-        # head-of-line-block every later request. Error it instead —
-        # submit()'s static check can't see pins made after submit.
-        pinned = sum(len(p) for p in self._prefix_pages.values())
-        n_shared_adopt = (int(self._prefixes[req.prefix_id].size) // ps
-                          if req.prefix_id >= 0 else 0)
-        if need_total - n_shared_adopt > self._n_pages - pinned:
-            self._put(req, ("error", ValueError(
-                f"request needs {need_total - n_shared_adopt} exclusive "
-                f"KV pages but only {self._n_pages - pinned} can ever "
-                f"be free ({pinned} pinned by prefixes)")))
-            self._put(req, _END)
+        n_tokens = req.prompt.size + req.max_new_tokens
+        # submit()'s check could not see pins made after it
+        never = self._pages.unservable(n_tokens, req.prefix_id)
+        if never:
+            self._fail_admission(req, ValueError(never))
             return "failed"
-        if req.prefix_id >= 0:
-            prefix_pages = self._prefix_pages[req.prefix_id]
-            plen = int(self._prefixes[req.prefix_id].size)
-            n_shared = plen // ps
-            excl = self._alloc_pages(need_total - n_shared)
-            if excl is None:
-                return "nopages"
-            slot = self._take_slot(req)
-            if plen % ps:
-                try:
-                    self._pools = self._runtime(
-                        self._copy_page_jit, self._pools,
-                        np.int32(prefix_pages[n_shared]),
-                        np.int32(excl[0]))
-                except BaseException as e:  # noqa: BLE001
-                    self._free_pages.extend(excl)
-                    self._free_slots.append(slot)
-                    req.slot = -1
-                    self._put(req, ("error", e))
-                    self._put(req, _END)
-                    return "failed"
-            all_pages = prefix_pages[:n_shared] + excl
-            self._slot_pages[slot] = (n_shared, all_pages)
-            self._set_page_row(slot, all_pages, length=plen)
-            self._disp_len[slot] = plen
-            req.prefill_pos = plen
-            self.stats["prefix_tokens_saved"] = (
-                self.stats.get("prefix_tokens_saved", 0) + plen)
-            return "ok"
-        pages = self._alloc_pages(need_total)
-        if pages is None:
+        got = self._pages.reserve(self._free_slots[-1], n_tokens,
+                                  req.prefix_id)
+        if got is None:
             return "nopages"
-        slot = self._take_slot(req)
-        self._slot_pages[slot] = (0, pages)
-        # the slot's device length restarts WITH its new row (the next
-        # program applies both before it reads either): a reused slot's
-        # stale length would aim inactive decode-steps' garbage writes
-        # at an arbitrary position — under a narrowed decode window the
-        # clamped scatter could then corrupt the NEW occupant's pages.
-        # With length 0, garbage always lands exactly where the next
-        # prefill/chunk write goes (overwritten before any read).
-        self._set_page_row(slot, pages, length=0)
-        self._disp_len[slot] = 0
+        _row, restart, copy = got
+        self._take_slot(req)
+        if copy is not None:
+            try:
+                self._pools = self._runtime(
+                    self._copy_page_jit, self._pools, *map(np.int32, copy))
+            except BaseException as e:  # noqa: BLE001
+                self._fail_admission(req, e)
+                return "failed"
+        req.prefill_pos = restart
+        self.stats["prefix_tokens_saved"] += restart
         return "ok"
+
+    def _fail_admission(self, req: _Request, error) -> None:
+        """This request goes no further: its pages and slot (if it has
+        them) go back, its stream gets the error and ends."""
+        if req.slot >= 0:
+            self._pages.release(req.slot)
+            self._free_slots.append(req.slot)
+            req.slot = -1
+        self._put(req, ("error", error))
+        self._put(req, _END)
 
     def _admit_all(self, inflight) -> None:
         """Dispatch prefills for every waiting request that can get a
@@ -2037,14 +1886,10 @@ class LLMEngine:
             toks_dev, lps_dev, _ = self._step(
                 self._prefill_paged_jit, (
                     np.int32(g_real), slots, lens, temps, top_ps,
-                    self._page_table, tokens), pad_len=pad_len, **kw)
+                    self._pages.rows(), tokens), pad_len=pad_len, **kw)
         except BaseException as e:  # noqa: BLE001
-            for req, slot in members:
-                self._free_slot_pages(slot)
-                self._free_slots.append(slot)
-                req.slot = -1
-                self._put(req, ("error", e))
-                self._put(req, _END)
+            for req, _slot in members:
+                self._fail_admission(req, e)
             return
         dispatch_ms = (time.time() - t_dispatch) * 1000
         # first dispatch of a bucket blocks on its jit compile: record it
@@ -2054,7 +1899,7 @@ class LLMEngine:
             int(req.prompt.size) for req, _ in members))
         for req, slot in members:
             req.prefill_dispatch_ms = dispatch_ms
-            self._disp_len[slot] = req.prompt.size
+            self._pages.set_length(slot, req.prompt.size)
             self._active[slot] = req
         self._mask_dirty = True
         self._pen_coef_dirty = True
@@ -2105,18 +1950,14 @@ class LLMEngine:
                 self._chunk_paged_jit, (
                     np.int32(req.slot), np.int32(start),
                     np.int32(start + true), np.float32(req.temperature),
-                    np.float32(req.top_p), self._page_table, tokens),
+                    np.float32(req.top_p), self._pages.rows(), tokens),
                 chunk=C, sample=is_last, **kw)
         except BaseException as e:  # noqa: BLE001
             self._prefilling.popleft()
-            self._free_slot_pages(req.slot)
-            self._free_slots.append(req.slot)
-            req.slot = -1
-            self._put(req, ("error", e))
-            self._put(req, _END)
+            self._fail_admission(req, e)
             return
         req.prefill_pos = start + true
-        self._disp_len[req.slot] = req.prefill_pos
+        self._pages.set_length(req.slot, req.prefill_pos)
         req.prefill_dispatch_ms += (time.time() - t_dispatch) * 1000
         self._count_prefill(C, 1, 1, true)
         self._progress_ts = time.time()   # watchdog: chunk advanced
@@ -2141,19 +1982,15 @@ class LLMEngine:
 
     def _step(self, program, parts, *args, **kw):
         """Enqueue one step program on the carried state. `parts` are
-        its host arguments in its own _unpack order; the pending length
-        edits go in front and are forgotten once the program is queued.
+        its host arguments in its own _unpack order; the allocator's
+        pending length edits go in front.
         Returns (fetch, logps, what else the program returns)."""
-        edits = np.full((self._n_slots,), -1, np.int32)
-        for slot, n in self._len_edits.items():
-            edits[slot] = n
-        ctl = _pack(edits, *parts)
+        ctl = _pack(self._pages.take_length_edits(), *parts)
         self.stats["runtime_calls"] += 1
         fetch, logps, pools, state, *rest = self._spans.call(
             "runtime.step", program, self.params, self._pools,
             self._state, ctl, *args, **kw)
         self._spans.call("step.release", self._carry, pools, state)
-        self._len_edits.clear()
         return fetch, logps, rest
 
     def _carry(self, pools, state):
@@ -2325,53 +2162,6 @@ class LLMEngine:
                         self.abort(sink.request_id)
                         self._requests.pop(sink.request_id, None)
 
-    # ---- page allocator (host side) ---------------------------------------
-    def _pages_needed(self, req: _Request) -> int:
-        """Whole pages reserved at admission: prompt + generation budget.
-        Full reservation means decode can never hit page exhaustion
-        mid-stream (no preemption machinery needed)."""
-        ps = self.cfg.kv_page_size
-        return -(-(req.prompt.size + req.max_new_tokens) // ps)
-
-    def _alloc_pages(self, n: int) -> "Optional[List[int]]":
-        if len(self._free_pages) < n:
-            return None
-        pages = [self._free_pages.pop() for _ in range(n)]
-        in_use = self._n_pages - len(self._free_pages)
-        self._page_hwm = max(self._page_hwm, in_use)
-        return pages
-
-    def _set_page_row(self, slot: int, pages: "List[int]",
-                      length: Optional[int] = None) -> None:
-        """Write a slot's page-table row (unused entries -> trash) and,
-        where given, the length its sequence restarts from. Both reach
-        the device with the next program dispatched, whichever kind,
-        before it reads either: programs queued earlier keep the rows
-        they were dispatched with and run first."""
-        row = self._page_table[slot]
-        row[:] = self._trash_page
-        row[:len(pages)] = pages
-        if not pages:
-            # a row that holds no page holds no key: the decode kernel
-            # walks a row's length, so a stale one would cost its pages
-            length = 0
-        if length is not None:
-            self._len_edits[slot] = length
-
-    def _free_slot_pages(self, slot: int) -> None:
-        """Return the slot's exclusive pages to the pool (shared prefix
-        pages stay pinned) and point its row at the trash page so lagged
-        decode writes can't corrupt a reused page: whoever is given
-        these pages next is dispatched after this edit, and every
-        program dispatched from here on sees the trash row."""
-        entry = self._slot_pages.pop(slot, None)
-        self._disp_len.pop(slot, None)
-        if entry is None:
-            return
-        n_shared, pages = entry
-        self._free_pages.extend(pages[n_shared:])
-        self._set_page_row(slot, [])
-
     def _shed_expired(self, req: _Request) -> None:
         """Queued request whose propagated deadline passed: error the
         consumer (typed, retriable upstream decision) without ever
@@ -2400,7 +2190,7 @@ class LLMEngine:
         # bookkeeping dispatch raises.
         try:
             if req.slot >= 0:
-                self._free_slot_pages(req.slot)
+                self._pages.release(req.slot)
                 self._free_slots.append(req.slot)
                 self._slot_freed_ns[req.slot] = time.perf_counter_ns()
                 self._active.pop(req.slot, None)
@@ -2423,34 +2213,16 @@ class LLMEngine:
             # must not park the loop on a blocking put
             self._put(req, _END)
 
-    def _decode_window_pages(self) -> int:
-        """Power-of-2 page window covering every slot that holds KV
-        (active AND chunk-prefilling — a narrower window would let the
-        decode scatter's clamped index corrupt a prefilling slot's
-        pages) plus this dispatch's new tokens. 0 = full width. The
-        static window buckets keep compile count at O(log2 P) while
-        decode cost tracks the longest REAL sequence."""
-        ps = self.cfg.kv_page_size
-        need = (max(self._disp_len.values(), default=0)
-                + max(1, self.cfg.decode_block))
-        w = _next_pow2(-(-need // ps))
-        return 0 if w >= self._pages_per_slot else w
-
-    def _count_decode_pages(self, window: int) -> None:
-        """The old kernel's grid against what the rows hold: every row
-        times the window's pages, and the pages under each slot's
-        tokens once this dispatch has written its own."""
-        ps = self.cfg.kv_page_size
-        new = max(1, self.cfg.decode_block)
-        self.stats["decode_pages_window"] += \
-            self._n_slots * (window or self._pages_per_slot)
-        self.stats["decode_pages_live"] += sum(
-            -(-(n + new) // ps) for n in self._disp_len.values())
-        if self._n_state_layers:
-            self.stats["decode_state_rows_window"] += \
-                self._n_slots * self._n_state_layers
-            self.stats["decode_state_rows_live"] += \
-                len(self._active) * self._n_state_layers
+    def _count_decode(self, window: int) -> None:
+        """One decode dispatch's pages, as the allocator counts them,
+        and the state rows of a model that keeps per-slot state."""
+        st, layers = self.stats, self._pages.n_state_layers
+        in_window, live = self._pages.decode_pages(window, self._decode_new)
+        st["decode_pages_window"] += in_window
+        st["decode_pages_live"] += live
+        if layers:
+            st["decode_state_rows_window"] += self._n_slots * layers
+            st["decode_state_rows_live"] += len(self._active) * layers
 
     def _propose_ngram(self, req) -> "Optional[List[int]]":
         """Prompt-lookup proposal: the K tokens that followed the most
@@ -2642,8 +2414,7 @@ class LLMEngine:
                 top_ps[slot] = req.top_p
             self._mask_temps = (mask, temps, top_ps)
             self._mask_dirty = False
-        return (*self._mask_temps,
-                self._page_table[:, :window or self._pages_per_slot])
+        return (*self._mask_temps, self._pages.rows(window))
 
     def _drain_verify(self, snapshot, out_dev, ne_lp):
         """Emit a speculative verify step's 1..K+1 tokens per slot.
@@ -2687,10 +2458,11 @@ class LLMEngine:
             self.stats["decode_tokens_discarded"] += n - emitted
             self.stats["spec_accepted"] = (
                 self.stats.get("spec_accepted", 0) + max(0, emitted - 1))
-            if req.slot == slot and slot in self._disp_len:
+            if req.slot == slot:
                 # resync the window mirror to the true length (the
                 # dispatch bumped it by the K+1 upper bound)
-                self._disp_len[slot] = req.prompt.size + req.generated
+                self._pages.set_length(slot,
+                                       req.prompt.size + req.generated)
             full = (req.prompt.size + req.generated
                     >= self.cfg.max_seq_len)
             if req.generated >= req.max_new_tokens or full:
@@ -2857,11 +2629,10 @@ class LLMEngine:
                     props = (self._spec_plan()
                              if spec_sync and allow is None else None)
                     if props is not None:
-                        for slot in self._active:
-                            self._disp_len[slot] += \
-                                self.cfg.ngram_speculation + 1
-                    window = self._decode_window_pages()
-                    self._count_decode_pages(window)
+                        self._pages.advance(
+                            self._active, self.cfg.ngram_speculation + 1)
+                    window = self._pages.decode_window(self._decode_new)
+                    self._count_decode(window)
                     snapshot = list(self._active.items())
                     ready = True
         if ready:
@@ -2880,9 +2651,7 @@ class LLMEngine:
             m["occupancy"].set(
                 len(self._active) / max(1, self.cfg.max_slots),
                 tags=self._mtags)
-            m["kv_util"].set(
-                (self._n_pages - len(self._free_pages))
-                / max(1, self._n_pages), tags=self._mtags)
+            m["kv_util"].set(self._pages.utilization(), tags=self._mtags)
         if not inflight:
             self._in_dispatch = False
             self._hand_over()   # what admission errored, shed or ended
@@ -2947,11 +2716,7 @@ class LLMEngine:
             if pen is not None:
                 self._pen_counts, = rest
             block = 1
-        for slot in self._active:
-            # KeyError here = an admission path forgot to seed
-            # _disp_len; fail loudly — a silent 0 default would shrink
-            # the window and corrupt KV untraceably
-            self._disp_len[slot] += block
+        self._pages.advance(self._active, block)
         self._start_fetch(toks)
         if self.cfg.logprobs:
             self._start_fetch(logps)

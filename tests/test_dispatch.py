@@ -117,22 +117,39 @@ def test_lease_results_preserve_order_and_values(rt):
     assert ray_tpu.get(refs, timeout=60) == [i * 7 for i in range(100)]
 
 
-def test_blocked_lease_head_releases_slots(rt):
+@ray_tpu.remote
+def _held_until(gate, v):
+    """Returns once the driver has made the file `gate` (at most a
+    minute): a chain that is unfinished for as long as the test says."""
+    deadline = time.time() + 60
+    while not os.path.exists(gate) and time.time() < deadline:
+        time.sleep(0.02)
+    return v
+
+
+def test_blocked_lease_head_releases_slots(rt, tmp_path):
     """A lease head blocking in get() must not pin unstarted slots
     behind it: the driver reclaims them (task.lease.revoke) and other
     workers (or fresh spawns) run them."""
-    slow = _sleep_then.remote("s", 4.0)
+    gate = str(tmp_path / "gate")
+    slow = _held_until.remote(gate, "s")
     time.sleep(0.3)   # let the sleeper occupy one worker
+    rev0 = rt.lease_revokes
     # blocker waits on the sleeper via a NESTED ref (not a dep), then a
     # quick task lands behind it in the same submit burst
     blocker = _blocked_get.remote([slow])
     quick = [_noop.remote(i) for i in range(6)]
-    t0 = time.time()
-    vals = ray_tpu.get(quick, timeout=30)
-    took = time.time() - t0
-    assert vals == list(range(6))
-    # the quick tasks must NOT have waited for the 4s sleeper chain
-    assert took < 3.0, f"quick tasks waited {took:.2f}s behind a blocked lease"
+    try:
+        # the quick tasks must NOT wait for the sleeper's chain, which
+        # ends only when the gate opens below: pinned behind the
+        # blocker they would not come back at all
+        assert ray_tpu.get(quick, timeout=30) == list(range(6))
+        ready, _ = ray_tpu.wait([blocker], timeout=0)
+        assert not ready, "the blocked head finished before its gate"
+        assert rt.lease_revokes > rev0, \
+            "get()-blocked lease head kept its unstarted slots pinned"
+    finally:
+        open(gate, "w").close()
     assert ray_tpu.get(blocker, timeout=30) == "s"
 
 

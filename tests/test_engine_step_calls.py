@@ -171,7 +171,8 @@ def _straight_line(eng, key, prompt, n_new, slot, temperature, top_p):
     pools = [tuple(jnp.zeros_like(a) for a in layer)
              for layer in eng._pools]
     n_pages = -(-(len(prompt) + n_new) // ps)
-    table = np.full((S, eng._pages_per_slot), eng._trash_page, np.int32)
+    table = np.full((S, eng._pages.pages_per_slot), eng._pages.trash_page,
+                    np.int32)
     table[slot, :n_pages] = np.arange(n_pages)
     table = jnp.asarray(table)
     pad = eng._bucket(len(prompt))

@@ -108,7 +108,8 @@ def test_engine_logits_against_the_reference(tiny, prompt_len):
         assert not {"_dispatch_prefill", "_dispatch_decode",
                     "_apply_counted"} & set(vars(eng))
         assert len(eng._free_slots) == 3 and not eng._active
-        assert len(eng._free_pages) == eng._n_pages
+        pages = eng._pages.kv_pages()
+        assert pages["free"] == pages["total"] and not pages["in_use"]
         assert eng.generate_sync(prompt, max_new_tokens=6) == answer
     finally:
         eng.shutdown()

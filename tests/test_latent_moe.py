@@ -324,9 +324,9 @@ def test_prefix_registration_and_page_copy_on_a_latent_pool(share):
         got = eng.generate_sync(suffix, max_new_tokens=6, prefix_id=pid)
         assert got == want
         assert eng.get_stats()["prefix_tokens_saved"] == 12
-        # _copy_page_impl on the pool's own arrays: page 1 onto page 3
+        # the allocator's copy_page on the pool's own arrays: page 1 onto 3
         before = [np.asarray(layer[0]) for layer in eng._pools]
-        pools = eng._copy_page_impl(eng._pools, jnp.int32(1), jnp.int32(3))
+        pools = eng._pages.copy_page(eng._pools, jnp.int32(1), jnp.int32(3))
         for old, (new,) in zip(before, pools):
             np.testing.assert_array_equal(np.asarray(new)[24:32],
                                           old[8:16])
@@ -396,7 +396,7 @@ def _decode_text(name: str):
         s = 5
         return jax.jit(eng._decode_paged_impl,
                        static_argnames=("window_pages",)).lower(
-            params, eng._pools, eng._page_table, eng._state.lengths,
+            params, eng._pools, eng._pages.rows(), eng._state.lengths,
             jnp.zeros((s,), jnp.int32), jnp.ones((s,), bool),
             jnp.zeros((s,), jnp.float32), jnp.ones((s,), jnp.float32),
             jax.random.PRNGKey(0), window_pages=2).as_text()

@@ -157,7 +157,8 @@ def test_engine_logits_against_the_reference(tiny, prompt_len):
                     "_apply_counted"} & set(vars(eng))
         assert eng.model is tiny[0]
         assert len(eng._free_slots) == 3 and not eng._active
-        assert len(eng._free_pages) == eng._n_pages
+        pages = eng._pages.kv_pages()
+        assert pages["free"] == pages["total"] and not pages["in_use"]
         assert eng.generate_sync(prompt, max_new_tokens=6) == answer
         stats = eng.get_stats()
     finally:
@@ -216,7 +217,7 @@ def test_an_idle_rows_conv_state_is_unchanged_by_a_decode_step(tiny):
     eng = _engine(tiny, max_prefill_batch=1)
     try:
         eng.generate_sync(np.arange(1, 20), max_new_tokens=4)
-        conv = [i for i, c in enumerate(eng._cache_spec) if c.by_slot]
+        conv = [i for i, c in enumerate(eng._pages.spec) if c.by_slot]
         before = [np.asarray(eng._pools[i][0]) for i in conv]
         eng.generate_sync(np.arange(5, 30), max_new_tokens=9)
         after = [np.asarray(eng._pools[i][0]) for i in conv]

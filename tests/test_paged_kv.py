@@ -235,3 +235,30 @@ def test_default_config_is_paged_with_scratch_slot(tiny_llm):
         assert len(eng.generate_sync(np.arange(2, 10), 4)) == 4
     finally:
         eng.shutdown()
+
+
+def test_the_dispatched_lengths_the_benchmark_reads_are_the_allocators(
+        tiny_llm):
+    """`LLMEngine._disp_len` is what benchmarks/harness/replica.py reads
+    (from its sampler's thread) for the rooflines' live context: a
+    live, read-only view of the page allocator's own mirror."""
+    eng = _engine(tiny_llm)
+    seen = []
+
+    def dispatch_decode(*args):
+        type(eng)._dispatch_decode(eng, *args)
+        seen.append(dict(getattr(eng, "_disp_len", {}) or {}))
+    try:
+        assert dict(eng._disp_len) == {}
+        slot = eng._free_slots[-1]
+        eng._dispatch_decode = dispatch_decode
+        eng.generate_sync(np.arange(2, 12) % 128, max_new_tokens=8)
+        # the prompt's 10, and one more with every decode dispatched
+        # (the loop may have dispatched past the request's last token)
+        assert seen[:7] == [{slot: 11 + i} for i in range(7)]
+        assert dict(eng._disp_len) == {}
+        assert eng._disp_len is eng._pages.dispatched_lengths
+        with pytest.raises(TypeError):
+            eng._disp_len[0] = 0
+    finally:
+        eng.shutdown()
