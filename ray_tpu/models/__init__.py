@@ -25,6 +25,9 @@ _REGISTRY = {
     "mixtral-debug": lambda **kw: Mixtral(MixtralConfig.debug(**kw)),
     "sarvam-105b": lambda **kw: LatentMoE(LatentMoEConfig.sarvam_105b(**kw)),
     "latent-moe-debug": lambda **kw: LatentMoE(LatentMoEConfig.debug(**kw)),
+    "xing4.0-29b-a4b": lambda **kw: LatentMoE(
+        LatentMoEConfig.xing4_29b_a4b(**kw)),
+    "xing-debug": lambda **kw: LatentMoE(LatentMoEConfig.xing_debug(**kw)),
     "olmo-hybrid-7b": lambda **kw: Hybrid(HybridConfig.olmo_hybrid_7b(**kw)),
     "hybrid-debug": lambda **kw: Hybrid(HybridConfig.debug(**kw)),
     "lfm2-24b-a2b": lambda **kw: Hybrid(HybridConfig.lfm2_24b_a2b(**kw)),

@@ -1,14 +1,21 @@
 """Latent-attention sparse-expert decoders (the `sarvam_mla` family:
-sarvam-105b), TPU-first.
+sarvam-105b; `xing4_0`: Xing4.0-29B-A4B), TPU-first.
 
 A block is latent attention followed by a dense SwiGLU MLP (the leading
 `first_dense` layers) or by an expert layer: routed SwiGLU experts beside
-a shared one.
+a shared one. Its residual path is the plain sum (`hc_mult` None) or
+`hc_mult` streams of `d_model` mixed around each of the two sub-layers
+by manifold-constrained hyper-connections (ops/hyper_connections.py has
+the equations): the hidden state is then (B, S, hc_mult * d_model), the
+embedding repeated into every stream on the way in and the streams
+summed before the final norm.
 
 Latent attention, token at position p, h = RMSNorm(x):
   q = W_q h, heads of [nope | rope]; each head's query takes a learned
   RMSNorm (one weight shared by the heads), then its rope part is
-  rotated. [c | k_r] = W_dkv h; c takes a learned RMSNorm, k_r (ONE rope
+  rotated. With `q_lora_rank` the query is low-rank instead,
+  q = W_qb RMSNorm(W_qa h), and takes no norm a head.
+  [c | k_r] = W_dkv h; c takes a learned RMSNorm, k_r (ONE rope
   key for all heads) is rotated. What is cached is [c | k_r], a token's
   latent. Keys and values are [k_nope_h | v_h] = W_ukv,h c.
 Two forms of the same mathematics:
@@ -40,7 +47,7 @@ width.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -48,6 +55,7 @@ import jax.numpy as jnp
 
 from ..ops import (apply_rotary, rms_norm, swiglu, yarn_frequencies,
                    yarn_softmax_scale)
+from ..ops import hyper_connections as hc
 from ..ops.attention import (LayerCache, PagedLatent,
                              latent_cached_attention, uneven_head_attention)
 from ..ops.moe import MOE_STATS, moe_dropless, route
@@ -64,6 +72,9 @@ class LatentMoEConfig:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
+    # None: a full-rank query with a learned norm a head (sarvam's); a
+    # rank: q = W_qb RMSNorm(W_qa h), no norm a head
+    q_lora_rank: Optional[int] = None
     d_ff: int = 16384               # the dense layers' SwiGLU
     first_dense: int = 1            # leading layers with a dense MLP
     d_expert: int = 2048            # one expert's (and the shared) width
@@ -84,6 +95,11 @@ class LatentMoEConfig:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 1.0
+    # residual streams (ops/hyper_connections.py); None: the plain sum
+    hc_mult: Optional[int] = None
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     # storage dtype of embeddings and matmul kernels; norm weights, the
@@ -108,6 +124,10 @@ class LatentMoEConfig:
         return (self.n_experts if self.expert_count is None
                 else self.expert_count)
 
+    def dense_ff(self, i: int) -> bool:
+        """Layer i's feed-forward is the dense SwiGLU, not experts."""
+        return i < self.first_dense
+
     @property
     def q_head_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
@@ -123,6 +143,14 @@ class LatentMoEConfig:
         return -(-self.latent_width // 128) * 128
 
     @property
+    def hc_params(self) -> Optional[hc.HCParams]:
+        if self.hc_mult is None:
+            return None
+        return hc.HCParams(self.hc_mult, self.hc_sinkhorn_iters,
+                           self.hc_eps, self.norm_eps,
+                           tuple(self.hc_res_clamp))
+
+    @property
     def softmax_scale(self) -> float:
         return yarn_softmax_scale(self.q_head_dim, self.rope_factor,
                                   self.rope_mscale_all_dim)
@@ -134,12 +162,41 @@ class LatentMoEConfig:
         return LatentMoEConfig(**kw)
 
     @staticmethod
+    def xing4_29b_a4b(**kw) -> "LatentMoEConfig":
+        """Xing4.0-29B-A4B as published (config.json, model_type
+        xing4_0): 40 layers, the first 2 dense; four residual streams, a
+        low-rank query, 64 experts of 1 024, 4 a token. `max_seq_len`
+        stays the rope tables' rows (the published 262 144 positions
+        change no frequency); the multi-token-prediction module
+        (`num_nextn_predict_layers` 1) is a training objective and a
+        draft head, and is not built (docs/SERVING.md)."""
+        return LatentMoEConfig(**{**dict(
+            vocab_size=131072, d_model=3584, n_layers=40, n_heads=32,
+            q_lora_rank=768, d_ff=9216, first_dense=2, d_expert=1024,
+            n_experts=64, experts_per_token=4, routed_scaling=2.0,
+            rope_factor=64.0, hc_mult=4, hc_sinkhorn_iters=20,
+            hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0)), **kw})
+
+    @staticmethod
     def debug(**kw) -> "LatentMoEConfig":
         return LatentMoEConfig(**{**dict(
             vocab_size=256, d_model=64, n_layers=3, n_heads=4,
             qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, kv_lora_rank=32,
             d_ff=128, d_expert=32, n_experts=8, experts_per_token=2,
             max_seq_len=128, rope_original_max_len=32), **kw})
+
+    @staticmethod
+    def xing_debug(**kw) -> "LatentMoEConfig":
+        """The debug shape with the `xing4_0` mechanisms: four residual
+        streams, a low-rank query, every expert held."""
+        return LatentMoEConfig.debug(**{**dict(
+            hc_mult=4, q_lora_rank=24, routed_scaling=2.0,
+            rope_factor=64.0), **kw})
+
+
+# spreads of the seeded mapping biases (LatentMoEBlock._mapping)
+HC_GATE_SPREAD = 0.5
+HC_RES_SPREAD = 1.5
 
 
 def _dense(cfg: LatentMoEConfig, features: int, name: str):
@@ -166,11 +223,21 @@ class LatentAttention(nn.Module):
             # and copies the whole kernel column-major in HBM to match,
             # 100 MB a layer a step (tests/test_tpu_compile.py:
             # test_latent_attention_copies_no_parameter_in_hbm)
-            q = jax.lax.optimization_barrier(
-                _dense(cfg, h * cfg.q_head_dim, "q_proj")(x))
-            q = rms_norm(q.reshape(b, s, h, cfg.q_head_dim),
-                         self.param("q_norm", nn.initializers.ones,
-                                    (cfg.q_head_dim,)), cfg.norm_eps)
+            if cfg.q_lora_rank is None:
+                q = jax.lax.optimization_barrier(
+                    _dense(cfg, h * cfg.q_head_dim, "q_proj")(x))
+                q = rms_norm(q.reshape(b, s, h, cfg.q_head_dim),
+                             self.param("q_norm", nn.initializers.ones,
+                                        (cfg.q_head_dim,)), cfg.norm_eps)
+            else:
+                with jax.named_scope("mla.q_lora"):
+                    q = rms_norm(
+                        _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x),
+                        self.param("q_a_norm", nn.initializers.ones,
+                                   (cfg.q_lora_rank,)), cfg.norm_eps)
+                    q = jax.lax.optimization_barrier(
+                        _dense(cfg, h * cfg.q_head_dim, "q_b_proj")(q)
+                    ).reshape(b, s, h, cfg.q_head_dim)
             q_nope = q[..., :dn]
             q_rope = apply_rotary(q[..., dn:], cos, sin, positions)
             down = _dense(cfg, cfg.latent_width, "kv_down_proj")(x)
@@ -229,8 +296,12 @@ class ShareMoE(nn.Module):
     """Sigmoid-routed SwiGLU experts, of which this layer holds a share,
     beside a shared expert every token passes through (none where
     `n_shared_experts` is 0). `cfg` is any config with the fields read
-    here (models/hybrid.py's has them too)."""
+    here (models/hybrid.py's has them too). `stats_tail`: zeros behind
+    the layer's `step_stats` vector, for a model whose vector carries
+    further counters behind ops/moe.py's (the engine sums whole
+    vectors)."""
     cfg: Any
+    stats_tail: int = 0
 
     @nn.compact
     def __call__(self, x, row_mask=None):
@@ -265,6 +336,8 @@ class ShareMoE(nn.Module):
             tokens, weights, top_idx, w_gate, w_up, w_down,
             None if row_mask is None else row_mask.reshape(b * s),
             first=cfg.expert_first, count=held)
+        if self.stats_tail:
+            stats = jnp.pad(stats, (0, self.stats_tail))
         self.sow("step_stats", "moe", stats)
         # which experts each position chose, for a reference check
         self.sow("routing", "top_idx",
@@ -288,6 +361,9 @@ class LatentMoEBlock(nn.Module):
                                  (cfg.d_model,))
         mlp_norm_w = self.param("mlp_norm", nn.initializers.ones,
                                 (cfg.d_model,))
+        if cfg.hc_mult is not None:
+            return self._streams(x, cos, sin, cache, positions, row_mask,
+                                 attn_norm_w, mlp_norm_w)
         h, new_cache = LatentAttention(cfg, name="attention")(
             rms_norm(x, attn_norm_w, cfg.norm_eps), cos, sin, cache,
             positions)
@@ -299,6 +375,52 @@ class LatentMoEBlock(nn.Module):
             x = x + ShareMoE(cfg, name="moe")(h, row_mask)
         return x, new_cache
 
+    def _mapping(self, name: str):
+        """One sub-layer's (phi, b, a). phi is drawn so that m is about
+        unit normal on a normed stream; b and a so that no mapping is
+        constant or saturated under seeded weights: a = 1 on every arm,
+        b normal with spread HC_GATE_SPREAD on the two sigmoid arms
+        (gates between ~0.1 and ~0.9 of their range) and HC_RES_SPREAD
+        on Hres's logits (entries a few e-foldings apart: 20 Sinkhorn
+        iterations converge on all but a few tokens in a thousand, 2 do
+        not)."""
+        cfg = self.cfg
+        n = cfg.hc_mult
+        width = n * cfg.d_model
+        spread = jnp.concatenate([jnp.full((2 * n,), HC_GATE_SPREAD),
+                                  jnp.full((n * n,), HC_RES_SPREAD)])
+        return (
+            self.param(f"hc_{name}_phi",
+                       nn.initializers.normal(width ** -0.5),
+                       (hc.n_maps(n), width), cfg.param_dtype),
+            self.param(f"hc_{name}_b", lambda k, shape: spread
+                       * jax.random.normal(k, shape), (hc.n_maps(n),)),
+            self.param(f"hc_{name}_a", nn.initializers.ones, (3,)))
+
+    def _streams(self, x, cos, sin, cache, positions, row_mask,
+                 attn_norm_w, mlp_norm_w):
+        """The block over `hc_mult` residual streams, x (B, S, n * d):
+        each sub-layer reads h = sum_i Hpre[i] X[i], takes its own
+        pre-norm, and writes X'[i] = sum_j Hres[i, j] X[j] + Hpost[i] y."""
+        cfg, hp = self.cfg, self.cfg.hc_params
+        h, maps = hc.read(x, *self._mapping("attn"), hp)
+        y, new_cache = LatentAttention(cfg, name="attention")(
+            rms_norm(h, attn_norm_w, cfg.norm_eps), cos, sin, cache,
+            positions)
+        x = hc.write(x, y, maps, hp.n)
+        counted = hc.counters(maps, row_mask)
+        h, maps = hc.read(x, *self._mapping("mlp"), hp)
+        h = rms_norm(h, mlp_norm_w, cfg.norm_eps)
+        if self.dense:
+            y = _SwiGLU(cfg, cfg.d_ff, name="mlp")(h)
+        else:
+            y = ShareMoE(cfg, len(hc.HC_STATS), name="moe")(h, row_mask)
+        x = hc.write(x, y, maps, hp.n)
+        counted = counted + hc.counters(maps, row_mask)
+        self.sow("step_stats", "hc",
+                 jnp.pad(counted, (len(MOE_STATS), 0)))
+        return x, new_cache
+
 
 class LatentMoE(nn.Module):
     """tokens (B, S) -> (logits, cache): the calling convention of Llama
@@ -306,9 +428,14 @@ class LatentMoE(nn.Module):
     None (the plain forward, expanded form) or one PagedLatent a layer
     (`paged_cache_spec`). Under `mutable=["step_stats"]` every expert
     layer leaves the int32 vector `step_stats` names (ops/moe.py:
-    MOE_STATS), counted over the rows `row_mask` (B, S) marks as real."""
+    MOE_STATS), counted over the rows `row_mask` (B, S) marks as real;
+    with residual streams every block also leaves its two sub-layers'
+    ops/hyper_connections.py:HC_STATS behind them in the same vector."""
     cfg: LatentMoEConfig
-    step_stats = MOE_STATS
+
+    @property
+    def step_stats(self):
+        return MOE_STATS + (hc.HC_STATS if self.cfg.hc_mult else ())
 
     @nn.compact
     def __call__(self, tokens, cache=None, positions=None, row_mask=None):
@@ -316,6 +443,8 @@ class LatentMoE(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      embedding_init=nn.initializers.normal(0.02))(tokens)
+        if cfg.hc_mult is not None:
+            x = jnp.tile(x, (1, 1, cfg.hc_mult))
         cos, sin = yarn_frequencies(
             cfg.qk_rope_dim, cfg.max_seq_len, cfg.rope_theta,
             factor=cfg.rope_factor,
@@ -323,11 +452,14 @@ class LatentMoE(nn.Module):
             beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow)
         new_cache = []
         for i in range(cfg.n_layers):
-            x, c = LatentMoEBlock(cfg, i < cfg.first_dense,
+            x, c = LatentMoEBlock(cfg, cfg.dense_ff(i),
                                   name=f"layer_{i}")(
                 x, cos, sin, None if cache is None else cache[i],
                 positions, row_mask)
             new_cache.append(c)
+        if cfg.hc_mult is not None:
+            x = x.reshape(*x.shape[:-1], cfg.hc_mult, cfg.d_model).astype(
+                jnp.float32).sum(-2).astype(cfg.dtype)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.d_model,)), cfg.norm_eps)
         logits = _LMHead(cfg.vocab_size, cfg.param_dtype,

@@ -43,8 +43,11 @@ def test_a_llama_shaped_file_with_another_head_dim_is_still_refused(  # noqa: F8
     root = os.path.dirname(_HERE)
 
     def llama_shaped(entry):
+        # (a latent-attention file may publish num_key_value_heads too:
+        # what it caches is its kv_lora_rank)
         with open(os.path.join(root, entry["file"])) as f:
-            return "num_key_value_heads" in json.load(f)
+            cfg = json.load(f)
+        return "num_key_value_heads" in cfg and "kv_lora_rank" not in cfg
     _refused(dict(manifest, configs=[c for c in manifest["configs"]
                                      if llama_shaped(c)]), tmp_path)
 
@@ -157,6 +160,9 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
     from benchmarks import run as runmod
     from ray_tpu.models import Llama, LlamaConfig
     from ray_tpu.observability.profiler import GC_SPANS
+    # counters a model with residual streams leaves behind its
+    # expert layers' (the engine seeds them from `model.step_stats`)
+    from ray_tpu.ops.hyper_connections import HC_STATS
     from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig, engine
     readers = os.path.join(_BENCH, "readers")
     if readers not in sys.path:
@@ -198,14 +204,15 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
                 assert path[1] in seeded and path[2] in (0, 1, 3), (
                     name, path)
             else:
-                assert (len(path) == 1 and path[0].startswith(
-                    ("decode_", "prefill_"))) \
+                assert (len(path) == 1 and (path[0].startswith(
+                    ("decode_", "prefill_")) or path[0] in HC_STATS)) \
                     or stats_delta._at(fresh, path) is not None, (
                         name, path)
         got = runmod.read_metric(_BENCH, name[:-5], stats_run)
         assert got is None or isinstance(got, float), name
-    assert len(found) >= 19 and set(found) <= listed
-    assert "decode_live_state_share" in found
+    assert len(found) >= 21 and set(found) <= listed
+    assert {"decode_live_state_share", "hc_unconverged_share",
+            "hc_clamped_share"} <= set(found)
 
 
 _NEW_IN_PR_37 = {
@@ -327,10 +334,12 @@ def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
     whole = runmod.load_manifest()
     entry, = [m for m in whole["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [_LFM2_CELL]
-    assert whole["workloads"][-1]["name"] == _LFM2_CELL
-    assert whole["configs"][-1]["name"] == whole["workloads"][-1]["config"]
+    # the seventh cell and the sixth configuration; PR 48's go behind
+    assert whole["workloads"][6]["name"] == _LFM2_CELL
+    assert whole["configs"][5]["name"] == whole["workloads"][6]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    assert set(names[-3:]) == set(_NEW_IN_PR_40)
+    at = len(names) - len(_NEW_IN_PR_48) - 3
+    assert set(names[at:at + 3]) == set(_NEW_IN_PR_40)
 
 
 def test_the_lfm2moe_cell_is_in_what_every_saturated_serve_cell_reports():
@@ -343,14 +352,178 @@ def test_the_lfm2moe_cell_is_in_what_every_saturated_serve_cell_reports():
     for m in whole["end_to_end"] + whole["per_layer"]:
         w = m.get("workloads", [])
         if {"sarvam105b_decode_sat", "olmohybrid7b_decode_sat"} <= set(w):
-            assert w[-1] == _LFM2_CELL, m["name"]
+            assert w[w.index("olmohybrid7b_decode_sat") + 1] \
+                == _LFM2_CELL, m["name"]
     by_name = {m["name"]: m for m in whole["per_layer"]}
     for name in ("moe_dev_share", "moe_expert_load_max_over_mean",
                  "moe_pad_row_share", "decode_live_state_share"):
-        assert by_name[name]["workloads"][-1] == _LFM2_CELL, name
+        assert _LFM2_CELL in by_name[name]["workloads"][-2:], name
     for name in ("paged_kernel_roofline", "decode_step_roofline",
                  "expert_matmul_roofline", "decode_step_roofline.moe",
                  "expert_matmul_roofline.share", "latent_kernel_roofline",
                  "gdn_kernel_roofline", "paged_kernel_roofline.hybrid",
                  "moe_local_assignment_share"):
         assert _LFM2_CELL not in by_name[name]["workloads"], name
+
+
+_NEW_IN_PR_48 = {"hc_kernel_dev_share": None,
+                 "hc_kernel_roofline": "hc_kernels",
+                 "hc_unconverged_share": None, "hc_clamped_share": None,
+                 "latent_kernel_roofline.xing": "latent_kernel",
+                 "expert_matmul_roofline.xing": "experts",
+                 "decode_step_roofline.xing": "step"}
+_XING_CELL = "xing29b_decode_sat"
+
+
+def _xing_window(counters=True, kernels=True):
+    """What a traced run of the cell hands a reader: two readings of
+    `get_stats()` 1 000 decode steps apart (129 rows through 12
+    sub-layers a step, 3 rows in a thousand unconverged, none clamped),
+    a reduced trace of 100 decode runs, the model section, the peaks."""
+    import json
+    from benchmarks.harness import replica_xing
+    from benchmarks.harness.peaks import PEAKS
+    with open(os.path.join(_BENCH, "configs",
+                           "xing4.0-29b-a4b-serve-l6.json")) as f:
+        cfg = json.load(f)
+
+    def reading(k):
+        out = {"decode_steps": k, "prefill_calls": k // 50,
+               "decode_pages_live": k * 128 * 12,
+               "moe_assignments": k * 128 * 4 * 5, "moe_rows": k * 128 * 5,
+               "moe_experts_touched": k * 64 * 5,
+               "moe_expert_load_max": k * 12 * 5, "moe_pad_rows": k * 5,
+               "moe_routed_assignments": k * 128 * 4 * 5}
+        if counters:
+            out.update(hc_rows=k * 128 * 12, hc_clamped_rows=0,
+                       hc_unconverged_rows=k * 128 * 12 * 3 // 1000)
+        return out
+    ops = {"gmm": 1.2, "latent_decode_attention": 0.2, "fusion": 0.5}
+    if kernels:
+        ops.update(hc_mix_in=0.04, hc_mix_out=0.06)
+    return {"stats0": reading(1000), "stats1": reading(2000),
+            "trace": {"busy_s": 2.0, "window_s": 2.1, "ops": ops,
+                      "modules": {"jit__decode_paged_step":
+                                  {"count": 100, "seconds": 1.9}}},
+            "peaks": PEAKS["TPU v5e"], "config": cfg,
+            "model": replica_xing.model_section(cfg),
+            "trace_contexts": [900] * 128}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_48))
+def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
+    """Each file names a reader with a `read` that takes the file's
+    arguments, the decode program the engine has and, where it sums a
+    kernel's time, a kernel the program calls by that name; from a
+    window of known counters and kernel times it reads a share under
+    100 %; from a program without the counters, a trace without the
+    kernels, another family's model section or an empty run it reads
+    None and does not raise (what the parent's traced runs hand it);
+    its entry lists the one cell, behind every accepted entry."""
+    import inspect
+    import json
+    import re
+    from benchmarks import run as runmod
+    from ray_tpu.ops.pallas import hyper_connections as kernels
+    from ray_tpu.ops.pallas.latent_attention import latent_decode_attention
+    from ray_tpu.serve.llm.engine import LLMEngine
+    with open(os.path.join(_BENCH, "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    readers = os.path.join(_BENCH, "readers")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    read = importlib.import_module(spec["reader"]).read
+    assert set(spec["args"]) <= set(inspect.signature(read).parameters)
+    what = _NEW_IN_PR_48[name]
+    kernel = spec["args"].get("name_re")
+    if what is not None:
+        assert spec["args"]["what"] == what
+        assert re.search(spec["args"]["module_re"],
+                         "jit_" + LLMEngine._decode_paged_step.__name__)
+    if name.startswith("hc_kernel"):
+        text = inspect.getsource(kernels)
+        assert re.search(kernel, "hc_mix_in") and 'name="hc_mix_in"' in text
+        assert re.search(kernel, "hc_mix_out") \
+            and 'name="hc_mix_out"' in text
+    elif what == "latent_kernel":
+        assert re.search(kernel, latent_decode_attention.__name__)
+    elif what == "experts":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        assert re.search(kernel, gmm.__name__)
+    got = runmod.read_metric(_BENCH, name, _xing_window())
+    assert isinstance(got, float) and 0.0 <= got < 100.0, (name, got)
+    if name == "hc_unconverged_share":
+        assert got == pytest.approx(0.3, abs=0.01)
+    if name == "hc_kernel_dev_share":
+        assert got == pytest.approx(5.0)
+    # rule (beta): a parent's program has no such counter, kernel or
+    # model section, and its traced run still gives its result
+    lacking = _xing_window(counters=False, kernels=False)
+    if name.startswith("hc_"):
+        if name == "hc_kernel_dev_share":
+            assert runmod.read_metric(_BENCH, name, lacking) == 0.0
+        else:
+            assert runmod.read_metric(_BENCH, name, lacking) is None
+    other = dict(_xing_window(), model={"hidden_size": 4096,
+                                        "kv_lora_rank": 512})
+    if what is not None:
+        assert runmod.read_metric(_BENCH, name, other) is None
+    assert runmod.read_metric(_BENCH, name, {}) is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == [_XING_CELL]
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert (entry["unit"], entry["moves"]) == ("%", "out_tok_s")
+    assert entry["source"] == ("program_counter" if what is None
+                               and not name.startswith("hc_kernel")
+                               else "device_trace")
+    assert whole["workloads"][-1]["name"] == _XING_CELL
+    assert whole["configs"][-1]["name"] == whole["workloads"][-1]["config"]
+    names = [m["name"] for m in whole["per_layer"]]
+    assert set(names[-7:]) == set(_NEW_IN_PR_48)
+
+
+@pytest.mark.parametrize("name, reads", [
+    ("moe_expert_load_max_over_mean", 64 * 12 / (128 * 4)),
+    ("moe_pad_row_share", 100 / 129),
+    ("moe_local_assignment_share", 100.0),
+    ("moe_dev_share", 60.0), ("latent_kernel_dev_share", 10.0)])
+def test_an_accepted_expert_metric_reads_the_xing_window(name, reads):
+    """The accepted readers of the lists the cell was appended to find
+    their keys in this model's section (the published file says
+    n_routed_experts where `moe_counter` reads num_experts: the first
+    check of PR 48 was refused for a traced line without
+    `moe_expert_load_max_over_mean`) and in its counters."""
+    from benchmarks import run as runmod
+    assert runmod.read_metric(_BENCH, name, _xing_window()) \
+        == pytest.approx(reads)
+
+
+def test_the_xing_cell_is_in_what_every_saturated_serve_cell_reports():
+    """Eight cells, one of them on four chips. Every list that names the
+    sarvam cell and is not read by that model's own cost arithmetic
+    names this one too, at its end; `out_tok_s` as well; no list of
+    another model's cost arithmetic or state does."""
+    from benchmarks import run as runmod
+    whole = runmod.load_manifest()
+    assert len(whole["workloads"]) == 8 and len(whole["configs"]) == 7
+    assert [w["name"] for w in whole["workloads"] if w["chips"] == 4] \
+        == ["mistral7b_train_fsdp2_tp2"]
+    own = {"latent_kernel_roofline", "expert_matmul_roofline.share",
+           "decode_step_roofline.latent_moe"}
+    for m in whole["end_to_end"] + whole["per_layer"]:
+        w = m.get("workloads", [])
+        if "sarvam105b_decode_sat" in w and m["name"] not in own:
+            assert w[-1] == _XING_CELL, m["name"]
+        elif m["name"] not in _NEW_IN_PR_48:
+            assert _XING_CELL not in w, m["name"]
+    out, = [m for m in whole["end_to_end"] if m["name"] == "out_tok_s"]
+    assert out["workloads"][-1] == _XING_CELL
+    cell = whole["workloads"][-1]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "xing4.0-29b-a4b-serve-l6", "decode_sat_xing", 1)
+    config = whole["configs"][-1]
+    assert config["reduced"] == ["num_hidden_layers",
+                                 "first_k_dense_replace"]
+    assert len(cell["why"]) <= 200 and len(config["why"]) <= 200

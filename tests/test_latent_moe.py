@@ -217,7 +217,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
                     else v) for k, v in params.items()}
         out, sown = ShareMoE(part).apply({"params": held}, h,
                                          mutable=["step_stats"])
-        stats = dict(zip(LatentMoE.step_stats,
+        stats = dict(zip(LatentMoE(part).step_stats,
                          np.asarray(sown["step_stats"]["moe"][0])))
         assert stats["moe_routed_assignments"] == 19 * 2
         ran += stats["moe_assignments"]

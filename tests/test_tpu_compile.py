@@ -507,3 +507,60 @@ def test_train_step_hides_its_tp_sums_on_fsdp2_tp2(
     assert "tpu_custom_call" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= temp_gib * 2 ** 30, temp / 2 ** 30
+
+
+def test_xing_decode_step_fuses_the_residual_path_and_copies_nothing(
+        chip, monkeypatch):
+    """The benchmark's Xing4.0-29B-A4B cut (1 dense + 5 expert layers at
+    the published widths, bf16) in a decode step of 128 slots and the
+    scratch row against donated pools of 262 144 tokens: each of the 12
+    sub-layers' residual path is exactly two Mosaic kernels (`hc_mix_in`,
+    `hc_mix_out`), 24 in the program, beside the latent kernel of every
+    layer and the grouped matmuls; no parameter of 32 MB or more is
+    copied in HBM (the low-rank query's `q_b_proj` product is pinned
+    flat as `q_proj`'s is) and no pool is (1.9 GiB of them, updated in
+    place)."""
+    from ray_tpu.models import LatentMoE, LatentMoEConfig
+    from ray_tpu.ops.attention import PagedLatent
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LatentMoEConfig.xing4_29b_a4b(
+        n_layers=6, first_dense=1, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    model = LatentMoE(cfg)
+    rows, pages, ps, pool = 129, 64, 64, 262144
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(params, pools, tokens, table, lengths, mask):
+        entries = [PagedLatent(flat, table, lengths, ps) for flat in pools]
+        (logits, new), sown = model.apply(
+            {"params": params}, tokens, cache=entries,
+            positions=lengths[:, None], row_mask=mask,
+            mutable=["step_stats"])
+        counted = sum(jax.tree_util.tree_leaves(sown["step_stats"]))
+        return logits.argmax(-1), counted, [e.flat for e in new]
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]))
+    pools = [sds((pool + ps, cfg.cache_width), jnp.bfloat16)] * cfg.n_layers
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pools, sds((rows, 1), jnp.int32),
+        sds((rows, pages), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows, 1), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    named = {k: sum(f"/{k}" in line or f'"{k}"' in line for line in calls)
+             for k in ("hc_mix_in", "hc_mix_out", "latent_decode_attention")}
+    assert named == {"hc_mix_in": 12, "hc_mix_out": 12,
+                     "latent_decode_attention": 6}, named
+    assert parameter_copies_in_hbm(compiled) == []
+    pool_copies = [line.strip() for line in text.splitlines()
+                   if re.search(r"= bf16\[%d,%d\]\S* copy(-start)?\("
+                                % (pool + ps, cfg.cache_width), line)]
+    assert pool_copies == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * (pool + ps) * cfg.cache_width * 2
+    assert mem.temp_size_in_bytes < 0.6 * 2 ** 30
