@@ -23,7 +23,10 @@ paged KV, streaming) — re-designed TPU-first:
 * Sampling (greedy / temperature / global top-k / per-request nucleus
   top-p) happens on-device inside the jitted step; only the sampled
   token ids (max_slots int32) cross to host per step. Per-request stop
-  token ids terminate a stream like EOS.
+  token ids terminate a stream like EOS. The arg-max runs in every
+  call; the draw (divide, noise, a second arg-max) only in a call with
+  a temperature above 0, the nucleus sort only with a top_p below 1
+  among its rows (stats["decode_steps_drawn"] counts the former).
 * Pipelined host loop: the loop dispatches step programs AHEAD of the
   host-side token fetch (device->host copies start at dispatch time,
   `copy_to_host_async`) and drains the oldest result once more than a
@@ -580,6 +583,7 @@ class LLMEngine:
         # (active mask, temperatures, top-p) over the slots, rebuilt
         # when the active set changed
         self._mask_temps = None
+        self._temps_drawn = False   # some temperature of those is > 0
         self._guided_allow_buf = None
         self._guided_prev = None
         self._spec_idle = 0
@@ -597,6 +601,9 @@ class LLMEngine:
         # prompt+budget at admission, so mid-stream KV eviction (vLLM's
         # preemption trigger) cannot occur by construction
         self.stats = {"prefills": 0, "decode_steps": 0,
+                      # the decode steps whose temperatures had a value
+                      # above 0: the sampler's draw ran in those alone
+                      "decode_steps_drawn": 0,
                       "tokens_generated": 0, "prefix_tokens_saved": 0,
                       # decode rows: steps x max_slots of them ran; a
                       # row's token is emitted, or discarded (its slot
@@ -800,8 +807,9 @@ class LLMEngine:
         if self.cfg.top_k and self.cfg.top_k > 0:
             kth = jnp.sort(logits, axis=-1)[:, -self.cfg.top_k][:, None]
             logits = jnp.where(logits < kth, -jnp.inf, logits)
+        # every call needs the arg-max; each further pass over the
+        # (N, V) logits runs only where a row of THIS call asks for it
         greedy = jnp.argmax(logits, axis=-1)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
 
         def nucleus(scaled):
             # smallest prefix of the prob-sorted vocab whose mass reaches
@@ -817,12 +825,24 @@ class LLMEngine:
             use_top_p = (top_ps < 1.0)[:, None]
             return jnp.where(use_top_p & ~keep, -jnp.inf, scaled)
 
-        # the full-vocab sort only runs when some active request asked
-        # for top_p < 1 — the default path stays argmax + categorical
-        scaled = jax.lax.cond(jnp.any(top_ps < 1.0), nucleus,
-                              lambda s: s, scaled)
-        sampled = jax.random.categorical(rng_key, scaled, axis=-1)
-        toks = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        def drawn():
+            # the divide stands INSIDE each branch: without nucleus it
+            # fuses with the noise and the arg-max of the draw, and the
+            # scaled logits are never written; the full-vocab sort only
+            # runs when some row asked for top_p < 1
+            t = jnp.maximum(temps, 1e-6)[:, None]
+            sampled = jax.lax.cond(
+                jnp.any(top_ps < 1.0),
+                lambda: jax.random.categorical(
+                    rng_key, nucleus(logits / t), axis=-1),
+                lambda: jax.random.categorical(
+                    rng_key, logits / t, axis=-1))
+            return jnp.where(temps > 0, sampled, greedy)
+
+        # all rows greedy (every empty slot is): no divide, no noise, no
+        # second arg-max
+        toks = jax.lax.cond(jnp.any(temps > 0), drawn,
+                            lambda: greedy).astype(jnp.int32)
         if raw_logp is None:
             logps = jnp.zeros(toks.shape, jnp.float32)
         else:
@@ -1908,7 +1928,7 @@ class LLMEngine:
             self._start_fetch(lps_dev)
         inflight.append(("prefill_batch", [r for r, _ in members],
                          toks_dev, lps_dev if self.cfg.logprobs else None,
-                         time.perf_counter_ns()))
+                         time.perf_counter_ns(), False))
 
     def _count_prefill(self, width: int, rows: int, rows_padded: int,
                        tokens: int) -> None:
@@ -1972,7 +1992,7 @@ class LLMEngine:
                 self._start_fetch(lps_dev)
             inflight.append(("prefill_chunk", [req], toks_dev,
                              lps_dev if self.cfg.logprobs else None,
-                             time.perf_counter_ns()))
+                             time.perf_counter_ns(), False))
 
     def _runtime(self, fn, *args):
         """A call of the loop into the JAX runtime that is no step
@@ -2413,10 +2433,11 @@ class LLMEngine:
                 temps[slot] = req.temperature
                 top_ps[slot] = req.top_p
             self._mask_temps = (mask, temps, top_ps)
+            self._temps_drawn = bool((temps > 0).any())
             self._mask_dirty = False
         return (*self._mask_temps, self._pages.rows(window))
 
-    def _drain_verify(self, snapshot, out_dev, ne_lp):
+    def _drain_verify(self, snapshot, out_dev, ne_lp, drawn):
         """Emit a speculative verify step's 1..K+1 tokens per slot.
         Host emission may stop early (EOS / budget) — those requests
         release immediately, so the device-side length overshoot is
@@ -2436,6 +2457,7 @@ class LLMEngine:
                     self._release(req)
             return
         self.stats["decode_steps"] += 1
+        self.stats["decode_steps_drawn"] += drawn
         self.stats["decode_slot_steps"] += self.cfg.max_slots
         for slot, req in snapshot:
             n = int(n_emit[slot])
@@ -2483,10 +2505,13 @@ class LLMEngine:
         by the (req.slot == slot, generated < budget) guards; each is
         counted in stats["decode_tokens_discarded"]. Runs inside the
         loop's `engine.emit` span: only the fetch that blocks on the
-        device is `engine.drain_wait`."""
-        kind, payload, arr, lp_arr, dispatched_ns = inflight.popleft()
+        device is `engine.drain_wait`. An entry's last field says
+        whether its decode or verify program was handed a temperature
+        above 0 (stats["decode_steps_drawn"])."""
+        kind, payload, arr, lp_arr, dispatched_ns, drawn = \
+            inflight.popleft()
         if kind == "verify":
-            self._drain_verify(payload, arr, lp_arr)
+            self._drain_verify(payload, arr, lp_arr, drawn)
             return
         spans, st = self._spans, self.stats
         try:
@@ -2541,6 +2566,7 @@ class LLMEngine:
         spans.add("request.inflight_decode",
                   time.perf_counter_ns() - dispatched_ns)
         st["decode_steps"] += rows.shape[0]
+        st["decode_steps_drawn"] += rows.shape[0] * drawn
         st["decode_slot_steps"] += rows.shape[0] * self.cfg.max_slots
         for ri, row in enumerate(rows):
             for slot, req in payload:
@@ -2700,7 +2726,7 @@ class LLMEngine:
             inflight.append(
                 ("verify", snapshot, out,
                  (n_emit, logps if self.cfg.logprobs else None),
-                 time.perf_counter_ns()))
+                 time.perf_counter_ns(), self._temps_drawn))
             return
         if self._decode_block_paged_jit is not None \
                 and allow is None and pen is None:
@@ -2722,4 +2748,5 @@ class LLMEngine:
             self._start_fetch(logps)
         inflight.append(("decode", snapshot, toks,
                          logps if self.cfg.logprobs
-                         else None, time.perf_counter_ns()))
+                         else None, time.perf_counter_ns(),
+                         self._temps_drawn))

@@ -229,6 +229,41 @@ def test_carried_key_and_sampled_tokens_follow_the_eager_chain(tiny_llm):
     assert sampled != greedy
 
 
+@pytest.mark.parametrize("decode_block", [1, 3], ids=["step", "block"])
+def test_decode_steps_drawn_counts_the_steps_with_a_sampled_row(
+        tiny_llm, decode_block):
+    """`decode_steps_drawn` is the engagement of the sampler's draw
+    branch as the host knows it: 0 over greedy traffic (a top_p below 1
+    on a greedy row asks for nothing), and the steps of the decode
+    dispatches whose slots held a request with a temperature above 0,
+    no more: a greedy request goes on alone after the sampled one."""
+    eng = _engine(tiny_llm, decode_block=decode_block)
+    with_sampled = []
+    dispatch = eng._dispatch_decode
+
+    def counted(inflight, snapshot, *rest):
+        with_sampled.append(any(r.temperature > 0 for _s, r in snapshot))
+        return dispatch(inflight, snapshot, *rest)
+    try:
+        _mixed_traffic(eng)
+        greedy = _settle(eng)
+        assert greedy["decode_steps"] > 20
+        assert greedy["decode_steps_drawn"] == 0
+        eng._dispatch_decode = counted
+        long = eng.submit(np.arange(1, 9), max_new_tokens=40)
+        short = eng.submit(np.arange(2, 12), max_new_tokens=6,
+                           temperature=0.7)
+        assert len(list(eng.stream(short))) == 6
+        assert len(list(eng.stream(long))) == 40
+        st = _settle(eng)
+    finally:
+        eng.shutdown()
+    assert 0 < sum(with_sampled) < len(with_sampled)
+    assert st["decode_steps_drawn"] == decode_block * sum(with_sampled)
+    assert (st["decode_steps"] - greedy["decode_steps"]
+            == decode_block * len(with_sampled))
+
+
 # (c) a slot and its pages under a new owner, ten results in flight --------
 @pytest.mark.parametrize("with_prefix", [False, True],
                          ids=["plain", "adopted_prefix"])

@@ -364,14 +364,19 @@ def test_a_sliced_vocabulary_stays_inside_the_slice():
 # since PR 40 their pool rows hold eight heads side by side
 # (ops/attention.py:packed_kv_shape), which is a pad and three reshapes
 # a layer more than the programs PR 31 pinned (860 and 875 operations);
-# they are pinned as they lower now.
+# they are pinned as they lower now. PR 49 moved the sampler's draw
+# under its two `cond`s (13 operations more in the text: the draw stands
+# in both branches of the inner one) and nothing else: with the one-path
+# sampler of tests/test_engine_sampler.py in `_sample_tokens`' place all
+# three, and the two of tests/test_xing.py, lower to the texts pinned
+# before it.
 PARENT_DECODE_TEXT = {
     "llama-wide":
-        "56b36e53317b79016c6b708bf35854790157a00b096b0090d208454b138e9f8d",
+        "61f3e15ccdcdd190bcbad8e0f28842fd979e002e5bab4e101813a45373f5ac6d",
     "llama-debug":
-        "c534f6c0fb8b17a7a46432eb9362b139fe398ff88adc32c995473d52ae42beaa",
+        "39100b47f48095ff7e90353673d2e12d677af9bcea3291223a198eeb3a425d3d",
     "gpt2-debug":
-        "b0d5eb74347c444ed17f7fb7489b35ff382a197ae81e043a9791538afc6d3810",
+        "736f715b5403d8f231791479cd29191b20bc4eea9743145b2cde6af4c7f14471",
 }
 
 
@@ -417,12 +422,14 @@ def test_mixtral_lowers_the_decode_program_it_had_plus_one_counter():
     in each of the two expert layers; nothing else of the program
     moved (1 209 operations on the parent commit), until PR 40 packed
     the pool rows of its 16-wide heads: seven operations a layer more
-    (two constants and a pad for K and for V, the reshapes)."""
+    (two constants and a pad for K and for V, the reshapes), and PR 49
+    put the sampler's draw under its two `cond`s: 13 more, two
+    constants and two broadcasts among them."""
     ops = collections.Counter(re.findall(r"(?:stablehlo|chlo)\.\w+",
                                          _decode_text("mixtral-debug")))
-    assert sum(ops.values()) == 1209 + 6 + 14
+    assert sum(ops.values()) == 1209 + 6 + 14 + 13
     assert (ops["stablehlo.multiply"], ops["stablehlo.constant"],
-            ops["stablehlo.broadcast_in_dim"]) == (43, 215 + 4, 331)
+            ops["stablehlo.broadcast_in_dim"]) == (43, 215 + 4 + 2, 331 + 2)
 
 
 def test_get_model_builds_the_published_and_the_debug_shape():

@@ -10,6 +10,7 @@ runs, so nothing here is a result or a time.
 import math
 import os
 import re
+import types
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -564,3 +565,76 @@ def test_xing_decode_step_fuses_the_residual_path_and_copies_nothing(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 6 * (pool + ps) * cfg.cache_width * 2
     assert mem.temp_size_in_bytes < 0.6 * 2 ** 30
+
+
+def hlo_computations(text):
+    """A compiled program's text cut into its computations: name ->
+    (signature line, instruction lines); the entry's name is "ENTRY"."""
+    found, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if m:
+            name = "ENTRY" if m.group(1) else m.group(2)
+            found[name] = (line, [])
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            found[name][1].append(line.strip())
+    return found
+
+
+def test_sampler_makes_one_pass_when_all_rows_are_greedy(chip):
+    """The engine's sampler alone at the Xing4.0 cell's decode shape
+    (128 slots and the scratch row x 131 072 float32 logits): the entry
+    computation holds the greedy arg-max, the one fusion that reads the
+    logits, and one `conditional`; the draw, its divide and its noise
+    stand under it, so a step whose rows are all greedy makes one pass.
+    A step with sampled rows and no top_p makes one more, one fusion
+    that divides, adds the noise and reduces: the scaled logits are
+    never written (only the nucleus branch, which sorts them, does)."""
+    from ray_tpu.serve.llm import LLMEngine
+    n, v = 129, 131072
+    stub = types.SimpleNamespace(
+        _jnp=jnp, _jax=jax, cfg=types.SimpleNamespace(logprobs=False,
+                                                       top_k=0))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    compiled = jax.jit(
+        lambda *a: LLMEngine._sample_tokens(stub, *a)).lower(
+            sds((n, v), jnp.float32), sds((n,), jnp.float32),
+            sds((n,), jnp.float32), sds((2,), jnp.uint32)).compile()
+    comps = hlo_computations(compiled.as_text())
+    size = f"[{n},{v}]"
+
+    def calls(lines, op):
+        return [line for line in lines if re.search(rf" {op}\(", line)]
+
+    def wide_fusions(lines):
+        """The signatures of the fused computations of `lines` that read
+        or write an N x V array."""
+        called = (comps[re.search(r"calls=%([\w.\-]+)", line).group(1)][0]
+                  for line in calls(lines, "fusion"))
+        return [signature for signature in called if size in signature]
+
+    def branches(line):
+        return [comps[name][1] for name in re.search(
+            r"branch_computations=\{([^}]*)\}",
+            line).group(1).replace("%", "").split(", ")]
+    entry = comps["ENTRY"][1]
+    assert len(wide_fusions(entry)) == 1, wide_fusions(entry)
+    outer, = calls(entry, "conditional")
+    greedy, drawn = branches(outer)     # index 0 is the false branch
+    assert wide_fusions(greedy) == []
+    assert wide_fusions(drawn) == []    # the divide is not up here
+    inner, = calls(drawn, "conditional")
+    plain, nucleus = branches(inner)
+    assert calls(nucleus, "sort") and not calls(plain, "sort")
+    fused, = wide_fusions(plain)
+    assert size not in fused.split(" -> ")[1], fused
+    results = (re.match(r"(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+               for line in plain)
+    written = [m.group(0) for m in results
+               if m and "f32" + size in m.group(1)
+               and m.group(2) not in ("parameter", "get-tuple-element")]
+    assert written == [], written
