@@ -32,6 +32,9 @@ _REGISTRY = {
     "hybrid-debug": lambda **kw: Hybrid(HybridConfig.debug(**kw)),
     "lfm2-24b-a2b": lambda **kw: Hybrid(HybridConfig.lfm2_24b_a2b(**kw)),
     "lfm2-moe-debug": lambda **kw: Hybrid(HybridConfig.lfm2_debug(**kw)),
+    "solar-open2-250b": lambda **kw: Hybrid(
+        HybridConfig.solar_open2_250b(**kw)),
+    "solar-debug": lambda **kw: Hybrid(HybridConfig.solar_debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),
     "clip-debug": lambda **kw: CLIP(CLIPConfig.debug(**kw)),

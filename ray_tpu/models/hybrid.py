@@ -1,6 +1,7 @@
 """Hybrid decoders: a mixer a layer, chosen by `layer_types`, and a
-feed-forward a layer, chosen by the layer's index, TPU-first. Two
-families: `olmo_hybrid` (Olmo-Hybrid-7B) and `lfm2_moe` (LFM2-24B-A2B).
+feed-forward a layer, chosen by the layer's index, TPU-first. Three
+families: `olmo_hybrid` (Olmo-Hybrid-7B), `lfm2_moe` (LFM2-24B-A2B) and
+`solar_open2` (Solar-Open2-250B).
 
 A block is a mixer and a feed-forward around the residual stream. The
 mixer of layer i is what `layer_types[i]` names:
@@ -9,7 +10,10 @@ mixer of layer i is what `layer_types[i]` names:
     uses its `qk_norm` arm over the whole projected q and k and no
     rotation (the family's `rope_theta` is null: its full layers see no
     positions); `lfm2_moe` normalises each head's width of q and of k
-    (`qk_norm="head"`) and then rotates (`rope_theta`);
+    (`qk_norm="head"`) and then rotates (`rope_theta`); `solar_open2`
+    has grouped heads wider than d_model / n_heads (`attn_head_dim`), no
+    norm, no rotation and an output gate (`out_gate`):
+    y = W_o [softmax(q k^T / sqrt(hd)) v * sigmoid(W_gate x)];
   * "linear_attention": `GatedDeltaNet`, the layer of
     ops/gated_deltanet.py. With x the block's input, H heads of key
     width d_k and value width d_v:
@@ -24,6 +28,17 @@ mixer of layer i is what `layer_types[i]` names:
     Pallas kernel of ops/pallas/gdn_decode.py on the TPU). Projections,
     convolution and output in the activations' dtype, the state and
     everything that touches it in float32;
+  * "kda": `KimiDeltaAttention`, the delta rule with a decay a key
+    CHANNEL (Kimi Linear, arXiv:2510.26692), H heads of d_k = d_v, r =
+    `kda_rank` the width of the two low-rank pairs:
+        [q~ | k~ | v~] = SiLU(conv_K(W_qkv x))    depthwise, causal
+        q = l2norm(q~) d_k^-1/2, k = l2norm(k~)   over each head's d_k
+        g = -exp(A_log_h) softplus(W_f2 (W_f1 x) + dt_bias)  (H, d_k)
+        beta = sigmoid(w_b x) (x 2 if allow_neg_eigval)      a head
+        S <- S Diag(exp g) (I - beta k k^T) + beta v k^T,  o = S q
+        y = W_o [RMSNorm_dv(o_h) * sigmoid(W_g2 (W_g1 x))_h]
+    the same two forms and the same slot state as "linear_attention"
+    (the step kernel is `kda_decode_step`);
   * "conv": `ShortConv`, a gated short convolution of width K =
     `conv_kernel` over the model's width d, no bias, no activation:
         [B | C | X] = W_in x                      three blocks of d
@@ -36,13 +51,15 @@ The feed-forward of layer i is a dense SwiGLU (`LlamaMLP`, width `d_ff`)
 where `n_dense_layers` is None or i < `n_dense_layers`, and otherwise
 the expert layer of models/latent_moe.py (`ShareMoE`: sigmoid scores, a
 selection bias, `n_experts` SwiGLU experts of width `d_expert`,
-`experts_per_token` a token, no shared expert), which leaves the
-`step_stats` counters of ops/moe.py.
+`experts_per_token` a token; `lfm2_moe` holds them all and shares none,
+`solar_open2` holds `expert_count` from `expert_first`, a chip's share
+of an expert-parallel deployment, beside `n_shared_experts` shared),
+which leaves the `step_stats` counters of ops/moe.py.
 
 Where the norms stand is the family's (`pre_norm`): `olmo_hybrid`
 normalises each sub-layer's OUTPUT, as the OLMo 2 and 3 family does:
-h = x + Norm(Mixer(x)), y = h + Norm(FF(h)); `lfm2_moe` its input:
-h = x + Mixer(Norm(x)), y = h + FF(Norm(h)).
+h = x + Norm(Mixer(x)), y = h + Norm(FF(h)); `lfm2_moe` and `solar_open2`
+its input: h = x + Mixer(Norm(x)), y = h + FF(Norm(h)).
 
 What a layer caches it says itself (`paged_cache_spec`): a full layer
 pages K and V a token (heads narrower than 128 lanes packed side by
@@ -69,6 +86,7 @@ from .latent_moe import ShareMoE
 from .llama import LlamaAttention, LlamaMLP, _LMHead, _proj, head_logits
 
 LINEAR, FULL, CONV = "linear_attention", "full_attention", "conv"
+KDA = "kda"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,12 +105,16 @@ class HybridConfig:
     linear_conv_kernel: int = 4
     linear_allow_neg_eigval: bool = True
     linear_chunk: int = 64          # tokens a chunk of the chunkwise form
+    kda_rank: int = 128             # r of the "kda" layers' low-rank pairs
     max_seq_len: int = 65536
     norm_eps: float = 1e-6
     # LlamaAttention reads these two: True, the whole projected q and k;
     # "head", each head's width. None: no rotation
     qk_norm: "bool | str" = True
     rope_theta: Optional[float] = None
+    out_gate: bool = False          # LlamaAttention's sigmoid output gate
+    # a full layer's head width where it is not d_model / n_heads
+    attn_head_dim: Optional[int] = None
     conv_kernel: int = 3            # K of the "conv" layers
     # False: each sub-layer's output is normalised; True: its input
     pre_norm: bool = False
@@ -107,6 +129,13 @@ class HybridConfig:
     norm_topk_prob: bool = True
     route_norm_eps: float = 1e-6
     routed_scaling: float = 1.0
+    # what ShareMoE asks beside: shared experts every token passes, and
+    # the share of each expert layer held here, experts expert_first ..
+    # expert_first + expert_count - 1 of the router's n_experts (None:
+    # all of them)
+    n_shared_experts: int = 0
+    expert_first: int = 0
+    expert_count: Optional[int] = None
     dtype: Any = jnp.bfloat16
     # storage dtype of embeddings and matmul kernels; norm weights,
     # A_log and dt_bias stay float32
@@ -122,18 +151,24 @@ class HybridConfig:
         else:
             object.__setattr__(self, "layer_types",
                                tuple(self.layer_types))
-        bad = set(self.layer_types) - {LINEAR, FULL, CONV}
+        bad = set(self.layer_types) - {LINEAR, FULL, CONV, KDA}
         if bad or len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types must name {self.n_layers} layers as "
-                f"{LINEAR!r}, {FULL!r} or {CONV!r}; got "
+                f"{LINEAR!r}, {FULL!r} or {CONV!r} (or {KDA!r}); got "
                 f"{self.layer_types}")
+        if not (0 <= self.expert_first and self.expert_first
+                + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"experts {self.expert_first}.."
+                f"{self.expert_first + self.experts_held} are not among "
+                f"the router's {self.n_experts}")
         if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
             raise ValueError("d_model / n_heads / n_kv_heads do not divide")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def kv_pool_heads(self) -> int:
@@ -151,14 +186,10 @@ class HybridConfig:
         """Whether layer i's feed-forward is the dense SwiGLU."""
         return self.n_dense_layers is None or i < self.n_dense_layers
 
-    # every expert of a layer is held here and none is shared (what
-    # ShareMoE asks)
-    expert_first = 0
-    n_shared_experts = 0
-
     @property
     def experts_held(self) -> int:
-        return self.n_experts
+        return (self.n_experts if self.expert_count is None
+                else self.expert_count)
 
     @staticmethod
     def olmo_hybrid_7b(**kw) -> "HybridConfig":
@@ -180,6 +211,44 @@ class HybridConfig:
             experts_per_token=4, norm_topk_prob=True, routed_scaling=1.0,
             max_seq_len=128000, norm_eps=1e-5, qk_norm="head",
             rope_theta=1e6, pre_norm=True, tie_embeddings=True), **kw})
+
+    @staticmethod
+    def solar_open2_250b(**kw) -> "HybridConfig":
+        """Solar-Open2-250B as published (config.json, model_type
+        solar_open2): 48 layers, softmax attention at every index that
+        is 0 modulo 4 (`gqa_layers`), 64 query and 8 KV heads of 128
+        without rotation and with an output gate, and three "kda"
+        layers after each; every feed-forward 320 routed experts of
+        1 280, 8 a token, beside a shared one; fewer `n_layers` keep
+        the first of them."""
+        n = kw.get("n_layers", 48)
+        return HybridConfig(**{**dict(
+            vocab_size=196608, d_model=4096, n_layers=n,
+            layer_types=tuple(FULL if i % 4 == 0 else KDA
+                              for i in range(n)),
+            n_heads=64, n_kv_heads=8, attn_head_dim=128, out_gate=True,
+            qk_norm=False, rope_theta=None, linear_n_heads=64,
+            linear_key_dim=128, linear_value_dim=128, linear_conv_kernel=4,
+            linear_allow_neg_eigval=True, kda_rank=128, n_dense_layers=0,
+            d_expert=1280, n_experts=320, experts_per_token=8,
+            n_shared_experts=1, norm_topk_prob=True, routed_scaling=1.0,
+            max_seq_len=1048576, norm_eps=1e-5, pre_norm=True,
+            # on a TPU the flash kernel in every prefill bucket, not
+            # from 2 048 tokens as "auto" has it: a step program with
+            # delta-rule layers behind XLA's plain attention over 2 x
+            # 1 024 tokens never came back from the chip, though either
+            # kind of layer alone at that shape did (ROADMAP A1 (c))
+            attn_impl=("pallas" if jax.default_backend() == "tpu"
+                       else "auto")), **kw})
+
+    @staticmethod
+    def solar_debug(**kw) -> "HybridConfig":
+        return HybridConfig.solar_open2_250b(**{**dict(
+            vocab_size=256, d_model=64, n_layers=4, n_heads=4,
+            n_kv_heads=2, attn_head_dim=32, linear_n_heads=4,
+            linear_key_dim=16, linear_value_dim=16, linear_chunk=16,
+            kda_rank=8, d_expert=32, n_experts=8, experts_per_token=2,
+            max_seq_len=256), **kw})
 
     @staticmethod
     def debug(**kw) -> "HybridConfig":
@@ -214,15 +283,77 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def _delta_rule(mod, x, cache, u, gate, g, beta, scope: str, gate_fn):
+    """What the two delta-rule mixers share once the fused q | k | v
+    projection `u`, the output gate's `gate` and the gates `g`, `beta`
+    are made: the convolution and the norms, the recurrence in one of
+    its two forms against the slot state (frozen behind each row's true
+    length), the normed and gated output. `scope` prefixes the named
+    scopes; `g`'s rank says whether the decay is a head's or a
+    channel's (ops/gated_deltanet.py)."""
+    cfg = mod.cfg
+    h, dk, dv = (cfg.linear_n_heads, cfg.linear_key_dim,
+                 cfg.linear_value_dim)
+    b, s, _ = x.shape
+    conv_w = mod.param(
+        "conv_kernel", _uniform(cfg.linear_conv_kernel ** -0.5),
+        (cfg.linear_conv_kernel, cfg.conv_width), cfg.param_dtype)
+    state = tail = n_new = None
+    if cache is not None:
+        state, tail = cache.read()
+        n_new = cache.n_new
+        g, beta = gdn.freeze(
+            g, beta, jnp.arange(s)[None, :] < n_new[:, None])
+    with jax.named_scope(f"{scope}.conv"):
+        qkv, tail = gdn.causal_conv(u, conv_w, tail, n_new)
+        q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
+        q = gdn.l2norm(q.reshape(b, s, h, dk)) * dk ** -0.5
+        k = gdn.l2norm(k.reshape(b, s, h, dk))
+        v = v.reshape(b, s, h, dv)
+    if cache is not None and s == 1:
+        with jax.named_scope(f"{scope}.step"):
+            o, state = _step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                             beta[:, 0], state)
+            o = o[:, None]
+    else:
+        with jax.named_scope(f"{scope}.scan"):
+            o, state = gdn.chunk_scan(q, k, v, g, beta, state,
+                                      chunk=cfg.linear_chunk)
+    # what went into the recurrence and what came out, for a caller that
+    # asks for the collection (a check of the recurrence alone on
+    # bit-equal inputs); nothing is traced for one that does not
+    mod.sow("recurrence", "io", (q, k, v, g, beta, o))
+    with jax.named_scope(f"{scope}.gate_out"):
+        o = rms_norm(o, mod.param("o_norm", nn.initializers.ones,
+                                  (dv,)), cfg.norm_eps)
+        o = (o.reshape(b, s, h * dv)
+             * gate_fn(gate.astype(jnp.float32))).astype(cfg.dtype)
+        y = _proj(cfg, cfg.d_model, "o_proj")(o)
+    return y, (None if cache is None else cache.write(state, tail))
+
+
+def _step(q, k, v, g, beta, state):
+    """The one-token form: the fused kernel on the TPU (or under
+    RAY_TPU_PAGED_ATTN_IMPL=pallas, interpreted on the CPU), three
+    passes in plain XLA elsewhere (and under =gather). A rate a key
+    channel (g with d_k behind the heads) has a kernel of its own."""
+    impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
+    if impl != "gather" and (impl == "pallas"
+                             or jax.default_backend() == "tpu"):
+        from ..ops.pallas.gdn_decode import (  # noqa: PLC0415
+            gdn_decode_step, kda_decode_step)
+        kernel = kda_decode_step if g.ndim == 3 else gdn_decode_step
+        return kernel(q, k, v, g, beta, state)
+    return gdn.step(q, k, v, g, beta, state)
+
+
 class GatedDeltaNet(nn.Module):
     cfg: HybridConfig
 
     @nn.compact
     def __call__(self, x, cache: Optional[SlotState] = None):
         cfg = self.cfg
-        h, dk, dv = (cfg.linear_n_heads, cfg.linear_key_dim,
-                     cfg.linear_value_dim)
-        b, s, _ = x.shape
+        h, dv = cfg.linear_n_heads, cfg.linear_value_dim
         with jax.named_scope("gdn.project"):
             u = _proj(cfg, cfg.conv_width, "qkv_proj")(x)
             gate = _proj(cfg, h * dv, "g_proj")(x)
@@ -232,50 +363,36 @@ class GatedDeltaNet(nn.Module):
                 a, bb, self.param("A_log", _a_log_init, (h,)),
                 self.param("dt_bias", _dt_bias_init, (h,)),
                 cfg.linear_allow_neg_eigval)
-        conv_w = self.param(
-            "conv_kernel", _uniform(cfg.linear_conv_kernel ** -0.5),
-            (cfg.linear_conv_kernel, cfg.conv_width), cfg.param_dtype)
-        state = tail = n_new = None
-        if cache is not None:
-            state, tail = cache.read()
-            n_new = cache.n_new
-            g, beta = gdn.freeze(
-                g, beta, jnp.arange(s)[None, :] < n_new[:, None])
-        with jax.named_scope("gdn.conv"):
-            qkv, tail = gdn.causal_conv(u, conv_w, tail, n_new)
-            q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
-            q = gdn.l2norm(q.reshape(b, s, h, dk)) * dk ** -0.5
-            k = gdn.l2norm(k.reshape(b, s, h, dk))
-            v = v.reshape(b, s, h, dv)
-        if cache is not None and s == 1:
-            with jax.named_scope("gdn.step"):
-                o, state = self._step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                      beta[:, 0], state)
-                o = o[:, None]
-        else:
-            with jax.named_scope("gdn.scan"):
-                o, state = gdn.chunk_scan(q, k, v, g, beta, state,
-                                          chunk=cfg.linear_chunk)
-        with jax.named_scope("gdn.gate_out"):
-            o = rms_norm(o, self.param("o_norm", nn.initializers.ones,
-                                       (dv,)), cfg.norm_eps)
-            o = (o.reshape(b, s, h * dv)
-                 * jax.nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
-            y = _proj(cfg, cfg.d_model, "o_proj")(o)
-        return y, (None if cache is None else cache.write(state, tail))
+        return _delta_rule(self, x, cache, u, gate, g, beta, "gdn",
+                           jax.nn.silu)
 
-    @staticmethod
-    def _step(q, k, v, g, beta, state):
-        """The one-token form: the fused kernel on the TPU (or under
-        RAY_TPU_PAGED_ATTN_IMPL=pallas, interpreted on the CPU), three
-        passes in plain XLA elsewhere (and under =gather)."""
-        impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
-        if impl != "gather" and (impl == "pallas"
-                                 or jax.default_backend() == "tpu"):
-            from ..ops.pallas.gdn_decode import (  # noqa: PLC0415
-                gdn_decode_step)
-            return gdn_decode_step(q, k, v, g, beta, state)
-        return gdn.step(q, k, v, g, beta, state)
+
+class KimiDeltaAttention(nn.Module):
+    """The "kda" mixer (module docstring): GatedDeltaNet with a decay a
+    key channel through a low-rank pair, and a sigmoid output gate
+    through a second."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache: Optional[SlotState] = None):
+        cfg = self.cfg
+        h, dk, dv, r = (cfg.linear_n_heads, cfg.linear_key_dim,
+                        cfg.linear_value_dim, cfg.kda_rank)
+        b, s, _ = x.shape
+        with jax.named_scope("kda.proj"):
+            u = _proj(cfg, cfg.conv_width, "qkv_proj")(x)
+            gate = _proj(cfg, h * dv, "g_b_proj")(
+                _proj(cfg, r, "g_a_proj")(x))
+            a = _proj(cfg, h * dk, "f_b_proj")(_proj(cfg, r, "f_a_proj")(x))
+            bb = _proj(cfg, h, "b_proj")(x)
+        with jax.named_scope("kda.gates"):
+            g, beta = gdn.gates(
+                a.reshape(b, s, h, dk), bb,
+                self.param("A_log", _a_log_init, (h,)),
+                self.param("dt_bias", _dt_bias_init, (h, dk)),
+                cfg.linear_allow_neg_eigval)
+        return _delta_rule(self, x, cache, u, gate, g, beta, "kda",
+                           jax.nn.sigmoid)
 
 
 class ShortConv(nn.Module):
@@ -329,6 +446,8 @@ class HybridBlock(nn.Module):
                     x, cos, sin, cache, positions)
             if self.kind == CONV:
                 return ShortConv(cfg, name="conv")(x, cache)
+            if self.kind == KDA:
+                return KimiDeltaAttention(cfg, name="kda")(x, cache)
             return GatedDeltaNet(cfg, name="linear_attention")(x, cache)
 
         def ff(x):
@@ -407,17 +526,19 @@ class Hybrid(nn.Module):
         head_dim)` a token; a linear layer keeps, a slot, the float32
         state (d_k, H x d_v) and the convolution's last K - 1 inputs; a
         conv layer its last K - 1 inputs (ops/attention.py:
-        kv_cache_spec)."""
+        kv_cache_spec). A "kda" layer keeps what a linear layer
+        does."""
         cfg = self.cfg
         kv = packed_kv_shape(cfg.kv_pool_heads, cfg.head_dim)
+        linear = LayerCache(
+            SlotState,
+            ((cfg.linear_key_dim,
+              cfg.linear_n_heads * cfg.linear_value_dim),
+             (cfg.linear_conv_kernel - 1, cfg.conv_width)),
+            (jnp.float32, cfg.dtype), by_slot=True)
         by_kind = {
             FULL: LayerCache(PagedKV, (kv, kv), (cfg.dtype, cfg.dtype)),
-            LINEAR: LayerCache(
-                SlotState,
-                ((cfg.linear_key_dim,
-                  cfg.linear_n_heads * cfg.linear_value_dim),
-                 (cfg.linear_conv_kernel - 1, cfg.conv_width)),
-                (jnp.float32, cfg.dtype), by_slot=True),
+            LINEAR: linear, KDA: linear,
             CONV: LayerCache(
                 SlotState, ((cfg.conv_kernel - 1, cfg.d_model),),
                 (cfg.dtype,), by_slot=True)}

@@ -85,6 +85,10 @@ class LlamaConfig:
     # "head": over each head's width, one weight shared by the heads
     # (models/hybrid.py's short-convolution family).
     qk_norm: "bool | str" = False
+    # True: the attention's result is gated elementwise, before o_proj,
+    # by the sigmoid of a projection of the block's input of its own
+    # (models/hybrid.py's `solar_open2` family)
+    out_gate: bool = False
 
     def __post_init__(self):
         if self.d_model % self.n_heads:
@@ -239,6 +243,11 @@ class LlamaAttention(nn.Module):
                                               impl=cfg.attn_impl)
 
         out = out.reshape(b, s, cfg.n_heads * hd)
+        if cfg.out_gate:
+            with jax.named_scope("attn.out_gate"):
+                gate = _proj(cfg, cfg.n_heads * hd, "gate_proj")(x)
+                out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))
+                       ).astype(cfg.dtype)
         out = _row_proj(cfg, out, cfg.d_model, "o_proj")
         return out, new_cache
 

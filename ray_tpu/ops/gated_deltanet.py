@@ -33,6 +33,19 @@ by a third, and a decode step is elementwise work along it
   * `recurrent`: the recurrence itself, token by token in a `lax.scan`;
     what the two forms are tested against.
 
+A decay a key CHANNEL (Kimi Delta Attention, arXiv:2510.26692): g and
+alpha carry one more dimension, (.., H, d_k), and the rule reads
+
+    S_t = S_{t-1} Diag(alpha_t) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+
+`gates`, `recurrent` and `step` tell the two by g's rank. `chunk_scan`
+hands a rate a channel to `_chunk_scan_channel`: the intra-chunk matrix
+is then sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c]), and its factored form
+(k_t exp(G_t)) . (k_s exp(-G_s)) overflows float32 inside a 64-token
+chunk at the rates the layer draws, so the chunk is cut into sub-chunks:
+diagonal blocks with the difference inside the exponent, the blocks
+under them referred to the row sub-chunk's edge, both factors <= 1.
+
 Padded positions are frozen by the caller: g = 0 (alpha = 1) and
 beta = 0 leave the state as it was, so the state after a padded row is
 the state at its true length (`freeze`). The convolution's tail, the
@@ -57,8 +70,13 @@ def gates(a: jax.Array, b: jax.Array, a_log: jax.Array,
           dt_bias: jax.Array, allow_neg_eigval: bool
           ) -> Tuple[jax.Array, jax.Array]:
     """(g, beta) float32 from the two per-head projections a, b
-    (..., H): g = log alpha <= 0, beta in (0, 1) or (0, 2)."""
-    g = -jnp.exp(a_log.astype(F32)) * jax.nn.softplus(
+    (..., H): g = log alpha <= 0, beta in (0, 1) or (0, 2). A rate a
+    channel: a and dt_bias (..., H, d_k) beside a_log (H,), and g
+    (..., H, d_k)."""
+    a_log = a_log.astype(F32)
+    if a.ndim == b.ndim + 1:
+        a_log = a_log[:, None]
+    g = -jnp.exp(a_log) * jax.nn.softplus(
         a.astype(F32) + dt_bias.astype(F32))
     beta = jax.nn.sigmoid(b.astype(F32))
     return g, 2.0 * beta if allow_neg_eigval else beta
@@ -71,7 +89,8 @@ def freeze(g: jax.Array, beta: jax.Array, real: Optional[jax.Array]
     if real is None:
         return g, beta
     real = real[..., None]
-    return jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+    real_g = real[..., None] if g.ndim == beta.ndim + 1 else real
+    return jnp.where(real_g, g, 0.0), jnp.where(real, beta, 0.0)
 
 
 def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -122,8 +141,9 @@ def _heads_flat(s4: jax.Array) -> jax.Array:
 
 def recurrent(q, k, v, g, beta, state=None):
     """The recurrence, token by token. q, k (B, S, H, d_k) normalised,
-    v (B, S, H, d_v), g, beta (B, S, H), state (B, d_k, H * d_v) or
-    None (zeros). Returns (o (B, S, H, d_v) float32, final state)."""
+    v (B, S, H, d_v), g, beta (B, S, H) (g (B, S, H, d_k): a rate a
+    channel), state (B, d_k, H * d_v) or None (zeros). Returns
+    (o (B, S, H, d_v) float32, final state)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     s0 = (jnp.zeros((b, h, dk, dv), F32) if state is None
@@ -131,7 +151,8 @@ def recurrent(q, k, v, g, beta, state=None):
 
     def body(st, xs):
         qt, kt, vt, gt, bt = xs                     # (B, H, .)
-        st = st * jnp.exp(gt)[..., None, None]
+        st = st * (jnp.exp(gt)[..., None] if gt.ndim == 3
+                   else jnp.exp(gt)[..., None, None])
         kv = jnp.einsum("bhkv,bhk->bhv", st, kt, precision=_HI)
         st = st + kt[..., None] * (bt[..., None] * (vt - kv))[..., None, :]
         return st, jnp.einsum("bhkv,bhk->bhv", st, qt, precision=_HI)
@@ -143,11 +164,13 @@ def recurrent(q, k, v, g, beta, state=None):
 
 def step(q, k, v, g, beta, state):
     """One token in plain XLA. q, k (B, H, d_k), v (B, H, d_v), g, beta
-    (B, H), state (B, d_k, H * d_v) float32. Returns (o (B, H, d_v)
-    float32, new state)."""
+    (B, H) (g (B, H, d_k): a rate a channel), state (B, d_k, H * d_v)
+    float32. Returns (o (B, H, d_v) float32, new state)."""
     b, h, dk = q.shape
     dv = v.shape[-1]
-    s4 = state.reshape(b, dk, h, dv) * jnp.exp(g)[:, None, :, None]
+    s4 = state.reshape(b, dk, h, dv)
+    s4 = s4 * (jnp.swapaxes(jnp.exp(g), 1, 2)[..., None] if g.ndim == 3
+               else jnp.exp(g)[:, None, :, None])
     kv = jnp.einsum("bkhv,bhk->bhv", s4, k, precision=_HI)
     u = beta[..., None] * (v.astype(F32) - kv)
     s4 = s4 + jnp.swapaxes(k, 1, 2)[..., None] * u[:, None]
@@ -192,7 +215,10 @@ def chunk_scan(q, k, v, g, beta, state=None, chunk: int = 64):
         O   = (Gamma Q) S_0 + (Q K^T * Gamma_t / Gamma_s, s <= t) W
         S_C = Gamma_C S_0 + (K Gamma_C / Gamma)^T W
     Any length: the sequence is padded with frozen positions to a whole
-    number of chunks."""
+    number of chunks. g (B, S, H, d_k), a rate a channel:
+    `_chunk_scan_channel`."""
+    if g.ndim == 4:
+        return _chunk_scan_channel(q, k, v, g, beta, state, chunk)
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     c = min(chunk, s)
@@ -236,5 +262,96 @@ def chunk_scan(q, k, v, g, beta, state=None, chunk: int = 64):
     xs = tuple(jnp.moveaxis(x, 2, 0)
                for x in (v_w, k_w, attn, q_in, k_out, total))
     final, o = jax.lax.scan(body, s0, xs)                     # (n,B,H,c,dv)
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * c, dv)[:, :, :s]
+    return jnp.swapaxes(o, 1, 2), _heads_flat(final)
+
+
+SUB_CHUNK = 16
+
+
+def _chunk_scan_channel(q, k, v, g, beta, state=None, chunk: int = 64):
+    """`chunk_scan` with a rate a key channel, g (B, S, H, d_k). With
+    G_t[c] the summed rate of channel c from the chunk's start to t and
+    Gamma = exp(G), the chunk's matrices are
+        A[t, s] = sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])     s < t
+        P[t, s] = sum_c q_t[c] k_s[c] exp(G_t[c] - G_s[c])     s <= t
+    and with T = (I + diag(beta) A)^-1:
+        W   = T (beta V) - T (beta K Gamma) S_0
+        O   = (Q Gamma) S_0 + P W
+        S_C = Diag(Gamma_C) S_0 + (K Gamma_C / Gamma)^T W
+    exp(-G_s) alone passes float32's range inside a chunk (a rate of
+    1.6 a token after 55 tokens), so A and P are built from sub-chunks
+    of SUB_CHUNK tokens: a diagonal block with the difference inside
+    the exponent (<= 0 where s <= t, masked elsewhere), a block under
+    the diagonal as (x_t exp(G_t - E)) . (k_s exp(E - G_s)) with E the
+    summed rate at the row sub-chunk's edge, t >= edge > s, so that
+    both exponents are <= 0: a factor that underflows belongs to a
+    product that is nothing beside the diagonal's. Everything of a
+    chunk is computed inside the scan over chunks: the (sub-chunk x
+    sub-chunk x d_k) intermediates live for one chunk only."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    sc = min(SUB_CHUNK, chunk)
+    if chunk % sc:
+        raise ValueError(f"chunk={chunk} is no multiple of {sc}")
+    c = min(chunk, -(-s // sc) * sc)
+    n, ns = -(-s // c), c // sc
+    pad = n * c - s
+
+    def chunks(x):                      # (B, S, H, ...) -> (n, B, H, c, ...)
+        x = x.astype(F32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape(b, n, c, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+
+    mm = functools.partial(jnp.einsum, precision=_HI)
+    low = jnp.tril(jnp.ones((sc, sc), bool))[..., None]       # (t, s, 1)
+    # columns that lie before each row sub-chunk's edge
+    before = (jnp.arange(c)[None, :]
+              < (jnp.arange(ns) * sc)[:, None])[..., None]    # (ns, c, 1)
+    eye = jnp.eye(ns, dtype=F32)
+
+    def sub(x):                         # (B, H, c, .) -> (B, H, ns, sc, .)
+        return x.reshape(b, h, ns, sc, x.shape[-1])
+
+    def body(st, xs):
+        q, k, v, g, beta = xs           # (B, H, c, .), beta (B, H, c)
+        gc = jnp.cumsum(g, axis=-2)
+        gs, ks = sub(gc), sub(k)
+        edge = jnp.concatenate(
+            [jnp.zeros((b, h, 1, dk), F32), gs[:, :, :-1, -1]], axis=2)
+        rows = jnp.exp(gs - edge[:, :, :, None])              # <= 1
+        cols = jnp.where(before, k[:, :, None] * jnp.exp(jnp.where(
+            before, edge[:, :, :, None] - gc[:, :, None], 0.0)), 0.0)
+        ratio = jnp.where(low, jnp.exp(jnp.where(
+            low, gs[:, :, :, :, None] - gs[:, :, :, None, :], 0.0)), 0.0)
+
+        def matrix(x):
+            """sum_c x_t[c] k_s[c] exp(G_t[c] - G_s[c]), s <= t: (c, c)."""
+            xs_ = sub(x)
+            under = mm("bhitd,bhisd->bhits", xs_ * rows, cols)
+            diag = jnp.sum(xs_[:, :, :, :, None] * ks[:, :, :, None, :]
+                           * ratio, axis=-1)                  # (ns, sc, sc)
+            return (under + jnp.einsum("bhits,ij->bhitjs", diag, eye)
+                    .reshape(b, h, ns, sc, c)).reshape(b, h, c, c)
+
+        kb = k * beta[..., None]
+        t_inv = _unit_lower_inverse(
+            jnp.tril(matrix(k) * beta[..., None], -1))
+        decay = jnp.exp(gc)
+        v_w = mm("bhts,bhsv->bhtv", t_inv, v * beta[..., None])
+        k_w = mm("bhts,bhsk->bhtk", t_inv, kb * decay)
+        w = v_w - mm("bhtk,bhkv->bhtv", k_w, st)
+        o = mm("bhtk,bhkv->bhtv", q * decay, st) \
+            + mm("bhts,bhsv->bhtv", matrix(q), w)
+        k_out = k * jnp.exp(gc[:, :, -1:] - gc)
+        st = st * decay[:, :, -1, :, None] \
+            + mm("bhtk,bhtv->bhkv", k_out, w)
+        return st, o
+
+    s0 = (jnp.zeros((b, h, dk, dv), F32) if state is None
+          else _heads_last(state.astype(F32), h))
+    final, o = jax.lax.scan(body, s0, tuple(map(chunks, (q, k, v, g, beta))))
     o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * c, dv)[:, :, :s]
     return jnp.swapaxes(o, 1, 2), _heads_flat(final)

@@ -13,12 +13,12 @@ written once, the copies of the next slot running under the work on
 this one.
 
 Inside a step the heads are walked in groups whose columns are whole
-128-lane tiles (two heads of 192 = 384 columns). A head's k and q are
-columns of `(d_k, H)` inputs broadcast along the lanes, so with kx, qx
-the group's (d_k, columns) expansions and a, b, v its rows of decay,
-beta and value:
+128-lane tiles (two heads of 192 = 384 columns). A head's k, q and
+decay a key channel are columns of `(d_k, H)` inputs broadcast along the
+lanes, so with kx, qx, ax the group's (d_k, columns) expansions and b, v
+its rows of beta and value:
 
-    Sd = S * a ; u = b * (v - sum_k Sd * kx) ; S' = Sd + kx * u
+    Sd = S * ax ; u = b * (v - sum_k Sd * kx) ; S' = Sd + kx * u
     o  = sum_k S' * qx
 
 All of it elementwise work and sublane sums in float32; no matrix unit.
@@ -28,8 +28,17 @@ its prompt, the scratch row) come with alpha = 1, beta = 0 and k = 0
 from the caller and are WRITTEN THROUGH UNCHANGED: the grid is every
 row of the pool, which is what the engine counts as
 `decode_state_rows_window` beside the `decode_state_rows_live` that
-moved. Its name on a trace's `XLA Ops` line is `gdn_decode_step`.
-Inference only: no backward pass.
+moved. Inference only: no backward pass.
+
+ONE kernel for a decay a head (Gated DeltaNet: `gdn_decode_step`, the
+head's rate in every key channel) and a decay a key channel (Kimi Delta
+Attention: `kda_decode_step`; ops/gated_deltanet.py), under the name
+its caller's metrics look for. At Olmo-Hybrid-7B's shape (30 heads of
+96 x 192, 65 rows) the kernel with the decay as a third expanded input
+takes 0.4376 ms, what the kernel with the decay as a row took (0.4376:
+tools/kda_microbench.py on the chip, PERF.md, PR 52), and gives the same
+bits. One slot's state is 128 x 8 192 float32 = 4 MiB at Solar-Open2's
+widths, in and out double-buffered 16 MiB of VMEM_LIMIT_BYTES.
 """
 from __future__ import annotations
 
@@ -53,12 +62,12 @@ def heads_per_group(n_heads: int, d_v: int) -> int:
     return n if n_heads % n == 0 else n_heads
 
 
-def _kernel(qt_ref, kt_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
+def _kernel(qt_ref, kt_ref, at_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
             n_heads: int, d_v: int, group: int):
     d_k = s_ref.shape[1]
     width = group * d_v
     lane = jax.lax.broadcasted_iota(jnp.int32, (d_k, width), 1)
-    qt, kt = qt_ref[0], kt_ref[0]                         # (d_k, H)
+    qt, kt, at = qt_ref[0], kt_ref[0], at_ref[0]          # (d_k, H)
 
     def expand(cols, h0):
         """(d_k, width): head h0 + j's column over its d_v lanes."""
@@ -71,15 +80,54 @@ def _kernel(qt_ref, kt_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
     for gi in range(n_heads // group):
         cols = slice(gi * width, (gi + 1) * width)
         v = rows_ref[0, 0:1, cols]
-        a = rows_ref[0, 1:2, cols]
-        b = rows_ref[0, 2:3, cols]
+        b = rows_ref[0, 1:2, cols]
         kx = expand(kt, gi * group)
-        sd = s_ref[0, :, cols] * a
+        sd = s_ref[0, :, cols] * expand(at, gi * group)
         u = b * (v - jnp.sum(sd * kx, axis=0, keepdims=True))
         new = sd + kx * u
         s_out_ref[0, :, cols] = new
         o_ref[0, :, cols] = jnp.sum(new * expand(qt, gi * group), axis=0,
                                     keepdims=True)
+
+
+def _decode_step(q, k, v, g, beta, state, name: str, interpret):
+    b, h, d_k = q.shape
+    d_v = v.shape[-1]
+    hv = h * d_v
+    assert state.shape == (b, d_k, hv) and state.dtype == jnp.float32, \
+        (state.shape, state.dtype)
+    assert g.shape == (b, h, d_k), g.shape
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    f32 = jnp.float32
+    # a frozen row's k is zeroed too: 0 x a garbage k stays 0
+    k = jnp.where((beta != 0)[..., None], k.astype(f32), 0.0)
+    rows = jnp.stack([
+        v.astype(f32).reshape(b, hv),
+        jnp.repeat(beta.astype(f32), d_v, axis=-1)], axis=1)   # (B, 2, HV)
+    kernel = functools.partial(_kernel, n_heads=h, d_v=d_v,
+                               group=heads_per_group(h, d_v))
+    row3 = lambda i: (i, 0, 0)                                # noqa: E731
+    cols = pl.BlockSpec((1, d_k, h), row3)
+    o, new_state = pl.pallas_call(
+        kernel,
+        grid=(b,),
+        in_specs=[cols, cols, cols,
+                  pl.BlockSpec((1, 2, hv), row3),
+                  pl.BlockSpec((1, d_k, hv), row3)],
+        out_specs=[pl.BlockSpec((1, 1, hv), row3),
+                   pl.BlockSpec((1, d_k, hv), row3)],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, hv), f32),
+                   jax.ShapeDtypeStruct((b, d_k, hv), f32)],
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name=name,
+    )(jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k, 1, 2),
+      jnp.swapaxes(jnp.exp(g.astype(f32)), 1, 2), rows, state)
+    return o.reshape(b, h, d_v), new_state
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -89,39 +137,13 @@ def gdn_decode_step(q, k, v, g, beta, state, interpret=None):
     A row to leave alone comes with g = 0 and beta = 0. Returns
     (o (B, H, d_v) float32, new state). interpret defaults to True only
     on the CPU backend."""
-    b, h, d_k = q.shape
-    d_v = v.shape[-1]
-    hv = h * d_v
-    assert state.shape == (b, d_k, hv) and state.dtype == jnp.float32, \
-        (state.shape, state.dtype)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    f32 = jnp.float32
-    # a frozen row's k is zeroed too: 0 x a garbage k stays 0
-    k = jnp.where((beta != 0)[..., None], k.astype(f32), 0.0)
-    rows = jnp.stack([
-        v.astype(f32).reshape(b, hv),
-        jnp.repeat(jnp.exp(g.astype(f32)), d_v, axis=-1),
-        jnp.repeat(beta.astype(f32), d_v, axis=-1)], axis=1)   # (B, 3, HV)
-    kernel = functools.partial(_kernel, n_heads=h, d_v=d_v,
-                               group=heads_per_group(h, d_v))
-    row3 = lambda i: (i, 0, 0)                                # noqa: E731
-    o, new_state = pl.pallas_call(
-        kernel,
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, d_k, h), row3),
-                  pl.BlockSpec((1, d_k, h), row3),
-                  pl.BlockSpec((1, 3, hv), row3),
-                  pl.BlockSpec((1, d_k, hv), row3)],
-        out_specs=[pl.BlockSpec((1, 1, hv), row3),
-                   pl.BlockSpec((1, d_k, hv), row3)],
-        out_shape=[jax.ShapeDtypeStruct((b, 1, hv), f32),
-                   jax.ShapeDtypeStruct((b, d_k, hv), f32)],
-        input_output_aliases={3: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=pltpu.InterpretParams() if interpret else False,
-        name="gdn_decode_step",
-    )(jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k, 1, 2), rows, state)
-    return o.reshape(b, h, d_v), new_state
+    return _decode_step(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                        beta, state, "gdn_decode_step", interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_decode_step(q, k, v, g, beta, state, interpret=None):
+    """`gdn_decode_step` with g = log alpha (B, H, d_k), a rate a key
+    channel."""
+    return _decode_step(q, k, v, g, beta, state, "kda_decode_step",
+                        interpret)

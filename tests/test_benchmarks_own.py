@@ -47,7 +47,10 @@ def test_a_llama_shaped_file_with_another_head_dim_is_still_refused(  # noqa: F8
         # what it caches is its kv_lora_rank)
         with open(os.path.join(root, entry["file"])) as f:
             cfg = json.load(f)
-        return "num_key_value_heads" in cfg and "kv_lora_rank" not in cfg
+        # (nor is a hybrid whose full layers' heads are wider than
+        # hidden_size / heads: its section is replica_solar's)
+        return ("num_key_value_heads" in cfg and "kv_lora_rank" not in cfg
+                and "linear_attn_config" not in cfg)
     _refused(dict(manifest, configs=[c for c in manifest["configs"]
                                      if llama_shaped(c)]), tmp_path)
 
@@ -334,11 +337,12 @@ def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
     whole = runmod.load_manifest()
     entry, = [m for m in whole["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [_LFM2_CELL]
-    # the seventh cell and the sixth configuration; PR 48's go behind
+    # the seventh cell and the sixth configuration; PR 48's and PR 52's
+    # go behind
     assert whole["workloads"][6]["name"] == _LFM2_CELL
     assert whole["configs"][5]["name"] == whole["workloads"][6]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_48) - 3
+    at = len(names) - len(_NEW_IN_PR_52) - len(_NEW_IN_PR_48) - 3
     assert set(names[at:at + 3]) == set(_NEW_IN_PR_40)
 
 
@@ -357,7 +361,7 @@ def test_the_lfm2moe_cell_is_in_what_every_saturated_serve_cell_reports():
     by_name = {m["name"]: m for m in whole["per_layer"]}
     for name in ("moe_dev_share", "moe_expert_load_max_over_mean",
                  "moe_pad_row_share", "decode_live_state_share"):
-        assert _LFM2_CELL in by_name[name]["workloads"][-2:], name
+        assert _LFM2_CELL in by_name[name]["workloads"][-3:], name
     for name in ("paged_kernel_roofline", "decode_step_roofline",
                  "expert_matmul_roofline", "decode_step_roofline.moe",
                  "expert_matmul_roofline.share", "latent_kernel_roofline",
@@ -438,6 +442,18 @@ def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
     kernel = spec["args"].get("name_re")
     if what is not None:
         assert spec["args"]["what"] == what
+    if what == "kda_scan":
+        from benchmarks.harness import replica_solar
+        from ray_tpu.ops import gated_deltanet
+        # the loop the replica looks for is a `lax.scan` over chunks
+        # inside the engine's prefill program
+        assert "jax.lax.scan(body" in inspect.getsource(
+            gated_deltanet._chunk_scan_channel)
+        assert re.search(
+            inspect.signature(replica_solar.chunk_scan_seconds)
+            .parameters["prefill_re"].default,
+            "jit_" + LLMEngine._prefill_paged_step.__name__)
+    elif what is not None:
         assert re.search(spec["args"]["module_re"],
                          "jit_" + LLMEngine._decode_paged_step.__name__)
     if name.startswith("hc_kernel"):
@@ -478,10 +494,12 @@ def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
     assert entry["source"] == ("program_counter" if what is None
                                and not name.startswith("hc_kernel")
                                else "device_trace")
-    assert whole["workloads"][-1]["name"] == _XING_CELL
-    assert whole["configs"][-1]["name"] == whole["workloads"][-1]["config"]
+    # the eighth cell and the seventh configuration; PR 52's go behind
+    assert whole["workloads"][7]["name"] == _XING_CELL
+    assert whole["configs"][6]["name"] == whole["workloads"][7]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    assert set(names[-7:]) == set(_NEW_IN_PR_48)
+    at = len(names) - len(_NEW_IN_PR_52) - 7
+    assert set(names[at:at + 7]) == set(_NEW_IN_PR_48)
 
 
 @pytest.mark.parametrize("name, reads", [
@@ -501,29 +519,310 @@ def test_an_accepted_expert_metric_reads_the_xing_window(name, reads):
 
 
 def test_the_xing_cell_is_in_what_every_saturated_serve_cell_reports():
-    """Eight cells, one of them on four chips. Every list that names the
-    sarvam cell and is not read by that model's own cost arithmetic
-    names this one too, at its end; `out_tok_s` as well; no list of
-    another model's cost arithmetic or state does."""
+    """The eighth cell of (since PR 52) nine, one of them on four chips.
+    Every list that names the sarvam cell and is not read by that
+    model's own cost arithmetic names this one too, behind every cell
+    accepted before it (a later PR's cell goes behind this one in
+    turn); `out_tok_s` as well; no list of another model's cost
+    arithmetic or state does."""
     from benchmarks import run as runmod
     whole = runmod.load_manifest()
-    assert len(whole["workloads"]) == 8 and len(whole["configs"]) == 7
+    assert len(whole["workloads"]) == 9 and len(whole["configs"]) == 8
     assert [w["name"] for w in whole["workloads"] if w["chips"] == 4] \
         == ["mistral7b_train_fsdp2_tp2"]
     own = {"latent_kernel_roofline", "expert_matmul_roofline.share",
            "decode_step_roofline.latent_moe"}
+
+    def last_of_its_day(w):
+        return [c for c in w if c != _SOLAR_CELL][-1]
     for m in whole["end_to_end"] + whole["per_layer"]:
         w = m.get("workloads", [])
         if "sarvam105b_decode_sat" in w and m["name"] not in own:
-            assert w[-1] == _XING_CELL, m["name"]
+            assert last_of_its_day(w) == _XING_CELL, m["name"]
         elif m["name"] not in _NEW_IN_PR_48:
             assert _XING_CELL not in w, m["name"]
     out, = [m for m in whole["end_to_end"] if m["name"] == "out_tok_s"]
-    assert out["workloads"][-1] == _XING_CELL
-    cell = whole["workloads"][-1]
+    assert last_of_its_day(out["workloads"]) == _XING_CELL
+    cell = whole["workloads"][7]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "xing4.0-29b-a4b-serve-l6", "decode_sat_xing", 1)
-    config = whole["configs"][-1]
+    config = whole["configs"][6]
     assert config["reduced"] == ["num_hidden_layers",
                                  "first_k_dense_replace"]
     assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
+
+
+_NEW_IN_PR_52 = {"kda_kernel_roofline": "kda_kernel",
+                 "kda_kernel_dev_share": None,
+                 "kda_scan_dev_share": "kda_scan",
+                 "moe_dev_share.solar": None,
+                 "expert_matmul_roofline.solar": "experts",
+                 "paged_kernel_roofline.solar": "paged_kernel",
+                 "decode_step_roofline.solar": "step"}
+_SOLAR_CELL = "solar250b_decode_sat"
+
+
+def _solar_window(counters=True, kernels=True):
+    """What a traced run of the cell hands a reader: two readings of
+    `get_stats()` 1 000 decode steps apart (128 rows decoding of 129,
+    three delta-rule layers' state rows, 14 live pages a row in the one
+    full layer, 3.2 rows an expert held in 4 layers), a reduced trace of
+    100 decode runs of 16 ms, the model section, the peaks."""
+    import json
+    from benchmarks.harness import replica_solar
+    from benchmarks.harness.peaks import PEAKS
+    with open(os.path.join(_BENCH, "configs",
+                           "solar-open2-250b-serve-ep8-l4.json")) as f:
+        cfg = json.load(f)
+
+    def reading(k):
+        out = {"decode_steps": k, "prefill_calls": k // 50,
+               "decode_pages_live": k * 128 * 14,
+               "decode_pages_window": k * 129 * 32,
+               "moe_assignments": k * 128 * 4, "moe_rows": k * 128 * 4,
+               "moe_experts_touched": k * 38 * 4,
+               "moe_expert_load_max": k * 9 * 4, "moe_pad_rows": k * 4,
+               "moe_routed_assignments": k * 128 * 8 * 4}
+        if counters:
+            out.update(decode_state_rows_live=k * 128 * 3,
+                       decode_state_rows_window=k * 129 * 3)
+        return out
+    ops = {"gmm": 0.7, "paged_decode_attention": 0.07, "fusion": 0.4,
+           "sort": 0.01}
+    scan = {}
+    if kernels:
+        # the chunk scan's loop and the grouped matmul's binary search
+        # are both a `while`: the replica tells them apart
+        # (`replica_solar.chunk_scan_seconds`)
+        ops.update({"kda_decode_step": 0.5, "while": 0.11})
+        scan = {"kda_scan_s": 0.1}
+    return {"stats0": reading(1000), "stats1": reading(2000),
+            "trace": {"busy_s": 2.0, "window_s": 2.02, "ops": ops, **scan,
+                      "modules": {"jit__decode_paged_step":
+                                  {"count": 100, "seconds": 1.6}}},
+            "peaks": PEAKS["TPU v5e"], "config": cfg,
+            "model": replica_solar.model_section(cfg),
+            "trace_contexts": [900] * 128}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_52))
+def test_a_metric_file_new_in_pr_52_reads_its_window_and_nothing_else(name):
+    """As PR 48's case: each file names a reader with a `read` that
+    takes the file's arguments, the decode program the engine has and,
+    where it sums a kernel's time, a kernel the program calls by that
+    name; from a window of known counters and kernel times it reads a
+    share under 100 %; from a program without the counters, a trace
+    without the kernels, another family's model section or an empty run
+    it reads None (a plain share of the trace: 0) and does not raise
+    (what the parent's traced runs hand it); its entry lists the one
+    cell, behind every accepted entry."""
+    import inspect
+    import json
+    import re
+    from benchmarks import run as runmod
+    from ray_tpu.ops.pallas import gdn_decode, paged_attention
+    from ray_tpu.serve.llm.engine import LLMEngine
+    with open(os.path.join(_BENCH, "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    readers = os.path.join(_BENCH, "readers")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    read = importlib.import_module(spec["reader"]).read
+    assert set(spec["args"]) <= set(inspect.signature(read).parameters)
+    what = _NEW_IN_PR_52[name]
+    kernel = spec["args"].get("name_re")
+    if what is not None:
+        assert spec["args"]["what"] == what
+    if what == "kda_scan":
+        from benchmarks.harness import replica_solar
+        from ray_tpu.ops import gated_deltanet
+        # the loop the replica looks for is a `lax.scan` over chunks
+        # inside the engine's prefill program
+        assert "jax.lax.scan(body" in inspect.getsource(
+            gated_deltanet._chunk_scan_channel)
+        assert re.search(
+            inspect.signature(replica_solar.chunk_scan_seconds)
+            .parameters["prefill_re"].default,
+            "jit_" + LLMEngine._prefill_paged_step.__name__)
+    elif what is not None:
+        assert re.search(spec["args"]["module_re"],
+                         "jit_" + LLMEngine._decode_paged_step.__name__)
+    if name.startswith("kda_kernel"):
+        assert re.search(kernel, gdn_decode.kda_decode_step.__name__)
+        # the one kernel runs under the name its caller hands it
+        assert '"kda_decode_step"' in inspect.getsource(
+            gdn_decode.kda_decode_step.__wrapped__)
+        # and not the sibling's kernel, which its own metrics read
+        assert not re.search(kernel, gdn_decode.gdn_decode_step.__name__)
+    elif what == "paged_kernel":
+        assert re.search(kernel,
+                         paged_attention.paged_decode_attention.__name__)
+    elif what == "experts":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        assert re.search(kernel, gmm.__name__)
+    got = runmod.read_metric(_BENCH, name, _solar_window())
+    assert isinstance(got, float) and 0.0 < got < 100.0, (name, got)
+    if name == "kda_kernel_dev_share":
+        assert got == pytest.approx(25.0)
+    if name == "kda_scan_dev_share":
+        assert got == pytest.approx(5.0)
+    if name == "moe_dev_share.solar":
+        assert got == pytest.approx(35.0)
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        assert re.search(kernel, gmm.__name__)
+    if name == "kda_kernel_roofline":
+        # 128 rows x 3 layers x 2 x 4 MiB over 819 GB/s, in 5 ms a run
+        assert got == pytest.approx(
+            100 * 128 * 3 * 2 * 4 * 2 ** 20 / 819e9 / 5e-3, rel=1e-3)
+    # rule (beta): a parent's program has no such counter or kernel,
+    # and its traced run still gives its result
+    lacking = _solar_window(counters=False, kernels=False)
+    if name.startswith("kda_"):
+        assert runmod.read_metric(_BENCH, name, lacking) in (None, 0.0)
+        assert (runmod.read_metric(_BENCH, name, lacking) is None) \
+            == (what is not None)
+    other = dict(_solar_window(), model={"hidden_size": 4096,
+                                         "conv_L_cache": 3})
+    if what is not None:
+        assert runmod.read_metric(_BENCH, name, other) is None
+    assert runmod.read_metric(_BENCH, name, {}) is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == [_SOLAR_CELL]
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert (entry["unit"], entry["moves"], entry["source"]) \
+        == ("%", "out_tok_s", "device_trace")
+    assert whole["workloads"][8]["name"] == _SOLAR_CELL
+    assert whole["configs"][7]["name"] == whole["workloads"][8]["config"]
+    names = [m["name"] for m in whole["per_layer"]]
+    at = names.index("decode_step_roofline.xing") + 1
+    assert set(names[at:at + 7]) == set(_NEW_IN_PR_52)
+
+
+def test_the_chunk_scans_loops_are_told_from_the_searches(monkeypatch):
+    """Both are a `while` on the trace. In a run of a prefill program the
+    longest `while` operations, one a delta-rule layer, are the chunk
+    scan's; the searches inside the grouped matmuls there, and every
+    `while` of a decode program, are not counted."""
+    from types import SimpleNamespace as NS
+    import jax.profiler
+    from benchmarks.harness import replica_solar, trace_reduce
+
+    def ev(name, start_us, dur_us):
+        return NS(name=name, start_ns=start_us * 1e3, duration_ns=dur_us * 1e3)
+    ops = [ev("%while.79 = (f32[2,64]) while(%tuple.1)", 100, 1000),
+           ev("%while.80 = (f32[2,64]) while(%tuple.2)", 1200, 2000),
+           ev("%while.81 = (f32[2,64]) while(%tuple.3)", 3300, 1500)]
+    ops += [ev(f"%while.{82 + i} = (s32[40]) while(%t)", 5000 + 20 * i, 10)
+            for i in range(4)]
+    ops += [ev("%fusion.5 = f32[8] fusion(%p)", 6000, 3000),
+            ev("%while.3 = (s32[40]) while(%t)", 10100, 10),
+            ev("%while.4 = (s32[40]) while(%t)", 10200, 12)]
+    plane = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Modules", events=[
+            ev("jit__prefill_paged_step(123)", 0, 10000),
+            ev("jit__decode_paged_step(456)", 10000, 2000)]),
+        NS(name="XLA Ops", events=ops)])
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        staticmethod(lambda path: NS(
+                            planes=[plane, NS(name="/host:CPU", lines=[])])))
+    assert replica_solar.chunk_scan_seconds("x", 3) \
+        == pytest.approx(4.5e-3)
+    # a program without such layers, or a trace without a device
+    assert replica_solar.chunk_scan_seconds("x", 0) == 0.0
+    plane.name = "/host:other"
+    assert replica_solar.chunk_scan_seconds("x", 3) == 0.0
+
+
+@pytest.mark.parametrize("name, reads", [
+    ("moe_expert_load_max_over_mean", 40 * 9 / 128),
+    ("moe_pad_row_share", 100 / 129),
+    ("moe_local_assignment_share", 12.5),
+    ("decode_live_state_share", 100 * 128 / 129),
+    ("decode_live_page_share", 100 * 128 * 14 / (129 * 32)),
+    ("attention_kernel_dev_share", 3.5)])
+def test_an_accepted_metric_reads_the_solar_window(name, reads):
+    """Rule (gamma): the accepted readers of the lists the cell was
+    appended to find their keys in this model's section (the published
+    file says n_routed_experts where `moe_counter` reads num_experts)
+    and in its counters. `moe_dev_share` is not among them: it counts
+    `while` by name, and in this cell the chunk scan's loop over chunks
+    is one (`moe_dev_share.solar` reads the layer by its scope)."""
+    from benchmarks import run as runmod
+    assert runmod.read_metric(_BENCH, name, _solar_window()) \
+        == pytest.approx(reads)
+
+
+def test_the_solar_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state_reports():  # noqa: E501
+    """Nine cells, one of them on four chips. Every list that names the
+    LFM2-MoE cell (a hybrid with experts and slot state) and is not read
+    by that model's own cost arithmetic names this one too, at its end,
+    `moe_local_assignment_share` (a share) and `out_tok_s` as well; no
+    list of another model's cost arithmetic does; and the traced line's
+    metrics are `cell_metrics`'."""
+    from benchmarks import run as runmod
+    whole = runmod.load_manifest()
+    own = {"expert_matmul_roofline.lfm2moe", "paged_kernel_roofline.packed",
+           "decode_step_roofline.lfm2moe",
+           # matches `while` by name: here the chunk scan's loop too
+           "moe_dev_share"}
+    for m in whole["end_to_end"] + whole["per_layer"]:
+        w = m.get("workloads", [])
+        if ("lfm2moe24b_decode_sat" in w and m["name"] not in own) \
+                or m["name"] in _NEW_IN_PR_52 \
+                or m["name"] == "moe_local_assignment_share":
+            assert w[-1] == _SOLAR_CELL, m["name"]
+        else:
+            assert _SOLAR_CELL not in w, m["name"]
+    cell, config = whole["workloads"][-1], whole["configs"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
+        == (_SOLAR_CELL, "solar-open2-250b-serve-ep8-l4",
+            "decode_sat_solar", 1)
+    assert config["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                 "vocab_size"]
+    assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
+    assert "8x a deployment chip's rows" in cell["why"] \
+        and "4.8 rows an expert" in cell["why"] and "192 slots" in cell["why"]
+    # every per-layer metric the cell is listed for reads the synthetic
+    # window (what a traced line is held against)
+    run = _solar_window()
+    run.update(streams=[], t0=0.0, t1=1.0, late_ms=[1.0],
+               compiles_in_window=0, backlog_end=0)
+    silent = []
+    for m in runmod.cell_metrics(whole, _SOLAR_CELL, "per_layer"):
+        with open(os.path.join(_BENCH, "metrics",
+                               m["name"] + ".json")) as f:
+            reader = __import__("json").load(f)["reader"]
+        if reader in ("solar_roofline", "trace_share", "moe_counter",
+                      "moe_local_share"):
+            if runmod.read_metric(_BENCH, m["name"], run) is None:
+                silent.append(m["name"])
+    assert silent == []
+
+
+def test_the_solar_cell_rehearses_through_run_py(tmp_path):
+    """`run.py --rehearse` of the cell on the CPU at toy widths: the
+    family's runner, replica, reference and traffic files are found by
+    name, the engine serves the mix, the check against
+    `reference_solar` passes, and the line holds the cell's metrics
+    without a device number."""
+    import json
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        [sys.executable, os.path.join(_BENCH, "run.py"), "--workload",
+         _SOLAR_CELL, "--rehearse", "--seed", "5200000011", "--seconds",
+         "3", "--trace", "0", "--out", str(tmp_path)],
+        cwd=_ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["rehearsal"] and not line["failed"]
+    assert line["attempted"] > 0
+    assert set(line["metrics"]) == {"out_tok_s", "setup_s"}
+    assert all(v["value"] is None for v in line["metrics"].values())
+    ref = line["checks"]["reference"]
+    assert ref["ok"] and ref["not_followed"] == 0 and ref["layers"] == 4
+    assert ref["prefill_bucket"] > ref["prompt_len"]

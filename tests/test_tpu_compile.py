@@ -296,7 +296,9 @@ def test_latent_attention_copies_no_parameter_in_hbm(chip, form,
 
 @pytest.mark.parametrize("family,rows", [
     ("sarvam", 129), ("sarvam", 4096), ("lfm2", 128), ("lfm2", 2048),
-], ids=["decode", "prefill", "lfm2_decode", "lfm2_prefill"])
+    ("solar", 129), ("solar", 4096),
+], ids=["decode", "prefill", "lfm2_decode", "lfm2_prefill", "solar_decode",
+        "solar_prefill"])
 def test_expert_share_layer_compiles_for_v5e(chip, family, rows,
                                              monkeypatch):
     """An expert layer that holds 32 of 128 experts (4 096 x 2 048, 8 a
@@ -306,14 +308,18 @@ def test_expert_share_layer_compiles_for_v5e(chip, family, rows,
     layer holds all 64 of its experts (2 048 x 1 536, 4 a token): its
     tiles divide 1 536 (`gmm_tiling`: one 1 536 wide, 3 MB a buffer of
     the weights' tile) and the compile fits the chip's VMEM; sarvam's
-    are the 1 024 x 1 024 they were."""
+    are the 1 024 x 1 024 they were. Solar-Open2-250B's share holds 40
+    of a 320-wide router's experts (4 096 x 1 280, 8 a token): 1 280 is
+    ten lane tiles and one whole tile of its side."""
     from ray_tpu.ops.moe import gmm_tiling, moe_dropless, route
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     held, routed, first, d, f, k, scale, up, down = {
         "sarvam": (32, 128, 32, 4096, 2048, 8, 2.5,
                    (1024, 1024), (1024, 1024)),
         "lfm2": (64, 64, 0, 2048, 1536, 4, 1.0,
-                 (1024, 1536), (1536, 1024))}[family]
+                 (1024, 1536), (1536, 1024)),
+        "solar": (40, 320, 0, 4096, 1280, 8, 1.0,
+                  (1024, 1280), (1280, 1024))}[family]
     assert (gmm_tiling(d, f), gmm_tiling(f, d)) == ((128, *up),
                                                     (128, *down))
 
@@ -357,6 +363,32 @@ def test_gdn_decode_step_compiles_for_v5e(chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= rows * dk * h * dv * 4
     assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_kda_decode_step_compiles_for_v5e(chip):
+    """Solar-Open2-250B's decode step of the recurrence with a decay a
+    key channel at the cell's shapes (128 slots and the scratch row, 64
+    heads, keys and values of 128): one slot's float32 state (128 x
+    8 192 = 4 MiB) a grid step, in and out double-buffered inside the
+    kernel's 32 MiB of VMEM, updated in place: the state is aliased, and
+    the program holds no second copy of the pool (516 MiB a layer)."""
+    from ray_tpu.ops.pallas.gdn_decode import kda_decode_step
+    rows, h, dk, dv = 129, 64, 128, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(q, k, v, g, beta, state):
+        return kda_decode_step(q, k, v, g, beta, state, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        sds((rows, h, dk)), sds((rows, h, dk)), sds((rows, h, dv)),
+        sds((rows, h, dk)), sds((rows, h)),
+        sds((rows, dk, h * dv))).compile()
+    _assert_mosaic(compiled)
+    assert "kda_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * dk * h * dv * 4
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
 
 
 def test_paged_decode_over_a_pool_laid_out_for_32_heads_compiles(chip):
