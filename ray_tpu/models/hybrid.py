@@ -317,8 +317,8 @@ def _delta_rule(mod, x, cache, u, gate, g, beta, scope: str, gate_fn):
             o = o[:, None]
     else:
         with jax.named_scope(f"{scope}.scan"):
-            o, state = gdn.chunk_scan(q, k, v, g, beta, state,
-                                      chunk=cfg.linear_chunk)
+            o, state = _scan(q, k, v, g, beta, state, n_new,
+                             cfg.linear_chunk)
     # what went into the recurrence and what came out, for a caller that
     # asks for the collection (a check of the recurrence alone on
     # bit-equal inputs); nothing is traced for one that does not
@@ -330,6 +330,20 @@ def _delta_rule(mod, x, cache, u, gate, g, beta, scope: str, gate_fn):
              * gate_fn(gate.astype(jnp.float32))).astype(cfg.dtype)
         y = _proj(cfg, cfg.d_model, "o_proj")(o)
     return y, (None if cache is None else cache.write(state, tail))
+
+
+def _scan(q, k, v, g, beta, state, n_new, chunk: int):
+    """The chunkwise form. A rate a key channel over a slot state on the
+    TPU (serving: `n_new` rides with the cache entry) is the fused
+    kernel, which does nothing for the chunks past a row's true length
+    (ops/pallas/kda_prefill.py; inference only). Everything else, the
+    plain forward and training among it, is the `jax.numpy` form, which
+    has a backward."""
+    if g.ndim == 4 and n_new is not None \
+            and jax.default_backend() == "tpu":
+        from ..ops.pallas.kda_prefill import kda_chunk_scan  # noqa: PLC0415
+        return kda_chunk_scan(q, k, v, g, beta, state, n_new, chunk=chunk)
+    return gdn.chunk_scan(q, k, v, g, beta, state, chunk=chunk)
 
 
 def _step(q, k, v, g, beta, state):
@@ -520,6 +534,13 @@ class Hybrid(nn.Module):
 
     def init_params(self, rng, batch=1, seq=8):
         return self.init(rng, jnp.zeros((batch, seq), jnp.int32))["params"]
+
+    def chunk_scan_layers(self):
+        """(delta-rule layers, tokens a chunk of their chunkwise form):
+        what the serving engine counts prefill's chunks from
+        (`prefill_chunks_window`, `prefill_chunks_live`)."""
+        return (sum(kind in (LINEAR, KDA) for kind in self.cfg.layer_types),
+                self.cfg.linear_chunk)
 
     def paged_cache_spec(self):
         """A full layer pages K and V of `packed_kv_shape(kv_pool_heads,

@@ -39,7 +39,10 @@ alpha carry one more dimension, (.., H, d_k), and the rule reads
     S_t = S_{t-1} Diag(alpha_t) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
 
 `gates`, `recurrent` and `step` tell the two by g's rank. `chunk_scan`
-hands a rate a channel to `_chunk_scan_channel`: the intra-chunk matrix
+hands a rate a channel to `_chunk_scan_channel` (the serving engine's
+prefill on a TPU runs the same mathematics as ONE fused kernel bounded
+by each row's true length, ops/pallas/kda_prefill.py, and the tests
+hold that kernel to this function): the intra-chunk matrix
 is then sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c]), and its factored form
 (k_t exp(G_t)) . (k_s exp(-G_s)) overflows float32 inside a 64-token
 chunk at the rates the layer draws, so the chunk is cut into sub-chunks:

@@ -623,6 +623,12 @@ class LLMEngine:
                       "prefill_calls": 0, "prefill_rows_real": 0,
                       "prefill_rows_padded": 0, "prefill_tokens_real": 0,
                       "prefill_tokens_padded": 0, "prefill_shapes": {},
+                      # chunks of the delta-rule layers' chunkwise form
+                      # that the padded calls spanned, and those that
+                      # hold a real position: all the fused kernel runs
+                      # (ops/pallas/kda_prefill.py); zeros for a model
+                      # with no such layer
+                      "prefill_chunks_window": 0, "prefill_chunks_live": 0,
                       # the hand-off: _hand_over calls that carried
                       # something and the items they carried (awaitable
                       # consumers), tokens put on a blocking queue
@@ -637,6 +643,9 @@ class LLMEngine:
                       # dispatch (_InflightDepth), summed and counted
                       "decode_inflight_target_sum": 0,
                       "decode_inflight_target_n": 0}
+        # (delta-rule layers, tokens a chunk of their chunkwise form)
+        self._scan_layers = getattr(model, "chunk_scan_layers",
+                                    lambda: (0, 1))()
         if self._pages.n_state_layers:
             # per-slot state rows of decode dispatches, summed over the
             # layers that keep one: rows the step read and wrote (every
@@ -1941,8 +1950,7 @@ class LLMEngine:
         # first dispatch of a bucket blocks on its jit compile: record it
         self._prefill_compile_ms.setdefault(pad_len, round(dispatch_ms, 1))
         self.stats["prefills"] += g_real
-        self._count_prefill(pad_len, g_real, g, sum(
-            int(req.prompt.size) for req, _ in members))
+        self._count_prefill(pad_len, g_real, lens)
         for req, slot in members:
             req.prefill_dispatch_ms = dispatch_ms
             self._pages.set_length(slot, req.prompt.size)
@@ -1956,18 +1964,25 @@ class LLMEngine:
                          toks_dev, lps_dev if self.cfg.logprobs else None,
                          time.perf_counter_ns(), False))
 
-    def _count_prefill(self, width: int, rows: int, rows_padded: int,
-                       tokens: int) -> None:
-        """One prefill program dispatched: `rows` prompts holding
-        `tokens` prompt tokens ran as `rows_padded` x `width`."""
+    def _count_prefill(self, width: int, rows: int, lens) -> None:
+        """One prefill program dispatched: `rows` prompts ran as
+        `len(lens)` x `width`, `lens` every row's true length (the
+        prompts' first, 1 a padding row)."""
         st = self.stats
+        rows_padded = len(lens)
         st["prefill_calls"] += 1
         st["prefill_rows_real"] += rows
         st["prefill_rows_padded"] += rows_padded
-        st["prefill_tokens_real"] += tokens
+        st["prefill_tokens_real"] += int(sum(lens[:rows]))
         st["prefill_tokens_padded"] += rows_padded * width
         shape = f"{width}x{rows_padded}"
         st["prefill_shapes"][shape] = st["prefill_shapes"].get(shape, 0) + 1
+        layers, chunk = self._scan_layers
+        chunk = min(chunk, width)
+        st["prefill_chunks_window"] += \
+            layers * rows_padded * -(-width // chunk)
+        st["prefill_chunks_live"] += layers * sum(
+            -(-int(n) // chunk) for n in lens)
 
     def _dispatch_chunk(self, inflight) -> None:
         """Advance the oldest chunk-prefilling request by ONE chunk. The
@@ -2005,7 +2020,7 @@ class LLMEngine:
         req.prefill_pos = start + true
         self._pages.set_length(req.slot, req.prefill_pos)
         req.prefill_dispatch_ms += (time.time() - t_dispatch) * 1000
-        self._count_prefill(C, 1, 1, true)
+        self._count_prefill(C, 1, [true])
         self._progress_ts = time.time()   # watchdog: chunk advanced
         if is_last:
             self._prefilling.popleft()

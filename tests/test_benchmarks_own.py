@@ -342,7 +342,8 @@ def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
     assert whole["workloads"][6]["name"] == _LFM2_CELL
     assert whole["configs"][5]["name"] == whole["workloads"][6]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_52) - len(_NEW_IN_PR_48) - 3
+    at = len(names) - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) \
+        - len(_NEW_IN_PR_48) - 3
     assert set(names[at:at + 3]) == set(_NEW_IN_PR_40)
 
 
@@ -498,7 +499,7 @@ def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
     assert whole["workloads"][7]["name"] == _XING_CELL
     assert whole["configs"][6]["name"] == whole["workloads"][7]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_52) - 7
+    at = len(names) - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) - 7
     assert set(names[at:at + 7]) == set(_NEW_IN_PR_48)
 
 
@@ -560,6 +561,8 @@ _NEW_IN_PR_52 = {"kda_kernel_roofline": "kda_kernel",
                  "paged_kernel_roofline.solar": "paged_kernel",
                  "decode_step_roofline.solar": "step"}
 _SOLAR_CELL = "solar250b_decode_sat"
+# PR 53's, behind them: a share of the engine's own counters
+_NEW_IN_PR_53 = ("prefill_live_chunk_share",)
 
 
 def _solar_window(counters=True, kernels=True):
@@ -585,7 +588,11 @@ def _solar_window(counters=True, kernels=True):
                "moe_routed_assignments": k * 128 * 8 * 4}
         if counters:
             out.update(decode_state_rows_live=k * 128 * 3,
-                       decode_state_rows_window=k * 129 * 3)
+                       decode_state_rows_window=k * 129 * 3,
+                       # a 1 024-wide call of one row of 700 tokens in
+                       # three delta-rule layers, chunks of 64
+                       prefill_chunks_live=(k // 50) * 11 * 3,
+                       prefill_chunks_window=(k // 50) * 16 * 3)
         return out
     ops = {"gmm": 0.7, "paged_decode_attention": 0.07, "fusion": 0.4,
            "sort": 0.01}
@@ -772,6 +779,7 @@ def test_the_solar_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state
         w = m.get("workloads", [])
         if ("lfm2moe24b_decode_sat" in w and m["name"] not in own) \
                 or m["name"] in _NEW_IN_PR_52 \
+                or m["name"] in _NEW_IN_PR_53 \
                 or m["name"] == "moe_local_assignment_share":
             assert w[-1] == _SOLAR_CELL, m["name"]
         else:
@@ -800,6 +808,26 @@ def test_the_solar_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state
             if runmod.read_metric(_BENCH, m["name"], run) is None:
                 silent.append(m["name"])
     assert silent == []
+
+
+def test_prefill_live_chunk_share_reads_the_engines_counters():
+    """PR 53's metric: chunks of the delta-rule layers' chunkwise form
+    that hold a prompt token over those the padded calls span, from the
+    window's two readings of `get_stats()`; listed for the solar cell
+    alone, at the end of the list, and silent (no number, no error)
+    against a program that does not count them, as the parent commit's
+    does not."""
+    from benchmarks import run as runmod
+    whole = runmod.load_manifest()
+    entry = whole["per_layer"][-1]
+    assert entry == {"name": "prefill_live_chunk_share", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "kernels (ops/pallas)", "moves": "out_tok_s",
+                     "workloads": [_SOLAR_CELL]}
+    got = runmod.read_metric(_BENCH, entry["name"], _solar_window())
+    assert got == pytest.approx(100 * 11 / 16)
+    assert runmod.read_metric(_BENCH, entry["name"],
+                              _solar_window(counters=False)) is None
 
 
 def test_the_solar_cell_rehearses_through_run_py(tmp_path):

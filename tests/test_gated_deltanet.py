@@ -1,16 +1,24 @@
-"""ops/gated_deltanet.py and ops/pallas/gdn_decode.py: the chunkwise
-form, the one-token step and the fused step kernel (interpreted) against
-the recurrence token by token, and the layer of models/hybrid.py over a
-per-slot state: padded == unpadded, prefill then steps == one prefill."""
+"""ops/gated_deltanet.py, ops/pallas/gdn_decode.py and
+ops/pallas/kda_prefill.py: the chunkwise form, the one-token step, the
+fused step kernel and the fused chunk kernel (both interpreted) against
+the recurrence token by token, and the layers of models/hybrid.py over
+a per-slot state: padded == unpadded, prefill then steps == one
+prefill."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.hybrid import GatedDeltaNet, HybridConfig
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.models.hybrid import (GatedDeltaNet, HybridConfig,
+                                   KimiDeltaAttention)
 from ray_tpu.ops import gated_deltanet as gdn
 from ray_tpu.ops.attention import SlotState
 from ray_tpu.ops.pallas.gdn_decode import gdn_decode_step, heads_per_group
+from ray_tpu.ops.pallas.kda_prefill import kda_chunk_scan
 
 
 def _inputs(b, s, h, dk, dv, seed=0, with_state=True):
@@ -108,9 +116,97 @@ def test_the_step_kernel_interpreted_is_the_step(b, h, dk, dv):
         or heads_per_group(h, dv) == h
 
 
-def _layer(**kw):
-    cfg = HybridConfig.debug(dtype=jnp.float32, **kw)
-    layer = GatedDeltaNet(cfg)
+def _channel_inputs(b, s, h, dk, dv, rate, seed=0, constant=False):
+    """Keys after a SiLU, as the layer makes them, and a rate a key
+    channel drawn up to `rate` a token (or `constant`: every channel at
+    it)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = gdn.l2norm(jax.nn.silu(jax.random.normal(ks[0], (b, s, h, dk)))) \
+        * dk ** -0.5
+    k = gdn.l2norm(jax.nn.silu(jax.random.normal(ks[1], (b, s, h, dk))))
+    v = jax.random.normal(ks[2], (b, s, h, dv))
+    g = -rate * (jnp.ones((b, s, h, dk)) if constant
+                 else jax.random.uniform(ks[3], (b, s, h, dk)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    state = jax.random.normal(ks[5], (b, dk, h * dv))
+    return q, k, v, g, beta, state
+
+
+@pytest.mark.parametrize("s,chunk,lens,carried,rate,constant", [
+    (150, 64, None, True, 0.5, False),      # no multiple of the chunk
+    (37, 16, None, True, 0.5, False),       # nor of the sub-chunk
+    (100, 64, None, False, 0.5, False),     # from nothing
+    (128, 32, (128, 45), True, 0.5, False),  # two rows, two true lengths
+    (96, 64, (70, 96), True, 1.6, True),    # a fast channel
+], ids=["past_a_chunk", "past_a_sub_chunk", "no_state", "two_lengths",
+        "fast_channel"])
+def test_the_chunk_kernel_interpreted_is_the_recurrence(
+        s, chunk, lens, carried, rate, constant):
+    """ops/pallas/kda_prefill.py against the recurrence token by token
+    and against the plain chunkwise form it replaces on the TPU, at the
+    real positions (a row's outputs past its true length are the
+    caller's to drop; past its last live chunk they are zero)."""
+    b, h, dk, dv = 2, 3, 16, 8
+    q, k, v, g, beta, state = _channel_inputs(b, s, h, dk, dv, rate,
+                                              constant=constant)
+    n_new = None if lens is None else jnp.asarray(lens, jnp.int32)
+    real = jnp.ones((b, s), bool) if lens is None \
+        else jnp.arange(s)[None, :] < n_new[:, None]
+    g, beta = gdn.freeze(g, beta, real)
+    state = state if carried else None
+    want_o, want_s = gdn.recurrent(q, k, v, g, beta, state)
+    plain_o, plain_s = gdn.chunk_scan(q, k, v, g, beta, state, chunk=chunk)
+    got_o, got_s = kda_chunk_scan(q, k, v, g, beta, state, n_new,
+                                  chunk=chunk, interpret=True)
+    assert got_o.shape == want_o.shape and got_s.shape == want_s.shape
+    assert bool(jnp.isfinite(got_o).all())
+    mask = real[..., None, None]
+    for ref_o, ref_s in ((want_o, want_s), (plain_o, plain_s)):
+        np.testing.assert_allclose(got_o * mask, ref_o * mask, atol=1e-5)
+        np.testing.assert_allclose(got_s, ref_s, atol=2e-5)
+    if lens is not None:
+        c = min(chunk, s)
+        dead = jnp.arange(s)[None, :] >= -(-n_new[:, None] // c) * c
+        assert bool((jnp.where(dead[..., None, None], got_o, 0.0)
+                     == 0.0).all())
+
+
+def test_the_chunk_kernel_never_reads_a_dead_chunk():
+    """NaNs planted in q, k, v of the chunks past a row's true length
+    reach neither the state nor a real output row, and the bounded grid
+    gives the full loop's result bit for bit: the state after the last
+    live chunk, the same outputs where the row is real."""
+    b, s, h, dk, dv, chunk = 2, 128, 2, 16, 8, 32
+    q, k, v, g, beta, state = _channel_inputs(b, s, h, dk, dv, 0.5, seed=3)
+    n_new = jnp.asarray([40, 97], jnp.int32)
+    real = jnp.arange(s)[None, :] < n_new[:, None]
+    g, beta = gdn.freeze(g, beta, real)
+    whole_o, whole_s = kda_chunk_scan(q, k, v, g, beta, state, None,
+                                      chunk=chunk, interpret=True)
+    dead = (jnp.arange(s)[None, :]
+            >= -(-n_new[:, None] // chunk) * chunk)[..., None, None]
+    assert int(dead.sum()) == 64         # the first row's last two chunks
+    nan = lambda x: jnp.where(dead, jnp.nan, x)              # noqa: E731
+    got_o, got_s = kda_chunk_scan(nan(q), nan(k), nan(v), g, beta, state,
+                                  n_new, chunk=chunk, interpret=True)
+    assert bool(jnp.isfinite(got_o).all()) and bool(
+        jnp.isfinite(got_s).all())
+    np.testing.assert_array_equal(got_s, whole_s)
+    np.testing.assert_array_equal(
+        jnp.where(real[..., None, None], got_o, 0.0),
+        jnp.where(real[..., None, None], whole_o, 0.0))
+    # and the padded row is the unpadded row bit for bit
+    for r, n in enumerate([40, 97]):
+        cut_o, cut_s = kda_chunk_scan(
+            *(x[r:r + 1, :n] for x in (q, k, v, g, beta)), state[r:r + 1],
+            None, chunk=chunk, interpret=True)
+        np.testing.assert_array_equal(cut_o[0], got_o[r, :n])
+        np.testing.assert_array_equal(cut_s[0], got_s[r])
+
+
+def _layer(kind=GatedDeltaNet, preset=HybridConfig.debug, **kw):
+    cfg = preset(dtype=jnp.float32, **kw)
+    layer = kind(cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.d_model))
     params = layer.init(jax.random.PRNGKey(4), x)["params"]
     return cfg, layer, params, x
@@ -122,21 +218,33 @@ def _pool(cfg, slots):
             jnp.zeros((slots, cfg.linear_conv_kernel - 1, cfg.conv_width)))
 
 
+@pytest.mark.parametrize("route", ["plain", "kernel"])
 @pytest.mark.parametrize("true_len", [19, 5, 32])
-def test_a_padded_row_is_the_unpadded_row(true_len):
+def test_a_padded_row_is_the_unpadded_row(true_len, route, monkeypatch):
     """A 32-wide bucket over `true_len` real positions: outputs of the
     real positions, the state and the convolution's tail are those of
     the unpadded row; and the other row, with no real position at all,
-    keeps what its slot held."""
-    cfg, layer, params, x = _layer()
+    keeps what its slot held. `kernel`: the layer with a decay a key
+    channel on the route a TPU takes (models/hybrid.py:_scan), the
+    chunk kernel interpreted: chunks of 16, so a row of 5 or 19 leaves
+    one or none of the bucket's two to the bound."""
+    if route == "kernel":
+        cfg, layer, params, x = _layer(KimiDeltaAttention,
+                                       HybridConfig.solar_debug)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        interpreted = pltpu.force_tpu_interpret_mode()
+    else:
+        cfg, layer, params, x = _layer()
+        interpreted = contextlib.nullcontext()
     held = tuple(a + 1.0 for a in _pool(cfg, 3))
     padded = SlotState(*held, jnp.asarray([0, 2]),
                        jnp.asarray([true_len, 0]),
                        jnp.asarray([True, False]))
-    y_pad, new = layer.apply({"params": params}, x, padded)
     exact = SlotState(*_pool(cfg, 1), jnp.asarray([0]),
                       jnp.asarray([true_len]), None, fresh=True)
-    y, want = layer.apply({"params": params}, x[:1, :true_len], exact)
+    with interpreted:
+        y_pad, new = layer.apply({"params": params}, x, padded)
+        y, want = layer.apply({"params": params}, x[:1, :true_len], exact)
     np.testing.assert_allclose(y_pad[0, :true_len], y[0], atol=2e-5)
     for got, ref, was in zip(new.arrays, want.arrays, held):
         np.testing.assert_allclose(got[0], ref[0], atol=2e-5)

@@ -145,6 +145,15 @@ SIBLING_PROGRAMS = {
         "1029592e79e1e62fd4bc0bc24ed0c6a4a39c411793cec70fad6a6c3fff290789",
     ("lfm2-moe-debug", "prefill"):
         "1b412dbfc2c7807f6c363a33163ef2c06a2edb870ea11bf7a0e8787fe44c36aa",
+    # this family's own, taken on the commit before the chunk kernel
+    # (PR 52's) and again after it: off the TPU `models/hybrid.py:_scan`
+    # keeps the plain chunkwise form, so the prefill program lowers as
+    # it did, like the decode program, which the kernel never touched.
+    # What the TPU's prefill programs hold is tests/test_tpu_compile.py's
+    ("solar-debug", "decode"):
+        "c55fbb558679e3a178a0ebdec0f1f9a05250379e4891d5a026ba0a106d22ca23",
+    ("solar-debug", "prefill"):
+        "39dac1b25b435c8a9f79f4b931d28ed66a8b38d3d8b8554bd3944acb84777d83",
 }
 
 
@@ -404,6 +413,30 @@ def test_seven_requests_through_three_slots_answer_as_one_at_a_time(tiny):
     finally:
         eng.shutdown()
     assert got == alone
+
+
+def test_prefill_counts_the_chunks_its_rows_fill(tiny):
+    """`prefill_chunks_window` / `prefill_chunks_live` by hand: chunks of
+    16 in three delta-rule layers. Prompts of 40 and 50 tokens share a
+    64-wide call of two rows (2 x 4 chunks; 3 + 4 hold a token), a
+    prompt of 5 runs alone in 16 (1; 1)."""
+    eng = _engine(tiny)
+    assert tiny[0].chunk_scan_layers() == (3, 16)
+    rids = []
+
+    def submit_all():       # on the loop thread: one admission pass
+        for n in (40, 50, 5):
+            rids.append(eng.submit(np.arange(1, n + 1), max_new_tokens=2))
+    try:
+        eng._run_on_loop(submit_all)
+        for rid in rids:
+            assert len(list(eng.stream(rid))) == 2
+        st = eng.get_stats()
+    finally:
+        eng.shutdown()
+    assert st["prefill_shapes"] == {"64x2": 1, "16x1": 1}
+    assert st["prefill_chunks_window"] == 3 * (2 * 4 + 1)
+    assert st["prefill_chunks_live"] == 3 * (3 + 4 + 1)
 
 
 # ---- (d) the share -------------------------------------------------------

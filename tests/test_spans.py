@@ -316,6 +316,8 @@ def test_prefill_counters_are_bucket_times_padded_group(drained):
     assert st["prefill_tokens_real"] == sum(PROMPT_LENS)
     assert st["prefill_tokens_padded"] == 16 * 4
     assert st["prefill_shapes"] == {"16x4": 1}
+    # no delta-rule layer, no chunk of a chunkwise form
+    assert st["prefill_chunks_window"] == st["prefill_chunks_live"] == 0
 
 
 def test_a_group_of_three_pads_to_four_rows(tiny_llm):

@@ -391,6 +391,117 @@ def test_kda_decode_step_compiles_for_v5e(chip):
     assert mem.temp_size_in_bytes < 16 * 2 ** 20
 
 
+def test_kda_chunk_scan_compiles_for_v5e(chip):
+    """Solar-Open2-250B's chunkwise form of the recurrence with a decay a
+    key channel at the cell's largest prefill group (2 rows x 2 048
+    tokens, 64 heads, keys and values of 128): one Mosaic kernel under
+    its own name, the rows' state (2 x 4 MiB) updated in place."""
+    from ray_tpu.ops.pallas.kda_prefill import kda_chunk_scan
+    rows, s, h, dk, dv = 2, 2048, 64, 128, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def scan(q, k, v, g, beta, state, n_new):
+        return kda_chunk_scan(q, k, v, g, beta, state, n_new,
+                              interpret=False)
+    compiled = jax.jit(scan, donate_argnums=(5,)).lower(
+        sds((rows, s, h, dk)), sds((rows, s, h, dk)),
+        sds((rows, s, h, dv), jnp.bfloat16), sds((rows, s, h, dk)),
+        sds((rows, s, h)), sds((rows, dk, h * dv)),
+        sds((rows,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert "kda_chunk_scan" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= rows * dk * h * dv * 4
+
+
+def _loops_with_a_product(text: str) -> list:
+    """The `while` operations of a compiled program whose body, or a
+    computation it calls, holds a matrix product."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            name = m.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+
+    def has_product(comp, seen):
+        if comp in seen or comp not in bodies:
+            return False
+        seen.add(comp)
+        for line in bodies[comp]:
+            if re.search(r" (convolution|dot)\(", line):
+                return True
+            if any(has_product(c, seen) for c in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                    line)):
+                return True
+        return False
+    return [m.group(1) for lines in bodies.values() for line in lines
+            for m in [re.search(r" while\(.*body=%?([\w.\-]+)", line)]
+            if m and has_product(m.group(1), set())]
+
+
+# temp_size_in_bytes of the same programs at the parent commit (PR 52's,
+# the scan a `lax.scan` of 51 fusions): AOT, sandbox, this test's engine
+SOLAR_PREFILL_TEMP_MIB_BEFORE = {(1024, 1): 214.3, (2048, 2): 1043.2}
+
+
+@pytest.mark.parametrize("pad_len,group",
+                         sorted(SOLAR_PREFILL_TEMP_MIB_BEFORE))
+def test_solar_prefill_programs_hold_the_chunk_kernel(chip, monkeypatch,
+                                                      pad_len, group):
+    """`solar250b_decode_sat`'s own prefill programs (the cell's file,
+    the engine's jit, four slots for the pools' sake) compile for a
+    described v5e with the chunk kernel in each delta-rule layer and no
+    loop left that multiplies matrices (the grouped matmul's searches
+    stay). The largest program's temporaries are under the parent's;
+    the 1 024 x 1 program's heap packs 24 MiB worse than the parent's
+    (238.0 against 214.3 MiB; its peak of live bytes is 29 MB lower),
+    which one (1 024, 8 192) float32 array more than covers."""
+    from benchmarks.harness import modelcfg, replica_solar
+    from ray_tpu import models
+    from ray_tpu.serve.llm.engine import LLMEngine, LLMEngineConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = modelcfg.load(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "configs",
+        "solar-open2-250b-serve-ep8-l4.json"), False)
+    model = models.Hybrid(replica_solar.hybrid_config(
+        cfg, param_dtype=jnp.bfloat16))
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0)))
+    ecfg = {**cfg["engine"], "max_slots": 4, "kv_pool_tokens": 4 * 4096,
+            "prefill_buckets": tuple(cfg["engine"]["prefill_buckets"])}
+    eng = LLMEngine(model, params, LLMEngineConfig(**ecfg))
+    try:
+        s, p = eng._pages.rows_shape()
+        compiled = eng._prefill_paged_jit.lower(
+            params, abstract(eng._pools), abstract(eng._state),
+            jax.ShapeDtypeStruct(
+                (s + 1 + 4 * group + s * p + group * pad_len,), jnp.int32,
+                sharding=chip), pad_len=pad_len).compile()
+    finally:
+        eng.shutdown()
+    text = compiled.as_text()
+    layers = sum(k == "kda" for k in model.cfg.layer_types)
+    assert layers == 3
+    assert len(re.findall(r"custom-call\(.*kda_chunk_scan", text)) == layers
+    assert _loops_with_a_product(text) == []
+    temp_mib = compiled.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib <= SOLAR_PREFILL_TEMP_MIB_BEFORE[pad_len, group] + 32, \
+        temp_mib
+    if (pad_len, group) == (2048, 2):
+        assert temp_mib < SOLAR_PREFILL_TEMP_MIB_BEFORE[pad_len, group]
+
+
 def test_paged_decode_over_a_pool_laid_out_for_32_heads_compiles(chip):
     """Olmo-Hybrid-7B's full layers: 30 KV heads fill no whole 8-row
     tile, so the pool is declared for 32 (models/hybrid.py:

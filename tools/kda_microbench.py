@@ -1,5 +1,6 @@
-"""One-chip microbenchmark of the delta rule's decode step kernels
-(PERF.md, PR 52).
+"""One-chip microbenchmark of the delta rule's kernels: the decode step
+(PERF.md, PR 52) and prefill's chunk scan with a decay a key channel
+(PERF.md, PR 53).
 
 One layer's one-token step over a slot pool at Solar-Open2-250B's
 widths (64 heads of 128 x 128, a decay a key channel, 4 MiB of float32
@@ -13,13 +14,29 @@ through). Every candidate is a jitted function of its own name, run
 `--reps` times under one profiler trace; its time is the device time of
 its program on the trace's `XLA Modules` line, not a host clock; the
 share is of the bytes `benchmarks/harness/costs_solar.py:kda_step`
-counts for the LIVE rows at the chip's bandwidth. Needs the chip:
+counts for the LIVE rows at the chip's bandwidth.
+
+`--what scan`: one layer's chunkwise form over a prefill group at
+Solar-Open2's widths with a carried state, 1 x 512, 1 x 1 024, 2 x 1 024
+and 2 x 2 048 tokens: the plain form (`_chunk_scan_channel`, a
+`lax.scan` over every chunk of the bucket) against the fused kernel
+(ops/pallas/kda_prefill.py, bounded by the rows' true lengths), the
+rows' lengths drawn as `solar250b_decode_sat` draws them inside that
+bucket and, `_full`, every row as long as the bucket. ms a call, us a
+chunk a row over the window's chunks and over the live ones (the whole program and the kernel alone:
+a stand-alone program pays for the head-major layout of q, k and g,
+which inside a model their own fusions write), and beside them what
+`chunk_floor`
+counts for a chunk of 64 heads: `exp`s, float32 vector operations and
+bfloat16 FLOPs of the six-pass products, the last as a share of the
+chip's matrix peak. Needs the chip:
 
     python -m tools.kda_microbench --out chiprun_out/kda.json
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import tempfile
@@ -27,15 +44,53 @@ import time
 
 SHAPES = {"solar": dict(rows=129, h=64, dk=128, dv=128, channel=True),
           "olmo": dict(rows=65, h=30, dk=96, dv=192, channel=False)}
+# prefill groups of the chunk scan: (rows, bucket)
+SCAN_GROUPS = ((1, 512), (1, 1024), (2, 1024), (2, 2048))
+CHUNK = 64
+
+
+def chunk_floor(h: int, dk: int, dv: int, c: int = CHUNK,
+                sc: int = 16) -> dict:
+    """What one chunk of one row costs the kernel by arithmetic, all
+    `h` heads: `exp`s, float32 vector operations (the diagonal blocks'
+    elementwise work and lane sums, the rank-one updates of the
+    substitution, the rest at ~30 passes over a (c, d_k) tile) and the
+    FLOPs of its products times the six bfloat16 passes of a float32
+    product at the highest precision."""
+    ns = c // sc
+    exps = c * sc * dk + (ns + 2) * c * dk
+    vector = 9 * c * sc * dk + 30 * c * dk + 2 * ns * sc * sc * dv
+    flops = 2 * (2 * c * dk * dv                    # the state's share
+                 + (ns - 1) * 2 * sc * dk * c       # blocks under the diagonal
+                 + (ns - 1) * sc * c * dv           # the substitution
+                 + c * c * dv + dk * c * dv)        # P W, the state's update
+    return {"exps": h * exps, "vector_ops": h * vector,
+            "bf16_flops_six_pass": 6 * h * flops}
+
+
+def cell_lengths(rng, rows: int, bucket: int) -> list:
+    """`rows` prompt lengths of those `solar250b_decode_sat` sends (the
+    traffic file's distribution at the quantiles a block of its
+    schedule draws from) that land in `bucket`."""
+    from benchmarks.harness.schedule import length_multiset
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "traffic",
+            "decode_sat_solar.json")) as f:
+        lengths = length_multiset(json.load(f)["prompt_len"], 256)
+    return rng.choices([n for n in lengths if bucket // 2 < n <= bucket],
+                       k=rows)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--what", default="step,scan")
     ap.add_argument("--shapes", default="solar,olmo")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+
+    import random
 
     import jax
     import jax.numpy as jnp
@@ -43,12 +98,14 @@ def main() -> None:
     from ray_tpu.ops import gated_deltanet as gdn
     from ray_tpu.ops.pallas.gdn_decode import (gdn_decode_step,
                                                kda_decode_step)
+    from ray_tpu.ops.pallas.kda_prefill import kda_chunk_scan
     from tools.gmm_microbench import device_times
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"kda_microbench needs the chip; this is {dev}")
-    peak_bw = peaks_for(dev.device_kind)["hbm_bytes_per_s"]
+    peaks = peaks_for(dev.device_kind)
+    peak_bw = peaks["hbm_bytes_per_s"]
     cands, inputs = {}, {}
 
     def add(name, fn, key, **meta):
@@ -58,41 +115,79 @@ def main() -> None:
         # trace
         tag = float(len(cands))
 
-        def step(q, k, v, g, beta, state):
-            o, new = fn(q, k, v, g, beta, state)
+        def step(q, k, v, g, beta, state, *rest):
+            o, new = fn(q, k, v, g, beta, state, *rest)
             return o, new, jnp.float32(tag)
         step.__name__ = name
         cands[name] = (jax.jit(step, donate_argnums=(5,)), key, meta)
 
-    for shape in args.shapes.split(","):
+    def draw(key, rows, h, dk, dv, channel, lead=()):
+        ks = jax.random.split(key, 6)
+        q = gdn.l2norm(jax.random.normal(ks[0], (*lead, rows, h, dk))) \
+            * dk ** -0.5
+        k = gdn.l2norm(jax.random.normal(ks[1], (*lead, rows, h, dk)))
+        v = jax.random.normal(ks[2], (*lead, rows, h, dv))
+        g = -jax.random.uniform(ks[3], (*lead, rows, h, dk) if channel
+                                else (*lead, rows, h))
+        beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (*lead, rows, h)))
+        return q, k, v, g, beta, ks[5]
+
+    what = args.what.split(",")
+    for shape in args.shapes.split(",") if "step" in what else ():
         s = SHAPES[shape]
         rows, h, dk, dv = s["rows"], s["h"], s["dk"], s["dv"]
-        ks = jax.random.split(jax.random.PRNGKey(args.seed), 6)
-        q = gdn.l2norm(jax.random.normal(ks[0], (rows, h, dk))) * dk ** -0.5
-        k = gdn.l2norm(jax.random.normal(ks[1], (rows, h, dk)))
-        v = jax.random.normal(ks[2], (rows, h, dv))
-        g = -jax.random.uniform(ks[3], (rows, h, dk) if s["channel"]
-                                else (rows, h))
-        beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, h)))
-        state0 = jax.random.normal(ks[5], (rows, dk, h * dv))
+        q, k, v, g, beta, key5 = draw(jax.random.PRNGKey(args.seed), rows,
+                                      h, dk, dv, s["channel"])
+        state0 = jax.random.normal(key5, (rows, dk, h * dv))
         kernel = kda_decode_step if s["channel"] else gdn_decode_step
         for live_name, every in (("live", 1), ("third_idle", 3)):
             live = (jnp.arange(rows) % every != every - 1) | (every == 1)
             gl = jnp.where(live.reshape((rows,) + (1,) * (g.ndim - 1)), g, 0.)
             bl = jnp.where(live[:, None], beta, 0.0)
             key = f"{shape}_{live_name}"
-            inputs[key] = ((q, k, v, gl, bl), state0)
+            inputs[key] = ((q, k, v, gl, bl), state0, ())
             state_bytes = 2 * 4 * int(live.sum()) * dk * h * dv
             add(f"{key}_kernel", kernel, key, bytes=state_bytes, rows=rows,
                 live_rows=int(live.sum()))
             add(f"{key}_xla", gdn.step, key, bytes=state_bytes, rows=rows,
                 live_rows=int(live.sum()))
 
+    if "scan" in what:
+        s = SHAPES["solar"]
+        h, dk, dv = s["h"], s["dk"], s["dv"]
+        rng = random.Random(args.seed)
+
+        def plain(q, k, v, g, beta, state, n_new):
+            return gdn.chunk_scan(q, k, v, g, beta, state, chunk=CHUNK)
+        for rows, bucket in SCAN_GROUPS:
+            q, k, v, g, beta, key5 = draw(
+                jax.random.PRNGKey(args.seed + bucket + rows), bucket, h, dk,
+                dv, True, lead=(rows,))
+            state0 = jax.random.normal(key5, (rows, dk, h * dv))
+            for tail, lens in (("", cell_lengths(rng, rows, bucket)),
+                               ("_full", [bucket] * rows)):
+                n_new = jnp.asarray(lens, jnp.int32)
+                gf, bf = gdn.freeze(
+                    g, beta, jnp.arange(bucket)[None, :] < n_new[:, None])
+                key = f"scan_{rows}x{bucket}{tail}"
+                inputs[key] = ((q, k, v.astype(jnp.bfloat16), gf, bf),
+                               state0, (n_new,))
+                meta = dict(
+                    rows=rows, bucket=bucket, lengths=lens,
+                    chunks_window=rows * bucket // CHUNK,
+                    chunks_live=sum(-(-n // CHUNK) for n in lens))
+                add(f"{key}_plain", plain, key, **meta)
+                add(f"{key}_kernel", functools.partial(
+                    kda_chunk_scan, chunk=CHUNK), key, **meta)
+
     rows_out, compiled, finals = {}, {}, {}
     for name, (fn, key, meta) in cands.items():
+        args_, state0, rest = inputs[key]
         try:
-            o, st, _tag = fn(*inputs[key][0], inputs[key][1] + 0.0)
+            t0 = time.perf_counter()
+            o, st, _tag = fn(*args_, state0 + 0.0, *rest)
             jax.block_until_ready(st)
+            meta["first_call_s"] = round(time.perf_counter() - t0, 2)
             compiled[name] = fn
             finals[name] = (o, st)
         except Exception as e:  # noqa: BLE001: what Mosaic refuses
@@ -104,15 +199,17 @@ def main() -> None:
     jax.block_until_ready(states)
     jax.profiler.start_trace(trace_dir)
     for name, fn in compiled.items():
+        args_, _state0, rest = inputs[cands[name][1]]
         st = states.pop(name)
         t0 = time.perf_counter()
         for _ in range(args.reps):
-            _o, st, _tag = fn(*inputs[cands[name][1]][0], st)
+            _o, st, _tag = fn(*args_, st, *rest)
             jax.block_until_ready(st)
         wall[name] = 1e3 * (time.perf_counter() - t0) / args.reps
         del st
     jax.profiler.stop_trace()
-    times = device_times(trace_dir, r"^%?(kda|gdn)_decode_step")
+    times = device_times(trace_dir, r"^%?(kda|gdn)_(decode_step|chunk_scan)")
+    floor = chunk_floor(64, 128, 128)
     for name in compiled:
         meta = cands[name][2]
         runs, seconds, kernel_s = times.get(f"jit_{name}", (0, 0.0, 0.0))
@@ -122,30 +219,59 @@ def main() -> None:
                               f"the trace, not {args.reps}"}
             continue
         ms = 1e3 * seconds / runs
-        rows_out[name] = {
-            **meta, "ms": round(ms, 4),
-            "kernel_ms": round(1e3 * kernel_s / runs, 4),
-            "wall_ms": round(wall[name], 4),
-            "gb_per_s": round(meta["bytes"] / (ms * 1e-3) / 1e9, 1),
-            "share_of_hbm_peak": round(
-                meta["bytes"] / (ms * 1e-3) / peak_bw, 4)}
+        row = {**meta, "ms": round(ms, 4),
+               "kernel_ms": round(1e3 * kernel_s / runs, 4),
+               "wall_ms": round(wall[name], 4)}
+        if "bytes" in meta:
+            row.update(gb_per_s=round(meta["bytes"] / (ms * 1e-3) / 1e9, 1),
+                       share_of_hbm_peak=round(
+                           meta["bytes"] / (ms * 1e-3) / peak_bw, 4))
+        else:
+            per_live = 1e3 * ms / meta["chunks_live"]
+            row.update(
+                us_per_chunk_row_window=round(
+                    1e3 * ms / meta["chunks_window"], 2),
+                us_per_chunk_row_live=round(per_live, 2),
+                # the kernel alone: the program around it also lays q, k,
+                # g out a head at a time, which the layer's own fusions
+                # do for nothing
+                kernel_us_per_chunk_row_live=round(
+                    1e6 * kernel_s / runs / meta["chunks_live"], 2),
+                share_of_matrix_peak_live=round(
+                    floor["bf16_flops_six_pass"] / (per_live * 1e-6)
+                    / peaks["bf16_flops"], 4))
+        rows_out[name] = row
     errs = {}
     for name in compiled:
-        if name.endswith("_kernel") and name[:-6] + "xla" in finals:
-            (o1, s1), (o2, s2) = finals[name], finals[name[:-6] + "xla"]
+        if not name.endswith("_kernel"):
+            continue
+        for other in ("xla", "plain"):
+            ref = name[:-len("kernel")] + other
+            if ref not in finals:
+                continue
+            (o1, s1), (o2, s2) = finals[name], finals[ref]
+            for n_new in inputs[cands[name][1]][2]:     # real positions
+                real = (jnp.arange(o1.shape[1])[None, :]
+                        < n_new[:, None])[..., None, None]
+                o1, o2 = o1 * real, o2 * real
             errs[name] = [float(jnp.max(jnp.abs(o1 - o2))),
                           float(jnp.max(jnp.abs(s1 - s2)))]
     result = {"device": {"platform": dev.platform, "kind": dev.device_kind,
                          "count": jax.device_count()},
               "hbm_bytes_per_s": peak_bw, "reps": args.reps,
-              "seed": args.seed, "max_abs_err_o_state_vs_plain_form": errs,
+              "seed": args.seed, "chunk_floor_64_heads": floor,
+              "max_abs_err_o_state_vs_plain_form": errs,
               "rows": rows_out}
     for name, row in rows_out.items():
         print(name, json.dumps({k: row[k] for k in (
             "ms", "kernel_ms", "gb_per_s", "share_of_hbm_peak", "live_rows",
-            "error") if k in row}), flush=True)
-    print(json.dumps({k: result[k] for k in
-                      ("device", "max_abs_err_o_state_vs_plain_form")}))
+            "lengths", "us_per_chunk_row_window", "us_per_chunk_row_live",
+            "kernel_us_per_chunk_row_live",
+            "share_of_matrix_peak_live", "first_call_s", "error")
+            if k in row}), flush=True)
+    print(json.dumps({k: result[k] for k in (
+        "device", "chunk_floor_64_heads",
+        "max_abs_err_o_state_vs_plain_form")}))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
