@@ -1360,6 +1360,9 @@ class WorkerLoop:
         self._group_pools: Dict[str, ThreadPoolExecutor] = {}
         self._async_loop = None
         self._async_sems: Dict[Optional[str], Any] = {}
+        # the loop's own rows of an async actor call, made with the
+        # loop (`_ensure_async_loop`): resolve, reply, telemetry
+        self._call_rows = None
         self._cancelled: set = set()
         # lease slots the driver reclaimed (blocked-head revoke): skip
         # silently when they surface in the queue. _queued_tasks mirrors
@@ -2096,6 +2099,8 @@ class WorkerLoop:
         num_returns=\"streaming\" on the call (enforced below — a plain
         call would otherwise try to seal an async_generator object)."""
         from ..exceptions import ActorExitRequest  # noqa: PLC0415
+        resolve_row, reply_row, telemetry_row = self._call_rows
+        began = time.perf_counter_ns()
         t0 = time.time()
         exec_span = tracing.new_span_id()
         status = "ok"
@@ -2112,6 +2117,7 @@ class WorkerLoop:
                         f"{spec.method_name} is an async generator; "
                         "call it with num_returns=\"streaming\"")
                 cancelled = False
+                resolve_row.since(began)
                 async for item in agen:
                     if spec.task_id in self._cancelled:
                         cancelled = True
@@ -2120,9 +2126,11 @@ class WorkerLoop:
                     self._put_gen_item(spec, item)
                 if cancelled:
                     status = "cancelled"
+                began = time.perf_counter_ns()
                 self._task_done(spec.task_id, [],
                                 "cancelled" if cancelled else None)
                 self._maybe_checkpoint()
+                reply_row.since(began)
         except ActorExitRequest:
             self._task_done(spec.task_id, [], None)
             self._batch.flush()
@@ -2140,7 +2148,9 @@ class WorkerLoop:
             # no tracing.active here: interleaved coroutines share the
             # loop thread, so a thread-local context would leak between
             # requests — the span record alone keeps the timeline link
+            began = time.perf_counter_ns()
             self._finish_task_telemetry(spec, exec_span, t0, status)
+            telemetry_row.since(began)
 
     async def _run_actor_task_async(self, spec: TaskSpec) -> None:
         from ..exceptions import ActorExitRequest  # noqa: PLC0415
@@ -2148,6 +2158,13 @@ class WorkerLoop:
             self._cancelled.discard(spec.task_id)
             self._task_done(spec.task_id, [], "cancelled")
             return
+        # this loop's own time in a call, by segment (wall; the
+        # process's table, observability/profiler.py:PROCESS_SPANS):
+        # entry to the first await, the reply, the telemetry. One
+        # `stream_next` reply a token makes this the hottest coroutine
+        # of a replica
+        resolve_row, reply_row, telemetry_row = self._call_rows
+        began = time.perf_counter_ns()
         t0 = time.time()
         exec_span = tracing.new_span_id()
         status = "ok"
@@ -2158,9 +2175,12 @@ class WorkerLoop:
                 method = getattr(self._actor_instance, spec.method_name)
                 args, kwargs = _resolve_args(self.rt, spec.args,
                                              spec.kwargs)
+                resolve_row.since(began)
                 result = await method(*args, **kwargs)
+                began = time.perf_counter_ns()
             self._actor_reply(spec, result, None)
             self._maybe_checkpoint()
+            began = reply_row.since(began)
         except ActorExitRequest:
             self._actor_reply(spec, None, None)
             self._batch.flush()
@@ -2173,13 +2193,23 @@ class WorkerLoop:
             status = "error"
             err = TaskError(repr(e), traceback.format_exc(),
                             f"async.{spec.method_name}")
+            began = time.perf_counter_ns()
             self._actor_reply(spec, None, err)
+            began = reply_row.since(began)
         finally:
+            # from the reply's own end stamp
             self._finish_task_telemetry(spec, exec_span, t0, status)
+            telemetry_row.since(began)
 
     def _ensure_async_loop(self):
         if self._async_loop is None:
             import asyncio  # noqa: PLC0415
+            from ..observability.profiler import (  # noqa: PLC0415
+                process_table)
+            self._call_rows = tuple(
+                process_table().tally(name) for name in (
+                    "actor.call.resolve", "actor.call.reply",
+                    "actor.call.telemetry"))
             self._async_loop = asyncio.new_event_loop()
             t = threading.Thread(target=self._async_loop.run_forever,
                                  daemon=True, name="actor-asyncio")

@@ -12,8 +12,12 @@ its thread's CPU clock, to an in-memory table and opens a
 capture the program's phases lie in the same `.xplane.pb`, on the same
 clock, as the device's operations. What holds a thread up from outside
 its spans is read beside the table: the collector's pauses
-(`SpanTable.watch_gc`) and the CPU clocks of the threads that share the
-interpreter lock (`thread_clocks`).
+(`SpanTable.watch_gc`), the CPU clocks of the threads that share the
+interpreter lock (`thread_clocks`), and how long a thread that lets the
+lock go waits to run again (`SpanTable.lock_probe`). The layers under
+the engine's consumers (the worker's actor loop, the replica's streaming
+calls) add to one table of the process, `process_table()`, which never
+imports jax.
 """
 from __future__ import annotations
 
@@ -27,11 +31,38 @@ from typing import Dict, List, Optional
 _active_dir: Optional[str] = None
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# resolved by the first SpanTable (spans are made where jax already is)
+# resolved by the first span or timed call (`_annotations`): a table
+# that is only added to never imports jax
 _annotation = _step_annotation = None
 _open = threading.local()       # .span: the innermost open span, per thread
 # the rows `SpanTable.watch_gc` keeps: every collection, the full ones
 GC_SPANS = ("gc.pause", "gc.pause.full")
+# the rows `SpanTable.lock_probe("lock.reacquire")` keeps: every probe;
+# the probes in which another thread took the lock (a wait over
+# _LOCK_LOST_NS: alone the thread is back in 0.4 us), whose mean is what
+# a release costs ONCE IT IS LOST (a probe's release is over in 0.1 us,
+# sooner than a parked rival wakes, so it is lost more rarely than a
+# long release is: the count says little, the mean a lot); and the
+# probes that waited longer than _LOCK_LONG_NS (a count: a row has no
+# percentile, and its maximum is over the process's life)
+LOCK_SPANS = ("lock.reacquire", "lock.reacquire.lost",
+              "lock.reacquire.long")
+_LOCK_LOST_NS = 10_000
+_LOCK_LONG_NS = 1_000_000
+# the rows of `process_table()`, seeded so that a reader finds each
+# whether or not its layer ran: an async actor call's entry up to its
+# first await, its reply, its telemetry (core/worker.py); the
+# synchronous parts of one `stream_next` call and of one buffered chunk
+# (serve/replica.py); one hand-over filed on the consumers' loop
+# (serve/llm/engine.py:_LoopSink.deliver)
+PROCESS_SPANS = ("actor.call.resolve", "actor.call.reply",
+                 "actor.call.telemetry", "replica.stream_next",
+                 "replica.stream_put", "consumer.deliver")
+_process_table: Optional["SpanTable"] = None
+_module_lock = threading.Lock()     # what this module makes on first use
+# () -> CLOCK_MONOTONIC ns, read with the interpreter lock released;
+# None where ctypes or the symbol is missing; resolved by the first probe
+_released_clock = _UNRESOLVED = object()
 
 
 def _on_duration(event: str, seconds: float, **_kw) -> None:
@@ -41,6 +72,50 @@ def _on_duration(event: str, seconds: float, **_kw) -> None:
     span = getattr(_open, "span", None)
     if span is not None and event == _COMPILE_EVENT:
         span.table.add_compile(span.name, int(seconds * 1e9))
+
+
+def _annotations():
+    """`jax.profiler.TraceAnnotation`, resolved once, with the process's
+    compile listener registered beside it."""
+    global _annotation, _step_annotation
+    with _module_lock:
+        if _annotation is None:
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _step_annotation = jax.profiler.StepTraceAnnotation
+            _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _resolve_released_clock():
+    """libc's `clock_gettime(CLOCK_MONOTONIC)` through `ctypes.CDLL`,
+    which drops the interpreter lock around the foreign call: the clock
+    `time.perf_counter_ns()` reads, read while another thread may run.
+    None where any piece is missing (no ctypes, no symbol, another
+    clock behind `perf_counter`): the probe then records nothing."""
+    try:
+        import ctypes
+        if "CLOCK_MONOTONIC" not in time.get_clock_info(
+                "perf_counter").implementation:
+            return None
+        clock_id = time.CLOCK_MONOTONIC
+
+        class timespec(ctypes.Structure):
+            _fields_ = [("tv_sec", ctypes.c_long),
+                        ("tv_nsec", ctypes.c_long)]
+        gettime = ctypes.CDLL(None).clock_gettime
+        gettime.argtypes = [ctypes.c_int, ctypes.POINTER(timespec)]
+        gettime.restype = ctypes.c_int
+    except (ImportError, OSError, AttributeError, ValueError):
+        return None
+    byref = ctypes.byref
+
+    def read() -> int:
+        ts = timespec()         # the caller's own: any thread may probe
+        gettime(clock_id, byref(ts))
+        return ts.tv_sec * 1_000_000_000 + ts.tv_nsec
+    return read
 
 
 class Span:
@@ -88,6 +163,35 @@ class _Row:
         self.n = self.total_ns = self.max_ns = self.cpu_ns = 0
 
 
+class _Tally:
+    """One thread's additions to one row, in plain integers: made by
+    `SpanTable.tally` for a row that one event loop writes thousands of
+    times a second (a locked `add` costs three times as much), read by
+    `snapshot()`. Wall time only."""
+    __slots__ = ("n", "total_ns", "max_ns")
+
+    def __init__(self):
+        self.n = self.total_ns = self.max_ns = 0
+
+    def add(self, ns: int) -> None:
+        self.n += 1
+        self.total_ns += ns
+        if ns > self.max_ns:
+            self.max_ns = ns
+
+    def since(self, t0: int) -> int:
+        """Add the time from the clock reading `t0` to now; returns
+        now, the reading that opens the neighbouring segment. (`add`
+        written out: a nested call is a third of the whole cost.)"""
+        now = time.perf_counter_ns()
+        ns = now - t0
+        self.n += 1
+        self.total_ns += ns
+        if ns > self.max_ns:
+            self.max_ns = ns
+        return now
+
+
 class SpanTable:
     """`{name: [n, total_ns, max_ns, cpu_ns]}`, owned by whoever makes it.
 
@@ -100,31 +204,30 @@ class SpanTable:
     interpreter lock. The attributes go to the annotation only.
     `add(name, ns, cpu_ns)` records an interval measured elsewhere (a
     request's stamps), with no annotation; `call(name, fn, ...)` is
-    `add` around one call. Any thread may add. Outside a capture an
-    annotation is inert; a span then costs its four clock reads and one
-    locked row update. The CPU clock is a system call (6 us a read on
-    the chip machine's host, where the wall clock costs 0.1): most of a
-    span's ~15 us there (docs/OBSERVABILITY.md).
+    `add` around one call, under an annotation of its own. Any thread
+    may add. Outside a capture an annotation is inert; a span then
+    costs its four clock reads and one locked row update. The CPU clock
+    is a system call (6 us a read on the chip machine's host, where the
+    wall clock costs 0.1): most of a span's ~15 us there
+    (docs/OBSERVABILITY.md). `add` and `tally` need no annotation, so a
+    table that only they touch never imports jax.
     """
 
     def __init__(self, names=()):
-        global _annotation, _step_annotation
-        if _annotation is None:
-            import jax
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_duration)
-            _step_annotation = jax.profiler.StepTraceAnnotation
-            _annotation = jax.profiler.TraceAnnotation
-        self._lock = threading.Lock()       # new rows, and the compiles
+        self._lock = threading.Lock()   # new rows, tallies, the compiles
         self._rows: Dict[str, _Row] = {n: _Row() for n in names}
+        self._tallies: List[tuple] = []              # (name, _Tally)
         self._compiles: Dict[str, List[int]] = {}    # name -> [n, ns]
         self._gc_began = None   # (wall, cpu) of the collection under way
 
     def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, _annotation(name, **attrs))
+        return Span(self, name, (_annotation or _annotations())(
+            name, **attrs))
 
     def step(self, name: str, step_num: int) -> Span:
         """A span the profiler also reads as one training step."""
+        if _step_annotation is None:
+            _annotations()
         return Span(self, name, _step_annotation(name, step_num=step_num))
 
     def add(self, name: str, ns: int, cpu_ns: int = 0) -> None:
@@ -139,17 +242,54 @@ class SpanTable:
             if ns > row.max_ns:
                 row.max_ns = ns
 
+    def tally(self, name: str) -> _Tally:
+        """A `_Tally` of `name` for ONE thread to add to; it stays in
+        the table for good (a row never counts backwards)."""
+        tally = _Tally()
+        with self._lock:
+            self._rows.setdefault(name, _Row())
+            self._tallies.append((name, tally))
+        return tally
+
     def call(self, name: str, fn, *args, **kw):
-        """`fn(*args, **kw)`, its wall and CPU time added to `name`:
-        no annotation, and no nesting (the span open around the call
-        keeps the interval in its own self time)."""
-        t0 = time.perf_counter_ns()
-        c0 = time.thread_time_ns()
-        try:
-            return fn(*args, **kw)
-        finally:
-            cpu = time.thread_time_ns() - c0
-            self.add(name, time.perf_counter_ns() - t0, cpu)
+        """`fn(*args, **kw)`, its wall and CPU time added to `name`,
+        under an annotation of that name (in a capture the call lies
+        inside the phase that made it, on the same thread's line) but
+        with no nesting: the span open around the call keeps the
+        interval in its own self time."""
+        with (_annotation or _annotations())(name):
+            t0 = time.perf_counter_ns()
+            c0 = time.thread_time_ns()
+            try:
+                return fn(*args, **kw)
+            finally:
+                cpu = time.thread_time_ns() - c0
+                self.add(name, time.perf_counter_ns() - t0, cpu)
+
+    def lock_probe(self, name: str) -> None:
+        """Let the interpreter lock go once and add to `name` how long
+        this thread then waited to run again: a clock read while the
+        lock is released (`_resolve_released_clock`), the same clock
+        read after it is taken back. That wait is what every call that
+        releases the lock pays when another thread wants it; a probe
+        pays it too, so the caller spaces its probes. A wait over
+        `_LOCK_LOST_NS` (another thread ran in between) is also added
+        to `name + ".lost"`, one over `_LOCK_LONG_NS` to `name +
+        ".long"` as well. Where the released read cannot be made,
+        nothing is added."""
+        global _released_clock
+        read = _released_clock
+        if read is _UNRESOLVED:
+            read = _released_clock = _resolve_released_clock()
+        if read is None:
+            return
+        released = read()
+        waited = time.perf_counter_ns() - released
+        self.add(name, waited)
+        if waited > _LOCK_LOST_NS:
+            self.add(name + ".lost", waited)
+            if waited > _LOCK_LONG_NS:
+                self.add(name + ".long", waited)
 
     def watch_gc(self) -> None:
         """From here to `unwatch_gc`, every collection of this process
@@ -191,6 +331,12 @@ class SpanTable:
         for name, row in list(self._rows.items()):
             with row.lock:
                 out[name] = [row.n, row.total_ns, row.max_ns, row.cpu_ns]
+        # unlocked: a tally's last addition may be seen half-made
+        for name, tally in list(self._tallies):
+            row = out.setdefault(name, [0, 0, 0, 0])
+            row[0] += tally.n
+            row[1] += tally.total_ns
+            row[2] = max(row[2], tally.max_ns)
         return out
 
     def compiles(self) -> Dict[str, List[int]]:
@@ -198,6 +344,18 @@ class SpanTable:
         the compiling thread."""
         with self._lock:
             return {k: list(v) for k, v in self._compiles.items()}
+
+
+def process_table() -> SpanTable:
+    """The one table of this process for the layers under the engine's
+    consumers (`PROCESS_SPANS`), made on first use; `LLMEngine.
+    get_stats()["spans"]` reports its rows beside the engine's own."""
+    global _process_table
+    if _process_table is None:
+        with _module_lock:
+            if _process_table is None:
+                _process_table = SpanTable(PROCESS_SPANS)
+    return _process_table
 
 
 def thread_clocks(**groups) -> Dict[str, int]:
@@ -303,5 +461,6 @@ def host_rss_bytes() -> int:
 
 
 __all__ = ["start_trace", "stop_trace", "trace", "Span", "SpanTable",
-           "GC_SPANS", "thread_clocks",
+           "GC_SPANS", "LOCK_SPANS", "PROCESS_SPANS", "process_table",
+           "thread_clocks",
            "device_memory_profile", "hbm_usage", "host_rss_bytes"]

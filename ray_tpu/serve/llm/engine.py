@@ -71,9 +71,15 @@ paged KV, streaming) — re-designed TPU-first:
   `engine.drain_wait`, the sleep in `engine.idle_sleep`, elsewhere the
   interpreter lock or a block inside the runtime). The `runtime.*` rows
   time the loop's runtime calls one by one, `step.release` the
-  destruction of the donated leaves after a step, `gc.pause*` the
-  collector, and get_stats()["threads"] reads the CPU clocks of the two
-  threads that share the lock: this one and the consumers' event loop.
+  destruction of the donated leaves after a step (each under an
+  annotation of its own, inside the phase's), `gc.pause*` the
+  collector, `lock.reacquire*` how long this thread waits to run again
+  after it lets the interpreter lock go (a probe every 8th step call),
+  and get_stats()["threads"] reads the CPU clocks of the two threads
+  that share the lock: this one and the consumers' event loop, whose
+  own rows (`actor.call.*`, `replica.*`, `consumer.deliver`: the
+  process's table, observability/profiler.py:process_table) are
+  reported beside these.
 """
 from __future__ import annotations
 
@@ -89,7 +95,8 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
-from ...observability.profiler import SpanTable, thread_clocks
+from ...observability.profiler import (LOCK_SPANS, SpanTable,
+                                        process_table, thread_clocks)
 from ...util import knobs
 
 # every phase of _engine_loop; seeded in the table so that a reader of
@@ -100,8 +107,7 @@ _LOOP_SPANS = ("engine.loop", "engine.control", "engine.admit",
                "engine.drain_wait", "engine.emit", "engine.deliver",
                "engine.bookkeep", "engine.idle_sleep")
 _REQUEST_SPANS = ("request.ingress", "request.inflight_prefill",
-                  "request.inflight_decode", "slot.refill",
-                  "slot.refill.starved", "stream.deliver")
+                  "slot.refill", "slot.refill.starved", "stream.deliver")
 # the loop's calls into the JAX runtime, timed one by one where
 # stats["runtime_calls"] counts them (_step, _start_fetch, _runtime):
 # rows added around the call, inside whichever phase makes it
@@ -111,6 +117,12 @@ _RUNTIME_SPANS = ("runtime.step", "runtime.fetch_start", "runtime.other")
 # were donated into it go. The runtime destroys each leaf then: most of
 # a dispatch phase's time on the chip (PERF.md section 6, PR 37)
 _STEP_SPANS = ("step.release",)
+# `_step` probes the interpreter lock (SpanTable.lock_probe, the rows
+# LOCK_SPANS) once in this many calls: a probe hands the lock away like
+# any release and may wait a whole switch interval (5 ms) to run again,
+# so the observer has to stay under a thousandth of the loop; ~6 probes
+# a second still give ~300 a 51 s window
+_LOCK_PROBE_EVERY = 8
 
 
 @dataclass
@@ -484,6 +496,7 @@ class _LoopSink:
     @staticmethod
     def deliver(batch) -> None:
         """On the loop: file one hand-over's (sink, item) pairs."""
+        t0 = time.perf_counter_ns()
         for sink, item in batch:
             if sink.closed:
                 continue
@@ -491,6 +504,8 @@ class _LoopSink:
             waiter = sink._waiter
             if waiter is not None and not waiter.done():
                 waiter.set_result(None)
+        process_table().add("consumer.deliver",
+                            time.perf_counter_ns() - t0)
 
     async def take(self):
         while not self._items:
@@ -639,6 +654,10 @@ class LLMEngine:
                       # _start_fetch, _runtime): 2 a dispatch when
                       # nothing eager is on the path
                       "runtime_calls": 0,
+                      # leaves of the carried pools and state that the
+                      # step calls let go (`_carry`), summed: over
+                      # spans["step.release"]'s n, the leaves a release
+                      "step_leaves_released": 0,
                       # the in-flight target in force at each decode
                       # dispatch (_InflightDepth), summed and counted
                       "decode_inflight_target_sum": 0,
@@ -665,8 +684,14 @@ class LLMEngine:
         for name in self._step_stats:
             self.stats[name] = 0
         self._spans = SpanTable(
-            _LOOP_SPANS + _REQUEST_SPANS + _RUNTIME_SPANS + _STEP_SPANS)
+            _LOOP_SPANS + _REQUEST_SPANS + _RUNTIME_SPANS + _STEP_SPANS
+            + LOCK_SPANS)
         self._spans.watch_gc()      # until shutdown()
+        self._step_calls = 0        # of `_step`: which of them probe
+        # counted here, once: every step program returns pools and
+        # state of the structure it was handed
+        self._carried_leaves = len(jax.tree_util.tree_leaves(
+            (self._pools, self._state)))
         # the threads astream_detailed was called on (the replica's
         # actor loop): get_stats()["threads"]["consumers"]
         self._consumer_threads: set = set()
@@ -1653,7 +1678,10 @@ class LLMEngine:
             out["ttft_breakdown_p50_ms"] = {
                 k: v for k, v in medians.items() if v is not None}
         out["prefill_compile_ms"] = dict(self._prefill_compile_ms)
-        out["spans"] = self._spans.snapshot()
+        # the process's rows (the actor loop under this engine's
+        # consumers) beside the engine's own: the prefixes differ
+        out["spans"] = {**process_table().snapshot(),
+                        **self._spans.snapshot()}
         out["threads"] = thread_clocks(engine=[self._loop_thread],
                                        consumers=self._consumer_threads)
         compiles = self._spans.compiles()
@@ -2051,6 +2079,12 @@ class LLMEngine:
         fetch, logps, pools, state, *rest = self._spans.call(
             "runtime.step", program, self.params, self._pools,
             self._state, ctl, *args, **kw)
+        # between the call and the release: where the releases that
+        # the probe stands for happen
+        self._step_calls += 1
+        if self._step_calls % _LOCK_PROBE_EVERY == 0:
+            self._spans.lock_probe(LOCK_SPANS[0])
+        self.stats["step_leaves_released"] += self._carried_leaves
         self._spans.call("step.release", self._carry, pools, state)
         return fetch, logps, rest
 
@@ -2604,8 +2638,6 @@ class LLMEngine:
         lp_rows = None
         if lps is not None:
             lp_rows = lps if lps.ndim == 2 else lps[None, :]
-        spans.add("request.inflight_decode",
-                  time.perf_counter_ns() - dispatched_ns)
         st["decode_steps"] += rows.shape[0]
         st["decode_steps_drawn"] += rows.shape[0] * drawn
         st["decode_slot_steps"] += rows.shape[0] * self.cfg.max_slots

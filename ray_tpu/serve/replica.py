@@ -20,6 +20,7 @@ import time
 from typing import Any, Dict, Optional
 
 from ..exceptions import DeadlineExceededError, ReplicaDrainingError
+from ..observability.profiler import process_table
 
 _STREAM_END = "__ray_tpu_stream_end__"
 
@@ -108,6 +109,11 @@ class Replica:
         # the handler's next chunk or on a full buffer), instead of
         # leaking the buffer and a permanently-elevated _ongoing count
         self._drains: Dict[str, asyncio.Task] = {}
+        # the streaming calls' own time on the actor's event loop
+        # (wall, this loop's thread alone adds): both synchronous parts
+        # of a `stream_next` call, once a call; a chunk's `buf.put`
+        self._next_row = process_table().tally("replica.stream_next")
+        self._put_row = process_table().tally("replica.stream_put")
 
         target = serialization.loads_call(callable_bytes)
         if inspect.isclass(target):
@@ -327,6 +333,12 @@ class Replica:
             _set_request_stamps(*stamps)
             return next(it, _STREAM_END)
 
+        async def put_chunk(chunk):
+            # a put parks only on a full buffer (1 024 chunks behind)
+            t0 = time.perf_counter_ns()
+            await buf.put(("chunk", chunk))
+            self._put_row.since(t0)
+
         async def _drain():
             try:
                 result = method(*args, **kwargs)
@@ -335,7 +347,7 @@ class Replica:
                 if inspect.isasyncgen(result):
                     try:
                         async for chunk in result:
-                            await buf.put(("chunk", chunk))
+                            await put_chunk(chunk)
                     finally:
                         # a cancelled stream closes its generator NOW:
                         # an LLM stream aborts its engine request there
@@ -348,9 +360,9 @@ class Replica:
                             None, _next_with_ctx, it)
                         if chunk == _STREAM_END:
                             break
-                        await buf.put(("chunk", chunk))
+                        await put_chunk(chunk)
                 else:  # unary result streamed as a single chunk
-                    await buf.put(("chunk", result))
+                    await put_chunk(result)
                 await buf.put(("end", None))
             except asyncio.CancelledError:
                 raise              # consumer gone: just stop pumping
@@ -381,25 +393,32 @@ class Replica:
         """Pull up to `batch` buffered chunks, waiting up to timeout_s
         for the first. Returns (chunks, done). Raises the handler's
         exception if the stream errored."""
-        buf = self._streams.get(stream_id)
-        if buf is None:
-            return [], True
-        from ..util import waits as waits_mod  # noqa: PLC0415
-        wtok = waits_mod.park("serve-stream", stream_id,
-                              pending=len(buf.items))
+        t0 = time.perf_counter_ns()
+        waited = 0      # inside `buf.wait`: not this call's own time
         try:
-            if not await buf.wait(timeout_s):
-                return [], False
+            buf = self._streams.get(stream_id)
+            if buf is None:
+                return [], True
+            from ..util import waits as waits_mod  # noqa: PLC0415
+            wtok = waits_mod.park("serve-stream", stream_id,
+                                  pending=len(buf.items))
+            t1 = time.perf_counter_ns()
+            try:
+                if not await buf.wait(timeout_s):
+                    return [], False
+            finally:
+                waited = time.perf_counter_ns() - t1
+                waits_mod.unpark(wtok)
+            chunks = []
+            while buf.items and len(chunks) < batch:
+                kind, payload = buf.pop()
+                if kind == "chunk":
+                    chunks.append(payload)
+                    continue
+                self._streams.pop(stream_id, None)
+                if kind == "error":
+                    raise payload
+                return chunks, True     # end
+            return chunks, False
         finally:
-            waits_mod.unpark(wtok)
-        chunks = []
-        while buf.items and len(chunks) < batch:
-            kind, payload = buf.pop()
-            if kind == "chunk":
-                chunks.append(payload)
-                continue
-            self._streams.pop(stream_id, None)
-            if kind == "error":
-                raise payload
-            return chunks, True     # end
-        return chunks, False
+            self._next_row.add(time.perf_counter_ns() - t0 - waited)

@@ -162,7 +162,8 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
     import jax
     from benchmarks import run as runmod
     from ray_tpu.models import Llama, LlamaConfig
-    from ray_tpu.observability.profiler import GC_SPANS
+    from ray_tpu.observability.profiler import (GC_SPANS, LOCK_SPANS,
+                                                PROCESS_SPANS)
     # counters a model with residual streams leaves behind its
     # expert layers' (the engine seeds them from `model.step_stats`)
     from ray_tpu.ops.hyper_connections import HC_STATS
@@ -173,7 +174,8 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
     import stats_delta
     stats_run = globals()["RUN"]
     seeded = set(engine._LOOP_SPANS + engine._REQUEST_SPANS
-                 + engine._RUNTIME_SPANS + engine._STEP_SPANS + GC_SPANS)
+                 + engine._RUNTIME_SPANS + engine._STEP_SPANS + GC_SPANS
+                 + LOCK_SPANS + PROCESS_SPANS)
     model = Llama(LlamaConfig(vocab_size=64, d_model=16, n_layers=1,
                               n_heads=2, n_kv_heads=1, d_ff=32,
                               max_seq_len=64, remat=False))
@@ -213,7 +215,11 @@ def test_every_metric_file_of_the_reader_names_paths_the_engine_seeds():  # noqa
                         name, path)
         got = runmod.read_metric(_BENCH, name[:-5], stats_run)
         assert got is None or isinstance(got, float), name
-    assert len(found) >= 21 and set(found) <= listed
+        if name[:-5] in _NEW_IN_PR_54:
+            assert isinstance(runmod.read_metric(
+                _BENCH, name[:-5], _lock_and_loop_window()), float), name
+    assert len(found) >= 21 + len(_NEW_IN_PR_54) and set(found) <= listed
+    assert set(_NEW_IN_PR_54) <= set(found)
     assert {"decode_live_state_share", "hc_unconverged_share",
             "hc_clamped_share"} <= set(found)
 
@@ -295,6 +301,97 @@ def test_a_metric_of_the_engine_threads_time_reads_its_rows(name):
         == set(_NEW_IN_PR_37)
 
 
+# PR 54's, at the end of `per_layer`: name -> (what `_lock_and_loop_
+# window` reads, unit, better, source, the layer's first words)
+_NEW_IN_PR_54 = {
+    "engine_lock_reacquire_us": (
+        40.0, "us", "lower", "program_span", "engine host loop"),
+    "engine_lock_long_wait_share": (
+        20.0, "%", "lower", "program_span", "engine host loop"),
+    "engine_release_still_share": (
+        100.0 * 5 / 7, "%", "lower", "program_span", "engine host loop"),
+    "engine_leaves_released_per_call": (
+        35.0, "leaves", "lower", "program_counter", "engine host loop"),
+    "stream_replies_per_token": (
+        1.2, "replies", "lower", "program_counter", "service"),
+    "consumer_cpu_us_per_reply": (
+        100.0, "us", "lower", "program_counter", "service"),
+    "consumer_telemetry_share": (
+        10.0, "%", "lower", "program_span", "service"),
+    "consumer_reply_share": (20.0, "%", "lower", "program_span", "service"),
+    "consumer_named_share": (51.0, "%", "higher", "program_span", "service"),
+    "deliver_items_per_batch": (
+        51.0, "items", "higher", "program_counter", "service")}
+
+
+def _lock_and_loop_window(with_rows=True):
+    """Two readings of `get_stats()` 1 000 step calls apart: a probe
+    every 8th call that waited 40 us, one in five over 1 ms; 7 ms to
+    release 35 leaves, 2 of them on the CPU; 50 tokens a step in 60
+    `stream_next` replies and one hand-over of 51 items; the consumers'
+    loop 6 ms of CPU a step, of which the reply 1.2, the telemetry and
+    `stream_next` 0.6 each, resolve and the chunks' puts 0.3 each, the
+    hand-over 0.06. A parent's window has the release's row, the
+    hand-over's counters and the clocks, and nothing else of these."""
+    def reading(k):
+        us = 1_000
+        out = {"tokens_generated": 50 * k, "deliver_items": 51 * k,
+               "deliver_batches": k,
+               "spans": {"step.release": [k, 7_000 * us * k, 0,
+                                          2_000 * us * k]},
+               "threads": {"consumers": 6_000 * us * k,
+                           "wall_ns": 10_000 * us * k}}
+        if with_rows:
+            out["step_leaves_released"] = 35 * k
+            out["spans"].update({
+                "lock.reacquire": [k // 8, k // 8 * 40 * us, 0, 0],
+                "lock.reacquire.lost": [k // 16, k // 16 * 70 * us, 0, 0],
+                "lock.reacquire.long": [k // 40, k // 40 * 3_000 * us,
+                                        0, 0],
+                "actor.call.resolve": [60 * k, 300 * us * k, 0, 0],
+                "actor.call.reply": [60 * k, 1_200 * us * k, 0, 0],
+                "actor.call.telemetry": [60 * k, 600 * us * k, 0, 0],
+                "replica.stream_next": [60 * k, 600 * us * k, 0, 0],
+                "replica.stream_put": [52 * k, 300 * us * k, 0, 0],
+                "consumer.deliver": [k, 60 * us * k, 0, 0]})
+        return out
+    return {"stats0": reading(1_000), "stats1": reading(2_000)}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_54))
+def test_a_metric_of_the_lock_or_the_actor_loop_reads_its_rows(name):
+    """Each file new in PR 54: its number from a window of known rows,
+    nothing from a parent that has no such row or counter (but for the
+    two that read what the parent already reports), its entry at the
+    end of the manifest as the issue gave it."""
+    from benchmarks import run as runmod
+    reads, unit, better, source, layer = _NEW_IN_PR_54[name]
+    assert runmod.read_metric(_BENCH, name, _lock_and_loop_window()) \
+        == pytest.approx(reads)
+    on_parent = runmod.read_metric(_BENCH, name,
+                                   _lock_and_loop_window(False))
+    if name in ("engine_release_still_share", "deliver_items_per_batch"):
+        assert on_parent == pytest.approx(reads)
+    else:
+        assert on_parent is None
+    assert runmod.read_metric(_BENCH, name, {}) is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    serve = [w["name"] for w in whole["workloads"]
+             if _runner_of(whole, w["name"]).startswith("serve_http")]
+    assert len(serve) == 8 and entry["workloads"] == serve
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert (entry["unit"], entry["better"], entry["source"],
+            entry["moves"]) == (unit, better, source, "out_tok_s")
+    assert entry["layer"].startswith(layer)
+    accepted = {m["layer"] for m in whole["per_layer"]
+                if m["name"] not in _NEW_IN_PR_54}
+    assert entry["layer"] in accepted       # letter for letter
+    assert {m["name"] for m in whole["per_layer"][-len(_NEW_IN_PR_54):]} \
+        == set(_NEW_IN_PR_54)
+
+
 _NEW_IN_PR_40 = {"expert_matmul_roofline.lfm2moe": "experts",
                  "paged_kernel_roofline.packed": "paged_kernel",
                  "decode_step_roofline.lfm2moe": "step"}
@@ -342,8 +439,8 @@ def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
     assert whole["workloads"][6]["name"] == _LFM2_CELL
     assert whole["configs"][5]["name"] == whole["workloads"][6]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) \
-        - len(_NEW_IN_PR_48) - 3
+    at = len(names) - len(_NEW_IN_PR_54) - len(_NEW_IN_PR_53) \
+        - len(_NEW_IN_PR_52) - len(_NEW_IN_PR_48) - 3
     assert set(names[at:at + 3]) == set(_NEW_IN_PR_40)
 
 
@@ -499,7 +596,8 @@ def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
     assert whole["workloads"][7]["name"] == _XING_CELL
     assert whole["configs"][6]["name"] == whole["workloads"][7]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) - 7
+    at = len(names) - len(_NEW_IN_PR_54) - len(_NEW_IN_PR_53) \
+        - len(_NEW_IN_PR_52) - 7
     assert set(names[at:at + 7]) == set(_NEW_IN_PR_48)
 
 
@@ -819,7 +917,8 @@ def test_prefill_live_chunk_share_reads_the_engines_counters():
     does not."""
     from benchmarks import run as runmod
     whole = runmod.load_manifest()
-    entry = whole["per_layer"][-1]
+    # PR 54's go behind it
+    entry = whole["per_layer"][-1 - len(_NEW_IN_PR_54)]
     assert entry == {"name": "prefill_live_chunk_share", "unit": "%",
                      "better": "higher", "source": "program_counter",
                      "layer": "kernels (ops/pallas)", "moves": "out_tok_s",

@@ -352,6 +352,51 @@ def test_profiler_trace_and_timing(tmp_path):
     assert table.snapshot()["demo-step"][0] == 1
 
 
+def test_an_async_actors_calls_are_rows_of_its_process_by_segment(rt):
+    """core/worker.py adds each async actor call's own time on the
+    actor's event loop to the process's table: entry to the first
+    await, the reply, the telemetry; the awaited time is in none."""
+    @ray_tpu.remote(max_concurrency=4)
+    class Napper:
+        async def nap(self, seconds):
+            import asyncio
+            await asyncio.sleep(seconds)
+            return seconds
+
+        async def fail(self):
+            raise ValueError("no")
+
+        async def rows(self):
+            import sys
+            from ray_tpu.observability.profiler import process_table
+            return process_table().snapshot(), "jax" in sys.modules
+
+        def rows_sync(self):
+            from ray_tpu.observability.profiler import process_table
+            return process_table().snapshot()
+
+    a = Napper.remote()
+    first, jax_loaded = ray_tpu.get(a.rows.remote(), timeout=60)
+    assert not jax_loaded       # the table asked for no annotation
+    # the call that reads the table has resolved; its reply is to come
+    assert first["actor.call.resolve"][0] == 1
+    assert first["actor.call.reply"][0] == 0
+    assert ray_tpu.get([a.nap.remote(0.2) for _ in range(3)],
+                       timeout=60) == [0.2] * 3
+    with pytest.raises(Exception, match="no"):
+        ray_tpu.get(a.fail.remote(), timeout=60)
+    # a synchronous method runs on a pool thread, not on the loop
+    rows = ray_tpu.get(a.rows_sync.remote(), timeout=60)
+    for name in ("actor.call.resolve", "actor.call.reply",
+                 "actor.call.telemetry"):
+        n, total, longest, cpu = rows[name]
+        # rows(), three naps, the failure (an error is a reply too)
+        assert n == 5 and cpu == 0 and 0 < longest <= total, name
+        # 0.6 s were slept: no segment holds an awaited interval
+        assert total < 100_000_000, (name, total)
+    assert rows["replica.stream_next"] == [0, 0, 0, 0]
+
+
 def test_cli_serve_run(tmp_path):
     """`ray_tpu serve run module:app` serves over real HTTP."""
     import json as _json

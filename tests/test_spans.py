@@ -17,7 +17,9 @@ import urllib.request
 import numpy as np
 import pytest
 
-from ray_tpu.observability.profiler import (GC_SPANS, SpanTable,
+from ray_tpu.observability import profiler
+from ray_tpu.observability.profiler import (GC_SPANS, LOCK_SPANS,
+                                            PROCESS_SPANS, SpanTable,
                                             thread_clocks)
 
 PROMPT_LENS = (10, 12, 9, 11)       # one bucket of 16, one group of 4
@@ -93,6 +95,154 @@ def test_call_times_one_call_without_nesting():
     assert n == 2 and 10_000_000 <= cpu <= total
     # nothing was taken off the span around the calls
     assert rows["phase"][3] >= cpu and rows["phase"][1] >= total
+
+
+def test_call_opens_an_annotation_of_its_rows_name(monkeypatch):
+    seen = []
+
+    class Spy:
+        def __init__(self, name, **attrs):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+    t = SpanTable()
+    t.call("warm", int)         # the real annotation is resolved
+    monkeypatch.setattr(profiler, "_annotation", Spy)
+    assert t.call("runtime.step", seen.append, "ran") is None
+    with pytest.raises(ZeroDivisionError):
+        t.call("step.release", lambda: 1 / 0)
+    assert seen == [("enter", "runtime.step"), "ran",
+                    ("exit", "runtime.step"), ("enter", "step.release"),
+                    ("exit", "step.release")]
+    t.add("stamped", 5)         # an `add` opens none
+    assert len(seen) == 5
+
+
+def _probe_mean_ns(rivals, probes):
+    """Mean wait of `probes` probes with some Python between them,
+    beside `rivals` threads that spin on the interpreter lock."""
+    t = SpanTable(LOCK_SPANS)
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            pass
+    threads = [threading.Thread(target=spin) for _ in range(rivals)]
+    for th in threads:
+        th.start()
+    try:
+        for _ in range(probes):
+            for _ in range(2000):
+                pass
+            t.lock_probe("lock.reacquire")
+    finally:
+        stop.set()
+        for th in threads:
+            th.join(timeout=30)
+            assert not th.is_alive()
+    n, total, longest, cpu = t.snapshot()["lock.reacquire"]
+    assert n == probes and cpu == 0 and longest <= total
+    return total / n
+
+
+def test_the_lock_probe_waits_beside_a_rival_and_hardly_without():
+    if profiler._resolve_released_clock() is None:
+        pytest.skip("no clock can be read with the lock released here")
+    quiet = _probe_mean_ns(0, 2000)
+    # measured 0.6 us alone, 90-150 us beside two spinning threads (a
+    # wait is now and then a whole switch interval of 5 ms)
+    assert quiet < 50_000
+    contended = _probe_mean_ns(2, 300)
+    assert contended > 4 * quiet and contended > 5_000
+
+
+@pytest.mark.parametrize("fault", [OSError("no libc"), "no symbol",
+                                   "another clock"])
+def test_a_probe_that_cannot_read_the_clock_adds_nothing(monkeypatch,
+                                                         fault):
+    import ctypes
+
+    class NoSymbol:
+        def __getattr__(self, name):
+            raise AttributeError(name)
+
+    def cdll(*_a, **_kw):
+        if isinstance(fault, Exception):
+            raise fault
+        return NoSymbol()
+    if fault == "another clock":
+        monkeypatch.setattr(time, "get_clock_info", lambda _name: type(
+            "info", (), {"implementation": "QueryPerformanceCounter()"}))
+    else:
+        monkeypatch.setattr(ctypes, "CDLL", cdll)
+    monkeypatch.setattr(profiler, "_released_clock", profiler._UNRESOLVED)
+    t = SpanTable(LOCK_SPANS)
+    for _ in range(3):
+        t.lock_probe("lock.reacquire")
+    assert profiler._released_clock is None     # resolved once, to nothing
+    assert t.snapshot() == {name: [0, 0, 0, 0] for name in LOCK_SPANS}
+
+
+def test_long_waits_are_counted_beside_every_probe(monkeypatch):
+    waits = iter([8_000, 900_000, 1_500_000, 5_000_000, 400])
+    # the released reading lies this long before the one that follows
+    monkeypatch.setattr(
+        profiler, "_released_clock",
+        lambda: time.perf_counter_ns() - next(waits))
+    t = SpanTable(LOCK_SPANS)
+    for _ in range(5):
+        t.lock_probe("lock.reacquire")
+    rows = t.snapshot()
+    assert rows["lock.reacquire"][0] == 5
+    # over 10 us: another thread ran in between
+    assert rows["lock.reacquire.lost"][0] == 3
+    assert rows["lock.reacquire.lost"][1] >= 900_000 + 6_500_000
+    assert rows["lock.reacquire.long"][0] == 2      # over 1 ms alone
+    assert 6_500_000 <= rows["lock.reacquire.long"][1] \
+        <= rows["lock.reacquire"][1] < 8_000_000
+    assert rows["lock.reacquire"][2] >= 5_000_000
+
+
+def test_a_tally_is_one_threads_plain_share_of_its_row():
+    t = SpanTable()
+    t.add("row", 4, 1)
+    mine, other = t.tally("row"), t.tally("row")
+    mine.add(10)
+    began = time.perf_counter_ns()
+    closed = other.since(began)
+    assert closed >= began
+    n, total, longest, cpu = t.snapshot()["row"]
+    assert (n, cpu) == (3, 1)
+    assert total == 14 + (closed - began)
+    assert longest == max(10, closed - began)
+    assert t.tally("unseeded").n == 0 and t.snapshot()["unseeded"][0] == 0
+
+
+def test_the_process_table_is_made_and_added_to_without_jax():
+    import subprocess
+    code = (
+        "import sys\n"
+        "from ray_tpu.observability import profiler\n"
+        "t = profiler.process_table()\n"
+        "assert t is profiler.process_table()\n"
+        "assert set(t.snapshot()) == set(profiler.PROCESS_SPANS)\n"
+        "t.add('actor.call.reply', 7)\n"
+        "t.tally('replica.stream_next').add(9)\n"
+        "t.lock_probe('lock.reacquire')\n"
+        "rows = t.snapshot()\n"
+        "assert rows['actor.call.reply'] == [1, 7, 7, 0], rows\n"
+        "assert rows['replica.stream_next'] == [1, 9, 9, 0], rows\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "t.call('timed', int)    # the first annotation brings jax\n"
+        "assert 'jax' in sys.modules\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], timeout=120,
+                         capture_output=True, text=True)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
 
 
 def test_a_collection_shows_in_the_gc_rows_until_unwatched():
@@ -293,8 +443,7 @@ def drained(tiny_llm, tmp_path_factory):
     try:
         eng._run_on_loop(submit_all)
         outs = [list(eng.stream_detailed(rid)) for rid in rids]
-        while eng.get_stats()["spans"]["request.inflight_decode"][0] \
-                < eng._decode_dispatches:
+        while eng.get_stats()["decode_steps"] < eng._decode_dispatches:
             time.sleep(0.01)        # the lagged steps drain
     finally:
         jax.profiler.stop_trace()
@@ -305,6 +454,8 @@ def drained(tiny_llm, tmp_path_factory):
     lived_ns = time.perf_counter_ns() - started
     return {"stats": stats, "spans": eng._spans.snapshot(), "outs": outs,
             "lived_ns": lived_ns, "trace_dir": trace_dir,
+            "carried_leaves": len(jax.tree_util.tree_leaves(
+                (eng._pools, eng._state))),
             "dispatches": eng._decode_dispatches,
             "metric": eng._m["tokens"].get(tags=eng._mtags)}
 
@@ -356,7 +507,8 @@ def test_discarded_tokens_are_the_slot_steps_not_emitted(drained):
 def test_request_stamps_count_requests_steps_and_tokens(drained):
     spans, st = drained["spans"], drained["stats"]
     assert spans["request.inflight_prefill"][0] == len(PROMPT_LENS)
-    assert spans["request.inflight_decode"][0] == st["decode_steps"]
+    # every dispatched decode program was drained, each counted once
+    assert st["decode_steps"] == drained["dispatches"] > 0
     assert spans["stream.deliver"][0] == sum(
         len(o) for o in drained["outs"])
     assert spans["slot.refill"][0] == 0         # no slot was used twice
@@ -413,6 +565,34 @@ def test_runtime_rows_count_the_loops_runtime_calls(drained):
     assert spans["engine.decode_dispatch"][3] > 0
 
 
+def test_the_engine_probes_every_eighth_step_call_and_counts_leaves(
+        drained, tiny_llm):
+    from ray_tpu.serve.llm.engine import _LOCK_PROBE_EVERY
+    spans, st = drained["spans"], drained["stats"]
+    calls = spans["runtime.step"][0]
+    assert _LOCK_PROBE_EVERY == 8 and calls >= 8
+    assert spans["lock.reacquire"][0] == calls // 8
+    assert spans["lock.reacquire.long"][0] \
+        <= spans["lock.reacquire.lost"][0] <= spans["lock.reacquire"][0]
+    # two layers' K and V pools, lengths, last tokens, the key
+    assert drained["carried_leaves"] == 2 * 2 + 3
+    assert st["step_leaves_released"] \
+        == spans["step.release"][0] * drained["carried_leaves"]
+    # 24 more step calls: three more probes, wherever the count stood
+    eng = _engine(tiny_llm)
+    try:
+        for _ in eng.stream(eng.submit(np.arange(1, 9),
+                                       max_new_tokens=24)):
+            pass
+        while eng.get_stats()["decode_steps"] < eng._decode_dispatches:
+            time.sleep(0.01)
+        rows = eng.get_stats()["spans"]
+        assert rows["lock.reacquire"][0] == rows["runtime.step"][0] // 8 \
+            >= 3
+    finally:
+        eng.shutdown()
+
+
 def test_a_fresh_engine_seeds_every_row_and_its_threads(tiny_llm):
     from ray_tpu.serve.llm.engine import (_LOOP_SPANS, _REQUEST_SPANS,
                                           _RUNTIME_SPANS, _STEP_SPANS)
@@ -421,7 +601,8 @@ def test_a_fresh_engine_seeds_every_row_and_its_threads(tiny_llm):
         st = eng.get_stats()
         assert set(st["spans"]) >= set(
             _LOOP_SPANS + _REQUEST_SPANS + _RUNTIME_SPANS + _STEP_SPANS
-            + GC_SPANS)
+            + GC_SPANS + LOCK_SPANS + PROCESS_SPANS)
+        assert "request.inflight_decode" not in st["spans"]
         assert {"runtime.step", "runtime.fetch_start", "runtime.other",
                 "step.release", "gc.pause", "gc.pause.full",
                 "slot.refill.starved"} <= set(st["spans"])
@@ -494,9 +675,38 @@ def test_capture_holds_decode_dispatch_events_with_their_step(drained):
     prefill = [s for name, s in events if name == "engine.prefill_dispatch"]
     assert prefill == [{"bucket": 16, "group": 4, "group_padded": 4}]
     # only the engine thread annotates: no per-request event can take
-    # a device idle gap from the loop's phases
-    assert not _host_events(drained["trace_dir"], "request.")
-    assert not _host_events(drained["trace_dir"], "stream.")
+    # a device idle gap from the loop's phases, nor one of the
+    # consumers' loop
+    for prefix in ("request.", "stream.", "lock.", "actor.", "replica.",
+                   "consumer."):
+        assert not _host_events(drained["trace_dir"], prefix), prefix
+
+
+def test_capture_holds_the_runtime_calls_inside_their_dispatch(drained):
+    """`SpanTable.call` annotates: each step call, release and fetch
+    start is an event of its row's name on the engine thread's line,
+    inside the dispatch phase that made it."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(
+        f"{drained['trace_dir']}/plugins/profile/*/*.xplane.pb"))[-1]
+    named = ("runtime.step", "step.release", "runtime.fetch_start")
+    counted = dict.fromkeys(named, 0)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                      for ev in line.events]
+            phases = [ev for ev in events if ev[0] in (
+                "engine.prefill_dispatch", "engine.decode_dispatch")]
+            for name, start, end in events:
+                if name in named:
+                    counted[name] += 1
+                    assert any(p[1] <= start and end <= p[2]
+                               for p in phases), (name, start)
+    spans = drained["spans"]
+    assert counted == {name: spans[name][0] for name in named}
+    assert counted["step.release"] == drained["dispatches"] + 1
 
 
 def test_slot_refill_counts_each_reuse_of_a_slot(tiny_llm):

@@ -408,6 +408,51 @@ def test_forty_streams_through_a_two_slot_replica_park_no_thread(
     assert rep._streams == {} and rep._drains == {}
 
 
+def test_the_actor_loops_rows_count_replies_chunks_and_hand_overs(
+        llm_replica):
+    """Forty streams again, read through the rows of the process's
+    table that `get_stats()["spans"]` reports beside the engine's own:
+    one `replica.stream_next` a call made, one `replica.stream_put` a
+    chunk buffered, one `consumer.deliver` a hand-over that carried
+    something."""
+    from ray_tpu.observability.profiler import PROCESS_SPANS
+    rep, eng = llm_replica, llm_replica._callable.engine
+    before = eng.get_stats()
+    assert set(PROCESS_SPANS) <= set(before["spans"])
+    calls = []
+
+    async def one(i):
+        body = {"prompt": list(range(1, 6 + i % 7)), "max_tokens": 12,
+                "temperature": 0.0, "stream": True}
+        sid = await rep.stream_start("__call__", (body,), {})
+        out = await _pull(rep, sid, lambda: calls.append(sid))
+        # a finished stream's id: answered at once, and counted
+        assert await rep.stream_next(sid) == ([], True)
+        calls.append(sid)
+        return out
+
+    async def main():
+        return await asyncio.gather(*(one(i) for i in range(40)))
+    started = time.perf_counter_ns()
+    outs = asyncio.run(main())
+    lived = time.perf_counter_ns() - started
+    assert all(len(out) == 12 + 2 for out in outs)
+    st = eng.get_stats()
+
+    def grew(name, col=0):
+        return st["spans"][name][col] - before["spans"][name][col]
+    assert grew("replica.stream_next") == len(calls) >= 40 * 2
+    assert grew("replica.stream_put") == 40 * (12 + 2)
+    assert grew("consumer.deliver") \
+        == st["deliver_batches"] - before["deliver_batches"] > 0
+    # wall time of one loop's thread, the parked polls left out: the
+    # rows together are less than the time the loop lived
+    own = sum(grew(name, 1) for name in PROCESS_SPANS)
+    assert 0 < own < lived
+    # nothing here went through an actor's call
+    assert grew("actor.call.reply") == 0
+
+
 def test_stream_cancel_in_mid_stream_frees_the_engine_slot(llm_replica):
     rep, eng = llm_replica, llm_replica._callable.engine
 
