@@ -65,7 +65,9 @@ What a layer caches it says itself (`paged_cache_spec`): a full layer
 pages K and V a token (heads narrower than 128 lanes packed side by
 side: ops/attention.py:packed_kv_shape), a linear layer keeps a state
 and the convolution's last K - 1 inputs a SLOT, a conv layer its last
-K - 1 inputs a slot (ops/attention.py:SlotState).
+K - 1 inputs a slot (ops/attention.py:SlotState), those inputs side by
+side in one row of (K - 1) x C lanes (ops/gated_deltanet.py:
+causal_conv says why they are not K - 1 rows).
 """
 from __future__ import annotations
 
@@ -304,17 +306,22 @@ def _delta_rule(mod, x, cache, u, gate, g, beta, scope: str, gate_fn):
         n_new = cache.n_new
         g, beta = gdn.freeze(
             g, beta, jnp.arange(s)[None, :] < n_new[:, None])
+    # a decode step is the one-token form on (B, H, .) arrays: an axis
+    # of one token in the tiled second-minor place costs a re-tiling of
+    # everything that carries it (ops/gated_deltanet.py:causal_conv)
+    one = cache is not None and s == 1
+    lead = (b,) if one else (b, s)
     with jax.named_scope(f"{scope}.conv"):
         qkv, tail = gdn.causal_conv(u, conv_w, tail, n_new)
-        q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
-        q = gdn.l2norm(q.reshape(b, s, h, dk)) * dk ** -0.5
-        k = gdn.l2norm(k.reshape(b, s, h, dk))
-        v = v.reshape(b, s, h, dv)
-    if cache is not None and s == 1:
+        q, k, v = jnp.split(qkv[:, 0] if one else qkv,
+                            [h * dk, 2 * h * dk], axis=-1)
+        q = gdn.l2norm(q.reshape(*lead, h, dk)) * dk ** -0.5
+        k = gdn.l2norm(k.reshape(*lead, h, dk))
+        v = v.reshape(*lead, h, dv)
+    if one:
         with jax.named_scope(f"{scope}.step"):
-            o, state = _step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                             beta[:, 0], state)
-            o = o[:, None]
+            o, state = _step(q, k, v, g[:, 0], beta[:, 0], state)
+            q, k, v, o = (x[:, None] for x in (q, k, v, o))
     else:
         with jax.named_scope(f"{scope}.scan"):
             o, state = _scan(q, k, v, g, beta, state, n_new,
@@ -412,8 +419,8 @@ class KimiDeltaAttention(nn.Module):
 class ShortConv(nn.Module):
     """The gated short convolution (module docstring). Its cache entry
     is a SlotState of one array, z at the sequence's last K - 1
-    positions, carried by the function GatedDeltaNet's convolution
-    uses."""
+    positions side by side in a row, carried by the function
+    GatedDeltaNet's convolution uses."""
     cfg: HybridConfig
 
     @nn.compact
@@ -545,22 +552,22 @@ class Hybrid(nn.Module):
     def paged_cache_spec(self):
         """A full layer pages K and V of `packed_kv_shape(kv_pool_heads,
         head_dim)` a token; a linear layer keeps, a slot, the float32
-        state (d_k, H x d_v) and the convolution's last K - 1 inputs; a
-        conv layer its last K - 1 inputs (ops/attention.py:
-        kv_cache_spec). A "kda" layer keeps what a linear layer
-        does."""
+        state (d_k, H x d_v) and the convolution's last K - 1 inputs,
+        one row of (K - 1) x C; a conv layer its last K - 1 inputs,
+        likewise (ops/attention.py:kv_cache_spec). A "kda" layer keeps
+        what a linear layer does."""
         cfg = self.cfg
         kv = packed_kv_shape(cfg.kv_pool_heads, cfg.head_dim)
         linear = LayerCache(
             SlotState,
             ((cfg.linear_key_dim,
               cfg.linear_n_heads * cfg.linear_value_dim),
-             (cfg.linear_conv_kernel - 1, cfg.conv_width)),
+             ((cfg.linear_conv_kernel - 1) * cfg.conv_width,)),
             (jnp.float32, cfg.dtype), by_slot=True)
         by_kind = {
             FULL: LayerCache(PagedKV, (kv, kv), (cfg.dtype, cfg.dtype)),
             LINEAR: linear, KDA: linear,
             CONV: LayerCache(
-                SlotState, ((cfg.conv_kernel - 1, cfg.d_model),),
+                SlotState, (((cfg.conv_kernel - 1) * cfg.d_model,),),
                 (cfg.dtype,), by_slot=True)}
         return [by_kind[kind] for kind in cfg.layer_types]

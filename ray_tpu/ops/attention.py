@@ -229,7 +229,8 @@ class SlotState:
     `(n_slots, *trailing)`, and know nothing of pages.
 
     arrays: the layer's pool arrays (for ops/gated_deltanet.py: the
-      float32 state and the convolution's last inputs).
+      float32 state and the convolution's last K - 1 inputs, side by
+      side in ONE row a slot: `causal_conv`).
     slots: (B,) int32, the pool row of each sequence of the call, or
       None where the call's rows ARE the pool's rows (a decode step
       over every slot).

@@ -107,24 +107,71 @@ def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
                 ) -> Tuple[jax.Array, jax.Array]:
     """Depthwise causal convolution, then `activation` (None: nothing;
     models/hybrid.py's short-convolution layer). u (B, S, C) new inputs,
-    w (K, C) with w[0] on the current token, tail (B, K - 1, C) the
-    inputs before them (None: zeros). Returns (the result (B, S, C) in
-    u's dtype, the new tail): the K - 1 inputs up to each row's true
-    length `n_new` (B,) (None: S), so a row with no real token keeps
-    its tail."""
+    w (K, C) with w[0] on the current token, tail (B, (K - 1) * C) the
+    K - 1 inputs before them side by side, the oldest first (None:
+    zeros). Returns (the result (B, S, C) in u's dtype, the new tail):
+    the K - 1 inputs up to each row's true length `n_new` (B,) (None:
+    S), so a row with no real token keeps its tail.
+
+    The tail is held a ROW a sequence and never as (B, K - 1, C): an
+    axis of K - 1 (or, behind a one-token `u`, of K or 1) rows in the
+    tiled second-minor place is given tiles of two and four rows on the
+    TPU, and the compiler then re-lays out the projection that made `u`
+    and copies the whole tail pool there and back, every decode step
+    (PERF.md section 6, PR 55). A tap is a slice of lanes (C fills whole
+    128-lane tiles at every published width). One token (S == 1, what
+    a decode step is) is elementwise work on (B, C) arrays."""
     b, s, c = u.shape
     k = w.shape[0]
     if tail is None:
-        tail = jnp.zeros((b, k - 1, c), u.dtype)
-    cat = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+        tail = jnp.zeros((b, (k - 1) * c), u.dtype)
+    tail = tail.astype(u.dtype)
+    taps = [tail[:, i * c:(i + 1) * c] for i in range(k - 1)]
     wf = w.astype(F32)
-    out = sum(wf[j] * cat[:, k - 1 - j:k - 1 - j + s].astype(F32)
-              for j in range(k))
-    if n_new is None:
-        new_tail = cat[:, s:]
+
+    def held(x):
+        """x in float32 as its own dtype holds it, and as the tail
+        will: where the compiler fuses the projection that made `u`
+        into these sums it would otherwise hand over the unrounded
+        float32 product, one step's tap 0 in another precision than
+        the next step's tap 1."""
+        fi = jnp.finfo(x.dtype)
+        return jax.lax.reduce_precision(x.astype(F32), fi.nexp, fi.nmant)
+
+    if s == 1:
+        x = u[:, 0]
+        out = sum(wf[j] * held(x if j == 0 else taps[k - 1 - j])
+                  for j in range(k))[:, None]
+        new_tail = jnp.concatenate(taps[1:] + [x], axis=-1)
+        if n_new is not None:
+            new_tail = jnp.where((n_new > 0)[:, None], new_tail, tail)
     else:
-        idx = n_new[:, None] + jnp.arange(k - 1)[None, :]       # (B, K-1)
-        new_tail = jnp.take_along_axis(cat, idx[:, :, None], axis=1)
+        # x_j[t] = u[t - j], and the tail's tap K - 1 - j + t where
+        # t < j: u shifted where it lies, its first rows from the tail;
+        # one fused pass over u on the TPU (the first K - 1 results
+        # computed apart and put in front of the rest compiled to two,
+        # with the float32 sums in HBM between them)
+        t = jnp.arange(s)[:, None]
+
+        def shifted(j):
+            x = jnp.pad(u[:, :max(s - j, 0)],
+                        ((0, 0), (min(j, s), 0), (0, 0)))
+            for i in range(min(j, s)):
+                x = jnp.where(t == i, taps[k - 1 - j + i][:, None], x)
+            return x
+        out = sum(wf[j] * held(shifted(j)) for j in range(k))
+        # position n_new + i of [tail | u], i = 0 .. K - 2: a row of u,
+        # or where the row is shorter than the tail one of the old taps
+        n = jnp.full((b,), s, jnp.int32) if n_new is None else n_new
+        rows = u.reshape(b * s, c)
+        new = []
+        for i in range(k - 1):
+            at = n + i
+            tap = rows[jnp.arange(b) * s + jnp.clip(at - (k - 1), 0, s - 1)]
+            for old in range(i, k - 1):
+                tap = jnp.where((at == old)[:, None], taps[old], tap)
+            new.append(tap)
+        new_tail = jnp.concatenate(new, axis=-1)
     if activation is not None:
         out = activation(out)
     return out.astype(u.dtype), new_tail

@@ -3,7 +3,8 @@ ops/pallas/kda_prefill.py: the chunkwise form, the one-token step, the
 fused step kernel and the fused chunk kernel (both interpreted) against
 the recurrence token by token, and the layers of models/hybrid.py over
 a per-slot state: padded == unpadded, prefill then steps == one
-prefill."""
+prefill; and the short convolution (`causal_conv`: the tail a row a
+sequence) against the form it had with the tail on the token axis."""
 import contextlib
 
 import jax
@@ -215,7 +216,8 @@ def _layer(kind=GatedDeltaNet, preset=HybridConfig.debug, **kw):
 def _pool(cfg, slots):
     return (jnp.zeros((slots, cfg.linear_key_dim,
                        cfg.linear_n_heads * cfg.linear_value_dim)),
-            jnp.zeros((slots, cfg.linear_conv_kernel - 1, cfg.conv_width)))
+            jnp.zeros((slots,
+                       (cfg.linear_conv_kernel - 1) * cfg.conv_width)))
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])
@@ -283,3 +285,141 @@ def test_chunks_carry_the_state_and_restart_clears_it():
         *e.arrays, slot, jnp.asarray([16]), jnp.asarray([False])))
     np.testing.assert_allclose(jnp.concatenate([y0, y1], 1), want, atol=2e-5)
     np.testing.assert_array_equal(e.arrays[0][0], dirty[0][0])
+
+
+# ---- the short convolution ------------------------------------------------
+
+def token_axis_conv(u, w, tail, n_new, activation):
+    """`ops/gated_deltanet.py:causal_conv` as it stood until PR 55, the
+    reference here and the other side of `tools/kda_microbench.py --what
+    conv` on the chip: tail (B, K - 1, C) (None: zeros) concatenated in
+    front of u (B, S, C) on the TOKEN axis, slices of that axis summed,
+    the new tail gathered from it at each row's true length."""
+    b, s, c = u.shape
+    k = w.shape[0]
+    if tail is None:
+        tail = jnp.zeros((b, k - 1, c), u.dtype)
+    cat = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    wf = w.astype(jnp.float32)
+    out = sum(wf[j] * cat[:, k - 1 - j:k - 1 - j + s].astype(jnp.float32)
+              for j in range(k))
+    if n_new is None:
+        new_tail = cat[:, s:]
+    else:
+        idx = n_new[:, None] + jnp.arange(k - 1)[None, :]
+        new_tail = jnp.take_along_axis(cat, idx[:, :, None], axis=1)
+    if activation is not None:
+        out = activation(out)
+    return out.astype(u.dtype), new_tail
+
+
+def _rows_conv(u, w, tail, n_new=None, activation=jax.nn.silu):
+    """The reference behind `causal_conv`'s signature: the tail a row a
+    sequence in, a row a sequence out."""
+    b, _, c = u.shape
+    out, new = token_axis_conv(
+        u, w, None if tail is None else tail.reshape(b, -1, c), n_new,
+        activation)
+    return out, new.reshape(b, -1)
+
+
+# true lengths of the five rows of a call of `s` new positions under a
+# kernel of `k` taps (one token: 1 or 0, whatever the name asks for)
+N_NEW = {"none": lambda s, k: None,
+         "zero": lambda s, k: [0] * 5,
+         "under_the_tail": lambda s, k: [min(s, i % (k - 1)) for i in range(5)],
+         "partial": lambda s, k: [min(s, k - 1 + i) for i in range(5)],
+         "full": lambda s, k: [s] * 5}
+
+
+@pytest.mark.parametrize("tail", ["fresh", "carried"])
+@pytest.mark.parametrize("activation", [None, jax.nn.silu],
+                         ids=["plain", "silu"])
+@pytest.mark.parametrize("k", [3, 4])
+@pytest.mark.parametrize("n_new", sorted(N_NEW))
+@pytest.mark.parametrize("s", [1, 2, 9])
+def test_causal_conv_is_the_token_axis_form_bit_for_bit(s, n_new, k,
+                                                        activation, tail):
+    """One token, fewer tokens than the tail holds, and a prompt: the
+    result and the new tail are the parent's to the bit (the float32
+    products and sums in its order), operation by operation as written
+    (a compiler may contract a product and a sum of either form)."""
+    rng = np.random.default_rng(s * 100 + k)
+    c = 256
+    u = jnp.asarray(rng.normal(size=(5, s, c)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(k, c)), jnp.float32)
+    held = None if tail == "fresh" else jnp.asarray(
+        rng.normal(size=(5, (k - 1) * c)), jnp.bfloat16)
+    n = N_NEW[n_new](s, k)
+    n = None if n is None else jnp.asarray(n, jnp.int32)
+    want_o, want_t = _rows_conv(u, w, held, n, activation)
+    got_o, got_t = gdn.causal_conv(u, w, held, n, activation)
+    assert (got_o.shape, got_o.dtype) == (want_o.shape, want_o.dtype)
+    assert (got_t.shape, got_t.dtype) == ((5, (k - 1) * c), want_t.dtype)
+    np.testing.assert_array_equal(got_o, want_o)
+    np.testing.assert_array_equal(got_t, want_t)
+
+
+@pytest.mark.parametrize("family", ["hybrid-debug", "lfm2-moe-debug",
+                                    "solar-debug"])
+def test_engine_tokens_and_tails_are_the_token_axis_forms(family,
+                                                          monkeypatch):
+    """The engine's own step functions: two prompts of unequal length
+    prefilled as one group into two of three slots, then three decode
+    steps over the pool with the third slot idle. The tokens and every
+    conv-carrying layer's tail pool are those of the same functions
+    over the reference form. The first such layer's tail to the bit (no
+    convolution stands before its input); behind it to float32
+    rounding: a compiled program contracts a product and a sum where
+    its fusions let it, and the two forms fuse differently."""
+    from ray_tpu.models import get_model
+    from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
+    model = get_model(family, dtype=jnp.float32)
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompts = [np.arange(3, 14), np.arange(40, 47)]
+    tokens = np.zeros((2, 16), np.int32)
+    for row, prompt in zip(tokens, prompts):
+        row[:len(prompt)] = prompt
+    lens = jnp.asarray([len(p) for p in prompts], jnp.int32)
+
+    def serve():
+        eng = LLMEngine(model, params, LLMEngineConfig(
+            max_slots=3, max_seq_len=64, prefill_buckets=(16,),
+            kv_page_size=8, max_prefill_batch=2))
+        try:
+            for slot, prompt in enumerate(prompts):
+                assert eng._pages.reserve(slot, len(prompt) + 4)
+            table = jnp.asarray(eng._pages.rows())
+            rows, key = table.shape[0], jax.random.PRNGKey(0)
+            toks, _, pools, lengths, *_ = jax.jit(
+                eng._prefill_paged_impl, static_argnames=("pad_len",))(
+                params, eng._pools, table, eng._state.lengths,
+                jnp.asarray(tokens), jnp.arange(2), lens, jnp.zeros((2,)),
+                jnp.ones((2,)), key, pad_len=16, n_real=jnp.int32(2))
+            step = jax.jit(eng._decode_paged_impl)
+            live = jnp.arange(rows) < 2
+            last = jnp.zeros((rows,), jnp.int32).at[:2].set(toks)
+            out = [last[:2]]
+            for _ in range(3):
+                last, _, pools, lengths, *_ = step(
+                    params, pools, table, lengths, last, live,
+                    jnp.zeros((rows,)), jnp.ones((rows,)), key)
+                out.append(last[:2])
+            tails = [np.asarray(pools[i][-1])
+                     for i, c in enumerate(eng._pages.spec) if c.by_slot]
+        finally:
+            eng.shutdown()
+        return np.stack(out, 1), tails
+    got_tokens, got_tails = serve()
+    monkeypatch.setattr(gdn, "causal_conv", _rows_conv)
+    want_tokens, want_tails = serve()
+    np.testing.assert_array_equal(got_tokens, want_tokens)
+    assert got_tokens.shape == (2, 4)
+    assert len(got_tails) == sum(
+        kind != "full_attention" for kind in model.cfg.layer_types)
+    np.testing.assert_array_equal(got_tails[0], want_tails[0])
+    for got, want in zip(got_tails, want_tails):
+        assert got.shape == want.shape and got.ndim == 2
+        # two slots hold a tail, the idle one and the scratch row none
+        assert list(np.abs(want).sum(1) > 0) == [True, True, False, False]
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)

@@ -72,10 +72,11 @@ def test_the_cache_is_declared_a_layer(tiny):
     assert [c.by_slot for c in spec] == [True, True, True, False]
     assert [c.entry for c in spec] == [SlotState] * 3 + [PagedKV]
     state, tail = spec[0].shapes
-    assert state == (8, 4 * 16) and tail == (3, 4 * (8 + 8 + 16))
+    # the tail a row a slot: K - 1 = 3 inputs of q | k | v side by side
+    assert state == (8, 4 * 16) and tail == (3 * 4 * (8 + 8 + 16),)
     assert spec[0].dtypes[0] == jnp.float32
     big = kv_cache_spec(get_model("olmo-hybrid-7b", n_layers=4))
-    assert big[0].shapes == ((96, 5760), (3, 11520))
+    assert big[0].shapes == ((96, 5760), (3 * 11520,))
     # 30 KV heads fill no whole 8-row tile: the pool is laid out for 32
     assert big[3].shapes == ((32, 128), (32, 128))
 

@@ -99,7 +99,8 @@ def test_the_cache_is_declared_a_layer(tiny):
     assert [c.by_slot for c in spec] == [True, True, False, True, True]
     assert [c.entry for c in spec] == [SlotState] * 2 + [PagedKV] \
         + [SlotState] * 2
-    assert spec[0].shapes == ((2, 64),)
+    # a conv layer's tail is one row a slot: K - 1 = 2 inputs of 64
+    assert spec[0].shapes == ((2 * 64,),)
     # 2 KV heads of 16 laid out as 8 (a whole tile of rows), packed
     # eight to a 128-lane row
     assert spec[2].shapes == ((1, 128), (1, 128))
@@ -223,7 +224,7 @@ def test_an_idle_rows_conv_state_is_unchanged_by_a_decode_step(tiny):
         after = [np.asarray(eng._pools[i][0]) for i in conv]
     finally:
         eng.shutdown()
-    changed = [sorted(set(np.nonzero((a != b).any((1, 2)))[0]))
+    changed = [sorted(set(np.nonzero((a != b).any(1))[0]))
                for a, b in zip(before, after)]
     # exactly one row a layer moved: the slot the second request took
     assert all(len(rows) == 1 for rows in changed), changed
@@ -238,16 +239,17 @@ def test_causal_conv_is_one_function_for_both_layer_kinds():
     u = jnp.asarray(rng.normal(size=(2, 6, 4)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 4)), jnp.float32)
     tail = jnp.asarray(rng.normal(size=(2, 2, 4)), jnp.float32)
-    plain, t1 = causal_conv(u, w, tail, jnp.asarray([4, 0]),
+    # the tail a row a sequence: its two inputs side by side
+    plain, t1 = causal_conv(u, w, tail.reshape(2, 8), jnp.asarray([4, 0]),
                             activation=None)
-    act, t2 = causal_conv(u, w, tail, jnp.asarray([4, 0]))
+    act, t2 = causal_conv(u, w, tail.reshape(2, 8), jnp.asarray([4, 0]))
     cat = np.concatenate([tail, u], axis=1)
     want = sum(np.asarray(w)[j] * cat[:, 2 - j:8 - j] for j in range(3))
     np.testing.assert_allclose(plain, want, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(act, jax.nn.silu(want), rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(t1, t2)
-    np.testing.assert_array_equal(t1[0], u[0, 2:4])
-    np.testing.assert_array_equal(t1[1], tail[1])
+    np.testing.assert_array_equal(t1[0], u[0, 2:4].reshape(8))
+    np.testing.assert_array_equal(t1[1], tail[1].reshape(8))
 
 
 def test_routing_selects_by_score_plus_bias_and_weighs_by_score():

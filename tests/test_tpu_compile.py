@@ -294,6 +294,122 @@ def test_latent_attention_copies_no_parameter_in_hbm(chip, form,
         assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
+def relayouts_in_hbm(text: str, elements: set) -> list:
+    """The `copy(` operations of a compiled program (a change of layout
+    or tiling, paid in HBM traffic both ways) whose result has one of
+    the `elements` counts, and the `copy-start(`s of such an array
+    between two places in HBM. A `copy-start` into or out of the fast
+    memory (`S(1)` on one side) is the array's one read or write."""
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(?\w+\[[\d,]*\]"
+                             r"\{[^}]*\})", text, re.M))
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \(?(\w+\[([\d,]*)\]\{[^}]*\}).* copy(-start)?"
+                      r"\((%[\w.\-]+)", line)
+        if m is None or not m.group(2) or math.prod(
+                map(int, m.group(2).split(","))) not in elements:
+            continue
+        if m.group(3) and ("S(1)" in m.group(1)
+                           or "S(1)" in shapes.get(m.group(4), "")):
+            continue
+        found.append(line.strip()[:200])
+    return found
+
+
+def small_tiled(text: str, scope: str, at_least=2 ** 20) -> list:
+    """The arrays of `at_least` bytes or more that operations of the
+    named scope leave in tiles of fewer than eight rows (`T(1,128)`,
+    `T(2,128)`, `T(4,128)`: an axis of 1 to 4 in the second-minor
+    place, padded and re-tiled around)."""
+    found = []
+    for m in re.finditer(
+            r"= (\w+?)(\d+)\[([\d,]+)\]\{[^}]*?T\(([124]),128\)[^}]*\} "
+            r"[\w\-]+\(.*op_name=\"[^\"]*" + re.escape(scope), text):
+        size = math.prod(map(int, m.group(3).split(","))) \
+            * int(m.group(2)) // 8
+        if size >= at_least:
+            found.append(m.group(0)[:120])
+    return found
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+@pytest.mark.parametrize("family", ["solar", "olmo_hybrid"])
+def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
+        chip, family, form, monkeypatch):
+    """One delta-rule layer (and the full layer an engine needs for its
+    lengths) at Solar-Open2's and Olmo-Hybrid's published widths through
+    the engine's own step functions at the cells' slot counts (193 and
+    65 pool rows): a decode step and a 1 024-token prefill group (2 rows
+    and 1). With the convolution's tail K - 1 = 3 tokens deep on the
+    tiled axis the compiler copied the q | k | v projection's result
+    into tiles of two rows and the whole tail pool into tiles of four
+    and back, a layer a decode step, and in a prefill call the pool
+    both ways and the whole projection batch-minor (PERF.md section 6,
+    PR 55). The tail a row a slot: no relayout of either, nothing of
+    the `conv` scope in tiles under eight rows, and the prefill's rows
+    go into the donated pool in place."""
+    from ray_tpu.models import Hybrid, HybridConfig
+    from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sized = dict(n_layers=2, vocab_size=1024, max_seq_len=4096,
+                 dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    if family == "solar":
+        cfg = HybridConfig.solar_open2_250b(
+            layer_types=("full_attention", "kda"), n_experts=8,
+            experts_per_token=2, **sized)
+        slots, group, scope = 192, 2, "kda.conv/"
+    else:
+        cfg = HybridConfig.olmo_hybrid_7b(
+            layer_types=("linear_attention", "full_attention"), **sized)
+        slots, group, scope = 64, 1, "gdn.conv/"
+    model = Hybrid(cfg)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+    params = abstract(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    pad = 1024
+    eng = LLMEngine(model, params, LLMEngineConfig(
+        max_slots=slots, max_seq_len=4096, kv_page_size=64,
+        kv_pool_tokens=8192, prefill_buckets=(pad,),
+        max_prefill_batch=group))
+    try:
+        s, p = eng._pages.rows_shape()
+        args = (params, abstract(eng._pools), abstract(eng._state))
+
+        def ctl(n):
+            return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip)
+        if form == "decode":
+            text = eng._decode_paged_jit.lower(
+                *args, ctl(s + 3 * s + s * p),
+                window_pages=0).compile().as_text()
+        else:
+            text = eng._prefill_paged_jit.lower(
+                *args, ctl(s + 1 + 4 * group + s * p + group * pad),
+                pad_len=pad).compile().as_text()
+    finally:
+        eng.shutdown()
+    tail = (cfg.linear_conv_kernel - 1) * cfg.conv_width
+    assert f"bf16[{s},{tail}]" in text                  # the pool, a row a slot
+    rows = s if form == "decode" else group * pad
+    assert relayouts_in_hbm(text, {s * tail, rows * cfg.conv_width}) == []
+    assert small_tiled(text, scope) == []
+    if form == "decode":
+        # `causal_conv`'s `held`: with the projection's matmul fused into
+        # the sums, what rounds its float32 product to the tail's dtype
+        # and so keeps a step's tap 0 the next step's tap 1 to the bit
+        # (an identity on the CPU, where no other test can miss it)
+        assert re.search(r" reduce-precision\(.*exponent_bits=8, "
+                         r"mantissa_bits=7.*op_name=\"[^\"]*"
+                         + re.escape(scope), text)
+    if form == "prefill":
+        # the group's rows go into the donated pool where it lies
+        assert re.search(rf"bf16\[{s},{tail}\]\S* dynamic-update-slice\(",
+                         text)
+
+
 @pytest.mark.parametrize("family,rows", [
     ("sarvam", 129), ("sarvam", 4096), ("lfm2", 128), ("lfm2", 2048),
     ("solar", 129), ("solar", 4096),
@@ -445,9 +561,31 @@ def _loops_with_a_product(text: str) -> list:
             if m and has_product(m.group(1), set())]
 
 
+def arrays_in_hbm(text: str, scope: str, at_least: int) -> list:
+    """(type and dimensions, MiB) of the arrays of `at_least` bytes or
+    more that the ENTRY computation's operations of the named scope
+    define outside the fast memory (no `S(1)`), a tuple's elements
+    each: what the scope's results take of the program's HBM heap."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(.*"
+                         r"op_name=\"[^\"]*" + re.escape(scope),
+                         entry[:entry.index("\n}")], re.M):
+        if m.group(2) in ("bitcast", "get-tuple-element", "parameter"):
+            continue                    # another operation's array
+        for kind, bits, dims, layout in re.findall(
+                r"([a-z]+)(\d+)\[([\d,]+)\]\{([^}]*)\}", m.group(1)):
+            size = math.prod(map(int, dims.split(","))) * int(bits) // 8
+            if size >= at_least and "S(1)" not in layout:
+                found.append((f"{kind}{bits}[{dims}]", size / 2 ** 20))
+    return found
+
+
 # temp_size_in_bytes of the same programs at the parent commit (PR 52's,
 # the scan a `lax.scan` of 51 fusions): AOT, sandbox, this test's engine
 SOLAR_PREFILL_TEMP_MIB_BEFORE = {(1024, 1): 214.3, (2048, 2): 1043.2}
+# and of the 1 024 x 1 program as it stands, measured (PR 55)
+SOLAR_PREFILL_1024_1_TEMP_MIB = 261.2
 
 
 @pytest.mark.parametrize("pad_len,group",
@@ -458,10 +596,24 @@ def test_solar_prefill_programs_hold_the_chunk_kernel(chip, monkeypatch,
     the engine's jit, four slots for the pools' sake) compile for a
     described v5e with the chunk kernel in each delta-rule layer and no
     loop left that multiplies matrices (the grouped matmul's searches
-    stay). The largest program's temporaries are under the parent's;
-    the 1 024 x 1 program's heap packs 24 MiB worse than the parent's
-    (238.0 against 214.3 MiB; its peak of live bytes is 29 MB lower),
-    which one (1 024, 8 192) float32 array more than covers."""
+    stay). The largest program's temporaries are under the parent's.
+
+    The 1 024 x 1 program's are pinned where they stand, with what
+    they hold. PR 53 left 238.0 MiB against PR 52's 214.3 (its heap
+    packs worse; its peak of live bytes is lower). PR 55 (the
+    convolution's tail a row a slot) leaves 261.2: by the compiler's
+    buffer assignment (`--xla_dump_to`) the heap in HBM went 225.95 ->
+    249.55 MiB, beside 128 MiB of fast memory in both. The three
+    `bf16[1027,24576]` arrays in HBM (48 MiB each: the projection with
+    its tail in front) are gone: the projection's and the convolution's
+    results both lie in the fast memory now, and with them there one of
+    the chunk kernel's four 32 MiB float32 inputs a layer (q and k, each
+    in two layouts) found no room and is written to HBM and fetched
+    back in quarters. HBM holds 9 such arrays where it held 6 and the 3
+    of 48 MiB (288 MiB written a call against 336); but the 48 MiB ones
+    were dead where the heap peaks, at the scan, and one array of 32 MiB
+    more is alive there: the heap's 23.6 MiB. Whoever moves this number
+    says which array moved."""
     from benchmarks.harness import modelcfg, replica_solar
     from ray_tpu import models
     from ray_tpu.serve.llm.engine import LLMEngine, LLMEngineConfig
@@ -496,10 +648,16 @@ def test_solar_prefill_programs_hold_the_chunk_kernel(chip, monkeypatch,
     assert len(re.findall(r"custom-call\(.*kda_chunk_scan", text)) == layers
     assert _loops_with_a_product(text) == []
     temp_mib = compiled.memory_analysis().temp_size_in_bytes / 2 ** 20
-    assert temp_mib <= SOLAR_PREFILL_TEMP_MIB_BEFORE[pad_len, group] + 32, \
-        temp_mib
     if (pad_len, group) == (2048, 2):
         assert temp_mib < SOLAR_PREFILL_TEMP_MIB_BEFORE[pad_len, group]
+        return
+    assert temp_mib <= SOLAR_PREFILL_1024_1_TEMP_MIB + 1, temp_mib
+    least = 32 * 2 ** 20
+    assert arrays_in_hbm(text, "kda.proj/qkv_proj/", least) == []
+    held = arrays_in_hbm(text, "kda.conv/", least)
+    assert sorted(held) == sorted(layers * [
+        ("f32[1,128,64,8,128]", 32.0), ("f32[128,8,64,128]", 32.0),
+        ("f32[128,8,64,128]", 32.0)]), held
 
 
 def test_paged_decode_over_a_pool_laid_out_for_32_heads_compiles(chip):

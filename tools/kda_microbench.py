@@ -1,6 +1,7 @@
 """One-chip microbenchmark of the delta rule's kernels: the decode step
 (PERF.md, PR 52) and prefill's chunk scan with a decay a key channel
-(PERF.md, PR 53).
+(PERF.md, PR 53); and of the short convolution in front of them
+(PERF.md, PR 55).
 
 One layer's one-token step over a slot pool at Solar-Open2-250B's
 widths (64 heads of 128 x 128, a decay a key channel, 4 MiB of float32
@@ -29,7 +30,25 @@ which inside a model their own fusions write), and beside them what
 `chunk_floor`
 counts for a chunk of 64 heads: `exp`s, float32 vector operations and
 bfloat16 FLOPs of the six-pass products, the last as a share of the
-chip's matrix peak. Needs the chip:
+chip's matrix peak.
+
+`--what conv`: one delta-rule layer's q | k | v projection, convolution
+and tail update over the engine's own `SlotState`, at Solar-Open2's
+decode shape (193 rows, C 24 576, K 4) and Olmo-Hybrid's (65 rows,
+C 11 520), a third of the rows idle, and at each cell's 1 024-token
+prefill group (2 rows and 1): `ops/gated_deltanet.py:causal_conv` (the
+tail a row a slot, one token on (rows, channels)) against the form it
+had until PR 55 (`tests/test_gated_deltanet.py:token_axis_conv`, the
+tests' reference: the tail K - 1 tokens deep, concatenated in front of
+the input on the token axis), each over a pool
+of its own shape, donated, and the projection alone (`_proj`). ms a
+call; `conv_ms`, the call less the projection's alone (a compiler fuses
+the matmul into other operations, so no operation's name is the
+matmul's in every candidate), beside `conv_floor_ms`, the time the
+tail's rows in and out and the projection's result take at the chip's
+bandwidth; the largest operations by name; and
+whether the two forms' results and tails are equal to the bit on the
+chip. Needs the chip:
 
     python -m tools.kda_microbench --out chiprun_out/kda.json
 """
@@ -44,6 +63,10 @@ import time
 
 SHAPES = {"solar": dict(rows=129, h=64, dk=128, dv=128, channel=True),
           "olmo": dict(rows=65, h=30, dk=96, dv=192, channel=False)}
+# the convolution: the cells' slot pools (scratch row and all), model
+# widths, kernel taps and 1 024-token prefill groups
+CONV_SHAPES = {"solar": dict(rows=193, d_model=4096, k=4, group=2),
+               "olmo": dict(rows=65, d_model=3840, k=4, group=1)}
 # prefill groups of the chunk scan: (rows, bucket)
 SCAN_GROUPS = ((1, 512), (1, 1024), (2, 1024), (2, 2048))
 CHUNK = 64
@@ -68,6 +91,36 @@ def chunk_floor(h: int, dk: int, dv: int, c: int = CHUNK,
             "bf16_flops_six_pass": 6 * h * flops}
 
 
+def op_times(trace_dir: str) -> dict:
+    """program -> {operation: seconds} on the first chip's `XLA Ops`
+    line, an operation counted to the program whose run on the `XLA
+    Modules` line covers its start."""
+    import re
+
+    from jax.profiler import ProfileData
+
+    from benchmarks.harness import trace_reduce
+    data = ProfileData.from_file(trace_reduce.find_xplane(trace_dir))
+    plane = next(p for p in data.planes
+                 if re.search(r"^/device:TPU:\d+$", p.name))
+    lines = {ln.name: ln for ln in plane.lines}
+    mods = sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
+                   re.sub(r"\(.*$", "", ev.name))
+                  for ev in lines["XLA Modules"].events)
+    ops = sorted((ev.start_ns, ev.duration_ns, ev.name)
+                 for ev in lines["XLA Ops"].events)
+    out, i = {}, 0
+    for start, end, name in mods:
+        row = out.setdefault(name, {})
+        while i < len(ops) and ops[i][0] < start:
+            i += 1
+        while i < len(ops) and ops[i][0] < end:
+            op = trace_reduce.category(ops[i][2])
+            row[op] = row.get(op, 0.0) + ops[i][1] * 1e-9
+            i += 1
+    return out
+
+
 def cell_lengths(rng, rows: int, bucket: int) -> list:
     """`rows` prompt lengths of those `solar250b_decode_sat` sends (the
     traffic file's distribution at the quantiles a block of its
@@ -83,7 +136,7 @@ def cell_lengths(rng, rows: int, bucket: int) -> list:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--what", default="step,scan")
+    ap.add_argument("--what", default="step,scan,conv")
     ap.add_argument("--shapes", default="solar,olmo")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -96,6 +149,7 @@ def main() -> None:
     import jax.numpy as jnp
     from benchmarks.harness.peaks import peaks_for
     from ray_tpu.ops import gated_deltanet as gdn
+    from ray_tpu.ops.attention import SlotState
     from ray_tpu.ops.pallas.gdn_decode import (gdn_decode_step,
                                                kda_decode_step)
     from ray_tpu.ops.pallas.kda_prefill import kda_chunk_scan
@@ -108,18 +162,18 @@ def main() -> None:
     peak_bw = peaks["hbm_bytes_per_s"]
     cands, inputs = {}, {}
 
-    def add(name, fn, key, **meta):
+    def add(name, fn, key, donate=5, **meta):
         # a number of its own beside the result: the runtime keeps one
         # executable for one HLO whatever its name, and two candidates
         # that differ in their inputs alone would share a name on the
         # trace
         tag = float(len(cands))
 
-        def step(q, k, v, g, beta, state, *rest):
-            o, new = fn(q, k, v, g, beta, state, *rest)
+        def step(*args):
+            o, new = fn(*args)
             return o, new, jnp.float32(tag)
         step.__name__ = name
-        cands[name] = (jax.jit(step, donate_argnums=(5,)), key, meta)
+        cands[name] = (jax.jit(step, donate_argnums=(donate,)), key, meta)
 
     def draw(key, rows, h, dk, dv, channel, lead=()):
         ks = jax.random.split(key, 6)
@@ -180,6 +234,63 @@ def main() -> None:
                 add(f"{key}_kernel", functools.partial(
                     kda_chunk_scan, chunk=CHUNK), key, **meta)
 
+    for shape in args.shapes.split(",") if "conv" in what else ():
+        c = CONV_SHAPES[shape]
+        rows, d, taps, group = c["rows"], c["d_model"], c["k"], c["group"]
+        h, dk, dv = (SHAPES[shape][x] for x in ("h", "dk", "dv"))
+        width = h * (2 * dk + dv)
+        ks = jax.random.split(jax.random.PRNGKey(args.seed + 55), 5)
+        w_proj = (jax.random.normal(ks[0], (d, width)) * d ** -0.5
+                  ).astype(jnp.bfloat16)
+        conv_w = jax.random.uniform(ks[1], (taps, width), jnp.float32,
+                                    -0.5, 0.5)
+        tails = jax.random.normal(ks[2], (rows, taps - 1, width)
+                                  ).astype(jnp.bfloat16)
+
+        def layer(conv, lead, h=h, dk=dk):
+            """x -> q, k, v a head and the pool with its new tail rows,
+            as models/hybrid.py:_delta_rule has them before the norms."""
+            def run(x, w_proj, conv_w, slots, n_new, pool):
+                entry = SlotState(pool, slots, n_new, None)
+                u = jnp.einsum("bsd,dc->bsc", x, w_proj)
+                (tail,) = entry.read()
+                qkv, tail = conv(u, conv_w, tail, n_new, jax.nn.silu)
+                if lead == 1:
+                    qkv = qkv[:, 0]
+                q, k, v = jnp.split(qkv, [h * dk, 2 * h * dk], axis=-1)
+                heads = tuple(a.reshape(*a.shape[:lead], h, -1)
+                              for a in (q, k, v))
+                return heads, entry.write(tail).arrays[0]
+            return run
+        # the form until PR 55 is kept where its tests are
+        from tests.test_gated_deltanet import (  # noqa: PLC0415
+            token_axis_conv)
+        forms = {"token_axis": (token_axis_conv, tails),
+                 "rows": (gdn.causal_conv, tails.reshape(rows, -1))}
+        for call, b, s_new, slots, n_new in (
+                ("decode", rows, 1, None,
+                 (jnp.arange(rows) % 3 != 2).astype(jnp.int32)),
+                ("prefill", group, 1024, jnp.arange(group, dtype=jnp.int32),
+                 jnp.asarray([1000, 700][:group], jnp.int32))):
+            x = jax.random.normal(ks[3 + (call == "prefill")],
+                                  (b, s_new, d)).astype(jnp.bfloat16)
+            # the projection alone (its result written out): what both
+            # forms' `conv_ms` leave out of their call
+            key = f"conv_{shape}_{call}_proj"
+            inputs[key] = ((x, w_proj, conv_w, slots, n_new),
+                           jnp.zeros((8, 128), jnp.bfloat16), ())
+            add(key, lambda x, w_proj, conv_w, slots, n_new, pool: (
+                jnp.einsum("bsd,dc->bsc", x, w_proj), pool), key,
+                rows=b, tokens=s_new, channels=width)
+            for form, (conv, pool) in forms.items():
+                key = f"conv_{shape}_{call}_{form}"
+                inputs[key] = ((x, w_proj, conv_w, slots, n_new), pool, ())
+                add(key, layer(conv, 1 if call == "decode" else 2), key,
+                    rows=b, tokens=s_new, channels=width, taps=taps,
+                    # what the call has to move: the tail's rows in and
+                    # out and the projection's result once
+                    bytes=2 * (2 * b * (taps - 1) + b * s_new) * width)
+
     rows_out, compiled, finals = {}, {}, {}
     for name, (fn, key, meta) in cands.items():
         args_, state0, rest = inputs[key]
@@ -209,7 +320,11 @@ def main() -> None:
         del st
     jax.profiler.stop_trace()
     times = device_times(trace_dir, r"^%?(kda|gdn)_(decode_step|chunk_scan)")
+    by_op = op_times(trace_dir) if "conv" in what else {}
     floor = chunk_floor(64, 128, 128)
+    proj_ms = {name[:-len("proj")]: 1e3 * times[f"jit_{name}"][1] / args.reps
+               for name in compiled
+               if name.endswith("_proj") and f"jit_{name}" in times}
     for name in compiled:
         meta = cands[name][2]
         runs, seconds, kernel_s = times.get(f"jit_{name}", (0, 0.0, 0.0))
@@ -222,7 +337,27 @@ def main() -> None:
         row = {**meta, "ms": round(ms, 4),
                "kernel_ms": round(1e3 * kernel_s / runs, 4),
                "wall_ms": round(wall[name], 4)}
-        if "bytes" in meta:
+        if name.startswith("conv_"):
+            ops = {k: 1e3 * v / runs for k, v in by_op[f"jit_{name}"].items()}
+            row["ops_ms"] = {k: round(v, 4) for k, v in sorted(
+                ops.items(), key=lambda kv: -kv[1])[:8]}
+            # the call less the `_proj` candidate's, the projection run
+            # alone at this shape: a compiler fuses the matmul into
+            # operations of other names in either form, so no name in
+            # `ops_ms` is the matmul's in every candidate. Beside it
+            # the time the call's bytes take at the chip's bandwidth:
+            # where `conv_ms` is under that floor (Olmo-Hybrid's decode
+            # shape) the projection alone costs more than the one fused
+            # into the call (it waits for its weights' prefetch), and
+            # the difference counts the convolution too low
+            form = next(f for f in ("token_axis", "rows", "proj")
+                        if name.endswith(f))
+            alone = proj_ms.get(name[:-len(form)])
+            if alone is not None and form != "proj":
+                row.update(
+                    proj_ms=round(alone, 4), conv_ms=round(ms - alone, 4),
+                    conv_floor_ms=round(1e3 * meta["bytes"] / peak_bw, 4))
+        elif "bytes" in meta:
             row.update(gb_per_s=round(meta["bytes"] / (ms * 1e-3) / 1e9, 1),
                        share_of_hbm_peak=round(
                            meta["bytes"] / (ms * 1e-3) / peak_bw, 4))
@@ -256,22 +391,34 @@ def main() -> None:
                 o1, o2 = o1 * real, o2 * real
             errs[name] = [float(jnp.max(jnp.abs(o1 - o2))),
                           float(jnp.max(jnp.abs(s1 - s2)))]
+    # the two forms of the convolution: q, k, v and the pool, to the bit
+    same = {}
+    for name in compiled:
+        other = name[:-len("rows")] + "token_axis"
+        if name.endswith("_rows") and other in finals:
+            (o1, t1), (o2, t2) = finals[name], finals[other]
+            same[name] = bool(all(
+                (a == b.reshape(a.shape)).all()
+                for a, b in zip((*o1, t1), (*o2, t2))))
     result = {"device": {"platform": dev.platform, "kind": dev.device_kind,
                          "count": jax.device_count()},
               "hbm_bytes_per_s": peak_bw, "reps": args.reps,
               "seed": args.seed, "chunk_floor_64_heads": floor,
               "max_abs_err_o_state_vs_plain_form": errs,
+              "conv_rows_form_equals_token_axis_form": same,
               "rows": rows_out}
     for name, row in rows_out.items():
         print(name, json.dumps({k: row[k] for k in (
             "ms", "kernel_ms", "gb_per_s", "share_of_hbm_peak", "live_rows",
             "lengths", "us_per_chunk_row_window", "us_per_chunk_row_live",
             "kernel_us_per_chunk_row_live",
-            "share_of_matrix_peak_live", "first_call_s", "error")
+            "share_of_matrix_peak_live", "proj_ms", "conv_ms",
+            "conv_floor_ms", "ops_ms", "first_call_s", "error")
             if k in row}), flush=True)
     print(json.dumps({k: result[k] for k in (
         "device", "chunk_floor_64_heads",
-        "max_abs_err_o_state_vs_plain_form")}))
+        "max_abs_err_o_state_vs_plain_form",
+        "conv_rows_form_equals_token_axis_form")}))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
