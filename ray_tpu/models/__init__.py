@@ -35,6 +35,9 @@ _REGISTRY = {
     "solar-open2-250b": lambda **kw: Hybrid(
         HybridConfig.solar_open2_250b(**kw)),
     "solar-debug": lambda **kw: Hybrid(HybridConfig.solar_debug(**kw)),
+    "nemotron-3-super-120b": lambda **kw: Hybrid(
+        HybridConfig.nemotron_3_super_120b(**kw)),
+    "nemotron-debug": lambda **kw: Hybrid(HybridConfig.nemotron_debug(**kw)),
     "vit-base": lambda **kw: ViT(ViTConfig.base(**kw)),
     "vit-debug": lambda **kw: ViT(ViTConfig.debug(**kw)),
     "clip-debug": lambda **kw: CLIP(CLIPConfig.debug(**kw)),

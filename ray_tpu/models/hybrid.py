@@ -1,7 +1,8 @@
 """Hybrid decoders: a mixer a layer, chosen by `layer_types`, and a
-feed-forward a layer, chosen by the layer's index, TPU-first. Three
-families: `olmo_hybrid` (Olmo-Hybrid-7B), `lfm2_moe` (LFM2-24B-A2B) and
-`solar_open2` (Solar-Open2-250B).
+feed-forward a layer, chosen by the layer's index (or by `ff_types`,
+among dense / experts / none), TPU-first. Four families: `olmo_hybrid`
+(Olmo-Hybrid-7B), `lfm2_moe` (LFM2-24B-A2B), `solar_open2`
+(Solar-Open2-250B) and `nemotron_h` (Nemotron-3-Super-120B-A12B).
 
 A block is a mixer and a feed-forward around the residual stream. The
 mixer of layer i is what `layer_types[i]` names:
@@ -46,6 +47,26 @@ mixer of layer i is what `layer_types[i]` names:
         c_t = sum_j w_j z_{t-j}, j = 0..K-1       depthwise, causal
         y = W_out (C * c)
     Its memory is z at the sequence's last K - 1 positions.
+  * "mamba2": `Mamba2`, the selective state-space layer of ops/ssm.py
+    (Dao, Gu, arXiv:2405.21060). H = `ssm_n_heads` heads of P =
+    `ssm_head_dim`, G = `ssm_groups` groups of N = `ssm_state` state
+    channels, head h reading group h // (H / G):
+        [z | u | dt~] = W_in x          H P | H P + 2 G N | H columns
+        [xs | B | C] = SiLU(conv_K(u) + b_conv)   depthwise, causal
+        dt = softplus(dt~ + dt_bias), a = exp(dt A), A = -exp(A_log)
+        S_h <- a_h S_h + (dt_h xs_h) B_g^T,  y_h = S_h C_g + D_h xs_h
+        out = W_out RMSNorm_G(y * SiLU(z))   the gate first, a norm a
+                                             group of H P / G channels
+    the delta rule without its correction (k = B, q = C, v = xs,
+    beta = dt), over the same slot state (N, H x P) float32 and the
+    same two forms (the step kernel is `ssm_decode_step`).
+
+`nemotron_h` publishes ONE sub-layer a layer, a mixer or a feed-forward
+behind one norm and one residual add. A pre-norm block here, h = x +
+Mixer(Norm x), y = h + FF(Norm h), is exactly two such layers where the
+pattern has a mixer and then experts, and one where the feed-forward is
+"none" (a mixer followed by a mixer): `nemotron_blocks` pairs a
+published pattern so, one cache entry a block as everywhere.
 
 The feed-forward of layer i is a dense SwiGLU (`LlamaMLP`, width `d_ff`)
 where `n_dense_layers` is None or i < `n_dense_layers`, and otherwise
@@ -53,8 +74,10 @@ the expert layer of models/latent_moe.py (`ShareMoE`: sigmoid scores, a
 selection bias, `n_experts` SwiGLU experts of width `d_expert`,
 `experts_per_token` a token; `lfm2_moe` holds them all and shares none,
 `solar_open2` holds `expert_count` from `expert_first`, a chip's share
-of an expert-parallel deployment, beside `n_shared_experts` shared),
-which leaves the `step_stats` counters of ops/moe.py.
+of an expert-parallel deployment, beside `n_shared_experts` shared;
+`nemotron_h` computes its routed experts, two matmuls and relu^2 each,
+in a latent of `moe_latent_dim` beside a shared expert of `d_shared` at
+full width), which leaves the `step_stats` counters of ops/moe.py.
 
 Where the norms stand is the family's (`pre_norm`): `olmo_hybrid`
 normalises each sub-layer's OUTPUT, as the OLMo 2 and 3 family does:
@@ -80,6 +103,7 @@ import jax.numpy as jnp
 
 from ..ops import rms_norm, rope_frequencies
 from ..ops import gated_deltanet as gdn
+from ..ops import ssm
 from ..ops.attention import (LayerCache, PagedKV, SlotState,
                              packed_kv_shape)
 from ..ops.moe import MOE_STATS
@@ -89,6 +113,14 @@ from .llama import LlamaAttention, LlamaMLP, _LMHead, _proj, head_logits
 
 LINEAR, FULL, CONV = "linear_attention", "full_attention", "conv"
 KDA = "kda"
+MAMBA2 = "mamba2"
+DENSE, EXPERTS, NO_FF = "dense", "experts", "none"
+
+
+# Nemotron-3-Super-120B-A12B's `hybrid_override_pattern`, as published
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,6 +140,13 @@ class HybridConfig:
     linear_allow_neg_eigval: bool = True
     linear_chunk: int = 64          # tokens a chunk of the chunkwise form
     kda_rank: int = 128             # r of the "kda" layers' low-rank pairs
+    # the "mamba2" layers': H heads of P, G groups of N state channels
+    ssm_n_heads: int = 128
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 8
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128            # tokens a chunk of the chunkwise form
     max_seq_len: int = 65536
     norm_eps: float = 1e-6
     # LlamaAttention reads these two: True, the whole projected q and k;
@@ -125,6 +164,9 @@ class HybridConfig:
     # are expert layers (models/latent_moe.py:ShareMoE reads the fields
     # below). None: every layer is dense
     n_dense_layers: Optional[int] = None
+    # one entry a layer, "dense", "experts" or "none" (the block is its
+    # mixer alone); None: by `n_dense_layers`
+    ff_types: Optional[Tuple[str, ...]] = None
     d_expert: int = 1536
     n_experts: int = 64
     experts_per_token: int = 4
@@ -138,6 +180,13 @@ class HybridConfig:
     n_shared_experts: int = 0
     expert_first: int = 0
     expert_count: Optional[int] = None
+    # ShareMoE's further arms: routed experts computed in a latent of
+    # this width (None: at d_model); experts of two matmuls and relu^2
+    # (False) instead of SwiGLU; the shared expert's width (None:
+    # n_shared_experts x d_expert)
+    moe_latent_dim: Optional[int] = None
+    expert_gated: bool = True
+    d_shared: Optional[int] = None
     dtype: Any = jnp.bfloat16
     # storage dtype of embeddings and matmul kernels; norm weights,
     # A_log and dt_bias stay float32
@@ -153,12 +202,20 @@ class HybridConfig:
         else:
             object.__setattr__(self, "layer_types",
                                tuple(self.layer_types))
-        bad = set(self.layer_types) - {LINEAR, FULL, CONV, KDA}
+        bad = set(self.layer_types) - {LINEAR, FULL, CONV, KDA, MAMBA2}
         if bad or len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types must name {self.n_layers} layers as "
-                f"{LINEAR!r}, {FULL!r} or {CONV!r} (or {KDA!r}); got "
-                f"{self.layer_types}")
+                f"{LINEAR!r}, {FULL!r} or {CONV!r} (or {KDA!r}, "
+                f"{MAMBA2!r}); got {self.layer_types}")
+        if self.ff_types is not None:
+            object.__setattr__(self, "ff_types", tuple(self.ff_types))
+            if set(self.ff_types) - {DENSE, EXPERTS, NO_FF} \
+                    or len(self.ff_types) != self.n_layers:
+                raise ValueError(
+                    f"ff_types must name {self.n_layers} layers as "
+                    f"{DENSE!r}, {EXPERTS!r} or {NO_FF!r}; got "
+                    f"{self.ff_types}")
         if not (0 <= self.expert_first and self.expert_first
                 + self.experts_held <= self.n_experts):
             raise ValueError(
@@ -184,9 +241,22 @@ class HybridConfig:
         return self.linear_n_heads * (2 * self.linear_key_dim
                                       + self.linear_value_dim)
 
+    @property
+    def ssm_conv_width(self) -> int:
+        """Columns of a "mamba2" layer's convolved xs | B | C."""
+        return (self.ssm_n_heads * self.ssm_head_dim
+                + 2 * self.ssm_groups * self.ssm_state)
+
+    def ff_kind(self, i: int) -> str:
+        """Layer i's feed-forward: "dense", "experts" or "none"."""
+        if self.ff_types is not None:
+            return self.ff_types[i]
+        return DENSE if self.n_dense_layers is None \
+            or i < self.n_dense_layers else EXPERTS
+
     def dense_ff(self, i: int) -> bool:
         """Whether layer i's feed-forward is the dense SwiGLU."""
-        return self.n_dense_layers is None or i < self.n_dense_layers
+        return self.ff_kind(i) == DENSE
 
     @property
     def experts_held(self) -> int:
@@ -253,6 +323,64 @@ class HybridConfig:
             max_seq_len=256), **kw})
 
     @staticmethod
+    def nemotron_blocks(pattern: str) -> Tuple[tuple, tuple]:
+        """(layer_types, ff_types) of the blocks that a published
+        `hybrid_override_pattern` pairs into (module docstring): `M` a
+        Mamba-2 layer, `*` attention, `E` experts, `-` a dense
+        feed-forward; a feed-forward rides with the mixer before it."""
+        mixers, ffs = [], []
+        for at, c in enumerate(pattern):
+            if c in "M*":
+                mixers.append(MAMBA2 if c == "M" else FULL)
+                ffs.append(NO_FF)
+            elif c in "E-" and ffs and ffs[-1] == NO_FF:
+                ffs[-1] = EXPERTS if c == "E" else DENSE
+            else:
+                raise ValueError(
+                    f"{pattern!r}: {c!r} at {at} is no mixer and follows "
+                    "none: a block is a mixer and at most one "
+                    "feed-forward")
+        return tuple(mixers), tuple(ffs)
+
+    @staticmethod
+    def nemotron_3_super_120b(pattern: str = NEMOTRON_3_SUPER_PATTERN,
+                              **kw) -> "HybridConfig":
+        """Nemotron-3-Super-120B-A12B as published (config.json,
+        model_type nemotron_h): 88 one-sub-layer layers by
+        `hybrid_override_pattern` (40 Mamba-2, 8 attention, 40 expert
+        layers), paired into 48 blocks; 32 query heads over 2 KV heads of
+        128 without rotation; 512 relu^2 experts of 2 688 in a latent of
+        1 024, 22 a token, scaling 5, beside a shared expert of 5 376 at
+        full width. A shorter `pattern` keeps those layers (`n_layers`
+        is its blocks). The multi-token-prediction module is not built
+        (docs/SERVING.md)."""
+        mixers, ffs = HybridConfig.nemotron_blocks(pattern)
+        return HybridConfig(**{**dict(
+            vocab_size=131072, d_model=4096, n_layers=len(mixers),
+            layer_types=mixers, ff_types=ffs, n_heads=32, n_kv_heads=2,
+            attn_head_dim=128, qk_norm=False, rope_theta=None,
+            ssm_n_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+            ssm_conv_kernel=4, ssm_chunk=128, d_expert=2688, n_experts=512,
+            experts_per_token=22, n_shared_experts=1, d_shared=5376,
+            moe_latent_dim=1024, expert_gated=False, norm_topk_prob=True,
+            route_norm_eps=1e-20, routed_scaling=5.0, max_seq_len=262144,
+            norm_eps=1e-5, pre_norm=True,
+            # as Solar-Open2's: the flash kernel in every prefill bucket
+            # on a TPU (ROADMAP A1 (c))
+            attn_impl=("pallas" if jax.default_backend() == "tpu"
+                       else "auto")), **kw})
+
+    @staticmethod
+    def nemotron_debug(**kw) -> "HybridConfig":
+        return HybridConfig.nemotron_3_super_120b(
+            kw.pop("pattern", "MEMEMEM*EME"), **{**dict(
+                vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2,
+                attn_head_dim=32, ssm_n_heads=8, ssm_head_dim=16,
+                ssm_state=16, ssm_groups=2, ssm_chunk=16, d_expert=32,
+                d_shared=48, moe_latent_dim=32, n_experts=8,
+                experts_per_token=3, max_seq_len=256), **kw})
+
+    @staticmethod
     def debug(**kw) -> "HybridConfig":
         return HybridConfig(**{**dict(
             vocab_size=256, d_model=64, n_layers=4, n_heads=4,
@@ -276,6 +404,11 @@ def _uniform(bound: float):
 def _a_log_init(key, shape, dtype=jnp.float32):
     """log A, A uniform in (0, 16): the published kernels' draw."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
+def _ssm_a_log_init(key, shape, dtype=jnp.float32):
+    """log A, A uniform in (1, 16): Mamba-2's draw."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -353,19 +486,22 @@ def _scan(q, k, v, g, beta, state, n_new, chunk: int):
     return gdn.chunk_scan(q, k, v, g, beta, state, chunk=chunk)
 
 
-def _step(q, k, v, g, beta, state):
+def _step(q, k, v, g, beta, state, state_space: bool = False):
     """The one-token form: the fused kernel on the TPU (or under
-    RAY_TPU_PAGED_ATTN_IMPL=pallas, interpreted on the CPU), three
-    passes in plain XLA elsewhere (and under =gather). A rate a key
-    channel (g with d_k behind the heads) has a kernel of its own."""
+    RAY_TPU_PAGED_ATTN_IMPL=pallas, interpreted on the CPU), plain XLA
+    elsewhere (and under =gather). ONE kernel under three names: a rate
+    a key channel (g with d_k behind the heads), a rate a head, and
+    `state_space`, the recurrence of ops/ssm.py (no correction, q and k
+    a group's)."""
     impl = knobs.get_str("RAY_TPU_PAGED_ATTN_IMPL")
     if impl != "gather" and (impl == "pallas"
                              or jax.default_backend() == "tpu"):
         from ..ops.pallas.gdn_decode import (  # noqa: PLC0415
-            gdn_decode_step, kda_decode_step)
-        kernel = kda_decode_step if g.ndim == 3 else gdn_decode_step
+            gdn_decode_step, kda_decode_step, ssm_decode_step)
+        kernel = ssm_decode_step if state_space else \
+            kda_decode_step if g.ndim == 3 else gdn_decode_step
         return kernel(q, k, v, g, beta, state)
-    return gdn.step(q, k, v, g, beta, state)
+    return (ssm.step if state_space else gdn.step)(q, k, v, g, beta, state)
 
 
 class GatedDeltaNet(nn.Module):
@@ -446,10 +582,75 @@ class ShortConv(nn.Module):
         return y, (None if cache is None else cache.write(tail))
 
 
+class Mamba2(nn.Module):
+    """The "mamba2" mixer (module docstring). Its cache entry is the
+    delta-rule layers': the float32 state (N, H x P) and the
+    convolution's last K - 1 inputs a slot."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, x, cache: Optional[SlotState] = None):
+        cfg = self.cfg
+        h, p, n, grp = (cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                        cfg.ssm_groups)
+        inner, width = h * p, cfg.ssm_conv_width
+        b, s, _ = x.shape
+        with jax.named_scope("ssm.proj"):
+            z, u, dt_raw = jnp.split(
+                _proj(cfg, inner + width + h, "in_proj")(x),
+                [inner, inner + width], axis=-1)
+            g, beta = ssm.gates(
+                dt_raw, self.param("A_log", _ssm_a_log_init, (h,)),
+                self.param("dt_bias", _dt_bias_init, (h,)))
+        conv_w = self.param(
+            "conv_kernel", _uniform(cfg.ssm_conv_kernel ** -0.5),
+            (cfg.ssm_conv_kernel, width), cfg.param_dtype)
+        conv_b = self.param("conv_bias",
+                            _uniform(cfg.ssm_conv_kernel ** -0.5), (width,))
+        state = tail = n_new = None
+        if cache is not None:
+            state, tail = cache.read()
+            n_new = cache.n_new
+            g, beta = gdn.freeze(
+                g, beta, jnp.arange(s)[None, :] < n_new[:, None])
+        # a decode step is the one-token form on (B, .) arrays
+        # (ops/gated_deltanet.py:causal_conv)
+        one = cache is not None and s == 1
+        lead = (b,) if one else (b, s)
+        with jax.named_scope("ssm.conv"):
+            xbc, tail = gdn.causal_conv(u, conv_w, tail, n_new, bias=conv_b)
+            xs, bm, cm = jnp.split(xbc[:, 0] if one else xbc,
+                                   [inner, inner + grp * n], axis=-1)
+            xs = xs.reshape(*lead, h, p)
+            bm = bm.reshape(*lead, grp, n)
+            cm = cm.reshape(*lead, grp, n)
+        if one:
+            with jax.named_scope("ssm.step"):
+                y, state = _step(cm, bm, xs, g[:, 0], beta[:, 0], state,
+                                 state_space=True)
+                cm, bm, xs, y = (a[:, None] for a in (cm, bm, xs, y))
+        else:
+            with jax.named_scope("ssm.scan"):
+                y, state = ssm.chunk_scan(cm, bm, xs, g, beta, state,
+                                          chunk=cfg.ssm_chunk)
+        # as `_delta_rule`: the recurrence's inputs and output, for a
+        # caller that asks for the collection
+        self.sow("recurrence", "io", (cm, bm, xs, g, beta, y))
+        with jax.named_scope("ssm.norm"):
+            skip = self.param("D", nn.initializers.ones, (h,))
+            y = y + skip.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
+            y = ssm.gated_group_norm(
+                y.reshape(b, s, inner), z,
+                self.param("norm", nn.initializers.ones, (inner,)), grp,
+                cfg.norm_eps).astype(cfg.dtype)
+            out = _proj(cfg, cfg.d_model, "out_proj")(y)
+        return out, (None if cache is None else cache.write(state, tail))
+
+
 class HybridBlock(nn.Module):
     cfg: HybridConfig
     kind: str
-    dense: bool = True
+    ff: str = DENSE
 
     @nn.compact
     def __call__(self, x, cos=None, sin=None, cache=None, positions=None,
@@ -457,8 +658,8 @@ class HybridBlock(nn.Module):
         cfg = self.cfg
         mixer_w = self.param("attn_norm", nn.initializers.ones,
                              (cfg.d_model,))
-        mlp_w = self.param("mlp_norm", nn.initializers.ones,
-                           (cfg.d_model,))
+        mlp_w = None if self.ff == NO_FF else self.param(
+            "mlp_norm", nn.initializers.ones, (cfg.d_model,))
 
         def mixer(x):
             if self.kind == FULL:
@@ -469,21 +670,25 @@ class HybridBlock(nn.Module):
                 return ShortConv(cfg, name="conv")(x, cache)
             if self.kind == KDA:
                 return KimiDeltaAttention(cfg, name="kda")(x, cache)
+            if self.kind == MAMBA2:
+                return Mamba2(cfg, name="mamba2")(x, cache)
             return GatedDeltaNet(cfg, name="linear_attention")(x, cache)
 
         def ff(x):
-            if self.dense:
+            if self.ff == DENSE:
                 return LlamaMLP(cfg, name="mlp")(x)
             return ShareMoE(cfg, name="moe")(x, row_mask)
 
         if cfg.pre_norm:
             h, new_cache = mixer(rms_norm(x, mixer_w, cfg.norm_eps))
             x = x + h
-            x = x + ff(rms_norm(x, mlp_w, cfg.norm_eps))
+            if self.ff != NO_FF:
+                x = x + ff(rms_norm(x, mlp_w, cfg.norm_eps))
         else:
             h, new_cache = mixer(x)
             x = x + rms_norm(h, mixer_w, cfg.norm_eps)
-            x = x + rms_norm(ff(x), mlp_w, cfg.norm_eps)
+            if self.ff != NO_FF:
+                x = x + rms_norm(ff(x), mlp_w, cfg.norm_eps)
         return x, new_cache
 
 
@@ -508,7 +713,8 @@ class Hybrid(nn.Module):
     @property
     def step_stats(self):
         cfg = self.cfg
-        return () if cfg.dense_ff(cfg.n_layers - 1) else MOE_STATS
+        return MOE_STATS if EXPERTS in map(cfg.ff_kind,
+                                           range(cfg.n_layers)) else ()
 
     @nn.compact
     def __call__(self, tokens, cache=None, positions=None, row_mask=None,
@@ -524,7 +730,7 @@ class Hybrid(nn.Module):
                                         cfg.rope_theta)
         new_cache = []
         for i, kind in enumerate(cfg.layer_types):
-            x, c = HybridBlock(cfg, kind, cfg.dense_ff(i),
+            x, c = HybridBlock(cfg, kind, cfg.ff_kind(i),
                                name=f"layer_{i}")(
                 x, cos, sin, None if cache is None else cache[i],
                 positions, row_mask)
@@ -546,7 +752,10 @@ class Hybrid(nn.Module):
         """(delta-rule layers, tokens a chunk of their chunkwise form):
         what the serving engine counts prefill's chunks from
         (`prefill_chunks_window`, `prefill_chunks_live`)."""
-        return (sum(kind in (LINEAR, KDA) for kind in self.cfg.layer_types),
+        kinds = self.cfg.layer_types
+        if MAMBA2 in kinds:
+            return kinds.count(MAMBA2), self.cfg.ssm_chunk
+        return (sum(kind in (LINEAR, KDA) for kind in kinds),
                 self.cfg.linear_chunk)
 
     def paged_cache_spec(self):
@@ -555,7 +764,8 @@ class Hybrid(nn.Module):
         state (d_k, H x d_v) and the convolution's last K - 1 inputs,
         one row of (K - 1) x C; a conv layer its last K - 1 inputs,
         likewise (ops/attention.py:kv_cache_spec). A "kda" layer keeps
-        what a linear layer does."""
+        what a linear layer does, and a "mamba2" layer the same two at
+        its own widths: the state (N, H x P)."""
         cfg = self.cfg
         kv = packed_kv_shape(cfg.kv_pool_heads, cfg.head_dim)
         linear = LayerCache(
@@ -567,6 +777,11 @@ class Hybrid(nn.Module):
         by_kind = {
             FULL: LayerCache(PagedKV, (kv, kv), (cfg.dtype, cfg.dtype)),
             LINEAR: linear, KDA: linear,
+            MAMBA2: LayerCache(
+                SlotState,
+                ((cfg.ssm_state, cfg.ssm_n_heads * cfg.ssm_head_dim),
+                 ((cfg.ssm_conv_kernel - 1) * cfg.ssm_conv_width,)),
+                (jnp.float32, cfg.dtype), by_slot=True),
             CONV: LayerCache(
                 SlotState, (((cfg.conv_kernel - 1) * cfg.d_model,),),
                 (cfg.dtype,), by_slot=True)}

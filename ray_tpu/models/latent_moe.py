@@ -58,6 +58,7 @@ from ..ops import (apply_rotary, rms_norm, swiglu, yarn_frequencies,
 from ..ops import hyper_connections as hc
 from ..ops.attention import (LayerCache, PagedLatent,
                              latent_cached_attention, uneven_head_attention)
+from ..ops.activations import relu2
 from ..ops.moe import MOE_STATS, moe_dropless, route
 from .llama import _LMHead, head_logits
 
@@ -292,11 +293,31 @@ class _SwiGLU(nn.Module):
         return _dense(cfg, cfg.d_model, "down_proj")(swiglu(gate, up))
 
 
+class _Relu2MLP(nn.Module):
+    """A feed-forward without a gate: down(relu(up x)^2)."""
+    cfg: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        return _dense(cfg, cfg.d_model, "down_proj")(
+            relu2(_dense(cfg, self.width, "up_proj")(x)))
+
+
 class ShareMoE(nn.Module):
     """Sigmoid-routed SwiGLU experts, of which this layer holds a share,
     beside a shared expert every token passes through (none where
     `n_shared_experts` is 0). `cfg` is any config with the fields read
-    here (models/hybrid.py's has them too). `stats_tail`: zeros behind
+    here (models/hybrid.py's has them too). Three further arms where
+    `cfg` has the field (models/hybrid.py's `nemotron_h`):
+    `moe_latent_dim`, the routed experts computed in a latent, l =
+    W_dn x BEFORE the dispatch (the gather moves latent-wide rows) and
+    W_up behind the combine (which is linear: a share's partial sum is
+    projected on its own), router and shared expert at full width;
+    `expert_gated` False, every expert (the shared one too) two matmuls
+    and relu^2; `d_shared`, the shared expert's own width.
+    `stats_tail`: zeros behind
     the layer's `step_stats` vector, for a model whose vector carries
     further counters behind ops/moe.py's (the engine sums whole
     vectors)."""
@@ -308,6 +329,9 @@ class ShareMoE(nn.Module):
         cfg = self.cfg
         b, s, d = x.shape
         held, f = cfg.experts_held, cfg.d_expert
+        latent = getattr(cfg, "moe_latent_dim", None)
+        gated = getattr(cfg, "expert_gated", True)
+        d_in = latent or d
         router_w = self.param("router_kernel", nn.initializers.normal(0.02),
                               (d, cfg.n_experts))
         # drawn non-zero (a tenth of the spread of the scores that normed
@@ -316,11 +340,11 @@ class ShareMoE(nn.Module):
         router_b = self.param("router_bias", nn.initializers.normal(0.025),
                               (cfg.n_experts,))
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate_kernel", init, (held, d, f),
-                            cfg.param_dtype)
-        w_up = self.param("experts_up_kernel", init, (held, d, f),
+        w_gate = self.param("experts_gate_kernel", init, (held, d_in, f),
+                            cfg.param_dtype) if gated else None
+        w_up = self.param("experts_up_kernel", init, (held, d_in, f),
                           cfg.param_dtype)
-        w_down = self.param("experts_down_kernel", init, (held, f, d),
+        w_down = self.param("experts_down_kernel", init, (held, f, d_in),
                             cfg.param_dtype)
         tokens = x.reshape(b * s, d).astype(cfg.dtype)
         with jax.named_scope("moe.route"):
@@ -332,10 +356,17 @@ class ShareMoE(nn.Module):
                 logits, cfg.experts_per_token, "sigmoid_bias",
                 cfg.norm_topk_prob, select_bias=router_b,
                 scale=cfg.routed_scaling, norm_eps=cfg.route_norm_eps)
+        routed_in = tokens
+        if latent:
+            with jax.named_scope("moe.latent_in"):
+                routed_in = _dense(cfg, latent, "latent_down_proj")(tokens)
         out, stats = moe_dropless(
-            tokens, weights, top_idx, w_gate, w_up, w_down,
+            routed_in, weights, top_idx, w_gate, w_up, w_down,
             None if row_mask is None else row_mask.reshape(b * s),
             first=cfg.expert_first, count=held)
+        if latent:
+            with jax.named_scope("moe.latent_out"):
+                out = _dense(cfg, d, "latent_up_proj")(out)
         if self.stats_tail:
             stats = jnp.pad(stats, (0, self.stats_tail))
         self.sow("step_stats", "moe", stats)
@@ -344,8 +375,10 @@ class ShareMoE(nn.Module):
                  top_idx.reshape(b, s, cfg.experts_per_token))
         if cfg.n_shared_experts:
             with jax.named_scope("moe.shared"):
-                out = out + _SwiGLU(cfg, cfg.n_shared_experts * f,
-                                    name="shared")(tokens)
+                width = getattr(cfg, "d_shared", None) \
+                    or cfg.n_shared_experts * f
+                out = out + (_SwiGLU if gated else _Relu2MLP)(
+                    cfg, width, name="shared")(tokens)
         return out.reshape(b, s, d).astype(cfg.dtype)
 
 

@@ -11,3 +11,8 @@ def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
 
 def geglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.gelu(gate) * up
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x)^2: the activation of a feed-forward without a gate."""
+    return jnp.square(jax.nn.relu(x))

@@ -103,9 +103,11 @@ def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
                 n_new: Optional[jax.Array] = None,
-                activation=jax.nn.silu
+                activation=jax.nn.silu,
+                bias: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal convolution, then `activation` (None: nothing;
+    """Depthwise causal convolution, plus `bias` (C,) where there is one
+    (the state-space mixer's), then `activation` (None: nothing;
     models/hybrid.py's short-convolution layer). u (B, S, C) new inputs,
     w (K, C) with w[0] on the current token, tail (B, (K - 1) * C) the
     K - 1 inputs before them side by side, the oldest first (None:
@@ -172,6 +174,8 @@ def causal_conv(u: jax.Array, w: jax.Array, tail: Optional[jax.Array],
                 tap = jnp.where((at == old)[:, None], taps[old], tap)
             new.append(tap)
         new_tail = jnp.concatenate(new, axis=-1)
+    if bias is not None:
+        out = out + bias.astype(F32)
     if activation is not None:
         out = activation(out)
     return out.astype(u.dtype), new_tail

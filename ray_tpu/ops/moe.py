@@ -37,7 +37,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .activations import swiglu
+from .activations import relu2, swiglu
 
 ROUTINGS = ("topk_softmax", "softmax_topk", "sigmoid_bias")
 
@@ -170,13 +170,15 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
 
 
 def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,
-                 w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                 w_gate: Optional[jax.Array], w_up: jax.Array,
+                 w_down: jax.Array,
                  row_mask: Optional[jax.Array] = None,
                  first: int = 0, count: Optional[int] = None):
     """x: (G, d) tokens; weights, top_idx: (G, k) from `route`; w_gate,
-    w_up: (E, d, f); w_down: (E, f, d). `row_mask` (G,) marks the real
-    rows: the others (bucket padding, empty slots) are given to no
-    expert, cost nothing in the grouped matmuls and come back as zeros.
+    w_up: (E, d, f); w_down: (E, f, d). `w_gate` None: experts without a
+    gate, two matmuls each, down(relu(up x)^2). `row_mask` (G,) marks
+    the real rows: the others (bucket padding, empty slots) are given to
+    no expert, cost nothing in the grouped matmuls and come back as zeros.
 
     `first`, `count` (static): the layer holds experts first ..
     first + count - 1 of those `top_idx` names, and E = count. An
@@ -188,7 +190,7 @@ def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,
     MOE_STATS names, counted over the real rows.
     """
     g, k = top_idx.shape
-    e = w_gate.shape[0]
+    e = w_up.shape[0]
     share = count is not None
     if share and count != e:
         raise ValueError(f"count={count} but the weights hold {e} experts")
@@ -212,10 +214,11 @@ def moe_dropless(x: jax.Array, weights: jax.Array, top_idx: jax.Array,
         rows = jnp.pad(order // k, (0, -(g * k) % _ROW_TILE))
         xs = x[rows]                                       # (M, d)
     with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(xs, w_gate.astype(x.dtype), group_sizes)
+        gate = None if w_gate is None else grouped_matmul(
+            xs, w_gate.astype(x.dtype), group_sizes)
         up = grouped_matmul(xs, w_up.astype(x.dtype), group_sizes)
-        ys = grouped_matmul(swiglu(gate, up), w_down.astype(x.dtype),
-                            group_sizes)
+        ys = grouped_matmul(relu2(up) if gate is None else swiglu(gate, up),
+                            w_down.astype(x.dtype), group_sizes)
     with jax.named_scope("moe.combine"):
         # back to (row, choice) order, then the weighted sum over the
         # row's k experts in float32; rows behind the last group hold

@@ -39,6 +39,15 @@ takes 0.4376 ms, what the kernel with the decay as a row took (0.4376:
 tools/kda_microbench.py on the chip, PERF.md, PR 52), and gives the same
 bits. One slot's state is 128 x 8 192 float32 = 4 MiB at Solar-Open2's
 widths, in and out double-buffered 16 MiB of VMEM_LIMIT_BYTES.
+
+A third recurrence is one static arm of the same kernel
+(`ssm_decode_step`; ops/ssm.py): Mamba-2's state-space step is the delta
+rule without its correction, u = b * v, so the `sum_k Sd * kx` pass is
+skipped; its decay is a scalar a head and rides as a third row beside v
+and beta, and its k and q are a GROUP's columns (G of them for H heads:
+`share` = H / G heads read one), so the `at` input is gone and the
+expansion of two heads of one group is one broadcast. Its state is
+128 x 8 192 a slot too.
 """
 from __future__ import annotations
 
@@ -62,19 +71,31 @@ def heads_per_group(n_heads: int, d_v: int) -> int:
     return n if n_heads % n == 0 else n_heads
 
 
-def _kernel(qt_ref, kt_ref, at_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
-            n_heads: int, d_v: int, group: int):
+def _kernel(*refs, n_heads: int, d_v: int, group: int, correct: bool = True,
+            share: int = 1):
+    """`correct` False is the state-space arm (module docstring): no
+    `at` input, the decay a third row of `rows`, and column h // share
+    of q and k for head h."""
+    if correct:
+        qt_ref, kt_ref, at_ref, rows_ref, s_ref, o_ref, s_out_ref = refs
+    else:
+        qt_ref, kt_ref, rows_ref, s_ref, o_ref, s_out_ref = refs
     d_k = s_ref.shape[1]
     width = group * d_v
     lane = jax.lax.broadcasted_iota(jnp.int32, (d_k, width), 1)
-    qt, kt, at = qt_ref[0], kt_ref[0], at_ref[0]          # (d_k, H)
+    qt, kt = qt_ref[0], kt_ref[0]                         # (d_k, H)
+    at = at_ref[0] if correct else None
 
     def expand(cols, h0):
         """(d_k, width): head h0 + j's column over its d_v lanes."""
-        out = jnp.broadcast_to(cols[:, h0:h0 + 1], (d_k, width))
+        at_col = lambda j: (h0 + j) // share              # noqa: E731
+        out = jnp.broadcast_to(cols[:, at_col(0):at_col(0) + 1],
+                               (d_k, width))
         for j in range(1, group):
+            if at_col(j) == at_col(j - 1):
+                continue
             out = jnp.where(lane >= j * d_v, jnp.broadcast_to(
-                cols[:, h0 + j:h0 + j + 1], (d_k, width)), out)
+                cols[:, at_col(j):at_col(j) + 1], (d_k, width)), out)
         return out
 
     for gi in range(n_heads // group):
@@ -82,51 +103,65 @@ def _kernel(qt_ref, kt_ref, at_ref, rows_ref, s_ref, o_ref, s_out_ref, *,
         v = rows_ref[0, 0:1, cols]
         b = rows_ref[0, 1:2, cols]
         kx = expand(kt, gi * group)
-        sd = s_ref[0, :, cols] * expand(at, gi * group)
-        u = b * (v - jnp.sum(sd * kx, axis=0, keepdims=True))
+        if correct:
+            sd = s_ref[0, :, cols] * expand(at, gi * group)
+            u = b * (v - jnp.sum(sd * kx, axis=0, keepdims=True))
+        else:
+            sd = s_ref[0, :, cols] * rows_ref[0, 2:3, cols]
+            u = b * v
         new = sd + kx * u
         s_out_ref[0, :, cols] = new
         o_ref[0, :, cols] = jnp.sum(new * expand(qt, gi * group), axis=0,
                                     keepdims=True)
 
 
-def _decode_step(q, k, v, g, beta, state, name: str, interpret):
-    b, h, d_k = q.shape
-    d_v = v.shape[-1]
+def _decode_step(q, k, v, g, beta, state, name: str, interpret,
+                 correct: bool = True):
+    """`correct` (static) False: the state-space arm. q and k are then
+    (B, G, d_k), a column for each run of H / G heads, and g (B, H)."""
+    b, h, d_v = v.shape
+    cols_n, d_k = q.shape[1:]
     hv = h * d_v
     assert state.shape == (b, d_k, hv) and state.dtype == jnp.float32, \
         (state.shape, state.dtype)
-    assert g.shape == (b, h, d_k), g.shape
+    assert g.shape == ((b, h, d_k) if correct else (b, h)), g.shape
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     f32 = jnp.float32
     # a frozen row's k is zeroed too: 0 x a garbage k stays 0
-    k = jnp.where((beta != 0)[..., None], k.astype(f32), 0.0)
-    rows = jnp.stack([
-        v.astype(f32).reshape(b, hv),
-        jnp.repeat(beta.astype(f32), d_v, axis=-1)], axis=1)   # (B, 2, HV)
+    live = (beta != 0)[..., None] if correct \
+        else (beta != 0).any(-1)[:, None, None]
+    k = jnp.where(live, k.astype(f32), 0.0)
+    rows = [v.astype(f32).reshape(b, hv),
+            jnp.repeat(beta.astype(f32), d_v, axis=-1)]
+    if not correct:
+        rows.append(jnp.repeat(jnp.exp(g.astype(f32)), d_v, axis=-1))
+    rows = jnp.stack(rows, axis=1)                        # (B, 2 or 3, HV)
     kernel = functools.partial(_kernel, n_heads=h, d_v=d_v,
-                               group=heads_per_group(h, d_v))
+                               group=heads_per_group(h, d_v),
+                               correct=correct, share=h // cols_n)
     row3 = lambda i: (i, 0, 0)                                # noqa: E731
-    cols = pl.BlockSpec((1, d_k, h), row3)
+    cols = pl.BlockSpec((1, d_k, cols_n), row3)
+    decay = [jnp.swapaxes(jnp.exp(g.astype(f32)), 1, 2)] if correct else []
+    n_in = 4 + len(decay)
     o, new_state = pl.pallas_call(
         kernel,
         grid=(b,),
-        in_specs=[cols, cols, cols,
-                  pl.BlockSpec((1, 2, hv), row3),
-                  pl.BlockSpec((1, d_k, hv), row3)],
+        in_specs=[cols] * (n_in - 2)
+        + [pl.BlockSpec((1, rows.shape[1], hv), row3),
+           pl.BlockSpec((1, d_k, hv), row3)],
         out_specs=[pl.BlockSpec((1, 1, hv), row3),
                    pl.BlockSpec((1, d_k, hv), row3)],
         out_shape=[jax.ShapeDtypeStruct((b, 1, hv), f32),
                    jax.ShapeDtypeStruct((b, d_k, hv), f32)],
-        input_output_aliases={4: 1},
+        input_output_aliases={n_in - 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=pltpu.InterpretParams() if interpret else False,
         name=name,
-    )(jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k, 1, 2),
-      jnp.swapaxes(jnp.exp(g.astype(f32)), 1, 2), rows, state)
+    )(jnp.swapaxes(q.astype(f32), 1, 2), jnp.swapaxes(k, 1, 2), *decay,
+      rows, state)
     return o.reshape(b, h, d_v), new_state
 
 
@@ -147,3 +182,14 @@ def kda_decode_step(q, k, v, g, beta, state, interpret=None):
     channel."""
     return _decode_step(q, k, v, g, beta, state, "kda_decode_step",
                         interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssm_decode_step(q, k, v, g, beta, state, interpret=None):
+    """The state-space arm (ops/ssm.py:step): q = C and k = B (B, G, N),
+    a group's for its H / G heads, v = xs (B, H, P), g = dt A and
+    beta = dt (B, H), state (B, N, H * P) float32, updated in place. A
+    row to leave alone comes with g = 0 and beta = 0. Returns (y
+    (B, H, P) float32 without the skip, new state)."""
+    return _decode_step(q, k, v, g, beta, state, "ssm_decode_step",
+                        interpret, correct=False)

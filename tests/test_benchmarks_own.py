@@ -49,8 +49,11 @@ def test_a_llama_shaped_file_with_another_head_dim_is_still_refused(  # noqa: F8
             cfg = json.load(f)
         # (nor is a hybrid whose full layers' heads are wider than
         # hidden_size / heads: its section is replica_solar's)
+        # (nor one whose layers are a pattern of state-space, attention
+        # and expert sub-layers: replica_nemotron's)
         return ("num_key_value_heads" in cfg and "kv_lora_rank" not in cfg
-                and "linear_attn_config" not in cfg)
+                and "linear_attn_config" not in cfg
+                and "hybrid_override_pattern" not in cfg)
     _refused(dict(manifest, configs=[c for c in manifest["configs"]
                                      if llama_shaped(c)]), tmp_path)
 
@@ -303,6 +306,17 @@ def test_a_metric_of_the_engine_threads_time_reads_its_rows(name):
 
 # PR 54's, at the end of `per_layer`: name -> (what `_lock_and_loop_
 # window` reads, unit, better, source, the layer's first words)
+# PR 56's cell and its seven metric files, behind everything (the cases
+# further down hold them; the older cases count them off the tail)
+_NEMOTRON_CELL = "nemotron120b_decode_sat"
+_NEW_IN_PR_56 = {"ssm_kernel_roofline": "ssm_kernel",
+                 "ssm_kernel_dev_share": None,
+                 "ssm_scan_dev_share": "ssm_scan",
+                 "expert_matmul_roofline.nemotron": "experts",
+                 "paged_kernel_roofline.nemotron": "paged_kernel",
+                 "decode_step_roofline.nemotron": "step",
+                 "moe_dev_share.nemotron": None}
+
 _NEW_IN_PR_54 = {
     "engine_lock_reacquire_us": (
         40.0, "us", "lower", "program_span", "engine host loop"),
@@ -379,16 +393,18 @@ def test_a_metric_of_the_lock_or_the_actor_loop_reads_its_rows(name):
     entry, = [m for m in whole["per_layer"] if m["name"] == name]
     serve = [w["name"] for w in whole["workloads"]
              if _runner_of(whole, w["name"]).startswith("serve_http")]
-    assert len(serve) == 8 and entry["workloads"] == serve
+    assert len(serve) == 9 and entry["workloads"] == serve
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     assert (entry["unit"], entry["better"], entry["source"],
             entry["moves"]) == (unit, better, source, "out_tok_s")
     assert entry["layer"].startswith(layer)
     accepted = {m["layer"] for m in whole["per_layer"]
-                if m["name"] not in _NEW_IN_PR_54}
+                if m["name"] not in _NEW_IN_PR_54
+                and m["name"] not in _NEW_IN_PR_56}
     assert entry["layer"] in accepted       # letter for letter
-    assert {m["name"] for m in whole["per_layer"][-len(_NEW_IN_PR_54):]} \
+    tail = whole["per_layer"][:-len(_NEW_IN_PR_56)]
+    assert {m["name"] for m in tail[-len(_NEW_IN_PR_54):]} \
         == set(_NEW_IN_PR_54)
 
 
@@ -439,8 +455,8 @@ def test_a_metric_file_new_in_pr_40_names_a_reader_and_arguments_that_exist(
     assert whole["workloads"][6]["name"] == _LFM2_CELL
     assert whole["configs"][5]["name"] == whole["workloads"][6]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_54) - len(_NEW_IN_PR_53) \
-        - len(_NEW_IN_PR_52) - len(_NEW_IN_PR_48) - 3
+    at = len(names) - len(_NEW_IN_PR_56) - len(_NEW_IN_PR_54) \
+        - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) - len(_NEW_IN_PR_48) - 3
     assert set(names[at:at + 3]) == set(_NEW_IN_PR_40)
 
 
@@ -459,7 +475,7 @@ def test_the_lfm2moe_cell_is_in_what_every_saturated_serve_cell_reports():
     by_name = {m["name"]: m for m in whole["per_layer"]}
     for name in ("moe_dev_share", "moe_expert_load_max_over_mean",
                  "moe_pad_row_share", "decode_live_state_share"):
-        assert _LFM2_CELL in by_name[name]["workloads"][-3:], name
+        assert _LFM2_CELL in by_name[name]["workloads"][-4:], name
     for name in ("paged_kernel_roofline", "decode_step_roofline",
                  "expert_matmul_roofline", "decode_step_roofline.moe",
                  "expert_matmul_roofline.share", "latent_kernel_roofline",
@@ -596,8 +612,8 @@ def test_a_metric_file_new_in_pr_48_reads_its_window_and_nothing_else(name):
     assert whole["workloads"][7]["name"] == _XING_CELL
     assert whole["configs"][6]["name"] == whole["workloads"][7]["config"]
     names = [m["name"] for m in whole["per_layer"]]
-    at = len(names) - len(_NEW_IN_PR_54) - len(_NEW_IN_PR_53) \
-        - len(_NEW_IN_PR_52) - 7
+    at = len(names) - len(_NEW_IN_PR_56) - len(_NEW_IN_PR_54) \
+        - len(_NEW_IN_PR_53) - len(_NEW_IN_PR_52) - 7
     assert set(names[at:at + 7]) == set(_NEW_IN_PR_48)
 
 
@@ -618,7 +634,7 @@ def test_an_accepted_expert_metric_reads_the_xing_window(name, reads):
 
 
 def test_the_xing_cell_is_in_what_every_saturated_serve_cell_reports():
-    """The eighth cell of (since PR 52) nine, one of them on four chips.
+    """The eighth cell of (since PR 56) ten, one of them on four chips.
     Every list that names the sarvam cell and is not read by that
     model's own cost arithmetic names this one too, behind every cell
     accepted before it (a later PR's cell goes behind this one in
@@ -626,14 +642,15 @@ def test_the_xing_cell_is_in_what_every_saturated_serve_cell_reports():
     arithmetic or state does."""
     from benchmarks import run as runmod
     whole = runmod.load_manifest()
-    assert len(whole["workloads"]) == 9 and len(whole["configs"]) == 8
+    assert len(whole["workloads"]) == 10 and len(whole["configs"]) == 9
     assert [w["name"] for w in whole["workloads"] if w["chips"] == 4] \
         == ["mistral7b_train_fsdp2_tp2"]
     own = {"latent_kernel_roofline", "expert_matmul_roofline.share",
            "decode_step_roofline.latent_moe"}
 
     def last_of_its_day(w):
-        return [c for c in w if c != _SOLAR_CELL][-1]
+        return [c for c in w
+                if c not in (_SOLAR_CELL, _NEMOTRON_CELL)][-1]
     for m in whole["end_to_end"] + whole["per_layer"]:
         w = m.get("workloads", [])
         if "sarvam105b_decode_sat" in w and m["name"] not in own:
@@ -861,7 +878,8 @@ def test_an_accepted_metric_reads_the_solar_window(name, reads):
 
 
 def test_the_solar_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state_reports():  # noqa: E501
-    """Nine cells, one of them on four chips. Every list that names the
+    """The ninth cell of (since PR 56) ten, one of them on four chips.
+    Every list that names the
     LFM2-MoE cell (a hybrid with experts and slot state) and is not read
     by that model's own cost arithmetic names this one too, at its end,
     `moe_local_assignment_share` (a share) and `out_tok_s` as well; no
@@ -879,10 +897,12 @@ def test_the_solar_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state
                 or m["name"] in _NEW_IN_PR_52 \
                 or m["name"] in _NEW_IN_PR_53 \
                 or m["name"] == "moe_local_assignment_share":
-            assert w[-1] == _SOLAR_CELL, m["name"]
+            # (PR 56's cell goes behind it in turn)
+            assert [c for c in w if c != _NEMOTRON_CELL][-1] \
+                == _SOLAR_CELL, m["name"]
         else:
             assert _SOLAR_CELL not in w, m["name"]
-    cell, config = whole["workloads"][-1], whole["configs"][-1]
+    cell, config = whole["workloads"][8], whole["configs"][7]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (_SOLAR_CELL, "solar-open2-250b-serve-ep8-l4",
             "decode_sat_solar", 1)
@@ -918,11 +938,14 @@ def test_prefill_live_chunk_share_reads_the_engines_counters():
     from benchmarks import run as runmod
     whole = runmod.load_manifest()
     # PR 54's go behind it
-    entry = whole["per_layer"][-1 - len(_NEW_IN_PR_54)]
+    entry = whole["per_layer"][-1 - len(_NEW_IN_PR_54)
+                               - len(_NEW_IN_PR_56)]
+    # (PR 56's cell counts its state-space layers' chunks by the same
+    # counters: appended)
     assert entry == {"name": "prefill_live_chunk_share", "unit": "%",
                      "better": "higher", "source": "program_counter",
                      "layer": "kernels (ops/pallas)", "moves": "out_tok_s",
-                     "workloads": [_SOLAR_CELL]}
+                     "workloads": [_SOLAR_CELL, _NEMOTRON_CELL]}
     got = runmod.read_metric(_BENCH, entry["name"], _solar_window())
     assert got == pytest.approx(100 * 11 / 16)
     assert runmod.read_metric(_BENCH, entry["name"],
@@ -953,3 +976,319 @@ def test_the_solar_cell_rehearses_through_run_py(tmp_path):
     ref = line["checks"]["reference"]
     assert ref["ok"] and ref["not_followed"] == 0 and ref["layers"] == 4
     assert ref["prefill_bucket"] > ref["prompt_len"]
+
+
+# ---- PR 56: the Nemotron-3-Super cell ------------------------------------
+
+def _nemotron_window(counters=True, kernels=True):
+    """What a traced run of the cell hands a reader: two readings of
+    `get_stats()` 1 000 decode steps apart (180 rows decoding of 193,
+    five Mamba-2 layers' state rows, 14 live pages a row in the one
+    attention layer, 7.7 rows an expert held in 5 expert layers), a
+    reduced trace of 160 decode runs of 22.5 ms, the model section, the
+    peaks."""
+    import json
+    from benchmarks.harness import replica_nemotron
+    from benchmarks.harness.peaks import PEAKS
+    with open(os.path.join(
+            _BENCH, "configs",
+            "nemotron-3-super-120b-serve-ep8-l11.json")) as f:
+        cfg = json.load(f)
+
+    def reading(k):
+        out = {"decode_steps": k, "prefill_calls": k // 50,
+               "decode_pages_live": k * 180 * 14,
+               "decode_pages_window": k * 193 * 32,
+               "moe_assignments": k * 495 * 5, "moe_rows": k * 180 * 5,
+               "moe_experts_touched": k * 64 * 5,
+               "moe_expert_load_max": k * 16 * 5, "moe_pad_rows": k * 13 * 5,
+               "moe_routed_assignments": k * 180 * 22 * 5}
+        if counters:
+            out.update(decode_state_rows_live=k * 180 * 5,
+                       decode_state_rows_window=k * 193 * 5,
+                       # a 1 024-wide call of one row of 700 tokens in
+                       # five Mamba-2 layers, chunks of 128
+                       prefill_chunks_live=(k // 50) * 6 * 5,
+                       prefill_chunks_window=(k // 50) * 8 * 5)
+        return out
+    ops = {"gmm": 0.8, "paged_decode_attention": 0.12, "fusion": 1.0,
+           "sort": 0.02}
+    scan = {}
+    if kernels:
+        ops["ssm_decode_step"] = 1.6
+        scan = {"ssm_scan_s": 0.1}      # `replica_nemotron.scope_seconds`
+    return {"stats0": reading(1000), "stats1": reading(2000),
+            "trace": {"busy_s": 4.0, "window_s": 4.02, "ops": ops, **scan,
+                      "modules": {"jit__decode_paged_step":
+                                  {"count": 160, "seconds": 3.6}}},
+            "peaks": PEAKS["TPU v5e"], "config": cfg,
+            "model": replica_nemotron.model_section(cfg),
+            "trace_contexts": [900] * 180}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_IN_PR_56))
+def test_a_metric_file_new_in_pr_56_reads_its_window_and_nothing_else(name):
+    """As PR 52's case: each file names a reader with a `read` that
+    takes the file's arguments, the decode program the engine has and,
+    where it sums a kernel's time, a kernel the program calls by that
+    name; from a window of known counters and kernel times it reads a
+    share under 100 %; from a program without the counters, a trace
+    without the kernels, another family's model section or an empty run
+    it reads nothing and raises nothing (rule (beta))."""
+    import importlib
+    import inspect
+    import json
+    import re
+    from benchmarks import run as runmod
+    from ray_tpu.ops.pallas import gdn_decode, paged_attention
+    from ray_tpu.serve.llm.engine import LLMEngine
+    with open(os.path.join(_BENCH, "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    readers = os.path.join(_BENCH, "readers")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    reader = importlib.import_module(spec["reader"])
+    args, what = spec["args"], _NEW_IN_PR_56[name]
+    assert set(args) <= set(inspect.signature(reader.read).parameters)
+    assert args.get("what") == what
+    if "module_re" in args:
+        assert re.search(args["module_re"],
+                         LLMEngine._decode_paged_step.__name__)
+    kernel = args.get("name_re")
+    if name.startswith("ssm_kernel"):
+        assert re.search(kernel, gdn_decode.ssm_decode_step.__name__)
+        assert '"ssm_decode_step"' in inspect.getsource(
+            gdn_decode.ssm_decode_step.__wrapped__)
+        # and neither sibling's arm, which their own metrics read
+        for other in (gdn_decode.gdn_decode_step,
+                      gdn_decode.kda_decode_step):
+            assert not re.search(kernel, other.__name__)
+    elif what == "paged_kernel":
+        assert re.search(kernel,
+                         paged_attention.paged_decode_attention.__name__)
+    elif what == "experts" or name == "moe_dev_share.nemotron":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        assert re.search(kernel, gmm.__name__)
+    elif what == "ssm_scan":
+        # read by the scope's name, which the program lowers
+        from ray_tpu.models import hybrid
+        assert '"ssm.scan"' in inspect.getsource(hybrid.Mamba2)
+    got = runmod.read_metric(_BENCH, name, _nemotron_window())
+    assert isinstance(got, float) and 0.0 < got < 100.0, (name, got)
+    if name == "ssm_kernel_dev_share":
+        assert got == pytest.approx(40.0)
+    if name == "ssm_scan_dev_share":
+        assert got == pytest.approx(2.5)
+    if name == "moe_dev_share.nemotron":
+        assert got == pytest.approx(20.0)
+    if name == "ssm_kernel_roofline":
+        # 180 rows x 5 layers x 2 x 4 MiB over 819 GB/s, in 10 ms a run
+        assert got == pytest.approx(
+            100 * 180 * 5 * 2 * 4 * 2 ** 20 / 819e9 / 10e-3, rel=1e-3)
+    if name == "paged_kernel_roofline.nemotron":
+        # the PUBLISHED 1 024 B a token, not the 4 096 B the pool lays out
+        assert got == pytest.approx(
+            100 * 180 * 14 * 64 * 1024 / 819e9 / 0.75e-3, rel=1e-3)
+    lacking = _nemotron_window(counters=False, kernels=False)
+    if name.startswith("ssm_"):
+        assert runmod.read_metric(_BENCH, name, lacking) in (None, 0.0)
+        assert (runmod.read_metric(_BENCH, name, lacking) is None) \
+            == (what is not None)
+    other = dict(_nemotron_window(), model={"hidden_size": 4096,
+                                            "kda_rank": 128})
+    if what is not None:
+        assert runmod.read_metric(_BENCH, name, other) is None
+    assert runmod.read_metric(_BENCH, name, {}) is None
+    # and on another family's window (the parent's side of a traced run
+    # of an accepted cell): nothing
+    if what is not None:
+        assert runmod.read_metric(_BENCH, name, _solar_window()) is None
+    whole = runmod.load_manifest()
+    entry, = [m for m in whole["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == [_NEMOTRON_CELL]
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert (entry["unit"], entry["moves"], entry["source"]) \
+        == ("%", "out_tok_s", "device_trace")
+    assert whole["workloads"][9]["name"] == _NEMOTRON_CELL
+    assert whole["configs"][8]["name"] == whole["workloads"][9]["config"]
+    assert {m["name"] for m in whole["per_layer"][-7:]} \
+        == set(_NEW_IN_PR_56)
+    accepted = {m["layer"] for m in whole["per_layer"][:-7]}
+    assert entry["layer"] in accepted       # letter for letter
+
+
+def test_a_scope_is_read_from_the_programs_the_profile_keeps(tmp_path,
+                                                            monkeypatch):
+    """`ssm_scan_dev_share` reads the chunkwise scan by its named scope.
+    A trace's events do not carry it, but the profile keeps every
+    program's HloProto (`/host:metadata`), each instruction with its
+    `op_name`: `scope_ops` reads those out of a REAL profile's bytes,
+    and `scope_seconds` sums the events of a program's runs whose
+    instruction lies under the scope, the union of their intervals (a
+    loop and its body's operations count once), a mean over the
+    devices; None where the profile keeps no program."""
+    import glob
+    from types import SimpleNamespace as NS
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.harness import replica_nemotron, trace_reduce
+
+    def f(x, w):
+        with jax.named_scope("ssm.scan"):
+            def body(c, _):
+                return jnp.tanh(c @ w), None
+            x, _ = jax.lax.scan(body, x, None, length=4)
+        with jax.named_scope("ssm.norm"):
+            return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True))
+    g = jax.jit(f)
+    x, w = jnp.ones((16, 32)), jnp.full((32, 32), 0.01)
+    g(x, w).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    g(x, w).block_until_ready()
+    jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    with open(path, "rb") as fh:
+        raw = fh.read()
+    found = replica_nemotron.scope_ops(raw, "ssm.scan")
+    program, = [k for k in found if k.startswith("jit_f(")]
+    scan, norm = found[program], replica_nemotron.scope_ops(
+        raw, "ssm.norm")[program]
+    assert any(n.startswith("while") for n in scan) and scan and norm
+    assert not scan & norm
+    assert replica_nemotron.scope_ops(raw, "no.such")[program] == set()
+    assert replica_nemotron.scope_ops(b"", "ssm.scan") == {}
+    # the intervals: two runs of the program, a loop around its body's
+    # operations, another program's operation of the same name
+    loop = next(n for n in scan if n.startswith("while"))
+    inside = next(n for n in scan if not n.startswith("while"))
+
+    def ev(name, start_us, dur_us):
+        return NS(name=name, start_ns=start_us * 1000,
+                  duration_ns=dur_us * 1000)
+    ops = [ev(f"%{loop} = (f32[2]) while(%t)", 100, 2000),
+           ev(f"%{inside} = f32[2] fusion(%p)", 150, 500),
+           ev(f"%{next(iter(norm))} = f32[2] fusion(%p)", 2200, 300),
+           ev(f"%{loop} = (f32[2]) while(%t)", 5100, 1000),
+           ev(f"%{loop} = (s32[4]) while(%t)", 9000, 700)]
+    plane = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Modules", events=[ev(program, 0, 3000),
+                                       ev(program, 5000, 2000),
+                                       ev("jit_other(77)", 8000, 2000)]),
+        NS(name="XLA Ops", events=ops)])
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: path)
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        staticmethod(lambda p: NS(
+                            planes=[plane, NS(name="/host:CPU", lines=[])])))
+    assert replica_nemotron.scope_seconds("x", "ssm.scan") \
+        == pytest.approx(3.0e-3)
+    assert replica_nemotron.scope_seconds("x", "ssm.norm") \
+        == pytest.approx(0.3e-3)
+    assert replica_nemotron.scope_seconds("x", "no.such") == 0.0
+    plane.name = "/host:other"
+    assert replica_nemotron.scope_seconds("x", "ssm.scan") is None
+    empty = tmp_path / "empty.pb"
+    empty.write_bytes(b"")
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: str(empty))
+    assert replica_nemotron.scope_seconds("x", "ssm.scan") is None
+
+
+@pytest.mark.parametrize("name, reads", [
+    ("moe_expert_load_max_over_mean", 64 * 16 / 495),
+    ("moe_pad_row_share", 100 * 13 / 193),
+    ("moe_local_assignment_share", 12.5),
+    ("decode_live_state_share", 100 * 180 / 193),
+    ("decode_live_page_share", 100 * 180 * 14 / (193 * 32)),
+    ("prefill_live_chunk_share", 75.0),
+    ("attention_kernel_dev_share", 3.0)])
+def test_an_accepted_metric_reads_the_nemotron_window(name, reads):
+    """Rule (gamma): the accepted readers of the lists the cell was
+    appended to find their keys in this model's section (num_experts for
+    `moe_counter`) and in its counters. `moe_dev_share` is not among
+    them: it counts `while` by name, and in this cell the chunkwise
+    scan's loop over chunks is one (`moe_dev_share.nemotron` reads the
+    grouped matmuls by name)."""
+    from benchmarks import run as runmod
+    assert runmod.read_metric(_BENCH, name, _nemotron_window()) \
+        == pytest.approx(reads)
+
+
+def test_the_nemotron_cell_is_in_what_a_saturated_serve_cell_with_experts_and_state_reports():  # noqa: E501
+    """Ten cells, one of them on four chips. Every list that names the
+    Solar-Open2 cell and is not read by that model's own cost arithmetic
+    or kernels names this one too, at its end, `out_tok_s` as well; no
+    list of another model's does; and every per-layer metric the cell
+    is listed for reads the synthetic window (what a traced line is
+    held against)."""
+    from benchmarks import run as runmod
+    whole = runmod.load_manifest()
+    assert len(whole["workloads"]) == 10 and len(whole["configs"]) == 9
+    assert [w["name"] for w in whole["workloads"] if w["chips"] == 4] \
+        == ["mistral7b_train_fsdp2_tp2"]
+    for m in whole["end_to_end"] + whole["per_layer"]:
+        w = m.get("workloads", [])
+        if (_SOLAR_CELL in w and m["name"] not in _NEW_IN_PR_52) \
+                or m["name"] in _NEW_IN_PR_56:
+            assert w[-1] == _NEMOTRON_CELL, m["name"]
+        else:
+            assert _NEMOTRON_CELL not in w, m["name"]
+    cell, config = whole["workloads"][-1], whole["configs"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
+        == (_NEMOTRON_CELL, "nemotron-3-super-120b-serve-ep8-l11",
+            "decode_sat_nemotron", 1)
+    assert config["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                 "vocab_size"]
+    assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
+    assert "8x a deployment chip's rows" in cell["why"] \
+        and "8.25 rows an expert" in cell["why"] \
+        and "192 slots" in cell["why"]
+    run = _nemotron_window()
+    run.update(streams=[], t0=0.0, t1=1.0, late_ms=[1.0],
+               compiles_in_window=0, backlog_end=0)
+    silent = []
+    for m in runmod.cell_metrics(whole, _NEMOTRON_CELL, "per_layer"):
+        with open(os.path.join(_BENCH, "metrics",
+                               m["name"] + ".json")) as f:
+            reader = __import__("json").load(f)["reader"]
+        if reader in ("nemotron_roofline", "trace_share", "moe_counter",
+                      "moe_local_share"):
+            if runmod.read_metric(_BENCH, m["name"], run) is None:
+                silent.append(m["name"])
+    assert silent == []
+
+
+def test_the_nemotron_cell_rehearses_through_run_py(tmp_path):
+    """`run.py --rehearse` of the cell on the CPU at toy widths: the
+    family's runner, replica, reference and traffic files are found by
+    name, the engine serves the mix, the check against
+    `reference_nemotron` passes (the eleven published layers, the first
+    Mamba-2 layer's recurrence tapped), and the traced line holds every
+    metric of the cell that needs no device trace, without a number."""
+    import json
+    import subprocess
+    from benchmarks import run as runmod
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        [sys.executable, os.path.join(_BENCH, "run.py"), "--workload",
+         _NEMOTRON_CELL, "--rehearse", "--seed", "5600000011", "--seconds",
+         "3", "--trace", "1", "--out", str(tmp_path)],
+        cwd=_ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["rehearsal"] and not line["failed"]
+    assert line["attempted"] > 0
+    assert all(v["value"] is None for v in line["metrics"].values())
+    ref = line["checks"]["reference"]
+    assert ref["ok"] and ref["not_followed"] == 0 and ref["layers"] == 11
+    assert ref["prefill_bucket"] > ref["prompt_len"]
+    assert ref["recurrence_err_rel"] < ref["recurrence_tol_rel"]
+    # rule (gamma), as far as a CPU can hold it: what the line lacks of
+    # the cell's per-layer metrics are those a device trace alone gives
+    whole = runmod.load_manifest()
+    listed = {m["name"]: m["source"] for m in
+              runmod.cell_metrics(whole, _NEMOTRON_CELL, "per_layer")}
+    lacking = set(listed) - set(line["metrics"])
+    assert {listed[n] for n in lacking} <= {"device_trace"}, lacking
+    assert set(_NEW_IN_PR_56) <= lacking

@@ -132,7 +132,7 @@ def test_a_scalar_decay_is_the_channel_decay_with_equal_entries():
 
 
 # sha256 of the lowered text of the engine's prefill (2 x 16) and decode
-# (5 rows, window 2 pages) programs over the two sibling families' debug
+# (5 rows, window 2 pages) programs over the sibling families' debug
 # shapes. Pinned on the commit before this family (PR 50's) and held
 # through PR 52-54: with the scalar decay, `out_gate` False,
 # `attn_head_dim` None and no share nothing of them moved. **All six
@@ -159,6 +159,25 @@ SIBLING_PROGRAMS = {
         "db2f127bde46d05b2a15cbb211338f38f60c72dfe68342f2f5d27dab47cf37f4",
     ("solar-debug", "prefill"):
         "5abf049b655f93d89e344d6c3e140f883e8c7a3c7831ff37409dc595756c1d68",
+    # the three expert families OUTSIDE models/hybrid.py (Sarvam's,
+    # OLMoE's, Xing's debug shapes), pinned on PR 55's commit when PR 56
+    # made `ops/moe.py:moe_dropless`'s gate optional and gave
+    # `models/latent_moe.py:ShareMoE` three arms that are off by default
+    # (a latent pair, experts without a gate, a shared expert of its own
+    # width). Off the TPU `grouped_matmul` lowers to `ragged_dot` where
+    # the chip runs `gmm`; nothing else of the path reads the backend
+    ("latent-moe-debug", "decode"):
+        "e0da4a83a4a47f6942a88f27a4629c83f1d58a3bdc0f4c6bcae786a8032d98ff",
+    ("latent-moe-debug", "prefill"):
+        "de64cdbd11f2dbd5086ece487dd51183f0cbda623a82bb2e1af30d6b06bebe5b",
+    ("mixtral-debug", "decode"):
+        "a754379d612472be9d9ee51306f13027c944b60351fc3aac1c2c60f75480d699",
+    ("mixtral-debug", "prefill"):
+        "00ee98786191bb634a8a07af55794475366bc842eceb892aa5e3188a892b7adf",
+    ("xing-debug", "decode"):
+        "6d301c58ffd02288e10cab14f709cf459dd58651698244d64f21a05900138b53",
+    ("xing-debug", "prefill"):
+        "7d951a00fb9cb86e500ee3b20fa6be2c5cca58ae244f2a3340aa45acbfcc9bb9",
 }
 
 

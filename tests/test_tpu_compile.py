@@ -333,7 +333,7 @@ def small_tiled(text: str, scope: str, at_least=2 ** 20) -> list:
 
 
 @pytest.mark.parametrize("form", ["decode", "prefill"])
-@pytest.mark.parametrize("family", ["solar", "olmo_hybrid"])
+@pytest.mark.parametrize("family", ["solar", "olmo_hybrid", "nemotron"])
 def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
         chip, family, form, monkeypatch):
     """One delta-rule layer (and the full layer an engine needs for its
@@ -347,7 +347,9 @@ def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
     both ways and the whole projection batch-minor (PERF.md section 6,
     PR 55). The tail a row a slot: no relayout of either, nothing of
     the `conv` scope in tiles under eight rows, and the prefill's rows
-    go into the donated pool in place."""
+    go into the donated pool in place. Nemotron-3-Super's Mamba-2 layer
+    (PR 56) runs the same convolution, with a bias, over the xs | B | C
+    columns of ONE fused projection (10 240 of 18 560), at 193 rows."""
     from ray_tpu.models import Hybrid, HybridConfig
     from ray_tpu.serve.llm import LLMEngine, LLMEngineConfig
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -358,6 +360,10 @@ def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
             layer_types=("full_attention", "kda"), n_experts=8,
             experts_per_token=2, **sized)
         slots, group, scope = 192, 2, "kda.conv/"
+    elif family == "nemotron":
+        sized.pop("n_layers")
+        cfg = HybridConfig.nemotron_3_super_120b("M*", **sized)
+        slots, group, scope = 192, 2, "ssm.conv/"
     else:
         cfg = HybridConfig.olmo_hybrid_7b(
             layer_types=("linear_attention", "full_attention"), **sized)
@@ -391,10 +397,13 @@ def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
                 pad_len=pad).compile().as_text()
     finally:
         eng.shutdown()
-    tail = (cfg.linear_conv_kernel - 1) * cfg.conv_width
+    taps, width = ((cfg.ssm_conv_kernel, cfg.ssm_conv_width)
+                   if family == "nemotron"
+                   else (cfg.linear_conv_kernel, cfg.conv_width))
+    tail = (taps - 1) * width
     assert f"bf16[{s},{tail}]" in text                  # the pool, a row a slot
     rows = s if form == "decode" else group * pad
-    assert relayouts_in_hbm(text, {s * tail, rows * cfg.conv_width}) == []
+    assert relayouts_in_hbm(text, {s * tail, rows * width}) == []
     assert small_tiled(text, scope) == []
     if form == "decode":
         # `causal_conv`'s `held`: with the projection's matmul fused into
@@ -405,9 +414,11 @@ def test_delta_rule_layer_re_lays_out_neither_its_tail_nor_its_projection(
                          r"mantissa_bits=7.*op_name=\"[^\"]*"
                          + re.escape(scope), text)
     if form == "prefill":
-        # the group's rows go into the donated pool where it lies
-        assert re.search(rf"bf16\[{s},{tail}\]\S* dynamic-update-slice\(",
-                         text)
+        # the group's rows go into the donated pool where it lies: two
+        # updates of a row each, and at the state-space layer's width
+        # (that family alone) one scatter of both
+        update = "scatter" if family == "nemotron" else "dynamic-update-slice"
+        assert re.search(rf"bf16\[{s},{tail}\]\S* {update}\(", text)
 
 
 @pytest.mark.parametrize("family,rows", [
@@ -504,6 +515,30 @@ def test_kda_decode_step_compiles_for_v5e(chip):
     assert "kda_decode_step" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= rows * dk * h * dv * 4
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+
+
+def test_ssm_decode_step_compiles_for_v5e(chip):
+    """Nemotron-3-Super's decode step of the state-space recurrence at
+    the cell's shapes (192 slots and the scratch row, 128 heads of 64
+    over 8 groups of 128 state channels): the no-correction arm of the
+    delta rule's kernel under its own name, q and k blocks of 8 columns,
+    the decay a third row; one slot's 4 MiB a grid step, aliased."""
+    from ray_tpu.ops.pallas.gdn_decode import ssm_decode_step
+    rows, h, p, grp, n = 193, 128, 64, 8, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(q, k, v, g, beta, state):
+        return ssm_decode_step(q, k, v, g, beta, state, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        sds((rows, grp, n)), sds((rows, grp, n)), sds((rows, h, p)),
+        sds((rows, h)), sds((rows, h)), sds((rows, n, h * p))).compile()
+    _assert_mosaic(compiled)
+    assert "ssm_decode_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * n * h * p * 4
     assert mem.temp_size_in_bytes < 16 * 2 ** 20
 
 

@@ -36,7 +36,11 @@ import numpy as np
 # experts, d, f, experts a token, rows of a decode step and of a prefill
 # call (LFM2: 128 slots, one 2 048-token prompt; OLMoE: PR 26's 65)
 SHAPES = {"lfm2": (64, 2048, 1536, 4, (128, 2048)),
-          "olmoe": (64, 2048, 1024, 8, (65, 2048))}
+          "olmoe": (64, 2048, 1024, 8, (65, 2048)),
+          # Nemotron-3-Super (PR 56): 64 of a router's 512 held, experts
+          # of TWO matmuls and relu^2 in a latent of 1 024, 22 a token;
+          # all rows x 22 assignments are sorted, 7 of 8 to no group
+          "nemotron": (64, 1024, 2688, 22, (192, 2048), 512, False)}
 CUT = 1024          # every side's cut before PR 41: the yardstick
 
 
@@ -113,7 +117,7 @@ def main() -> None:
 
     from benchmarks.harness import peaks
     from ray_tpu.ops import moe
-    from ray_tpu.ops.activations import swiglu
+    from ray_tpu.ops.activations import relu2, swiglu
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -138,21 +142,25 @@ def main() -> None:
             xs, w, sizes, preferred_element_type=xs.dtype,
             tiling=(tm, tk, tn))
 
-    def layer(matmul_up, matmul_down):
+    def layer(matmul_up, matmul_down, gated=True):
         def run(xs, wg, wu, wd, sizes):
-            return matmul_down(swiglu(matmul_up(xs, wg, sizes),
-                                      matmul_up(xs, wu, sizes)), wd, sizes)
+            gate = matmul_up(xs, wg, sizes) if gated else None
+            up = matmul_up(xs, wu, sizes)
+            return matmul_down(swiglu(gate, up) if gated else relu2(up),
+                               wd, sizes)
         return run
 
     for shape in args.shapes.split(","):
-        e, d, f, top_k, own_rows = SHAPES[shape]
+        e, d, f, top_k, own_rows, *share = SHAPES[shape]
+        router, gated = share or (e, True)
         ks = jax.random.split(jax.random.PRNGKey(args.seed), 3)
         wg, wu = (jax.random.normal(k, (e, d, f), jnp.bfloat16) * d ** -0.5
                   for k in ks[:2])
         wd = jax.random.normal(ks[2], (e, f, d), jnp.bfloat16) * f ** -0.5
         for rows in ([int(r) for r in args.rows.split(",")] if args.rows
                      else own_rows):
-            sizes = draw_group_sizes(rng, rows, e, top_k, args.skew)
+            sizes = draw_group_sizes(rng, rows, router, top_k,
+                                     args.skew)[:e]
             m = -(-rows * top_k // moe._ROW_TILE) * moe._ROW_TILE
             touched = int((sizes > 0).sum())
             meta = {"shape": shape, "rows": rows, "sorted_rows": m,
@@ -184,17 +192,19 @@ def main() -> None:
                             tiled(rt, ck, cn), (lhs, w, sizes), **mm,
                             tiling=[rt, ck, cn], cut_1024=False, chosen=False)
             lm = {**meta, "matmul": "layer",
-                  "weight_bytes": touched * 3 * d * f * 2}
+                  "weight_bytes": touched * (3 if gated else 2) * d * f * 2}
             largs = (xs, wg, wu, wd, sizes)
             add(f"{tag}_layer_chosen",
-                layer(moe.grouped_matmul, moe.grouped_matmul), largs, **lm,
+                layer(moe.grouped_matmul, moe.grouped_matmul, gated), largs,
+                **lm,
                 tiling=[list(chosen["up"]), list(chosen["down"])])
             add(f"{tag}_layer_cut_1024",
                 layer(tiled(moe._ROW_TILE, min(d, CUT), min(f, CUT)),
-                      tiled(moe._ROW_TILE, min(f, CUT), min(d, CUT))),
+                      tiled(moe._ROW_TILE, min(f, CUT), min(d, CUT)), gated),
                 largs, **lm)
             add(f"{tag}_layer_ragged_dot",
-                layer(jax.lax.ragged_dot, jax.lax.ragged_dot), largs, **lm)
+                layer(jax.lax.ragged_dot, jax.lax.ragged_dot, gated), largs,
+                **lm)
 
     rows_out, compiled = {}, {}
     for name, (fn, fargs, meta) in cands.items():

@@ -11,7 +11,13 @@ a head's rate in every key channel at Olmo's shape; its names
 `kda_decode_step` and `gdn_decode_step`) against XLA's own fusion of the plain step
 (ops/gated_deltanet.py:step), the state donated and updated in place in
 both, every row live and with a third of the rows idle (written
-through). Every candidate is a jitted function of its own name, run
+through). `--shapes nemotron` (PR 56): the kernel's state-space arm
+(`ssm_decode_step`, no correction pass, B and C a group's) at
+Nemotron-3-Super's widths and 193 rows against XLA's fusion of
+ops/ssm.py:step; `--rows N` runs every shape at N rows, and
+`--parent-dir <checkout>` adds the parent's kernel file as a candidate
+of its own at the delta rule's shapes (`_parent_kernel`) and says whether
+the two kernels' results are equal to the bit. Every candidate is a jitted function of its own name, run
 `--reps` times under one profiler trace; its time is the device time of
 its program on the trace's `XLA Modules` line, not a host clock; the
 share is of the bytes `benchmarks/harness/costs_solar.py:kda_step`
@@ -62,7 +68,11 @@ import tempfile
 import time
 
 SHAPES = {"solar": dict(rows=129, h=64, dk=128, dv=128, channel=True),
-          "olmo": dict(rows=65, h=30, dk=96, dv=192, channel=False)}
+          "olmo": dict(rows=65, h=30, dk=96, dv=192, channel=False),
+          # Nemotron-3-Super's Mamba-2 layer: the state-space arm of the
+          # same kernel (PR 56), 128 heads of 64 over 8 groups of 128
+          # state channels, the cell's 193 rows
+          "nemotron": dict(rows=193, h=128, dk=128, dv=64, groups=8)}
 # the convolution: the cells' slot pools (scratch row and all), model
 # widths, kernel taps and 1 024-token prefill groups
 CONV_SHAPES = {"solar": dict(rows=193, d_model=4096, k=4, group=2),
@@ -138,6 +148,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--what", default="step,scan,conv")
     ap.add_argument("--shapes", default="solar,olmo")
+    ap.add_argument("--rows", type=int, default=None)
+    ap.add_argument("--parent-dir", default=None)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
@@ -150,8 +162,10 @@ def main() -> None:
     from benchmarks.harness.peaks import peaks_for
     from ray_tpu.ops import gated_deltanet as gdn
     from ray_tpu.ops.attention import SlotState
+    from ray_tpu.ops import ssm
     from ray_tpu.ops.pallas.gdn_decode import (gdn_decode_step,
-                                               kda_decode_step)
+                                               kda_decode_step,
+                                               ssm_decode_step)
     from ray_tpu.ops.pallas.kda_prefill import kda_chunk_scan
     from tools.gmm_microbench import device_times
 
@@ -186,14 +200,31 @@ def main() -> None:
         beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (*lead, rows, h)))
         return q, k, v, g, beta, ks[5]
 
+    parent = None
+    if args.parent_dir:
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "parent_gdn_decode", os.path.join(
+                args.parent_dir, "ray_tpu/ops/pallas/gdn_decode.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+
     what = args.what.split(",")
     for shape in args.shapes.split(",") if "step" in what else ():
         s = SHAPES[shape]
-        rows, h, dk, dv = s["rows"], s["h"], s["dk"], s["dv"]
+        rows, h, dk, dv = args.rows or s["rows"], s["h"], s["dk"], s["dv"]
         q, k, v, g, beta, key5 = draw(jax.random.PRNGKey(args.seed), rows,
-                                      h, dk, dv, s["channel"])
+                                      h, dk, dv, s.get("channel", False))
         state0 = jax.random.normal(key5, (rows, dk, h * dv))
-        kernel = kda_decode_step if s["channel"] else gdn_decode_step
+        if "groups" in s:       # the state-space arm: B, C a group's,
+            #                     beta = dt in (0, 0.1], g = dt A
+            q, k = (x[:, :s["groups"]] for x in (q, k))
+            beta = 0.05 * beta
+            g = -16.0 * jax.random.uniform(key5, (h,)) * beta
+            kernel, plain = ssm_decode_step, ssm.step
+        else:
+            kernel = kda_decode_step if s["channel"] else gdn_decode_step
+            plain = gdn.step
         for live_name, every in (("live", 1), ("third_idle", 3)):
             live = (jnp.arange(rows) % every != every - 1) | (every == 1)
             gl = jnp.where(live.reshape((rows,) + (1,) * (g.ndim - 1)), g, 0.)
@@ -203,8 +234,12 @@ def main() -> None:
             state_bytes = 2 * 4 * int(live.sum()) * dk * h * dv
             add(f"{key}_kernel", kernel, key, bytes=state_bytes, rows=rows,
                 live_rows=int(live.sum()))
-            add(f"{key}_xla", gdn.step, key, bytes=state_bytes, rows=rows,
+            add(f"{key}_xla", plain, key, bytes=state_bytes, rows=rows,
                 live_rows=int(live.sum()))
+            if parent is not None and "groups" not in s:
+                add(f"{key}_parent_kernel", getattr(parent, kernel.__name__),
+                    key, bytes=state_bytes, rows=rows,
+                    live_rows=int(live.sum()))
 
     if "scan" in what:
         s = SHAPES["solar"]
@@ -319,7 +354,7 @@ def main() -> None:
         wall[name] = 1e3 * (time.perf_counter() - t0) / args.reps
         del st
     jax.profiler.stop_trace()
-    times = device_times(trace_dir, r"^%?(kda|gdn)_(decode_step|chunk_scan)")
+    times = device_times(trace_dir, r"^%?(kda|gdn|ssm)_(decode_step|chunk_scan)")
     by_op = op_times(trace_dir) if "conv" in what else {}
     floor = chunk_floor(64, 128, 128)
     proj_ms = {name[:-len("proj")]: 1e3 * times[f"jit_{name}"][1] / args.reps
@@ -391,6 +426,14 @@ def main() -> None:
                 o1, o2 = o1 * real, o2 * real
             errs[name] = [float(jnp.max(jnp.abs(o1 - o2))),
                           float(jnp.max(jnp.abs(s1 - s2)))]
+    # this tree's step kernel and the parent's: result and state, to the bit
+    as_parent = {}
+    for name in compiled:
+        other = name[:-len("kernel")] + "parent_kernel"
+        if name.endswith("_kernel") and other in finals \
+                and not name.endswith("_parent_kernel"):
+            (o1, s1), (o2, s2) = finals[name], finals[other]
+            as_parent[name] = bool((o1 == o2).all() and (s1 == s2).all())
     # the two forms of the convolution: q, k, v and the pool, to the bit
     same = {}
     for name in compiled:
@@ -406,6 +449,7 @@ def main() -> None:
               "seed": args.seed, "chunk_floor_64_heads": floor,
               "max_abs_err_o_state_vs_plain_form": errs,
               "conv_rows_form_equals_token_axis_form": same,
+              "step_kernel_equals_parents_to_the_bit": as_parent,
               "rows": rows_out}
     for name, row in rows_out.items():
         print(name, json.dumps({k: row[k] for k in (
@@ -418,7 +462,8 @@ def main() -> None:
     print(json.dumps({k: result[k] for k in (
         "device", "chunk_floor_64_heads",
         "max_abs_err_o_state_vs_plain_form",
-        "conv_rows_form_equals_token_axis_form")}))
+        "conv_rows_form_equals_token_axis_form",
+        "step_kernel_equals_parents_to_the_bit")}))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
